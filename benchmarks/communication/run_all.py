@@ -39,22 +39,11 @@ def main():
     p.add_argument("--json", default=None)
     args = p.parse_args()
 
-    if os.environ.get("DSTPU_BENCH_CPU"):
-        # must land before jax initializes: older jax (<0.5) has no
-        # jax_num_cpu_devices option, only the XLA flag
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = flags + \
-                " --xla_force_host_platform_device_count=" + \
-                os.environ["DSTPU_BENCH_CPU"]
     import jax
     if os.environ.get("DSTPU_BENCH_CPU"):
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices",
-                              int(os.environ.get("DSTPU_BENCH_CPU")))
-        except AttributeError:
-            pass   # jax<0.5: XLA_FLAGS above already set the count
+        jax.config.update("jax_num_cpu_devices",
+                          int(os.environ.get("DSTPU_BENCH_CPU")))
     import jax.numpy as jnp
     from jax import lax
     from deepspeed_tpu import comm as dist

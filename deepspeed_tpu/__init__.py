@@ -5,13 +5,9 @@ Keeps the reference's user-facing factory surface
 ``add_config_arguments`` :192) on a JAX/XLA/Pallas/pjit core.
 """
 
-from deepspeed_tpu.utils import jax_compat as _jax_compat
-
-_jax_compat.install()   # jax.shard_map alias on jax<0.5 runtimes
-
-from deepspeed_tpu.version import __version__  # noqa: F401,E402
-from deepspeed_tpu import comm  # noqa: F401,E402
-from deepspeed_tpu.utils.logging import log_dist, logger  # noqa: F401,E402
+from deepspeed_tpu.version import __version__  # noqa: F401
+from deepspeed_tpu import comm  # noqa: F401
+from deepspeed_tpu.utils.logging import log_dist, logger  # noqa: F401
 
 
 def initialize(args=None, model=None, optimizer=None, model_parameters=None,

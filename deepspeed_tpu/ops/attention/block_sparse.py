@@ -40,11 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.attention.flash import (NEG_INF, _bwd_p_ds,
                                                _causal_block_mask,
@@ -432,11 +428,6 @@ def sparse_flash_attention(q, k, v, sparsity_config, *, causal=True,
     host-built step arrays) are cached per (config, seq, heads, ...) so
     repeated calls/retraces skip the O(heads * blocks^2) layout
     compaction."""
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError(
-            "block-sparse attention needs the Pallas TPU backend "
-            "(jax.experimental.pallas.tpu); use mha_reference with "
-            "layout_to_bias as the fallback")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, q_len, h, d = q.shape

@@ -9,14 +9,18 @@ score tensors in HBM, with additive bias (position mask, ALiBi).
 Design:
   * caches stay in their storage layout [batch, max_len, kv_heads, dim] —
     BlockSpecs index directly into it, no transpose copies per token.
-  * grid = (batch, kv_heads, k_blocks); the k axis is innermost so the
-    online-softmax state lives in VMEM scratch across grid steps
+  * grid = (batch, k_blocks) / (slots, pages); every block spans ALL kv
+    heads (Mosaic refuses a block whose last two dims are neither
+    (8,128)-divisible nor the array's own), and the k axis is innermost
+    so the online-softmax state lives in VMEM scratch across grid steps
     (same scheme as ops/attention/flash.py).
-  * GQA is native: each kv head's grid step loads its whole group of
-    query heads ([group, dim] block), so grouped caches are never
-    expanded to num_heads (the `_repeat_kv` copy disappears).
-  * bias [batch, heads, 1, max_len] carries the validity mask (slots past
-    the write index) and any ALiBi term; fp32 statistics throughout.
+  * the paged kernel takes q grouped [slots, kv_heads, group, dim], so a
+    GQA pool is never expanded to num_heads and MHA is group == 1; the
+    contiguous kernel expands a grouped cache with `_repeat_kv`.
+  * the contiguous kernel's bias [batch, heads, 1, max_len] carries the
+    validity mask and any ALiBi term; the paged kernel computes the
+    mask in-kernel from the per-slot position.  fp32 statistics
+    throughout.
 """
 
 import functools
@@ -24,13 +28,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas TPU backend is absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
-from deepspeed_tpu.ops.attention.flash import NEG_INF, _pick_block
+from deepspeed_tpu.ops.attention.flash import NEG_INF, _inside_shard_map
 
 
 def _decode_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref,
@@ -113,9 +113,9 @@ def _decode_pallas(q, k_cache, v_cache, bias, *, scale, block_k, interpret):
         out_specs=pl.BlockSpec((1, h, 1, d), lambda ib, j: (ib, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
         scratch_shapes=[
-            pl.ANY if pltpu is None else pltpu.VMEM((scr_rows, 128), jnp.float32),
-            pl.ANY if pltpu is None else pltpu.VMEM((scr_rows, 128), jnp.float32),
-            pl.ANY if pltpu is None else pltpu.VMEM((scr_rows, d), jnp.float32),
+            pltpu.VMEM((scr_rows, 128), jnp.float32),
+            pltpu.VMEM((scr_rows, 128), jnp.float32),
+            pltpu.VMEM((scr_rows, d), jnp.float32),
         ],
         interpret=interpret,
     )(q_t, k_cache, v_cache, bias)
@@ -128,21 +128,6 @@ def _repeat_kv(x, n_rep):
     b, l, h, d = x.shape
     return jnp.broadcast_to(x[:, :, :, None], (b, l, h, n_rep, d)) \
         .reshape(b, l, h * n_rep, d)
-
-
-def _inside_shard_map(mesh):
-    """True when tracing INSIDE a ``shard_map`` body over ``mesh``: the
-    mesh axis names are bound as manual axes there, so probing any of
-    them succeeds.  The per-shard context must never re-trigger the
-    multi-chip dispatch decision — inside the body each device already
-    holds exactly its shard, and the kernel runs on local arrays."""
-    for a in mesh.axis_names:
-        try:
-            jax.lax.axis_size(a)
-            return True
-        except Exception:       # NameError: axis not bound -> outside
-            continue
-    return False
 
 
 def _multichip_mesh():
@@ -168,78 +153,32 @@ def _multichip_mesh():
     return not _inside_shard_map(mesh)
 
 
-def _paged_decode_kernel_quant(pt_ref, len_ref, q_ref, k_ref, v_ref,
-                               ks_ref, vs_ref, o_ref, m_scr, l_scr,
-                               acc_scr, *, scale, page_size, np_):
-    """Quantized-pool variant of ``_paged_decode_kernel``: the K/V page
-    block arrives int8/fp8 and its per-row scale block ([1, page_size,
-    h, 1] — the parallel scale pool, fetched through the SAME
-    scalar-prefetched page-table index map, so a page and its scales
-    are one unit) dequantizes in VMEM right before the dot — the
-    fused-dequant property that makes quantized decode a bandwidth win
-    rather than a copy: only quantized bytes ever stream from HBM."""
-    si = pl.program_id(0)
-    ki = pl.program_id(1)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
-        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
-
-    h = q_ref.shape[1]
-    pos = len_ref[si]
-
-    @pl.when(ki * page_size <= pos)
-    def _compute():
-        q = q_ref[0]                                      # [h, 1, d]
-        k = (k_ref[0].astype(jnp.float32) *
-             ks_ref[0].astype(jnp.float32)).astype(q.dtype)
-        v = (v_ref[0].astype(jnp.float32) *
-             vs_ref[0].astype(jnp.float32)).astype(q.dtype)
-        k = k.transpose(1, 0, 2)                          # [h, ps, d]
-        v = v.transpose(1, 0, 2)                          # [h, ps, d]
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale   # [h, 1, ps]
-        k_pos = ki * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page_size), 2)
-        s = jnp.where(k_pos <= pos, s, NEG_INF)
-        s = jnp.maximum(s, NEG_INF)
-
-        m_prev = m_scr[:h, :1]
-        l_prev = l_scr[:h, :1]
-        m_cur = jnp.max(s, axis=2)
-        m_new = jnp.maximum(m_prev, m_cur)
-        row_live = m_new > NEG_INF / 2
-        alpha = jnp.where(row_live, jnp.exp(m_prev - m_new), 0.0)
-        p = jnp.where(row_live[..., None], jnp.exp(s - m_new[..., None]),
-                      0.0)
-        l_new = alpha * l_prev + jnp.sum(p, axis=2)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)           # [h, 1, d]
-        acc_scr[:h] = acc_scr[:h] * alpha + pv[:, 0, :]
-        m_scr[:h] = jnp.broadcast_to(m_new, (h, m_scr.shape[1]))
-        l_scr[:h] = jnp.broadcast_to(l_new, (h, l_scr.shape[1]))
-
-    @pl.when(ki == np_ - 1)
-    def _finalize():
-        l = l_scr[:h, :1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = ((acc_scr[:h] / l)[:, None, :]).astype(o_ref.dtype)
-
-
-def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, scale, page_size, np_):
-    """Paged variant of ``_decode_kernel``: one grid step is ALL heads of
-    one slot against ONE cache page, fetched through the prefetched page
-    table (the BlockSpec index_map picks the page id, so K/V stream
+def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+                         scale, page_size, np_, quantized):
+    """Paged variant of ``_decode_kernel``: one grid step is ALL kv heads
+    of one slot against ONE cache page, fetched through the prefetched
+    page table (the BlockSpec index_map picks the page id, so K/V stream
     page-by-page from the shared pool — the gathered [slots, max_len]
-    copy of the jnp fallback never exists). The validity mask is computed
-    in-kernel from the prefetched per-slot position: key position
-    ``page * page_size + offset`` is live iff <= the slot's current
-    position."""
+    copy of the jnp fallback never exists).  ``q`` arrives grouped
+    [slots, kv_heads, group, d] (query head kv*group + g belongs to kv
+    head kv — the contiguous grouping ``_repeat_kv`` spells out), so MHA
+    is the group == 1 case of the same kernel and a GQA pool is never
+    expanded to full heads.  Every block spans the pool's trailing
+    (kv_heads, d) dims whole: Mosaic's lowering refuses a per-kv-head
+    block (its last two dims must be (8,128)-divisible or equal the
+    array's).  The validity mask is computed in-kernel from the
+    prefetched per-slot position: key position ``page * page_size +
+    offset`` is live iff <= the slot's current position.
+
+    ``quantized`` appends the per-row scale refs ([1, page_size,
+    kv_heads, 1] blocks of the parallel scale pool, fetched through the
+    SAME page-table index map, so a page and its scales are one unit)
+    and dequantizes in VMEM right before the dot — only quantized bytes
+    ever stream from HBM."""
+    if quantized:
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, m_scr, l_scr, acc_scr = rest
     si = pl.program_id(0)
     ki = pl.program_id(1)
 
@@ -249,7 +188,6 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    h = q_ref.shape[1]
     pos = len_ref[si]
 
     # skip pages entirely past the slot's live prefix (their state
@@ -257,120 +195,50 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # runs with the in-kernel mask
     @pl.when(ki * page_size <= pos)
     def _compute():
-        q = q_ref[0]                                      # [h, 1, d]
-        k = k_ref[0].transpose(1, 0, 2)                   # [h, ps, d]
-        v = v_ref[0].transpose(1, 0, 2)                   # [h, ps, d]
+        q = q_ref[0]                                      # [kv_h, g, d]
+        k = k_ref[0]                                      # [ps, kv_h, d]
+        v = v_ref[0]
+        if quantized:
+            k = (k.astype(jnp.float32) *
+                 ks_ref[0].astype(jnp.float32)).astype(q.dtype)
+            v = (v.astype(jnp.float32) *
+                 vs_ref[0].astype(jnp.float32)).astype(q.dtype)
+        # leading-batch dot over kv heads (Mosaic supports batch dims
+        # only at position 0 on both sides)
+        k = k.transpose(1, 0, 2)                          # [kv_h, ps, d]
+        v = v.transpose(1, 0, 2)
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale   # [h, 1, ps]
+            preferred_element_type=jnp.float32) * scale   # [kv_h, g, ps]
         k_pos = ki * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, page_size), 2)
         s = jnp.where(k_pos <= pos, s, NEG_INF)
-        s = jnp.maximum(s, NEG_INF)
 
-        m_prev = m_scr[:h, :1]
-        l_prev = l_scr[:h, :1]
-        m_cur = jnp.max(s, axis=2)
-        m_new = jnp.maximum(m_prev, m_cur)
-        row_live = m_new > NEG_INF / 2
-        alpha = jnp.where(row_live, jnp.exp(m_prev - m_new), 0.0)
-        p = jnp.where(row_live[..., None], jnp.exp(s - m_new[..., None]), 0.0)
-        l_new = alpha * l_prev + jnp.sum(p, axis=2)
+        m_prev = m_scr[:, :, :1]                          # [kv_h, g, 1]
+        l_prev = l_scr[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # position 0 of page 0 is live for every slot, so m_new is
+        # finite from the first computed page on and exp() needs no
+        # fully-masked-row guard
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)           # [h, 1, d]
-        acc_scr[:h] = acc_scr[:h] * alpha + pv[:, 0, :]
-        m_scr[:h] = jnp.broadcast_to(m_new, (h, m_scr.shape[1]))
-        l_scr[:h] = jnp.broadcast_to(l_new, (h, l_scr.shape[1]))
+            preferred_element_type=jnp.float32)           # [kv_h, g, d]
+        acc_scr[:] = acc_scr[:] * alpha + pv
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
     @pl.when(ki == np_ - 1)
     def _finalize():
-        l = l_scr[:h, :1]
+        l = l_scr[:, :, :1]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = ((acc_scr[:h] / l)[:, None, :]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
 
 
-def _paged_decode_kernel_gqa(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                             scale, page_size, np_, quantized):
-    """GQA-native paged decode: one grid step is ONE kv head's GROUP of
-    query heads against one page, so the grid is (slots, kv_heads,
-    pages) and the K/V BlockSpec picks a single kv head — the pool is
-    never expanded to full heads (the ``_repeat_kv`` copy the original
-    auto path paid group_factor x pool bytes for).  ``q`` arrives
-    pre-reshaped [slots, kv_heads, group, d] (query head kv*group + g
-    belongs to kv head kv — the same contiguous grouping
-    ``_repeat_kv`` spells out), so the per-step dot is a plain
-    [group, d] x [page_size, d]^T matmul.  ``quantized`` appends the
-    per-row scale refs ([1, page_size, 1, 1] blocks riding the SAME
-    prefetched page-table index map) and dequantizes in VMEM before
-    the dot.  Tiling note: blocks expose (group, d) / (page_size, d)
-    as their trailing dims; a sub-8 ``group`` relies on Mosaic padding
-    the sublane tile — interpret mode (CI) is exact either way, and
-    the real-TPU bench run is where the tile economics get measured."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, m_scr, l_scr, acc_scr = rest
-    si = pl.program_id(0)
-    ki = pl.program_id(2)
-
-    # pages is the innermost grid dim: ki resets to 0 whenever the
-    # (slot, kv head) pair advances, so this init starts a fresh
-    # online-softmax accumulation per pair
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
-        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
-
-    g = q_ref.shape[2]
-    pos = len_ref[si]
-
-    @pl.when(ki * page_size <= pos)
-    def _compute():
-        q = q_ref[0, 0]                                   # [group, d]
-        k = k_ref[0, :, 0, :]                             # [ps, d]
-        v = v_ref[0, :, 0, :]                             # [ps, d]
-        if quantized:
-            k = (k.astype(jnp.float32) *
-                 ks_ref[0, :, 0, :].astype(jnp.float32)).astype(q.dtype)
-            v = (v.astype(jnp.float32) *
-                 vs_ref[0, :, 0, :].astype(jnp.float32)).astype(q.dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [group, ps]
-        k_pos = ki * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        s = jnp.where(k_pos <= pos, s, NEG_INF)
-        s = jnp.maximum(s, NEG_INF)
-
-        m_prev = m_scr[:g, :1]
-        l_prev = l_scr[:g, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        row_live = m_new > NEG_INF / 2
-        alpha = jnp.where(row_live, jnp.exp(m_prev - m_new), 0.0)
-        p = jnp.where(row_live, jnp.exp(s - m_new), 0.0)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [group, d]
-        acc_scr[:g] = acc_scr[:g] * alpha + pv
-        m_scr[:g] = jnp.broadcast_to(m_new, (g, m_scr.shape[1]))
-        l_scr[:g] = jnp.broadcast_to(l_new, (g, l_scr.shape[1]))
-
-    @pl.when(ki == np_ - 1)
-    def _finalize():
-        l = l_scr[:g, :1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:g] / l).astype(o_ref.dtype)
-
-
-def _paged_decode_pallas_gqa(q, k_pages, v_pages, page_table, positions, *,
-                             scale, interpret, k_scale=None, v_scale=None):
-    """Grouped-query paged kernel dispatch: grid (slots, kv_heads,
-    pages), per-kv-head BlockSpecs — see ``_paged_decode_kernel_gqa``.
-    Shapes as in :func:`_paged_decode_pallas`."""
+def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
+                         scale, interpret, k_scale=None, v_scale=None):
     slots, one, h, d = q.shape
     page_size, kv_h = k_pages.shape[1], k_pages.shape[2]
     maxp = page_table.shape[1]
@@ -378,34 +246,32 @@ def _paged_decode_pallas_gqa(q, k_pages, v_pages, page_table, positions, *,
     quantized = k_scale is not None
     # [slots, 1, h, d] -> [slots, kv_h, group, d]: head kv*group + g is
     # kv head kv's g-th query head (the _repeat_kv grouping)
-    q_g = q.transpose(0, 2, 1, 3).reshape(slots, kv_h, group, d)
-    scr_rows = max(group, 8)   # TPU sublane tile
+    q_g = q.reshape(slots, kv_h, group, d)
 
-    page_spec = pl.BlockSpec(
-        (1, page_size, 1, d),
-        lambda si, hi, ki, pt, ln: (pt[si, ki], 0, hi, 0))
-    q_spec = pl.BlockSpec((1, 1, group, d),
-                          lambda si, hi, ki, pt, ln: (si, hi, 0, 0))
+    def page_index(si, ki, pt, ln):
+        return (pt[si, ki], 0, 0, 0)
+
+    q_spec = pl.BlockSpec((1, kv_h, group, d),
+                          lambda si, ki, pt, ln: (si, 0, 0, 0))
+    page_spec = pl.BlockSpec((1, page_size, kv_h, d), page_index)
     in_specs = [q_spec, page_spec, page_spec]
     operands = [q_g, k_pages, v_pages]
     if quantized:
-        scale_spec = pl.BlockSpec(
-            (1, page_size, 1, 1),
-            lambda si, hi, ki, pt, ln: (pt[si, ki], 0, hi, 0))
+        scale_spec = pl.BlockSpec((1, page_size, kv_h, 1), page_index)
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
-    kernel = functools.partial(_paged_decode_kernel_gqa, scale=scale,
+    kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                page_size=page_size, np_=maxp,
                                quantized=quantized)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(slots, kv_h, maxp),
+        grid=(slots, maxp),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((scr_rows, 128), jnp.float32),
-            pltpu.VMEM((scr_rows, 128), jnp.float32),
-            pltpu.VMEM((scr_rows, d), jnp.float32),
+            pltpu.VMEM((kv_h, group, 128), jnp.float32),
+            pltpu.VMEM((kv_h, group, 128), jnp.float32),
+            pltpu.VMEM((kv_h, group, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -413,67 +279,7 @@ def _paged_decode_pallas_gqa(q, k_pages, v_pages, page_table, positions, *,
         out_shape=jax.ShapeDtypeStruct((slots, kv_h, group, d), q.dtype),
         interpret=interpret,
     )(page_table, positions, *operands)
-    return out.reshape(slots, h, d)[:, None]              # [slots, 1, h, d]
-
-
-def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
-                         scale, interpret, k_scale=None, v_scale=None):
-    slots, one, h, d = q.shape
-    page_size = k_pages.shape[1]
-    maxp = page_table.shape[1]
-    kv_h = k_pages.shape[2]
-    quantized = k_scale is not None
-    if kv_h != h:
-        # grouped (GQA) pools get the per-kv-head BlockSpec kernel: the
-        # q-head group rides in per kv head and the pool streams its
-        # native grouped layout (no _repeat_kv expansion copying
-        # group x pool bytes per step)
-        return _paged_decode_pallas_gqa(
-            q, k_pages, v_pages, page_table, positions, scale=scale,
-            interpret=interpret, k_scale=k_scale, v_scale=v_scale)
-    scr_rows = max(h, 8)
-    q_t = q.transpose(0, 2, 1, 3)                         # [slots, h, 1, d]
-
-    page_spec = pl.BlockSpec((1, page_size, h, d),
-                             lambda si, ki, pt, ln: (pt[si, ki], 0, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, h, 1, d), lambda si, ki, pt, ln: (si, 0, 0, 0)),
-        page_spec, page_spec,
-    ]
-    operands = [q_t, k_pages, v_pages]
-    if quantized:
-        # the scale pools ride the SAME prefetched page-table index map
-        # as their payload: one grid step fetches a page and its scales
-        # as a unit, and the dequant happens in VMEM inside the kernel
-        scale_spec = pl.BlockSpec(
-            (1, page_size, h, 1),
-            lambda si, ki, pt, ln: (pt[si, ki], 0, 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
-        kernel = functools.partial(_paged_decode_kernel_quant,
-                                   scale=scale, page_size=page_size,
-                                   np_=maxp)
-    else:
-        kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                                   page_size=page_size, np_=maxp)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(slots, maxp),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, 1, d),
-                               lambda si, ki, pt, ln: (si, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((scr_rows, 128), jnp.float32),
-            pltpu.VMEM((scr_rows, 128), jnp.float32),
-            pltpu.VMEM((scr_rows, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, h, 1, d), q.dtype),
-        interpret=interpret,
-    )(page_table, positions, *operands)
-    return out.transpose(0, 2, 1, 3)                      # [slots, 1, h, d]
+    return out.reshape(slots, 1, h, d)
 
 
 _KERNEL_MODE = None       # None -> "auto"; see kernel_mode_scope
@@ -537,8 +343,6 @@ def paged_kernel_decision(*, num_heads, num_kv_heads, page_size,
     def ref(reason):
         return {"path": "reference", "dispatch": None, "reason": reason}
 
-    if pltpu is None:
-        return ref("this jax build has no Pallas TPU backend")
     if has_bias:
         return ref("additive bias (ALiBi) rides the gather reference "
                    "(the paged kernel computes only the positional "
@@ -663,8 +467,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, positions, *,
 
     The Pallas path streams K/V page-by-page via scalar-prefetched table
     lookups (true PagedAttention: no per-slot contiguous copy); GQA
-    pools run it with per-kv-head BlockSpecs (the q-head group rides in
-    per kv head — the pool is never expanded), and on a multi-device
+    pools run the same kernel with q grouped per kv head (the pool is
+    never expanded to full heads), and on a multi-device
     mesh it runs per-shard under ``shard_map`` (kv heads over
     ``model``, slots over ``data``; see ``_paged_decode_shard_map``).
     The fallback gathers pages into contiguous buffers and reuses
@@ -698,10 +502,9 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, positions, *,
     # Kernel-vs-reference dispatch (all static, scan-safe): the
     # decision is paged_kernel_decision's — the same function the
     # engine surfaces through serving_mesh_info()/health(), so the
-    # active path is always visible to operators.  GQA pools run the
-    # per-kv-head BlockSpec kernel (grid (slots, kv_heads, pages) — no
-    # pool expansion); on a multi-device mesh the kernel runs through
-    # the shard_map dispatch, each device over its kv-head/slot shard
+    # active path is always visible to operators.  On a multi-device
+    # mesh the kernel runs through the shard_map dispatch, each device
+    # over its kv-head/slot shard
     # (GSPMD cannot partition a pallas_call, so this dispatch is the
     # ONLY multi-chip kernel path — the jnp reference below remains
     # the GSPMD-partitionable correctness oracle).  Inside a shard_map
@@ -775,7 +578,14 @@ def decode_attention(q, k_cache, v_cache, *, bias, scale=None,
 
     if l == 1 and h % kv_h == 0 and max_len % (block_k or 128) == 0 and \
             (force_kernel or not (interpret or _multichip_mesh())):
-        block_k = block_k or _pick_block(max_len)
+        # the K and V blocks span ALL heads and are double-buffered (4
+        # resident copies): cap one block at 2 MiB so the set stays
+        # inside Mosaic's 16 MiB scoped-VMEM default at any head count
+        # (max_len is a multiple of 128 here, so 128 always divides)
+        cap = (2 << 20) // (h * d * k_cache.dtype.itemsize)
+        block_k = block_k or next(
+            (bk for bk in (1024, 512, 256) if bk <= cap
+             and max_len % bk == 0), 128)
         bias_full = jnp.broadcast_to(
             bias.astype(jnp.float32), (b, h, 1, max_len))
         return _decode_pallas(q, k_cache, v_cache, bias_full, scale=scale,

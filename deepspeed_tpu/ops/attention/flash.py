@@ -31,29 +31,33 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = pl.ANY
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = float(-1e30)  # large-negative instead of -inf: keeps exp() exact-0
                         # without nan from (-inf) - (-inf)
+
+
+def _inside_shard_map(mesh):
+    """True when tracing INSIDE a ``shard_map`` body over ``mesh``: the
+    mesh axis names are bound as manual axes there, so probing any of
+    them succeeds.  The per-shard context must never re-trigger a
+    multi-chip dispatch decision — inside the body each device already
+    holds exactly its shard, and the kernel runs on local arrays."""
+    for a in mesh.axis_names:
+        try:
+            jax.lax.axis_size(a)
+            return True
+        except Exception:       # NameError: axis not bound -> outside
+            continue
+    return False
 
 
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying the varying-manual-axes of `like`, so
     pallas_call works under shard_map with check_vma=True (ring/Ulysses
     call the kernel per shard)."""
-    try:
-        vma = jax.typeof(like).vma
-        if vma:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except Exception:
-        pass
-    return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _causal_block_mask(s, qi, ki, block_q, block_k, offset):
@@ -276,9 +280,9 @@ def _flash_fwd(q3, k3, v3, *, scale, block_q, block_k, causal, interpret):
             _sds((bh, q_len, 1), jnp.float32, q3),
         ],
         scratch_shapes=[
-            pl.ANY if pltpu is None else pltpu.VMEM((block_q, 128), jnp.float32),
-            pl.ANY if pltpu is None else pltpu.VMEM((block_q, 128), jnp.float32),
-            pl.ANY if pltpu is None else pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
     )(q3, k3, v3)
@@ -468,7 +472,7 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, *, scale, block_q, block_k, causal,
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j, k: (i, j, 0)),
         out_shape=_sds((bh, q_len, d), q3.dtype, q3),
         scratch_shapes=[
-            pl.ANY if pltpu is None else pltpu.VMEM((block_q, d), jnp.float32)],
+            pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
     )(q3, k3, v3, do3, lse, delta)
 
@@ -490,8 +494,8 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, *, scale, block_q, block_k, causal,
             _sds((bh, k_len, d), v3.dtype, v3),
         ],
         scratch_shapes=[
-            pl.ANY if pltpu is None else pltpu.VMEM((block_k, d), jnp.float32),
-            pl.ANY if pltpu is None else pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
     )(q3, k3, v3, do3, lse, delta)
@@ -569,6 +573,29 @@ def flash_attention(q, k, v, *, causal=True, scale=None, block_q=None,
     the block-sparse kernel (block_sparse.py): grid steps exist only for
     active blocks, so compute AND k/v traffic scale with layout density.
     """
+    from deepspeed_tpu import comm as dist
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    mesh = dist.get_mesh()
+    if not interpret and mesh is not None and mesh.size > 1 and \
+            not _inside_shard_map(mesh):
+        # GSPMD cannot partition a Mosaic kernel (jit over > 1 device
+        # raises "Mosaic kernels cannot be automatically partitioned"),
+        # so on a multi-device mesh the compiled kernel runs per shard:
+        # batch over `data`, heads over `model`, where they divide.
+        # Sparse layouts are per GLOBAL head, so they keep heads whole.
+        # (An interpret-mode kernel is plain jax ops GSPMD partitions.)
+        from deepspeed_tpu.ops.attention.ring import _bhd_spec
+        spec = _bhd_spec(mesh, q.shape, None)
+        if sparsity_config is not None:
+            spec = P(spec[0], None, None, None)
+        body = functools.partial(
+            flash_attention, causal=causal, scale=scale, block_q=block_q,
+            block_k=block_k, interpret=interpret,
+            sparsity_config=sparsity_config, with_lse=with_lse)
+        out_specs = (spec, P(spec[0], spec[2], None)) if with_lse else spec
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=out_specs, check_vma=False)(q, k, v)
     if q.dtype == jnp.float16 and jax.default_backend() == "tpu":
         # fp16 -> jnp-oracle FALLBACK (the documented contract, not an
         # accident): Mosaic has no f16 vector type on TPU ("Unsupported
@@ -604,8 +631,6 @@ def flash_attention(q, k, v, *, causal=True, scale=None, block_q=None,
         return sparse_flash_attention(q, k, v, sparsity_config,
                                       causal=causal, scale=scale,
                                       interpret=interpret)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     b, q_len, h, d = q.shape
     if block_q is None:
         block_q = _pick_block(q_len)

@@ -112,16 +112,9 @@ def ring_attention_local(q, k, v, axis_name, *, causal=True, scale=None,
         # the switch operands vary over every manual mesh axis q does
         # (data/model/...); the index only varies over the ring axis —
         # broadcast its varying-axes set so the vma checker accepts it
-        q_vma = getattr(jax.typeof(q), "vma", frozenset())
-        b_vma = getattr(jax.typeof(branch), "vma", frozenset())
-        missing = tuple(q_vma - b_vma)
+        missing = tuple(jax.typeof(q).vma - jax.typeof(branch).vma)
         if missing:
-            # lax.pvary is deprecated in favor of pcast(to='varying');
-            # keep the fallback for jax versions that predate pcast
-            if hasattr(lax, "pcast"):
-                branch = lax.pcast(branch, missing, to="varying")
-            else:   # pragma: no cover
-                branch = lax.pvary(branch, missing)
+            branch = lax.pcast(branch, missing, to="varying")
         return lax.switch(branch, [skip, diag, full], (q, k_cur, v_cur))
 
     def merge(m, l, acc, o_i, lse_i):

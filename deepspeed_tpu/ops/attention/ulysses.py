@@ -106,10 +106,6 @@ def ulysses_prefill_attention(q, k, v, k_pref, v_pref, prefix_len, mesh, *,
     partition of the head dim, so no per-rank slicing is needed and
     GSPMD reshards the (replicated) gather with a local slice, not a
     collective."""
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
     n = mesh.shape[axis]
     spec = _bhd_spec(mesh, q.shape, axis)
     model_ax = spec[2]
@@ -122,9 +118,9 @@ def ulysses_prefill_attention(q, k, v, k_pref, v_pref, prefix_len, mesh, *,
     pspec = P(spec[0], None, head_axes, None)
     fn = functools.partial(ulysses_prefill_attention_local,
                            axis_name=axis, scale=scale)
-    sharded = shard_map(fn, mesh=mesh,
-                        in_specs=(spec, spec, spec, pspec, pspec, P()),
-                        out_specs=spec)
+    sharded = jax.shard_map(fn, mesh=mesh,
+                            in_specs=(spec, spec, spec, pspec, pspec, P()),
+                            out_specs=spec)
     return sharded(q, k, v, k_pref, v_pref, prefix_len)
 
 
@@ -133,10 +129,6 @@ def ulysses_attention_sharded(q, k, v, mesh, *, axis="sequence", causal=True,
     """Global entry: q/k/v [b, L, h, d]; shards L over `axis`, swaps to
     heads for compute (DistributedAttention in deepspeed/sequence/layer.py
     of later snapshots)."""
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
     n = mesh.shape[axis]
     assert q.shape[2] % n == 0, \
         f"num_heads {q.shape[2]} must divide sequence axis size {n}"
@@ -149,6 +141,6 @@ def ulysses_attention_sharded(q, k, v, mesh, *, axis="sequence", causal=True,
             "heads per model shard must divide the sequence axis size"
     fn = functools.partial(ulysses_attention_local, axis_name=axis,
                            causal=causal, attn_fn=attn_fn)
-    sharded = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                        out_specs=spec)
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                            out_specs=spec)
     return sharded(q, k, v)

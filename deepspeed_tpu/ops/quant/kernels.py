@@ -26,11 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, q_ref, s_ref, o_ref, acc_scr, *, nk, gpb, group):
@@ -130,8 +126,7 @@ def int8_matmul(x, q, scale, *, block_m=None, block_n=None,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m_pad, n), x.dtype),
         scratch_shapes=[
-            pl.ANY if pltpu is None
-            else pltpu.VMEM((block_m, block_n), jnp.float32)],
+            pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
     )(x, q, scale.astype(jnp.float32).reshape(nk, gpb, n))
     return out[:m] if m_pad != m else out

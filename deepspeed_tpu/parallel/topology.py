@@ -19,6 +19,8 @@ import numpy as np
 import jax
 from jax.sharding import Mesh
 
+from deepspeed_tpu.utils.logging import logger
+
 # Canonical axis order, outermost -> innermost.
 MESH_AXES = ("pipe", "data", "expert", "sequence", "model")
 
@@ -150,10 +152,17 @@ def make_mesh(mesh_config=None, devices=None, allow_subset=False):
     shape = tuple(sizes[ax] for ax in MESH_AXES)
     total = int(np.prod(shape))
     devices = list(devices)[:total]
+    from jax.experimental import mesh_utils
     try:
-        from jax.experimental import mesh_utils
         device_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
+    except Exception as e:
+        # e.g. a device subset that is not a whole slice: the mesh still
+        # builds, but say so — on a multi-chip host a flat reshape may
+        # put mesh neighbours on chips that are not ICI neighbours
+        logger.warning(
+            f"create_device_mesh failed for mesh shape {shape} over "
+            f"{len(devices)} device(s) ({type(e).__name__}: {e}); falling "
+            "back to a flat reshape of the device list")
         device_array = np.asarray(devices).reshape(shape)
     return Mesh(device_array, MESH_AXES)
 

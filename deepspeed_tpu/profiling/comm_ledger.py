@@ -76,8 +76,9 @@ _COLLECTIVE_OPS = {
 
 _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
 _INSTR_RE = re.compile(
-    r"^(?:ROOT\s+)?%[\w.\-]+\s*=\s*(?P<shape>\([^)]*\)|\S+)\s+"
+    r"^(?:ROOT\s+)?%(?P<name>[\w.\-]+)\s*=\s*(?P<shape>\([^)]*\)|\S+)\s+"
     r"(?P<op>[\w\-]+)\(")
+_OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
 _COMP_RE = re.compile(r"^(?:ENTRY\s+)?%?(?P<name>[\w.\-]+)\s*\(.*\{\s*$")
 _GROUPS_LITERAL_RE = re.compile(r"replica_groups=\{(\{[\d, ]*\}(?:, ?\{[\d, ]*\})*)\}")
 _GROUPS_IOTA_RE = re.compile(
@@ -175,6 +176,7 @@ def _parse_module(text):
     entry = None
     name = None
     cur = None
+    shapes = {}   # instruction name -> shape string, per computation
     for raw in text.splitlines():
         if cur is None:
             m = _COMP_RE.match(raw)
@@ -184,6 +186,7 @@ def _parse_module(text):
                     entry = name
                 cur = {"collectives": [], "whiles": [], "calls": [],
                        "constants": [], "root_lt": False}
+                shapes = {}
             continue
         line = raw.strip()
         if raw.startswith("}") or line == "}":
@@ -196,6 +199,7 @@ def _parse_module(text):
         if m is None:
             continue
         op = m.group("op")
+        shapes[m.group("name")] = m.group("shape")
         if op == "constant" or "constant(" in line:
             cur["constants"] += [int(x) for x in _CONST_RE.findall(line)]
         if "compare(" in line and "direction=LT" in line and \
@@ -226,6 +230,13 @@ def _parse_module(text):
             continue
         paren = line.find("(", m.start("op"))
         operands, attrs = _split_operands(line, paren)
+        bytes_in = _shape_bytes(operands)
+        if not bytes_in:
+            # this XLA prints operands by name only (``all-reduce(%x)``):
+            # their shapes are on the defining instructions, which
+            # precede every use within a computation
+            bytes_in = sum(_shape_bytes(shapes.get(n, ""))
+                           for n in _OPERAND_NAME_RE.findall(operands))
         groups = None
         gm = _GROUPS_LITERAL_RE.search(attrs)
         if gm:
@@ -245,8 +256,7 @@ def _parse_module(text):
         out_bytes = _shape_bytes_max(m.group("shape")) \
             if op.endswith("-start") else _shape_bytes(m.group("shape"))
         cur["collectives"].append(_Collective(
-            _COLLECTIVE_OPS[op], _shape_bytes(operands), out_bytes,
-            groups, pairs))
+            _COLLECTIVE_OPS[op], bytes_in, out_bytes, groups, pairs))
     return comps, entry
 
 

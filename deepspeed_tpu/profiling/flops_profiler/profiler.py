@@ -34,32 +34,39 @@ def _num(x, suffix=""):
     return f"{x:.2f} E{suffix}"
 
 
-# bf16 dense peak per chip (the bench.py anchor table); "cpu" is a
-# NOMINAL 1 TFLOP/s so MFU stays a defined, comparable number on dev
-# rigs — absolute CPU MFU values are meaningless, their TRENDS are not
+# bf16 dense peak FLOP/s per chip, keyed by the ``device_kind`` string
+# the device reports (the spellings jax's own
+# ``_src/pallas/mosaic/tpu_info.py`` matches on).  Figures: Google Cloud
+# TPU documentation ("TPU v5e": 197 TFLOP/s; "TPU v5p": 459; "TPU v4":
+# 275).  "cpu" is a NOMINAL 1 TFLOP/s so MFU stays a defined,
+# comparable number in the CPU tests — absolute CPU MFU values are
+# meaningless, their TRENDS are not.
 PEAK_FLOPS_PER_CHIP = {
-    "v5e": 197e12,
-    "v5litepod": 197e12,
-    "v5p": 459e12,
-    "v4": 275e12,
+    "TPU v5 lite": 197e12,
+    "TPU v5e": 197e12,
+    "TPU v5": 459e12,
+    "TPU v5p": 459e12,
+    "TPU v4": 275e12,
     "cpu": 1e12,
 }
 
 
 def peak_flops_per_device(device=None):
-    """Best-effort peak model flops of one device, for MFU accounting
-    (live gauge: ``ResilientTrainer``; offline: ``bench.py``).  Matches
-    on ``device_kind`` substrings; unknown TPUs fall back to the v5e
-    figure, non-TPU platforms to the nominal CPU figure."""
+    """Peak model flops of one device, for MFU accounting (live gauge:
+    ``ResilientTrainer``; offline: ``bench.py``).  A TPU whose
+    ``device_kind`` is not in the table is an error, never another
+    chip's figure; non-TPU platforms get the nominal CPU row."""
     if device is None:
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for key, val in PEAK_FLOPS_PER_CHIP.items():
-        if key in kind:
-            return val
-    if getattr(device, "platform", "") != "tpu":
+    if device.platform != "tpu":
         return PEAK_FLOPS_PER_CHIP["cpu"]
-    return PEAK_FLOPS_PER_CHIP["v5e"]
+    try:
+        return PEAK_FLOPS_PER_CHIP[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak-flops row for device_kind {device.device_kind!r}; "
+            f"add it to PEAK_FLOPS_PER_CHIP with its source "
+            f"(known: {sorted(PEAK_FLOPS_PER_CHIP)})") from None
 
 
 def cost_analysis(fn, *args, static_argnums=(), **kwargs):
@@ -69,8 +76,6 @@ def cost_analysis(fn, *args, static_argnums=(), **kwargs):
         fn, static_argnums=static_argnums)
     compiled = jitted.lower(*args, **kwargs).compile()
     costs = compiled.cost_analysis()
-    if isinstance(costs, list):  # older jax returns [dict]
-        costs = costs[0] if costs else {}
     return {
         "flops": float(costs.get("flops", 0.0)),
         "bytes_accessed": float(costs.get("bytes accessed", 0.0)),

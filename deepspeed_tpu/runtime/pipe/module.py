@@ -137,10 +137,6 @@ def pipeline_spmd_forward(stage_params, x, *, block_apply, mesh,
     assert b % M == 0, f"batch {b} not divisible by microbatches {M}"
     xs = x.reshape(M, b // M, *x.shape[1:])
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
     def use(ax, dim):
         return ax if ax in mesh.shape and mesh.shape[ax] > 1 and \
             dim % mesh.shape[ax] == 0 else None
@@ -184,8 +180,8 @@ def pipeline_spmd_forward(stage_params, x, *, block_apply, mesh,
         return lax.psum(outs * mask, "pipe")
 
     out_spec = x_spec
-    fn = shard_map(per_stage, mesh=mesh, in_specs=(p_spec, x_spec),
-                   out_specs=out_spec)
+    fn = jax.shard_map(per_stage, mesh=mesh, in_specs=(p_spec, x_spec),
+                       out_specs=out_spec)
     outs = fn(stage_params, xs)
     return outs.reshape(b, *x.shape[1:])
 

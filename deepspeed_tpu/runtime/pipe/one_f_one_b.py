@@ -46,13 +46,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 
-def _get_shard_map():
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as sm
-    return sm
-
-
 def _unwrap(y):
     return y[0] if isinstance(y, tuple) else y
 
@@ -72,7 +65,6 @@ def make_pipeline_loss_fn(pipe, per_token_loss, *, mesh, num_microbatches):
     embed = pipe.embed
     head = pipe.head
     tied = pipe.tied_head
-    shard_map = _get_shard_map()
 
     def use(ax, dim):
         return ax if ax in mesh.shape and mesh.shape[ax] > 1 and \
@@ -152,9 +144,9 @@ def make_pipeline_loss_fn(pipe, per_token_loss, *, mesh, num_microbatches):
                 loss = lax.pmean(loss, "data")
             return loss
 
-        fn = shard_map(per_stage, mesh=mesh,
-                       in_specs=(p_spec, r_spec, h_spec, x_spec, x_spec),
-                       out_specs=P())
+        fn = jax.shard_map(per_stage, mesh=mesh,
+                           in_specs=(p_spec, r_spec, h_spec, x_spec, x_spec),
+                           out_specs=P())
         return fn(stages, embed_p, head_p, ids_m, lab_m)
 
     # ------------------------------------------------- interleaved 1F1B
@@ -288,9 +280,9 @@ def make_pipeline_loss_fn(pipe, per_token_loss, *, mesh, num_microbatches):
             pg = jax.tree.map(lambda a: a[None], pg)   # [1, k, ...] shard
             return loss, pg, eg, hg
 
-        fn = shard_map(per_stage, mesh=mesh,
-                       in_specs=(p_spec, r_spec, h_spec, x_spec, x_spec),
-                       out_specs=(P(), p_spec, r_spec, h_spec))
+        fn = jax.shard_map(per_stage, mesh=mesh,
+                           in_specs=(p_spec, r_spec, h_spec, x_spec, x_spec),
+                           out_specs=(P(), p_spec, r_spec, h_spec))
         loss, pg, eg, hg = fn(stages, embed_p, head_p, ids_m, lab_m)
         grads = {"stages": jax.tree.map(
                      lambda g, p: g.astype(jnp.asarray(p).dtype), pg, stages),

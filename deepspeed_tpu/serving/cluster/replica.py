@@ -504,8 +504,10 @@ class ProcessReplica:
                 cmd.append("--threefry-partitionable")
         except Exception:
             pass
+        # the child inherits the parent's platform selection: a worker
+        # on a chip machine serves from the chip (and needs one of its
+        # own — a parent that has touched JAX holds the one it took)
         env = os.environ.copy()
-        env.setdefault("JAX_PLATFORMS", "cpu")
         # the child must import THIS deepspeed_tpu however the parent
         # got it (site-packages, cwd, or an explicit sys.path entry —
         # the env of a driver script run from anywhere): the package's
@@ -521,8 +523,7 @@ class ProcessReplica:
         env.update(self._env)
         self._proc = subprocess.Popen(
             cmd, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-            stderr=subprocess.DEVNULL, text=True, env=env,
-            pass_fds=pass_fds)
+            text=True, env=env, pass_fds=pass_fds)   # stderr: inherited
         for fd in child_fds:
             os.close(fd)    # the child owns its end now
         self._events = deque()

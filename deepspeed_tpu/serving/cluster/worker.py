@@ -163,7 +163,9 @@ def main(argv=None):
     from deepspeed_tpu.serving.cluster import transport as tp
     from deepspeed_tpu.serving.scheduler import (TERMINAL,
                                                  ServingScheduler)
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     engine = _build_engine(args.model, args.dtype)
     tenancy = None
     if args.tenants is not None or args.lora is not None:

@@ -171,8 +171,8 @@ class ThroughputTimer:
         self.started = True
         if self.global_step_count >= self.start_step:
             # sync only at a measurement-window edge: a device barrier
-            # per step would serialize the async dispatch queue (and on
-            # relayed devices costs a full host round trip per step);
+            # per step would serialize the async dispatch queue (a
+            # full host round trip per step);
             # per-step wall deltas still sum to the true window time
             if self.global_step_count == self.start_step:
                 _sync()

@@ -23,10 +23,7 @@ def main():
         counts = re.findall(r"host_platform_device_count=(\d+)",
                             os.environ.get("XLA_FLAGS", ""))
         if counts:  # last occurrence wins, like XLA's own flag parsing
-            try:
-                jax.config.update("jax_num_cpu_devices", int(counts[-1]))
-            except AttributeError:
-                pass   # jax<0.5: XLA_FLAGS already carries the count
+            jax.config.update("jax_num_cpu_devices", int(counts[-1]))
 
     import numpy as np
     import jax.numpy as jnp

@@ -9,15 +9,11 @@ import sys
 
 import pytest
 
-from tests.unit.compat_markers import mp_collectives
-
-
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
 
 
-@mp_collectives
 def test_elastic_agent_restarts_and_resumes(tmp_path):
     out_dir = tmp_path / "out"
     out_dir.mkdir()

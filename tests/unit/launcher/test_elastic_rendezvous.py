@@ -14,9 +14,6 @@ import time
 
 import pytest
 
-from tests.unit.compat_markers import mp_collectives
-
-
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
@@ -73,7 +70,6 @@ def test_rendezvous_round_protocol():
         assert res[(0, "r2")][1] > 0
 
 
-@mp_collectives
 def test_two_agents_cross_node_restart(tmp_path):
     """elastic_worker kills global rank 1 (node 0's second worker) on
     attempt 0: agent 1's workers — a DIFFERENT node — must also restart

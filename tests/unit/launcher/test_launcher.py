@@ -11,9 +11,6 @@ import sys
 
 import pytest
 
-from tests.unit.compat_markers import mp_collectives
-
-
 
 from deepspeed_tpu.launcher.runner import fetch_hostfile, parse_args
 
@@ -75,7 +72,6 @@ def test_ds_bench_cli():
 
 
 @pytest.mark.parametrize("nproc", [2])
-@mp_collectives
 def test_cli_two_process_rendezvous_and_allreduce(tmp_path, nproc):
     """Spawn 2 real processes through the CLI; they rendezvous via
     jax.distributed and jointly reduce a sharded array."""

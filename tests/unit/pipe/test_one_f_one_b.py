@@ -21,20 +21,7 @@ from deepspeed_tpu.parallel.topology import make_mesh
 from deepspeed_tpu.runtime.config import MeshConfig
 from deepspeed_tpu.runtime.pipe.one_f_one_b import make_pipeline_loss_fn
 
-from deepspeed_tpu.utils import jax_compat
-
 from tests.unit.simple_model import random_lm_data
-
-# jax<0.5: the legacy shard_map replication checker cannot statically
-# infer the pipeline's replicated (P()) outputs — with the check off,
-# the transpose inserts a spurious cross-stage psum, so grad-exactness
-# against the sequential oracle only holds on current jax. Multi-stage
-# grad-parity cases are skipped there (the single-stage case and the
-# end-to-end training tests still run).
-legacy_grads = pytest.mark.skipif(
-    jax_compat.LEGACY_SHARD_MAP,
-    reason="legacy shard_map (jax<0.5) cannot infer replicated "
-           "pipeline outputs; grad transpose inserts a spurious psum")
 
 
 def seq_loss(pipe, cfg, params, ids, labels, per_token_loss):
@@ -72,13 +59,14 @@ def setup(S=4, M=4, dp=2, tie=True, layers=4):
 
 
 @pytest.mark.parametrize("S,M,dp,tie", [
-    pytest.param(4, 4, 2, True, marks=legacy_grads),
-    pytest.param(2, 8, 4, True, marks=legacy_grads),
-    pytest.param(2, 2, 1, False, marks=legacy_grads),
+    # 4 stages: ~36s of compile; the S=4 schedule stays in tier-1
+    # through test_1f1b_microbatch_count_invariance (slow lane since
+    # PR 22 to pay for the staging-alias and TPU-lowering tests)
+    pytest.param(4, 4, 2, True, marks=pytest.mark.slow),
+    (2, 8, 4, True),
+    (2, 2, 1, False),
     # degenerate single stage: correctness-redundant with the
-    # multi-stage cases on current jax, and the only variant that
-    # RUNS on legacy jax — too heavy (~36s) for the tier-1 wall
-    # budget there, so it rides the slow lane
+    # multi-stage cases, so it rides the slow lane
     pytest.param(1, 2, 4, True, marks=pytest.mark.slow),
 ])
 def test_1f1b_loss_and_grads_match_sequential(S, M, dp, tie):
@@ -101,7 +89,6 @@ def test_1f1b_loss_and_grads_match_sequential(S, M, dp, tie):
             err_msg=f"grad mismatch at {jax.tree_util.keystr(path)}")
 
 
-@legacy_grads
 def test_1f1b_nonuniform_stages():
     """5 blocks over 2 stages (3+2 split via layer weights): loss and
     grads still match the sequential oracle; padded slots contribute
@@ -132,7 +119,6 @@ def test_1f1b_nonuniform_stages():
     assert all(float(np.abs(np.asarray(l)).max()) == 0.0 for l in pad_leaf)
 
 
-@legacy_grads
 def test_1f1b_microbatch_count_invariance():
     """Same data, different microbatching -> same loss/grads (the 1F1B
     schedule must not change the math)."""

@@ -89,3 +89,19 @@ def test_flops_profile_with_gas():
         engine.step()
     prof = engine.flops_profile()
     assert prof["flops_per_step"] > 0
+
+
+def test_peak_table_keyed_by_reported_device_kind():
+    """Peaks are keyed by the ``device_kind`` string a device reports; an
+    unknown TPU is an error, never another chip's figure; non-TPU
+    platforms keep the nominal row the CPU MFU-trend tests read."""
+    from types import SimpleNamespace as Dev
+    from deepspeed_tpu.profiling.flops_profiler.profiler import (
+        peak_flops_per_device)
+    assert peak_flops_per_device(
+        Dev(platform="tpu", device_kind="TPU v5 lite")) == 197e12
+    with pytest.raises(ValueError, match="TPU v9"):
+        peak_flops_per_device(Dev(platform="tpu", device_kind="TPU v9"))
+    assert peak_flops_per_device(
+        Dev(platform="cpu", device_kind="cpu")) == 1e12
+    assert peak_flops_per_device() == 1e12      # this CPU test run

@@ -7,8 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tests.unit.compat_markers import needs_pinned_host
-
 import deepspeed_tpu
 
 
@@ -148,7 +146,6 @@ def test_inference_from_training_checkpoint(tmp_path, tiny_llama):
                                atol=1e-3, rtol=1e-3)
 
 
-@needs_pinned_host
 def test_zero_inference_host_offload(tiny_llama):
     """ZeRO-Inference (reference zero.stage=3 + init_inference): weights
     live in pinned host memory and stream to the device inside the jitted
@@ -175,7 +172,6 @@ def test_zero_inference_host_offload(tiny_llama):
     np.testing.assert_array_equal(out, ref_out)
 
 
-@needs_pinned_host
 def test_zero_inference_with_int8(tiny_llama):
     """Offload + int8: the host->device stream carries quantized bytes."""
     import deepspeed_tpu
@@ -194,7 +190,6 @@ def test_zero_inference_with_int8(tiny_llama):
     assert out.shape == (2, 12)
 
 
-@needs_pinned_host
 def test_zero_inference_checkpoint_restore_streams_to_host(tmp_path,
                                                            tiny_llama):
     """Offloaded engines restore checkpoints straight into host memory

@@ -6,8 +6,6 @@ module_inject/containers/megatron_gpt_moe.py:1)."""
 import numpy as np
 import pytest
 
-from tests.unit.compat_markers import needs_pinned_host
-
 import jax
 import jax.numpy as jnp
 
@@ -200,7 +198,6 @@ def test_moe_expert_parallel_serving(tmp_path):
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
 
 
-@needs_pinned_host
 def test_moe_zero_inference_offload():
     """ZeRO-Inference + MoE: expert weights live in pinned host memory
     and stream per decode step."""

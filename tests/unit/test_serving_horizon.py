@@ -163,3 +163,64 @@ def test_decode_compile_count_bounded_by_horizon_buckets(engine):
     # the fused path IS the decode path: the single-step primitive never
     # compiles in serving anymore
     assert engine.serving_decode_compile_count() == 0
+
+
+# ------------------------------------------------- host-input staging
+
+
+def test_staged_host_inputs_survive_mutation_after_dispatch(engine):
+    """``device_put`` may alias a numpy buffer (zero-copy on CPU) or
+    read it asynchronously (TPU), and the overlapped scheduler loop
+    mutates its live ``lengths`` / page table / ``last_tok`` right after
+    a dispatch returns.  The staging point must therefore hand the
+    device its OWN copy: scribbling over every host array immediately
+    after ``prefill_into_slots`` / ``decode_multi`` return — before
+    anything blocks on the result — must not change the result."""
+    slots, pages, ps, maxp, chunk = 3, 16, 16, 8, 8
+    prompt = [3, 1, 4, 1, 5]
+
+    def aligned(a):
+        """A 64-byte-aligned copy: the alignment at which jaxlib's CPU
+        client takes a numpy buffer zero-copy (numpy itself only
+        guarantees 16, so an unaligned test would pass by luck)."""
+        a = np.asarray(a)
+        raw = np.zeros(a.nbytes + 64, np.uint8)
+        off = -raw.ctypes.data % 64
+        out = raw[off:off + a.nbytes].view(a.dtype).reshape(a.shape)
+        out[...] = a
+        return out
+
+    def run(scribble):
+        pools = engine.init_paged_cache(pages, ps)
+        table = np.zeros((slots, maxp), np.int32)
+        table[:, 0] = [1, 2, 3]
+        ids = np.zeros((1, chunk), np.int32)
+        ids[0, :len(prompt)] = prompt
+        host = dict(ids=aligned(ids), table=aligned(table),
+                    lengths=aligned(np.zeros(slots, np.int32)))
+        logits, pools = engine.prefill_into_slots(
+            host["ids"], 0, len(prompt), host["table"], host["lengths"],
+            pools)
+        if scribble:
+            for a in host.values():
+                a[...] = 7
+        first = int(np.argmax(np.asarray(logits)))
+
+        host = dict(toks=aligned(np.array([first, 0, 0], np.int32)),
+                    active=aligned(np.array([True, False, False])),
+                    table=aligned(table),
+                    lengths=aligned(np.array([len(prompt), 0, 0],
+                                             np.int32)),
+                    budgets=aligned(np.array([6, 0, 0], np.int32)),
+                    eos=aligned(np.full(slots, -1, np.int32)))
+        out = engine.decode_multi(
+            host["toks"], host["active"], host["table"], host["lengths"],
+            pools, horizon=4, budgets=host["budgets"],
+            eos_ids=host["eos"])
+        if scribble:
+            for a in host.values():
+                a[...] = 1
+        toks_block, valid = np.asarray(out[0]), np.asarray(out[1])
+        return first, toks_block[valid].tolist(), np.asarray(out[4]).tolist()
+
+    assert run(scribble=True) == run(scribble=False)

@@ -674,3 +674,32 @@ def test_tuned_config_rejected_on_foreign_mesh(tmp_path):
     a = args_for(tuned=str(legacy))
     assert ds.apply_tuned_config(a) == str(legacy)
     assert a.num_pages == 64
+
+
+# --------------------------------------------------- ds_serve exit code
+
+
+def test_ds_serve_exits_nonzero_on_failed_rows(tmp_path, monkeypatch):
+    """A ``failed`` row is an exception inside a dispatch that the
+    scheduler contained (on a chip: a kernel the compiler refused) —
+    ``ds_serve`` must not exit like a clean run.  ``finished`` rows
+    exit 0."""
+    import json as _json
+    from deepspeed_tpu.inference.engine import InferenceEngine
+    ds = _load_ds_serve()
+    inp, out = tmp_path / "in.jsonl", tmp_path / "out.jsonl"
+    inp.write_text(_json.dumps({"prompt": [1, 2, 3],
+                                "max_new_tokens": 2}) + "\n")
+    argv = ["--model", "gpt2-tiny", "--mesh", "data=1", "--num-slots",
+            "2", "--num-pages", "8", "--input", str(inp), "--output",
+            str(out)]
+    assert ds.main(argv) == 0
+    assert _json.loads(out.read_text().splitlines()[0])["status"] == \
+        "finished"
+
+    def refuse(self, *a, **k):
+        raise RuntimeError("Mosaic refused the kernel")
+    monkeypatch.setattr(InferenceEngine, "prefill_into_slots", refuse)
+    assert ds.main(argv) == 1
+    row = _json.loads(out.read_text().splitlines()[0])
+    assert row["status"] == "failed" and "Mosaic refused" in row["error"]

@@ -1,0 +1,258 @@
+"""One cell of the chip benchmark, once.
+
+    python benchmarks/chip/run.py --workload <name> --seed <n> \
+        --seconds <s> --trace <0|1>
+
+device check -> compile cache -> the cell's driver (set-up, warm-up,
+measured window, output check) -> one JSON line.  What belongs to one
+configuration, traffic mix or per-layer metric is a data file found by
+the name ``BENCHMARK.json`` gives it; this file knows kinds of traffic
+(``traffic/<mix>.json``: ``"kind"`` -> ``drive_<kind>.py``) and nothing
+by name.  Without a TPU, or with fewer chips than the cell asks for, it
+exits nonzero and prints no result line.
+"""
+
+import time
+
+T_START = time.monotonic()
+
+import argparse            # noqa: E402
+import importlib.util      # noqa: E402
+import json                # noqa: E402
+import os                  # noqa: E402
+import shutil              # noqa: E402
+import sys                 # noqa: E402
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+# the profiler runs over the LAST seconds of the window (or the last
+# quarter of a short one): its stop takes ~10 s on the chip, and there
+# it falls after the window instead of stalling the loop in mid-window
+TRACE_SECONDS = 3.0
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def load_json(path):
+    with open(path) as f:
+        return json.load(f)
+
+
+def load_module(bench_dir, name):
+    """A harness module by file, from ``bench_dir`` (so a copy of the
+    harness runs its own files)."""
+    if name in sys.modules and getattr(
+            sys.modules[name], "__file__", "").startswith(bench_dir):
+        return sys.modules[name]
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(bench_dir, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def find(entries, name, what):
+    for e in entries:
+        if e["name"] == name:
+            return e
+    raise SystemExit(f"{what} {name!r} is not in BENCHMARK.json")
+
+
+def reports(metric, cell):
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+class CompileCount:
+    """Backend compilations (or loads from the persistent cache) since
+    the process began, from jax.monitoring's duration events."""
+
+    def __init__(self):
+        import jax
+        self.n = 0
+        self.secs = 0.0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, name, secs, **kw):
+        if name == COMPILE_EVENT:
+            self.n += 1
+            self.secs += secs
+
+
+class Context:
+    """What a driver is given, and the hooks it calls: ``begin_window``
+    / ``end_window`` around the measured window, ``tick(now)`` once per
+    loop iteration so that a traced run can switch the profiler on for
+    a few seconds of the steady window."""
+
+    def __init__(self, root, bench_dir, config, traffic, seed, seconds,
+                 trace_dir=None, devices=(), compiles=None, t_start=None):
+        self.root, self.bench_dir = root, bench_dir
+        self.config, self.traffic = config, traffic
+        self.seed, self.seconds = seed, seconds
+        self.trace_dir, self.devices = trace_dir, list(devices)
+        self.compiles = compiles
+        self.t_start = time.monotonic() if t_start is None else t_start
+        self.setup_s = None
+        self.window_compiles = None
+        self._n0 = 0
+        self.notes = {}
+        self._trace_state = "off" if trace_dir is None else "armed"
+        self._trace_len = min(TRACE_SECONDS, seconds / 4)
+        self._trace_at = seconds - self._trace_len
+
+    def begin_window(self):
+        self.setup_s = time.monotonic() - self.t_start
+        self._n0 = self.compiles.n if self.compiles else 0
+
+    def end_window(self):
+        self.window_compiles = (self.compiles.n - self._n0) \
+            if self.compiles else 0
+
+    def tracing(self):
+        return self._trace_state == "on"
+
+    def traced(self):
+        """True in a run that traces: the profiler's start and stop
+        stall the loop, so such a run's host-clock numbers are its own."""
+        return self._trace_state != "off"
+
+    def memory(self, tag):
+        """Bytes in use and the peak so far on the fullest chip, under
+        ``tag`` in the notes."""
+        stats = [d.memory_stats() for d in self.devices]
+        if stats and stats[0]:
+            self.notes["mem_" + tag] = [
+                max(s["bytes_in_use"] for s in stats),
+                max(s["peak_bytes_in_use"] for s in stats)]
+            self.notes["mem_limit"] = stats[0].get("bytes_limit")
+
+    def tick(self, now, end=False):
+        import jax
+        if self._trace_state == "armed" and not end and \
+                now >= self._trace_at:
+            shutil.rmtree(self.trace_dir, ignore_errors=True)
+            # device planes and the benchmark's own annotations only:
+            # the Python tracer and the HLO protos slow the host loop
+            # and the stop by seconds and no reader uses them
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.enable_hlo_proto = False
+            t = time.monotonic()
+            jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
+            self.notes["trace_start_s"] = time.monotonic() - t
+            self._trace_state = "on"
+        elif self._trace_state == "on" and \
+                (end or now >= self._trace_at + self._trace_len):
+            t = time.monotonic()
+            jax.profiler.stop_trace()
+            self.notes["trace_stop_s"] = time.monotonic() - t
+            self._trace_state = "done"
+
+
+def layer_metrics(bench_dir, manifest, cell, reader_ctx):
+    """The cell's per-layer metrics, each by the reader its own file
+    names; a reader that finds nothing to read is left out."""
+    out = {}
+    for metric in manifest["per_layer"]:
+        if not reports(metric, cell):
+            continue
+        spec = load_json(os.path.join(bench_dir, "layer_metrics",
+                                      metric["name"] + ".json"))
+        mod, fn = spec["reader"].split(":")
+        value = getattr(load_module(bench_dir, mod), fn)(
+            reader_ctx, **spec.get("args", {}))
+        if value is not None:
+            out[metric["name"]] = {"value": float(value),
+                                   "unit": metric["unit"]}
+    return out
+
+
+def run_cell(ctx, kind):
+    """The driver of the traffic's kind, from the harness's directory."""
+    return load_module(ctx.bench_dir, "drive_" + kind).run(ctx)
+
+
+def main(argv=None):
+    p = argparse.ArgumentParser()
+    p.add_argument("--workload", required=True)
+    p.add_argument("--seed", type=int, required=True)
+    p.add_argument("--seconds", type=float, required=True)
+    p.add_argument("--trace", type=int, choices=[0, 1], default=0)
+    args = p.parse_args(argv)
+
+    manifest = load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    cell = find(manifest["workloads"], args.workload, "workload")
+    config = load_json(os.path.join(
+        ROOT, find(manifest["configs"], cell["config"], "config")["file"]))
+    traffic = load_json(os.path.join(HERE, "traffic",
+                                     cell["traffic"] + ".json"))
+    sys.path[:0] = [HERE, ROOT]
+
+    import jax
+    devices = jax.devices()
+    dev = devices[0]
+    if dev.platform != "tpu" or len(devices) < cell["chips"]:
+        print(f"{args.workload}: needs {cell['chips']} TPU chip(s); JAX "
+              f"found {len(devices)} x {dev.platform!r} "
+              f"({dev.device_kind})", file=sys.stderr)
+        return 1
+    peaks = load_json(os.path.join(HERE, "peaks.json"))["devices"]
+    if dev.device_kind not in peaks:
+        print(f"{dev.device_kind!r} is not in peaks.json", file=sys.stderr)
+        return 1
+
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+    cache_dir = enable_compile_cache()
+    # every program, however small, goes to the persistent cache: the
+    # second run of a cell in a checkout compiles nothing
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+    trace_dir = os.path.join(ROOT, ".bench_trace", args.workload) \
+        if args.trace else None
+    ctx = Context(ROOT, HERE, config, traffic, args.seed, args.seconds,
+                  trace_dir=trace_dir, devices=devices[:cell["chips"]],
+                  compiles=CompileCount(), t_start=T_START)
+    res = run_cell(ctx, traffic["kind"])
+
+    res["checks"]["no_compile_in_window"] = ctx.window_compiles == 0
+    correct = all(res["checks"].values())
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(devices),
+              "memory_peak_bytes": max(
+                  d.memory_stats()["peak_bytes_in_use"]
+                  for d in ctx.devices)}
+    e2e = dict(res["end_to_end"], setup_s=ctx.setup_s)
+    line = {"correct": correct, "attempted": res["attempted"],
+            "failed": res["failed"], "device": device}
+    if args.trace:
+        readers = load_module(HERE, "readers")
+        trace = readers.load_trace(trace_dir)
+        line["metrics"] = layer_metrics(HERE, manifest, args.workload, {
+            "trace": trace, "counters": res["counters"],
+            "static": res["static"], "end_to_end": e2e,
+            "peaks": peaks[dev.device_kind], "chips": cell["chips"]})
+        if trace is not None:
+            device["busy_s"] = trace.busy_s()
+            device["window_s"] = trace.window_s
+            line["breakdown"] = {"device_ops": trace.top_ops(),
+                                 "idle_gaps": trace.top_gaps()}
+    else:
+        line["metrics"] = {
+            m["name"]: {"value": float(e2e[m["name"]]), "unit": m["unit"]}
+            for m in manifest["end_to_end"]
+            if reports(m, args.workload) and e2e.get(m["name"]) is not None}
+    print(json.dumps({"workload": args.workload, "seed": args.seed,
+                      "checks": res["checks"],
+                      "notes": dict(res["notes"], **ctx.notes),
+                      "end_to_end": e2e,
+                      "window_compiles": ctx.window_compiles,
+                      "compile_events": ctx.compiles.n,
+                      "compile_s": ctx.compiles.secs,
+                      "cache_dir": cache_dir}))
+    print(json.dumps(line))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

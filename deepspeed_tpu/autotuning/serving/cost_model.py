@@ -374,7 +374,9 @@ class ServingCostModel:
         LIVE signal (``sequence_axis_size``, from the engine's resolved
         plan); it defaults to 1, so the term is honest on a rig without
         a sequence axis — routing there is a scheduler degrade, and the
-        model prices it as one."""
+        model prices it as one.  NOTE: this still prices one dispatch
+        per SLOT; the scheduler now carries every prefilling slot's
+        chunk in one [rows, chunk] dispatch per step (ROADMAP D6/S7)."""
         chunk = max(1, int(k["prefill_chunk"]))
         seq = max(1, int(self.live.get("sequence_axis_size", 1)))
         thr = int(k.get("seq_parallel_threshold") or 0)

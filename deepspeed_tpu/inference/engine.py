@@ -790,9 +790,9 @@ class InferenceEngine:
                 cache["adapters"] = adapters
             logits, cache = module.apply({"params": materialize(params)},
                                          ids, cache=cache)
-            # the model already reduced to the chunk's boundary row (the
-            # only position a scheduler ever samples from)
-            return logits[0, 0], {"layers": cache["layers"]}
+            # the model already reduced each row to its chunk's boundary
+            # position (the only one a scheduler ever samples from)
+            return logits[:, 0], {"layers": cache["layers"]}
 
         seq_plan = self.seq_parallel_plan()
 
@@ -813,7 +813,7 @@ class InferenceEngine:
                          seq_axis=seq_plan.axis, seq_impl=seq_plan.impl)
             logits, cache = module.apply({"params": materialize(params)},
                                          ids, cache=cache)
-            return logits[0, 0], {"layers": cache["layers"]}
+            return logits[:, 0], {"layers": cache["layers"]}
 
         def decode(params, toks, active, page_table, lengths, pools, rng,
                    do_sample, temperature, top_k, top_p):
@@ -1351,18 +1351,26 @@ class InferenceEngine:
 
     def prefill_into_slots(self, ids_chunk, slot, n_valid, page_table,
                            lengths, pools, adapter_ids=None, adapters=None):
-        """One prefill chunk of one slot: write the chunk's K/V through
-        the page table and return (boundary logits [vocab], new pools).
-        ``ids_chunk`` is [1, chunk] (padded past ``n_valid``); the pages
-        covering positions lengths[slot] .. +n_valid must be allocated.
+        """One prefill chunk for each of ``rows`` slots in ONE dispatch:
+        row r writes the K/V of ``ids_chunk[r, :n_valid[r]]`` through
+        slot ``slot[r]``'s page table and the call returns (boundary
+        logits [rows, vocab], new pools).  ``ids_chunk`` is [rows,
+        chunk] (padded past ``n_valid[r]``), ``slot`` / ``n_valid`` are
+        int32 [rows] (a scalar is one row); the pages covering
+        positions lengths[slot[r]] .. +n_valid[r] must be allocated.  A
+        padding row has ``n_valid == 0`` and any live slot id: it
+        writes nothing and its logits row is garbage.  The slots of the
+        non-padding rows must be distinct.
 
-        The chunk's positions (and rotary offsets) start at
-        ``lengths[slot]``, which need not be 0 OR page-aligned: a
-        prefix-cache hit seeds ``lengths[slot]`` to the cached boundary
-        and prefill resumes there with this same single jit signature —
-        per-row start offsets are data (the lengths array), never
-        shape."""
+        Row r's positions (and rotary offsets) start at
+        ``lengths[slot[r]]``, which need not be 0 OR page-aligned: a
+        prefix-cache hit seeds it to the cached boundary and prefill
+        resumes there — start offsets, slots and valid counts are data,
+        never shape, so there is one jit signature per ROW COUNT (the
+        scheduler packs rows into a few row buckets)."""
         assert self.params is not None, "set_params/init_params first"
+        slot = np.asarray(slot, np.int32).reshape(-1)
+        n_valid = np.asarray(n_valid, np.int32).reshape(-1)
         shd = self._serving_shardings(num_slots=int(np.shape(lengths)[0]))
         if getattr(self, "_paged_prefill_fn", None) is None:
             self._build_serving_fns()
@@ -1384,8 +1392,9 @@ class InferenceEngine:
         args = (self.params, ids_chunk, slot, n_valid, page_table,
                 lengths, pools, ad)
         if self._comm_capture is not None:   # label cost only when armed
+            rows, chunk = np.shape(ids_chunk)
             self._capture_comm_sig(
-                "prefill", f"prefill[chunk={np.shape(ids_chunk)[1]}]",
+                "prefill", f"prefill[rows={rows},chunk={chunk}]",
                 "_paged_prefill_fn", args)
         with self._serving_scope():
             return self._dispatch("prefill", self._paged_prefill_fn,
@@ -1406,9 +1415,10 @@ class InferenceEngine:
 
     def prefill_sequence_parallel(self, ids_chunk, slot, n_valid,
                                   page_table, lengths, pools):
-        """Sequence-parallel twin of :meth:`prefill_into_slots`: same
-        arguments, same ``(boundary logits [vocab], new pools)`` return,
-        same paged landing — but ``ids_chunk`` stages SHARDED over the
+        """Sequence-parallel twin of :meth:`prefill_into_slots` for ONE
+        row: same arguments, same ``(boundary logits [1, vocab], new
+        pools)`` return, same paged landing — but ``ids_chunk`` ([1,
+        chunk]) stages SHARDED over the
         sequence mesh axis, the per-token pipeline (embedding, rotary,
         MLP) runs 1/P-sized per device under GSPMD, and the chunk's
         attention runs through the Ulysses all-to-all (or ring
@@ -1426,6 +1436,8 @@ class InferenceEngine:
         assert chunk % plan.size == 0, \
             (f"chunk length {chunk} must be a multiple of the "
              f"'{plan.axis}' axis size {plan.size}")
+        slot = np.asarray(slot, np.int32).reshape(1)
+        n_valid = np.asarray(n_valid, np.int32).reshape(1)
         shd = self._serving_shardings(num_slots=int(np.shape(lengths)[0]))
         if getattr(self, "_paged_prefill_sp_fn", None) is None:
             self._build_serving_fns()
@@ -1795,6 +1807,14 @@ class InferenceEngine:
                               top_p)
         out = [int(t) for t in np.asarray(jax.device_get(toks))]
         return out[0] if single else out
+
+    def serving_prefill_compile_count(self):
+        """Compiled signatures behind prefill_into_slots — bounded by
+        the scheduler's prefill row-bucket set (one per distinct row
+        count), never by request churn: slots / n_valid / start
+        offsets are traced data, the row count is the only shape that
+        varies."""
+        return jit_cache_size(getattr(self, "_paged_prefill_fn", None))
 
     def serving_seq_prefill_compile_count(self):
         """Compiled signatures behind prefill_sequence_parallel —

@@ -168,10 +168,12 @@ class SelfAttention(nn.Module):
                 alibi = (alibi_slopes(cfg.num_heads)[None, :, None, None]
                          * k_pos[None, None, None, :])
             if "slot" in cache:
-                # chunked prefill into ONE slot: b == 1, l == chunk;
-                # rows past n_valid are padding — their K/V writes drop
+                # chunked prefill, one row per prefilling slot: row r
+                # carries the next chunk of slot[r] (b == rows, l ==
+                # chunk).  Columns past n_valid[r] are padding (a
+                # padding ROW has n_valid == 0) — their K/V writes drop
                 # (out-of-bounds page id) and their outputs are unused.
-                # The chunk starts at lengths[slot], which a prefix-
+                # Row r starts at lengths[slot[r]], which a prefix-
                 # cache hit seeds to the cached boundary (not 0, not
                 # page-aligned): writes only touch positions >= it, so
                 # shared read-only pages below the boundary stay
@@ -179,44 +181,43 @@ class SelfAttention(nn.Module):
                 # the copy-on-write tail page's stale region harmless
                 # (every stale position is either overwritten first or
                 # masked out by k_pos <= position)
-                slot = cache["slot"]
-                pos = positions[0]                       # [l]
-                valid = jnp.arange(l) < cache["n_valid"]
-                page_ids = jnp.where(valid, pt[slot, pos // ps], num_pages)
+                slot = cache["slot"]                     # [rows]
+                pos = positions                          # [rows, l]
+                valid = jnp.arange(l)[None, :] < cache["n_valid"][:, None]
+                page_ids = jnp.where(valid, pt[slot[:, None], pos // ps],
+                                     num_pages)
                 # write through the (possibly int8/fp8-quantized) pool:
                 # quantized pools carry parallel per-row scale pools
                 # that the same masked page ids update atomically
                 # (ops/quant/kv.py); float pools take the byte-identical
                 # legacy path
-                pools_out = paged_write(cache, page_ids, pos % ps,
-                                        k[0], v[0])
+                pools_out = paged_write(cache, page_ids, pos % ps, k, v)
+                k_slot, v_slot = paged_gather(pools_out, pt[slot], q.dtype)
                 seq_ax = cache.get("seq_axis")
                 if seq_ax is not None:
                     # sequence-parallel prefill (static trace-time
                     # marker — the engine's seq-parallel closure builds
-                    # the cache with it): the paged_write above already
-                    # landed the chunk's KV — with ids sequence-sharded,
-                    # GSPMD all-gathers k/v over the axis for the pool
-                    # scatter, the collective the comm ledger prices —
-                    # and attention runs distributed over the axis
-                    # against the pool gather.  Pages in the pool are
-                    # identical to the chunked path's, so decode/COW/
-                    # donation/handoff downstream never notice.
+                    # the cache with it; one row): the paged_write
+                    # above already landed the chunk's KV — with ids
+                    # sequence-sharded, GSPMD all-gathers k/v over the
+                    # axis for the pool scatter, the collective the
+                    # comm ledger prices — and attention runs
+                    # distributed over the axis against the pool
+                    # gather.  Pages in the pool are identical to the
+                    # chunked path's, so decode/COW/donation/handoff
+                    # downstream never notice.
+                    assert b == 1, "sequence-parallel prefill is one row"
                     assert alibi is None, \
                         "sequence-parallel prefill does not support alibi"
                     from deepspeed_tpu import comm as dist
                     from deepspeed_tpu.sequence.prefill import (
                         paged_prefill_attention)
-                    k_pref, v_pref = paged_gather(pools_out,
-                                                  pt[slot][None], q.dtype)
                     out = paged_prefill_attention(
-                        q, k, v, k_pref, v_pref, positions[0, 0],
+                        q, k, v, k_slot, v_slot, positions[0, 0],
                         dist.get_mesh(), axis=seq_ax,
                         impl=cache["seq_impl"])
                 else:
-                    k_slot, v_slot = paged_gather(pools_out, pt[slot][None],
-                                                  q.dtype)
-                    mask = k_pos[None, None, :] <= positions[:, :, None]
+                    mask = k_pos[None, None, :] <= pos[:, :, None]
                     bias = jnp.where(mask, 0.0,
                                      jnp.finfo(jnp.float32).min)[:, None]
                     if alibi is not None:
@@ -498,9 +499,9 @@ class GPT2(nn.Module):
         if positions is None:
             if paged:
                 lens = cache["lengths"]
-                if "slot" in cache:      # chunked prefill (b == 1)
-                    positions = (lens[cache["slot"]] +
-                                 jnp.arange(l))[None, :]
+                if "slot" in cache:      # chunked prefill (row per slot)
+                    positions = lens[cache["slot"]][:, None] + \
+                        jnp.arange(l)[None, :]
                 elif "widths" in cache:  # teacher-forced verify (l == K+1)
                     positions = lens[:, None] + jnp.arange(l)[None, :]
                 else:                    # continuous-batch decode (l == 1)
@@ -607,10 +608,12 @@ class GPT2(nn.Module):
                 new_layer_caches.append(new_c)
 
         if paged and "slot" in cache:
-            # chunked prefill consumes ONLY the boundary row — skip the
-            # full-vocab head for the chunk's other positions (~30% of a
-            # prefill step at gpt2-small shapes)
-            x = lax.dynamic_slice_in_dim(x, cache["n_valid"] - 1, 1, axis=1)
+            # chunked prefill consumes ONLY each row's boundary position
+            # — skip the full-vocab head for the chunk's other positions
+            # (~30% of a prefill step at gpt2-small shapes)
+            x = jnp.take_along_axis(
+                x, jnp.maximum(cache["n_valid"] - 1, 0)[:, None, None],
+                axis=1)
         logits = _head_logits(x, cfg, wte_v=wte_v, dense_ctor=_dense)
         if paged:
             if "slot" in cache:

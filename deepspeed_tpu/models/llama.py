@@ -53,7 +53,8 @@ class RMSNorm(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.with_partitioning(
+        from deepspeed_tpu.ops.quant.qdense import shaped_param
+        scale = shaped_param(self, "scale", nn.with_partitioning(
             nn.initializers.ones_init(), ("embed",)), (x.shape[-1],),
             jnp.float32)
         scale = scale.value if hasattr(scale, "value") else scale
@@ -119,48 +120,51 @@ class LlamaAttention(nn.Module):
             num_pages, ps = k_pages.shape[0], k_pages.shape[1]
             pt = cache["page_table"]
             max_len = pt.shape[1] * ps
-            if "slot" in cache:          # chunked prefill (b == 1)
-                # the chunk starts at lengths[slot] — a prefix-cache
-                # hit seeds it to the cached (possibly mid-page)
-                # boundary: rotary offsets follow the positions array,
-                # writes never touch shared read-only pages below the
+            if "slot" in cache:
+                # chunked prefill, one row per prefilling slot: row r
+                # carries the next chunk of slot[r] (b == rows, l ==
+                # chunk).  Columns past n_valid[r] are padding (a
+                # padding ROW has n_valid == 0): their K/V writes drop
+                # (out-of-bounds page id) and their outputs are unused.
+                # Row r starts at lengths[slot[r]] — a prefix-cache hit
+                # seeds it to the cached (possibly mid-page) boundary:
+                # rotary offsets follow the positions array, writes
+                # never touch shared read-only pages below the
                 # boundary, and the copy-on-write tail page's stale
                 # region is overwritten-before-gather or masked.
                 # paged_write quantizes to int8/fp8 pools (with parallel
                 # per-row scale pools) when the cache carries them;
                 # float pools take the byte-identical legacy path
-                slot = cache["slot"]
-                pos = positions[0]
-                valid = jnp.arange(l) < cache["n_valid"]
-                page_ids = jnp.where(valid, pt[slot, pos // ps], num_pages)
-                pools_out = paged_write(cache, page_ids, pos % ps,
-                                        k[0], v[0])
+                slot = cache["slot"]                     # [rows]
+                pos = positions                          # [rows, l]
+                valid = jnp.arange(l)[None, :] < cache["n_valid"][:, None]
+                page_ids = jnp.where(valid, pt[slot[:, None], pos // ps],
+                                     num_pages)
+                pools_out = paged_write(cache, page_ids, pos % ps, k, v)
+                k_slot, v_slot = paged_gather(pools_out, pt[slot], q.dtype)
                 seq_ax = cache.get("seq_axis")
                 if seq_ax is not None:
                     # sequence-parallel prefill (static trace-time
-                    # marker, same contract as models/gpt2.py): the
-                    # write above already landed the chunk's KV in the
-                    # standard pool; attention runs distributed over
+                    # marker, same contract as models/gpt2.py; one row):
+                    # the write above already landed the chunk's KV in
+                    # the standard pool; attention runs distributed over
                     # the sequence axis against the pool gather.  The
                     # distributed transports take full-head k/v, so GQA
                     # pools expand to h heads HERE only — the pool
                     # itself stays grouped
+                    assert b == 1, "sequence-parallel prefill is one row"
                     from deepspeed_tpu import comm as dist
                     from deepspeed_tpu.sequence.prefill import (
                         paged_prefill_attention)
-                    k_pref, v_pref = paged_gather(pools_out,
-                                                  pt[slot][None], q.dtype)
                     rep = h // kv_h
                     out = paged_prefill_attention(
                         q, _repeat_kv(k, rep), _repeat_kv(v, rep),
-                        _repeat_kv(k_pref, rep), _repeat_kv(v_pref, rep),
+                        _repeat_kv(k_slot, rep), _repeat_kv(v_slot, rep),
                         positions[0, 0], dist.get_mesh(), axis=seq_ax,
                         impl=cache["seq_impl"])
                 else:
-                    k_slot, v_slot = paged_gather(pools_out, pt[slot][None],
-                                                  q.dtype)
                     k_pos = jnp.arange(max_len)
-                    mask = k_pos[None, None, :] <= positions[:, :, None]
+                    mask = k_pos[None, None, :] <= pos[:, :, None]
                     bias = jnp.where(mask, 0.0,
                                      jnp.finfo(jnp.float32).min)[:, None]
                     out = decode_attention(q, k_slot, v_slot, bias=bias)
@@ -319,9 +323,9 @@ class Llama(nn.Module):
         if positions is None:
             if paged:
                 lens = cache["lengths"]
-                if "slot" in cache:      # chunked prefill (b == 1)
-                    positions = (lens[cache["slot"]] +
-                                 jnp.arange(l))[None, :]
+                if "slot" in cache:      # chunked prefill (row per slot)
+                    positions = lens[cache["slot"]][:, None] + \
+                        jnp.arange(l)[None, :]
                 elif "widths" in cache:  # teacher-forced verify (l == K+1)
                     positions = lens[:, None] + jnp.arange(l)[None, :]
                 else:                    # continuous-batch decode (l == 1)
@@ -362,9 +366,11 @@ class Llama(nn.Module):
             new_layer_caches.append(new_c)
 
         if paged and "slot" in cache:
-            # chunked prefill consumes ONLY the boundary row — skip the
-            # full-vocab head for the chunk's other positions
-            x = lax.dynamic_slice_in_dim(x, cache["n_valid"] - 1, 1, axis=1)
+            # chunked prefill consumes ONLY each row's boundary position
+            # — skip the full-vocab head for the chunk's other positions
+            x = jnp.take_along_axis(
+                x, jnp.maximum(cache["n_valid"] - 1, 0)[:, None, None],
+                axis=1)
         x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x)
         if cfg.tie_embeddings:
             logits = jnp.einsum("ble,ve->blv", x, embed_v.astype(cfg.dtype))

@@ -39,13 +39,14 @@ import jax.numpy as jnp
 def adapter_rows(adapters, cache):
     """Per-batch-row adapter ids for the current paged-cache marker.
 
-    Chunked prefill runs b == 1 for ONE slot (the ``slot`` marker), so
-    the row id is that slot's entry; decode (l == 1) and teacher-forced
-    verify (``widths`` marker) run b == num_slots with one row per
-    slot, so the ids array maps through unchanged."""
+    Chunked prefill runs one row per prefilling slot (the ``slot``
+    marker is int32 [rows]), so row r's id is ``ids[slot[r]]``; decode
+    (l == 1) and teacher-forced verify (``widths`` marker) run b ==
+    num_slots with one row per slot, so the ids array maps through
+    unchanged."""
     ids = adapters["ids"]
     if "slot" in cache:
-        return ids[cache["slot"]][None]
+        return ids[cache["slot"]]
     return ids
 
 

@@ -130,6 +130,30 @@ def _repeat_kv(x, n_rep):
         .reshape(b, l, h * n_rep, d)
 
 
+def _gqa_reference(q, k, v, bias, scale):
+    """``mha_reference`` over a GROUPED cache, without expanding it:
+    query head ``kv * g + i`` attends kv head ``kv`` (the ``_repeat_kv``
+    grouping), so the products, the fp32 softmax statistics and the
+    output are those of ``mha_reference(q, _repeat_kv(k), _repeat_kv(v))``
+    — but K/V are read once per kv head instead of being materialized
+    ``g`` times.  A batched multi-token prefill over a slot's whole
+    capacity spent more device time writing that expansion than in its
+    matmuls.  ``bias`` is 4-D, broadcastable to [b, h, l, max_len]."""
+    b, l, h, d = q.shape
+    kv_h = k.shape[2]
+    g = h // kv_h
+    logits = jnp.einsum("bqhgd,bkhd->bhgqk", q.reshape(b, l, kv_h, g, d), k,
+                        preferred_element_type=jnp.float32) * scale
+    bias = bias.astype(jnp.float32)
+    bias = bias[:, :, None] if bias.shape[1] == 1 else \
+        bias.reshape(bias.shape[0], kv_h, g, *bias.shape[2:])
+    logits = logits + bias
+    weights = jnp.exp(logits - logits.max(axis=-1, keepdims=True))
+    weights = weights / weights.sum(axis=-1, keepdims=True)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", weights.astype(v.dtype), v)
+    return out.reshape(b, l, h, d).astype(q.dtype)
+
+
 def _multichip_mesh():
     """True when the trace-time serving mesh spans more than one device
     on the ``model``/``data`` axes — AND we are not already inside a
@@ -591,7 +615,7 @@ def decode_attention(q, k_cache, v_cache, *, bias, scale=None,
         return _decode_pallas(q, k_cache, v_cache, bias_full, scale=scale,
                               block_k=block_k, interpret=interpret)
 
-    k_full = _repeat_kv(k_cache, h // kv_h)
-    v_full = _repeat_kv(v_cache, h // kv_h)
-    return mha_reference(q, k_full, v_full, causal=False, bias=bias,
-                         scale=scale)
+    if h == kv_h:
+        return mha_reference(q, k_cache, v_cache, causal=False, bias=bias,
+                             scale=scale)
+    return _gqa_reference(q, k_cache, v_cache, bias, scale)

@@ -29,6 +29,7 @@ from typing import Any, Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from flax import errors as flax_errors
 
 from deepspeed_tpu.ops.quant.quantizer import QTensor
 
@@ -62,6 +63,26 @@ def quant_matmul(x, qt, impl="auto"):
     return y.reshape(*lead, y.shape[-1])
 
 
+def shaped_param(module, name, init_fn, shape, dtype):
+    """``module.param(name, init_fn, shape, dtype)`` for an initializer
+    whose output has the shape it is given, with the apply-time shape
+    check made directly.  For a parameter that already exists flax
+    re-traces ``init_fn`` under ``jax.eval_shape`` just to learn the
+    shape it would have — a third of the Python trace time of a
+    16-layer Llama program, paid again for every compiled signature
+    (each prefill row bucket and decode horizon bucket) at every
+    start-up, which no compile cache holds."""
+    if not module.has_variable("params", name):
+        return module.param(name, init_fn, shape, dtype)
+    value = nn.meta.unbox(module.get_variable("params", name))
+    # a QTensor's first leaf is its int8 payload, kernel-shaped
+    got = jnp.shape(jax.tree_util.tree_leaves(value)[0])
+    if got != shape:
+        raise flax_errors.ScopeParamShapeError(
+            name, module.scope.path_text, got, shape)
+    return value
+
+
 class QDense(nn.Module):
     """Drop-in ``nn.Dense`` with a QTensor fast path (see module doc)."""
 
@@ -75,11 +96,11 @@ class QDense(nn.Module):
 
     @nn.compact
     def __call__(self, inputs):
-        kernel = self.param("kernel", self.kernel_init,
-                            (jnp.shape(inputs)[-1], self.features),
-                            self.param_dtype)
-        bias = self.param("bias", self.bias_init, (self.features,),
-                          self.param_dtype) if self.use_bias else None
+        kernel = shaped_param(self, "kernel", self.kernel_init,
+                              (jnp.shape(inputs)[-1], self.features),
+                              self.param_dtype)
+        bias = shaped_param(self, "bias", self.bias_init, (self.features,),
+                            self.param_dtype) if self.use_bias else None
         if isinstance(kernel, QTensor):
             x = inputs.astype(self.dtype or kernel.dtype)
             y = quant_matmul(x, kernel, impl=self.quant_impl)

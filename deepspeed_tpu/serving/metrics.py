@@ -64,6 +64,10 @@ class ServingMetrics:
         self.handoff_transport_ms = 0.0  # wall ms moving chains
         self.handoff_aborted = 0       # transfers torn down mid-chain
         # sequence-parallel prefill (long-context routing)
+        self.prefill_dispatches = 0    # shared [rows, chunk] dispatches
+        self.prefill_rows = 0          # slot chunks those carried
+        self.prefill_padded_rows = 0   # rows dispatched incl. bucket pad
+        self.prefill_tokens = 0        # prompt tokens those landed
         self.seq_prefill_routed = 0    # prompts routed onto the sp path
         self.seq_prefill_chunks = 0    # sp chunk dispatches
         self.seq_prefill_tokens = 0    # prompt tokens landed via sp chunks
@@ -150,6 +154,29 @@ class ServingMetrics:
                 ("serving/prefix_cache/prefill_tokens_saved",
                  self.prefill_tokens_saved, step),
             ])
+
+    def record_prefill_dispatch(self, step, *, rows, padded_rows, tokens):
+        """One shared prefill dispatch carried the next chunk of
+        ``rows`` prefilling slots (``tokens`` prompt tokens) in a
+        ``padded_rows``-row bucket."""
+        self.prefill_dispatches += 1
+        self.prefill_rows += rows
+        self.prefill_padded_rows += padded_rows
+        self.prefill_tokens += tokens
+        self._write([("serving/prefill/rows", rows, step),
+                     ("serving/prefill/padded_rows", padded_rows, step),
+                     ("serving/prefill/tokens", tokens, step)])
+
+    def prefill_rows_per_dispatch(self):
+        """Mean prefilling slots per shared prefill dispatch — how often
+        the batching engages (near 1: the traffic bypasses it)."""
+        return self.prefill_rows / self.prefill_dispatches \
+            if self.prefill_dispatches else 0.0
+
+    def prefill_pad_share(self):
+        """Share of dispatched prefill rows that were bucket padding."""
+        return 1.0 - self.prefill_rows / self.prefill_padded_rows \
+            if self.prefill_padded_rows else 0.0
 
     def record_seq_prefill_route(self, step, prompt_tokens, reserved_pages):
         """One admission routed onto the sequence-parallel prefill path:
@@ -515,6 +542,13 @@ class ServingMetrics:
             "handoff_chunks": self.handoff_chunks,
             "handoff_transport_ms": round(self.handoff_transport_ms, 3),
             "handoff_aborted": self.handoff_aborted,
+            "prefill_dispatches": self.prefill_dispatches,
+            "prefill_rows": self.prefill_rows,
+            "prefill_padded_rows": self.prefill_padded_rows,
+            "prefill_tokens": self.prefill_tokens,
+            "prefill_rows_per_dispatch":
+            round(self.prefill_rows_per_dispatch(), 3),
+            "prefill_pad_share": round(self.prefill_pad_share(), 4),
             "seq_prefill_routed": self.seq_prefill_routed,
             "seq_prefill_chunks": self.seq_prefill_chunks,
             "seq_prefill_tokens": self.seq_prefill_tokens,

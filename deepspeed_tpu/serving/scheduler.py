@@ -11,7 +11,8 @@ granularity.  Each ``step()`` is one scheduler iteration:
      cannot possibly meet sheds it NOW instead of wasting pool pages),
   3. advance every admitted-but-unprefilled slot by ONE prompt chunk
      (chunked prefill — long prompts never stall running decoders for
-     more than a chunk),
+     more than a chunk), all of them in ONE ``[rows, prefill_chunk]``
+     dispatch (rows padded to a power-of-four bucket),
   4. run ONE fused multi-step decode ("horizon") over all running
      slots: up to ``decode_horizon_steps`` tokens per slot in a single
      ``decode_multi`` dispatch, with token feedback, EOS detection and
@@ -155,6 +156,26 @@ CANCELLED, FAILED, SHED = "cancelled", "failed", "shed"
 # THIS scheduler, live for the cluster request it belongs to
 HANDOFF = "handoff"
 TERMINAL = (FINISHED, CANCELLED, FAILED, SHED, HANDOFF)
+
+
+def _geometric_buckets(lo, hi, factor=2):
+    """``lo, factor*lo, factor**2*lo, ... , hi`` (``hi`` itself always
+    included): the small constant set a traced size is quantized to, so
+    compiled signatures stay bounded by the set (horizons, spec K,
+    prefill rows, sequence-parallel chunks)."""
+    buckets, b = [lo], lo
+    while b < hi:
+        b = min(b * factor, hi)
+        buckets.append(b)
+    return buckets
+
+
+def _bucket_ceil(buckets, n):
+    """Smallest bucket covering ``n`` (the largest when none does)."""
+    for b in buckets:
+        if b >= n:
+            return b
+    return buckets[-1]
 
 
 class _PoolsRef:
@@ -488,11 +509,18 @@ class ServingScheduler:
         # signatures (decode_horizon_steps=1 recovers the legacy
         # one-token-per-step loop exactly)
         self.decode_horizon_steps = max(1, int(decode_horizon_steps))
-        buckets, b = {1}, 1
-        while b < self.decode_horizon_steps:
-            b = min(b * 2, self.decode_horizon_steps)
-            buckets.add(b)
-        self.horizon_buckets = sorted(buckets)
+        self.horizon_buckets = _geometric_buckets(
+            1, self.decode_horizon_steps)
+        # batched prefill: every prefilling slot's next chunk rides ONE
+        # [rows, prefill_chunk] dispatch per boundary step; the row
+        # count pads up to a power-of-FOUR bucket (the last is
+        # num_slots), so the compile count is pinned by the bucket set
+        # exactly like decode horizons.  Four, not two: every bucket is
+        # one more trace + load of the whole model at start-up (~1.4 s
+        # each for a 16-layer 7B on a v5e host, PERF.md PR 28), which
+        # cost more than the padding rows of the coarser set
+        self.prefill_row_buckets = _geometric_buckets(
+            1, self.num_slots, factor=4)
         # ---- sequence-parallel prefill routing (long-context path) ----
         # prompts with >= seq_parallel_threshold tokens left to prefill
         # route through engine.prefill_sequence_parallel: the chunk
@@ -513,12 +541,8 @@ class ServingScheduler:
             plan = getattr(engine, "seq_parallel_plan", lambda: None)()
             if plan is not None and plan.usable:
                 self.seq_plan = plan
-                buckets, b = {plan.size}, plan.size
-                top = self.prefill_chunk * plan.size
-                while b < top:
-                    b = min(b * 2, top)
-                    buckets.add(b)
-                self.sp_chunk_buckets = sorted(buckets)
+                self.sp_chunk_buckets = _geometric_buckets(
+                    plan.size, self.prefill_chunk * plan.size)
             else:
                 self._sp_degrade_reason = None if plan is None \
                     else plan.reason
@@ -546,11 +570,7 @@ class ServingScheduler:
         # sampled mode disables spec rather than silently changing the
         # sampled stream)
         self.spec_k = max(1, int(spec_k))
-        buckets, b = {1}, 1
-        while b < self.spec_k:
-            b = min(b * 2, self.spec_k)
-            buckets.add(b)
-        self.spec_k_buckets = sorted(buckets)
+        self.spec_k_buckets = _geometric_buckets(1, self.spec_k)
         self._spec = None
         self.spec_mode = "off"
         greedy = not do_sample or not temperature
@@ -1608,97 +1628,156 @@ class ServingScheduler:
                 args={"tokens": pending, "reserved_pages": need,
                       "impl": self.seq_plan.impl})
 
-    def _sp_chunk(self, pending):
-        """Smallest sp chunk bucket covering ``pending`` tokens (the
-        largest bucket when none does) — same quantization idea as the
-        decode-horizon buckets, pinning one jit signature per bucket."""
-        for b in self.sp_chunk_buckets:
-            if b >= pending:
-                return b
-        return self.sp_chunk_buckets[-1]
-
     def _prefill(self):
-        """One prompt chunk per prefilling slot.  The per-slot body is
-        attributable to ONE request, so containment wraps it: a
-        per-request failure frees the slot and moves on.  Slots
-        finishing their prompt this step sample their first token in
-        ONE batched device call instead of one tiny dispatch each."""
-        finishing = []
+        """One prompt chunk per prefilling slot, all in ONE ``[rows,
+        prefill_chunk]`` dispatch per boundary step (a step reads the
+        weights once for prefill, not once per slot).  Host preparation
+        (page growth, chunk slicing) is attributable to ONE request, so
+        containment wraps it per slot: a per-request failure frees the
+        slot and moves on.  The dispatch itself is shared work like the
+        decode horizon: an error there is not attributable to one
+        request and surfaces loudly.  Requests routed to
+        sequence-parallel prefill keep their own one-row dispatch of a
+        wide sharded chunk.  Slots finishing their prompt this step
+        sample their first token in ONE batched device call over the
+        dispatch's whole logits block."""
+        rows = []        # (slot, req, chunk) riding the shared dispatch
+        blocks = []      # (logits [n, vocab], [(row, slot, req)] finishing)
         for slot in range(self.num_slots):
             req = self.slot_req[slot]
             if req is None or req.state != PREFILL:
                 continue
             try:
-                sp = getattr(req, "seq_parallel", False) \
-                    and self.seq_plan is not None
-                width = self._sp_chunk(len(req.prompt) - req.prefill_pos) \
-                    if sp else self.prefill_chunk
+                if getattr(req, "seq_parallel", False) \
+                        and self.seq_plan is not None:
+                    logits = self._prefill_seq_parallel(slot, req)
+                    if logits is not None:
+                        blocks.append((logits, [(0, slot, req)]))
+                    continue
                 chunk = req.prompt[req.prefill_pos:
-                                   req.prefill_pos + width]
-                n_valid = len(chunk)
-                if not self._grow_or_evict(slot, req.prefill_pos + n_valid):
-                    continue      # self-preempted: back in the queue
-                ids = np.zeros((1, width), np.int32)
-                ids[0, :n_valid] = chunk
-                with self.tracer.span(
-                        "prefill_chunk", track=slot, rid=req.trace_rid,
-                        args={"tokens": n_valid, "pos": req.prefill_pos,
-                              "seq_parallel": sp}
-                        if self.tracer.enabled else None):
-                    if sp:
-                        logits, self.pools = \
-                            self.engine.prefill_sequence_parallel(
-                                ids, slot, n_valid, self.kv.table,
-                                self.lengths, self.pools)
-                    else:
-                        a_ids, a_pack = self._adapter_args()
-                        logits, self.pools = \
-                            self.engine.prefill_into_slots(
-                                ids, slot, n_valid, self.kv.table,
-                                self.lengths, self.pools,
-                                adapter_ids=a_ids, adapters=a_pack)
-                if sp:
-                    self.metrics.record_seq_prefill_chunk(self.step_idx,
-                                                          n_valid)
-                self.lengths[slot] += n_valid
-                req.prefill_pos += n_valid
-                if req.prefill_pos == len(req.prompt):
-                    finishing.append((slot, req, logits))
+                                   req.prefill_pos + self.prefill_chunk]
+                if self._grow_or_evict(slot, req.prefill_pos + len(chunk)):
+                    rows.append((slot, req, chunk))
+                # else self-preempted: back in the queue
             except PagePoolExhausted as e:
                 self._close_slot(slot, SHED, f"page capacity: {e}")
             except Exception as e:   # containment: fail one, not all
                 self._close_slot(slot, FAILED,
                                  f"{type(e).__name__}: {e}")
-        # a later slot's growth may have evicted an earlier finishing
-        # slot — drop stale entries BEFORE the batched sample (the
-        # policy-table gathers index by slot, so a vacated slot must
-        # not reach them)
-        finishing = [(s, r, lg) for s, r, lg in finishing
+        # a later row's growth may have evicted an earlier row's slot:
+        # its pages are gone (maybe already another row's), so it must
+        # not ride the dispatch
+        rows = [(s, r, c) for s, r, c in rows
+                if self.slot_req[s] is r and r.state == PREFILL]
+        if rows:
+            logits = self._prefill_dispatch(rows)
+            done = []
+            for i, (slot, req, chunk) in enumerate(rows):
+                self.lengths[slot] += len(chunk)
+                req.prefill_pos += len(chunk)
+                if req.prefill_pos == len(req.prompt):
+                    done.append((i, slot, req))
+            if done:
+                blocks.append((logits, done))
+        for logits, done in blocks:
+            self._sample_boundary(logits, done)
+
+    def _prefill_dispatch(self, rows):
+        """Pack ``rows`` into the smallest row bucket and launch the
+        shared prefill dispatch; returns its [bucket, vocab] boundary
+        logits (row i belongs to ``rows[i]``).  Padding rows carry
+        ``n_valid == 0`` and a live slot id: they write nothing."""
+        padded = _bucket_ceil(self.prefill_row_buckets, len(rows))
+        ids = np.zeros((padded, self.prefill_chunk), np.int32)
+        slots = np.full(padded, rows[0][0], np.int32)
+        n_valid = np.zeros(padded, np.int32)
+        for i, (slot, _, chunk) in enumerate(rows):
+            ids[i, :len(chunk)] = chunk
+            slots[i] = slot
+            n_valid[i] = len(chunk)
+        tokens = int(n_valid.sum())
+        with self.tracer.span(
+                "prefill_chunk", cat="dispatch",
+                args={"rows": len(rows), "padded_rows": padded,
+                      "tokens": tokens}
+                if self.tracer.enabled else None):
+            a_ids, a_pack = self._adapter_args()
+            logits, self.pools = self.engine.prefill_into_slots(
+                ids, slots, n_valid, self.kv.table, self.lengths,
+                self.pools, adapter_ids=a_ids, adapters=a_pack)
+        self.metrics.record_prefill_dispatch(
+            self.step_idx, rows=len(rows), padded_rows=padded,
+            tokens=tokens)
+        return logits
+
+    def _prefill_seq_parallel(self, slot, req):
+        """One wide sequence-sharded chunk of ONE routed request (its
+        own dispatch); returns its [1, vocab] boundary logits when the
+        prompt finished, else None."""
+        width = _bucket_ceil(self.sp_chunk_buckets,
+                             len(req.prompt) - req.prefill_pos)
+        chunk = req.prompt[req.prefill_pos:req.prefill_pos + width]
+        n_valid = len(chunk)
+        if not self._grow_or_evict(slot, req.prefill_pos + n_valid):
+            return None       # self-preempted: back in the queue
+        ids = np.zeros((1, width), np.int32)
+        ids[0, :n_valid] = chunk
+        with self.tracer.span(
+                "prefill_chunk", track=slot, rid=req.trace_rid,
+                args={"tokens": n_valid, "pos": req.prefill_pos,
+                      "seq_parallel": True}
+                if self.tracer.enabled else None):
+            logits, self.pools = self.engine.prefill_sequence_parallel(
+                ids, slot, n_valid, self.kv.table, self.lengths,
+                self.pools)
+        self.metrics.record_seq_prefill_chunk(self.step_idx, n_valid)
+        self.lengths[slot] += n_valid
+        req.prefill_pos += n_valid
+        return logits if req.prefill_pos == len(req.prompt) else None
+
+    def _sample_boundary(self, logits, finishing):
+        """First tokens of the requests whose prompt finished in one
+        prefill dispatch: ``logits`` is the dispatch's whole [n, vocab]
+        block and ``finishing`` lists ``(row, slot, req)``.  The sample
+        runs over EVERY row of the block (one program per row bucket,
+        never per finishing count or row index); non-finishing and
+        padding rows' tokens are dropped on the host."""
+        # a later slot's growth (a sequence-parallel reservation) may
+        # have evicted an earlier finishing slot — drop stale entries
+        # BEFORE the batched sample (the policy-table gathers index by
+        # slot, so a vacated slot must not reach them)
+        finishing = [(i, s, r) for i, s, r in finishing
                      if self.slot_req[s] is r and r.state == PREFILL]
         if not finishing:
             return
         # the batched sample is shared work (like the decode dispatch);
         # emit/callback stays contained per request below
-        rows = [lg for _, _, lg in finishing]
-        if self._batch_needs_policy([s for s, _, _ in finishing]):
+        if self._batch_needs_policy([s for _, s, _ in finishing]):
             # boundary token under the decoding policy: same pipeline,
             # same position-keyed stream as the fused decode (token 0
-            # of the request draws from fold_in(key, sample_offset))
+            # of the request draws from fold_in(key, sample_offset)).
+            # Per-row lanes cover the whole block; a non-finishing row
+            # borrows the first finishing slot's lanes (its draw is
+            # independent of the other rows' and dropped below)
             self._ensure_policy_tables()
-            sl = [s for s, _, _ in finishing]
-            idx = np.array([r.sample_offset + len(r.out_tokens)
-                            for _, r, _ in finishing], np.int32)
+            n = int(np.shape(logits)[0])
+            sl = np.full(n, finishing[0][1], np.int64)
+            idx = np.zeros(n, np.int32)
+            for i, s, r in finishing:
+                sl[i] = s
+                idx[i] = r.sample_offset + len(r.out_tokens)
             toks = self.engine.sample_from_logits_policy(
-                rows, self._samp_keys[sl], idx, self._samp_temps[sl],
+                logits, self._samp_keys[sl], idx, self._samp_temps[sl],
                 self._samp_topk[sl], self._samp_topp[sl],
                 self._samp_rep[sl], self._samp_pres[sl],
                 self._samp_freq[sl], self._tok_counts[sl],
                 self._grammar_masks[sl])
         else:
-            toks = self.engine.sample_from_logits(rows, **self.sampling)
-        for (slot, req, _), tok in zip(finishing, toks):
+            toks = self.engine.sample_from_logits(logits, **self.sampling)
+        for i, slot, req in finishing:
             if self.slot_req[slot] is not req or req.state != PREFILL:
-                continue   # a later slot's growth evicted this one
+                continue   # closed by an earlier row's emit epilogue
+            tok = toks[i]
             try:
                 self._emit(req, tok)
                 self._note_emitted(slot, req, tok)
@@ -2854,6 +2933,17 @@ class ServingScheduler:
             else self.seq_plan.impl,
             "seq_parallel_degrade_reason": self._sp_degrade_reason,
             "sp_chunk_buckets": list(self.sp_chunk_buckets),
+            # batched prefill: one [rows, prefill_chunk] dispatch per
+            # boundary step; rows per dispatch near 1 means the traffic
+            # bypasses the batching, pad share is the bucket padding
+            "prefill_row_buckets": list(self.prefill_row_buckets),
+            "prefill_dispatches": m.prefill_dispatches,
+            "prefill_rows": m.prefill_rows,
+            "prefill_padded_rows": m.prefill_padded_rows,
+            "prefill_tokens": m.prefill_tokens,
+            "prefill_rows_per_dispatch":
+            round(m.prefill_rows_per_dispatch(), 3),
+            "prefill_pad_share": round(m.prefill_pad_share(), 4),
             "prefill_reserve_cap": self.prefill_reserve_cap,
             "seq_prefill_routed": m.seq_prefill_routed,
             "seq_prefill_chunks": m.seq_prefill_chunks,

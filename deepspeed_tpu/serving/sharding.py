@@ -196,7 +196,8 @@ class ServingShardings:
     [num_slots, H|K+1] token/valid blocks AND the [num_slots,
     max_pages] page table (both shard dim 0 over the slots axis),
     ``pool`` the per-layer [num_pages, page_size, kv_heads, head_dim]
-    KV pools, ``logits`` a prefill chunk's [vocab] boundary row."""
+    KV pools, ``logits`` a prefill dispatch's [rows, vocab] boundary
+    logits."""
     mesh: object
     config: ServingShardingConfig
     kv_axis: object
@@ -223,7 +224,7 @@ class ServingShardings:
 
     @property
     def logits(self):
-        return NamedSharding(self.mesh, P(self.vocab_axis))
+        return NamedSharding(self.mesh, P(None, self.vocab_axis))
 
     def describe(self):
         """Logical-axis -> resolved mesh axis map (health()/logs)."""

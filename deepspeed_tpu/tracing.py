@@ -66,6 +66,13 @@ EVENT_TAXONOMY = {
     "serving/ttft_ms": "submit -> first token, per request",
     "serving/token_latency_ms": "inter-token gap, per token",
     "serving/tbt_ms": "time between token bursts (horizon cadence)",
+    # batched prefill (one [rows, prefill_chunk] dispatch per step)
+    "serving/prefill/rows":
+        "prefilling slots one shared prefill dispatch carried",
+    "serving/prefill/padded_rows":
+        "rows of that dispatch including its row-bucket padding",
+    "serving/prefill/tokens":
+        "prompt tokens one shared prefill dispatch landed",
     # fused horizons
     "serving/horizon": "fused decode horizon harvested",
     "serving/horizon_tokens": "tokens delivered by one horizon",

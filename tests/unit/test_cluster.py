@@ -504,6 +504,13 @@ HEALTH_SCHEMA = {
     "seq_parallel_impl": (str, type(None)),
     "seq_parallel_degrade_reason": (str, type(None)),
     "sp_chunk_buckets": (list,),
+    "prefill_row_buckets": (list,),
+    "prefill_dispatches": (int,),
+    "prefill_rows": (int,),
+    "prefill_padded_rows": (int,),
+    "prefill_tokens": (int,),
+    "prefill_rows_per_dispatch": (int, float),
+    "prefill_pad_share": (int, float),
     "prefill_reserve_cap": (int,),
     "seq_prefill_routed": (int,),
     "seq_prefill_chunks": (int,),

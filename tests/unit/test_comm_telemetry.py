@@ -353,7 +353,7 @@ def test_comm_telemetry_off_is_zero_cost_serving(engine):
                 engine.serving_decode_compile_count(),
                 engine.serving_verify_compile_count(),
                 engine.serving_page_copy_compile_count(),
-                jit_cache_size(engine._paged_prefill_fn))
+                engine.serving_prefill_compile_count())
 
     for horizon in (1, 8):
         engine.enable_comm_telemetry(False)
@@ -454,6 +454,10 @@ def test_watchdog_fires_exactly_one_flight_dump(engine, tmp_path):
                              compile_watchdog=wd, tracer=tracer, **CFG)
     for p in prompts:
         sched.submit(p, max_new_tokens=5)
+    sched.run()
+    # warm-up covers the prefill row buckets the steady traffic uses:
+    # two prompts together above, a lone one here
+    sched.submit(prompts[1], max_new_tokens=5)
     sched.run()
     assert fr.dumps == [], "warmup compiles must not dump"
     wd.mark_steady()

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 
 import deepspeed_tpu
 from deepspeed_tpu.models.gpt2 import GPT2, gpt2_tiny
-from deepspeed_tpu.tracing import jit_cache_size
 from deepspeed_tpu.ops.quant.kv import fp8_supported, kv_page_bytes
 from deepspeed_tpu.serving import ServingScheduler
 from deepspeed_tpu.serving.cluster import (ClusterRouter,
@@ -161,12 +160,12 @@ def test_int8_bounded_divergence_and_signature_stability(engine):
         PS, kv_dtype="int8")
 
     c_multi = engine.serving_decode_multi_compile_count()
-    c_prefill = jit_cache_size(engine._paged_prefill_fn)
+    c_prefill = engine.serving_prefill_compile_count()
     _, got2 = _serve(engine, prompts, max_new, kv_dtype="int8",
                      audit_every=1)
     assert got2 == got                     # deterministic quantization
     assert engine.serving_decode_multi_compile_count() == c_multi
-    assert jit_cache_size(engine._paged_prefill_fn) == c_prefill
+    assert engine.serving_prefill_compile_count() == c_prefill
 
 
 @pytest.mark.skipif(not fp8_supported(), reason="jax build lacks "

@@ -321,7 +321,9 @@ def test_completed_history_is_bounded(gpt2_engine):
 
 
 def test_single_jit_signature_across_churn(gpt2_engine):
-    """The no-per-step-recompilation guarantee: one prefill compile and
+    """The no-per-step-recompilation guarantee: at most one prefill
+    compile PER ROW BUCKET (the step's prefilling slots ride one
+    dispatch, padded to a power-of-four row count) and
     at most one fused-decode compile PER HORIZON BUCKET regardless of
     request churn, lengths, joins and retirements. The scheduler here
     uses the SAME (slots, pages, page_size, chunk) constants as every
@@ -338,7 +340,9 @@ def test_single_jit_signature_across_churn(gpt2_engine):
     sched.run()
     assert 1 <= gpt2_engine.serving_decode_multi_compile_count() <= \
         len(sched.horizon_buckets)
-    assert gpt2_engine._paged_prefill_fn._cache_size() == 1
+    assert sched.prefill_row_buckets == [1, 3]
+    assert 1 <= gpt2_engine.serving_prefill_compile_count() <= \
+        len(sched.prefill_row_buckets)
 
 
 # ------------------------------------------------------ paged attention

@@ -149,7 +149,8 @@ def test_cancel_mid_horizon_honored_at_next_boundary(engine):
 def test_decode_compile_count_bounded_by_horizon_buckets(engine):
     """Slot churn, mixed lengths, joins and retirements never add jit
     signatures: fused-decode compiles stay <= the horizon bucket set
-    (for this module's single serving config), prefill stays at one."""
+    (for this module's single serving config), prefill compiles stay
+    <= the row bucket set."""
     rng = np.random.default_rng(2)
     sched = ServingScheduler(engine, decode_horizon_steps=8, **CFG)
     for n, m in [(5, 4), (9, 9), (5, 2), (9, 7), (5, 11), (9, 3)]:
@@ -159,7 +160,8 @@ def test_decode_compile_count_bounded_by_horizon_buckets(engine):
     assert sched.horizon_buckets == [1, 2, 4, 8]
     assert 1 <= engine.serving_decode_multi_compile_count() <= \
         len(sched.horizon_buckets)
-    assert engine._paged_prefill_fn._cache_size() == 1
+    assert 1 <= engine.serving_prefill_compile_count() <= \
+        len(sched.prefill_row_buckets)
     # the fused path IS the decode path: the single-step primitive never
     # compiles in serving anymore
     assert engine.serving_decode_compile_count() == 0

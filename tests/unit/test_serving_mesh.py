@@ -128,10 +128,12 @@ def test_mesh_serving_token_exact(engines, workload, model_ax, data_ax):
         assert all("model" in s for s in specs), specs
 
     # mesh churn adds no per-step recompiles: one fused-decode
-    # signature per horizon bucket actually used, prefill stays at one
+    # signature per horizon bucket actually used, one prefill
+    # signature per row bucket actually used
     assert 1 <= eng.serving_decode_multi_compile_count() <= \
         len(sched.horizon_buckets)
-    assert eng._paged_prefill_fn._cache_size() == 1
+    assert 1 <= eng.serving_prefill_compile_count() <= \
+        len(sched.prefill_row_buckets)
 
     # operators can see the topology: health() reports the shape and
     # the per-device KV-pool footprint
@@ -391,7 +393,8 @@ def _run_kernel_oracle(eng, oracle_engine, kv_dtype="float32"):
 
     assert 1 <= eng.serving_decode_multi_compile_count() <= \
         len(sched.horizon_buckets)
-    assert eng._paged_prefill_fn._cache_size() == 1
+    assert 1 <= eng.serving_prefill_compile_count() <= \
+        len(sched.prefill_row_buckets)
     sched.prefix_cache.evict(10 ** 6)
     assert sched.kv.pool.pages_in_use == 0
     return sched
@@ -680,12 +683,15 @@ def test_tuned_config_rejected_on_foreign_mesh(tmp_path):
 
 
 def test_ds_serve_exits_nonzero_on_failed_rows(tmp_path, monkeypatch):
-    """A ``failed`` row is an exception inside a dispatch that the
-    scheduler contained (on a chip: a kernel the compiler refused) —
-    ``ds_serve`` must not exit like a clean run.  ``finished`` rows
-    exit 0."""
+    """A ``failed`` row is an exception attributable to ONE request
+    that the scheduler contained — ``ds_serve`` must not exit like a
+    clean run.  ``finished`` rows exit 0.  An exception inside the
+    SHARED prefill dispatch (on a chip: a kernel the compiler refused)
+    belongs to no single request and leaves the loop loudly, like a
+    failed decode dispatch."""
     import json as _json
     from deepspeed_tpu.inference.engine import InferenceEngine
+    from deepspeed_tpu.resilience import faults
     ds = _load_ds_serve()
     inp, out = tmp_path / "in.jsonl", tmp_path / "out.jsonl"
     inp.write_text(_json.dumps({"prompt": [1, 2, 3],
@@ -697,9 +703,15 @@ def test_ds_serve_exits_nonzero_on_failed_rows(tmp_path, monkeypatch):
     assert _json.loads(out.read_text().splitlines()[0])["status"] == \
         "finished"
 
+    inj = faults.FaultInjector(seed=0)
+    inj.on("serve.request", nth=1, exc=RuntimeError("callback broke"))
+    with faults.injected(inj):
+        assert ds.main(argv) == 1
+    row = _json.loads(out.read_text().splitlines()[0])
+    assert row["status"] == "failed" and "callback broke" in row["error"]
+
     def refuse(self, *a, **k):
         raise RuntimeError("Mosaic refused the kernel")
     monkeypatch.setattr(InferenceEngine, "prefill_into_slots", refuse)
-    assert ds.main(argv) == 1
-    row = _json.loads(out.read_text().splitlines()[0])
-    assert row["status"] == "failed" and "Mosaic refused" in row["error"]
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        ds.main(argv)

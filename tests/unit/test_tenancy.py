@@ -178,12 +178,12 @@ def test_rank_bucket_warmup_then_zero_extra_signatures(engine):
 
     run(["a0", "a1", "a2", None])            # rank-bucket warmup
     decode0 = engine.serving_decode_multi_compile_count()
-    prefill0 = engine._paged_prefill_fn._cache_size()
+    prefill0 = engine.serving_prefill_compile_count()
     run(["a2", None, "a0", "a1"])            # churned striping
     run([None, None, None, None])            # base-only, store loaded
     assert engine.serving_decode_multi_compile_count() == decode0, \
         "adapter churn compiled a new decode signature"
-    assert engine._paged_prefill_fn._cache_size() == prefill0, \
+    assert engine.serving_prefill_compile_count() == prefill0, \
         "adapter churn compiled a new prefill signature"
 
 
@@ -200,7 +200,7 @@ def test_base_only_byte_identical_with_tenancy_off(engine):
             for p, m in zip(prompts, max_new)]
     got_plain = plain.run()
     decode0 = engine.serving_decode_multi_compile_count()
-    prefill0 = engine._paged_prefill_fn._cache_size()
+    prefill0 = engine.serving_prefill_compile_count()
 
     tenanted = ServingScheduler(
         engine, tenancy=TenantRegistry([TenantConfig("acme")]), **CFG)
@@ -210,7 +210,7 @@ def test_base_only_byte_identical_with_tenancy_off(engine):
     assert [got_t[r.rid] for r in reqs_t] == \
         [got_plain[r.rid] for r in reqs]
     assert engine.serving_decode_multi_compile_count() == decode0
-    assert engine._paged_prefill_fn._cache_size() == prefill0
+    assert engine.serving_prefill_compile_count() == prefill0
     h = tenanted.health()
     assert h["tenancy"] and h["adapters"] == 0
     assert h["tenants"]["acme"]["completed"] == len(prompts)

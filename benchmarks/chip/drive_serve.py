@@ -20,7 +20,6 @@ import time
 import numpy as np
 
 import loadgen
-import reference
 
 # a served token must score within EPS_ULPS bf16 ulps (2**-8 relative)
 # of the float32 reference's maximum at the observed logit scale.
@@ -62,6 +61,12 @@ def build_module(config, **dtypes):
     fields.update(prog.get("extra", {}))
     return load_object(prog["module"])(
         load_object(prog["config"])(**dtypes, **fields))
+
+
+def numeric_items(mapping, prefix=""):
+    """The items of a counters map that are plain numbers."""
+    return {prefix + k: v for k, v in mapping.items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
 
 
 def seed_key(seed):
@@ -323,10 +328,12 @@ def drive(sched, items, prompts, mix, seconds, ctx):
                  if occupancy else None}
 
 
-def check_outputs(engine, config, rows, cap, seed):
+def check_outputs(engine, ref_hidden, ref_logits, ref_args, rows, cap, seed):
     """Teacher-force prompt + served tokens of a seeded sample of
-    finished requests through the plain float32 reference and hold every
-    served token to the eps-argmax rule.  Returns (ok, notes)."""
+    finished requests through the plain float32 reference
+    (``ref_hidden(params, ids, **ref_args)`` -> final hidden states,
+    ``ref_logits(params, rows)`` -> logits of picked rows) and hold
+    every served token to the eps-argmax rule.  Returns (ok, notes)."""
     import jax
     import jax.numpy as jnp
     done = [r for r in rows if r["state"] == "finished" and r["n_out"] > 0]
@@ -346,13 +353,10 @@ def check_outputs(engine, config, rows, cap, seed):
         # logits at position i score token i + 1
         pos[j, :len(t)] = len(p) - 1 + np.arange(len(t))
         valid[j, :len(t)] = True
-    ref = config["reference"]
     with jax.default_matmul_precision("highest"):
-        hidden = getattr(reference, ref["hidden"])(
-            engine.params, jnp.asarray(ids),
-            **{k: config[v] for k, v in ref["args"].items()})
+        hidden = ref_hidden(engine.params, jnp.asarray(ids), **ref_args)
         rows_h = jnp.take_along_axis(hidden, jnp.asarray(pos)[..., None], 1)
-        lg = getattr(reference, ref["logits"])(engine.params, rows_h)
+        lg = ref_logits(engine.params, rows_h)
     served = jnp.take_along_axis(jnp.asarray(ids), jnp.asarray(pos) + 1, 1)
     got = jnp.take_along_axis(lg, served[..., None], -1)[..., 0]
     margin = np.asarray(jnp.max(lg, -1) - got)[valid]
@@ -369,9 +373,11 @@ def check_outputs(engine, config, rows, cap, seed):
 
 def run(ctx):
     config, mix = ctx.config, ctx.traffic
+    ref_hidden, ref_logits = ctx.reference("hidden"), ctx.reference("logits")
     cli, serve = cli_args(ctx.root, config, mix)
     engine = build_engine(config, ctx.seed, cli)
     ctx.memory("weights")
+    ctx.mark("engine_built")
     vocab = config["vocab_size"]
     items = loadgen.make_requests(mix, ctx.seconds)
     if mix["loop"] == "open":
@@ -390,6 +396,7 @@ def run(ctx):
     # a fresh scheduler, so its counters hold the window and nothing else
     sched = build_scheduler(engine, cli, serve["max_queue"])
     ctx.memory("warm")
+    ctx.mark("warm_up_done")
     ctx.begin_window()
     rec, counts = drive(sched, items, prompts, mix, ctx.seconds, ctx)
     ctx.end_window()
@@ -418,7 +425,8 @@ def run(ctx):
     cap = cli.max_pages_per_slot * sched.kv.page_size
     del sched
     gc.collect()
-    ok, notes = check_outputs(engine, config, rec.rows, cap, ctx.seed)
+    ok, notes = check_outputs(engine, ref_hidden, ref_logits,
+                              ctx.reference_args(), rec.rows, cap, ctx.seed)
     checks["reference"] = ok
     notes.update(counts["step_clock"])
     notes.update(lateness_mean_s=m["lateness_mean_s"],
@@ -430,12 +438,15 @@ def run(ctx):
                  finished_in_window=sum(
                      1 for r in rec.rows if r["state"] == "finished"
                      and r["finish_s"] <= ctx.seconds))
-    counters = {
-        "slot_occupancy": counts["slot_occupancy"],
-        "device_wait_frac": summary.get("device_wait_frac"),
-        "page_util_mean": summary.get("page_util_mean"),
-        "steps": counts["steps"],
-    }
+    # every number the program's own summary keeps, so that a metric
+    # file can read a counter a later PR adds; the driver's own win
+    counters = dict(numeric_items(summary),
+                    slot_occupancy=counts["slot_occupancy"],
+                    steps=counts["steps"])
+    compared = {"reference_worst_margin": [
+        notes.get("reference_worst_margin"), notes.get("reference_eps")]}
+    if "generator_on_time" in checks:
+        compared["lateness_mean_s"] = [m["lateness_mean_s"], step_s]
     return {"checks": checks, "attempted": m["attempted"],
             "failed": m["failed"], "end_to_end": m, "counters": counters,
-            "static": {}, "notes": notes}
+            "compared": compared, "static": {}, "notes": notes}

@@ -9,8 +9,7 @@ import time
 import numpy as np
 
 import loadgen
-import reference
-from drive_serve import build_module, program_field
+from drive_serve import build_module, numeric_items, program_field
 
 # the engine's step-0 loss (bf16 compute, float32 master weights) against
 # the plain float32 reference on the same batch and the same initial
@@ -33,6 +32,7 @@ def run(ctx):
     import jax.numpy as jnp
     import deepspeed_tpu
     config, mix = ctx.config, ctx.traffic
+    reference_loss = ctx.reference("loss")
     train = config["train"]
     seq, vocab = mix["seq_len"], config["vocab_size"]
     dp = int(train["mesh"].get("data", 1))
@@ -55,13 +55,36 @@ def run(ctx):
         "steps_per_print": 10 ** 9,
     }
     first = chunk()
+    mesh = None
+    if len(ctx.devices) < len(jax.devices()):
+        # a host with more devices than the cell takes (the 8 virtual
+        # CPU devices of the tests): the program's own mesh builder over
+        # the cell's devices; otherwise the engine builds it itself
+        from deepspeed_tpu.parallel.topology import make_mesh
+        from deepspeed_tpu.runtime.config import MeshConfig
+        mesh = make_mesh(MeshConfig(**train["mesh"]), devices=ctx.devices)
     engine, _, _, _ = deepspeed_tpu.initialize(
         model=build_module(config, dtype=jnp.dtype(config["dtype"])),
-        config=ds_config,
+        config=ds_config, mesh=mesh,
         example_batch=first[0], seed=int(ctx.seed) & 0x7FFFFFFF)
-    init_params = jax.tree.map(jnp.copy, engine.state.params)
+    # the initial parameters, for the step-0 reference after the window:
+    # a second copy on the device, or on the host where the sharded
+    # state leaves the step program no room beside one
+    on_host = train.get("initial_params", "device") == "host"
+    if on_host:
+        shardings = jax.tree.map(lambda a: a.sharding, engine.state.params)
+        init_params = jax.device_get(engine.state.params)
+    else:
+        init_params = jax.tree.map(jnp.copy, engine.state.params)
+    ctx.mark("engine_built")
     warm_losses = np.asarray(engine.train_loop(first, sync=True))
+    # a mix with few steps to a dispatch warms more of them: the engine's
+    # throughput timer syncs (and compiles a scalar add) when its step
+    # count reaches 2, which must not fall inside the window
+    for _ in range(int(mix.get("warm_dispatches", 1)) - 1):
+        engine.train_loop(chunk(), sync=True)
     ctx.memory("warm")
+    ctx.mark("warm_up_done")
 
     ann = jax.profiler.TraceAnnotation
     losses, steps, traced_steps = [], 0, 0
@@ -85,23 +108,28 @@ def run(ctx):
     n_params = sum(int(np.prod(l.shape))
                    for l in jax.tree.leaves(engine.state.params))
     tenth = max(1, len(losses) // 10)
-    ref = config["reference"]
+    if on_host:
+        init_params = jax.device_put(init_params, shardings)
     with jax.default_matmul_precision("highest"):
-        ref_loss = float(getattr(reference, ref["loss"])(
+        ref_loss = float(reference_loss(
             init_params, jnp.asarray(first[0]["input_ids"]),
-            **{a: config[b] for a, b in ref["args"].items()}))
+            **ctx.reference_args()))
     del init_params
     compiled = {n: c for n, c in engine.train_compile_counts().items() if c}
+    # at random init the logits' variance is hidden x 0.02**2 and the loss
+    # sits half of that over ln(vocab): a wider model states its own room
+    ln_vocab_limit = train.get("step0_ln_vocab_limit", 0.3)
+    off_ln_vocab = abs(float(warm_losses[0]) - math.log(vocab))
+    off_reference = abs(float(warm_losses[0]) - ref_loss)
+    first_tenth = float(np.mean(losses[:tenth]))
+    last_tenth = float(np.mean(losses[-tenth:]))
     hlo = engine.compiled_step_text(first[0])
     checks = {
         "finite": bool(np.all(np.isfinite(losses))
                        and np.all(np.isfinite(warm_losses))),
-        "step0_is_ln_vocab": abs(float(warm_losses[0]) - math.log(vocab))
-        <= 0.3,
-        "step0_matches_reference": abs(float(warm_losses[0]) - ref_loss)
-        <= LOSS_TOLERANCE,
-        "loss_falls": float(np.mean(losses[-tenth:]))
-        < float(np.mean(losses[:tenth])),
+        "step0_is_ln_vocab": off_ln_vocab <= ln_vocab_limit,
+        "step0_matches_reference": off_reference <= LOSS_TOLERANCE,
+        "loss_falls": last_tenth < first_tenth,
         "one_step_loop_compile": compiled == {"step_loop": 1},
         "mosaic_in_step": 'custom_call_target="tpu_custom_call"' in hlo
         or train.get("expect_mosaic", True) is False,
@@ -112,14 +140,20 @@ def run(ctx):
     heads, hidden, layers = (program_field(config, f) for f in (
         "num_heads", "hidden_size", "num_layers"))
     notes = {"loss_step0": float(warm_losses[0]), "loss_reference": ref_loss,
-             "loss_first_tenth": float(np.mean(losses[:tenth])),
-             "loss_last_tenth": float(np.mean(losses[-tenth:])),
+             "loss_first_tenth": first_tenth, "loss_last_tenth": last_tenth,
              "steps": steps, "window_s": window, "compiled": compiled,
              "n_params": n_params}
     return {
         "checks": checks, "attempted": steps, "failed": 0,
         "end_to_end": {"train_tokens_per_s": steps * batch * seq / window},
-        "counters": {"traced_steps": traced_steps, "steps": steps},
+        # the train engine keeps no counters object of its own: its
+        # compile counts per jitted callable are what it can be asked
+        "counters": dict(numeric_items(compiled, "compiled."),
+                         traced_steps=traced_steps, steps=steps),
+        "compared": {
+            "loss_step0_vs_ln_vocab": [off_ln_vocab, ln_vocab_limit],
+            "loss_step0_vs_reference": [off_reference, LOSS_TOLERANCE],
+            "loss_last_tenth_vs_first": [last_tenth, first_tenth]},
         "static": {
             "train": {"n_params": n_params, "layers": layers,
                       "hidden": hidden, "seq": seq},

@@ -260,7 +260,8 @@ def train_flops_per_token(n_params, layers, hidden, seq):
 
 # ------------------------------------------------------------------ readers
 # ctx: {"trace": Trace|None, "counters": dict, "static": dict,
-#       "end_to_end": dict, "peaks": dict, "chips": int}
+#       "end_to_end": dict, "peaks": dict, "chips": int,
+#       "config": the configuration's file, "traffic": the mix's file}
 
 
 def idle_share(ctx):
@@ -330,17 +331,25 @@ def counter(ctx, key, scale=1.0, one_minus=False):
 def exposed_collective_share(ctx, collective_substrs):
     """Collective operations' intervals minus their overlap with compute
     operations on the same device, over the traced window; averaged over
-    devices."""
+    devices.  A collective is an event whose OWN instruction name holds
+    one of ``collective_substrs`` (``%all-gather-start.7``): an event's
+    name is its whole HLO text, and a fusion that takes ``%all-gather.7``
+    as an operand is compute."""
     tr = ctx["trace"]
     if tr is None or tr.window_s <= 0 or not tr.device_ops:
         return None
 
     def is_coll(n):
-        return any(x in n for x in collective_substrs)
+        own = own_name(n)
+        return any(x in own for x in collective_substrs)
+
+    def is_compute(n):
+        # a loop's or a call's event spans the operations inside it
+        return not is_coll(n) and own_name(n) not in WRAPPERS
     shares = []
     for d in tr.device_ops:
         coll = tr.busy(d, is_coll)
-        comp = tr.busy(d, lambda n: not is_coll(n))
+        comp = tr.busy(d, is_compute)
         shares.append(total(subtract(coll, comp)) / 1e9)
     if not any(shares):
         return None

@@ -51,6 +51,13 @@ def load_module(bench_dir, name):
     return mod
 
 
+def load_function(bench_dir, spec, default=None):
+    """``"module:function"`` -> the function of ``<module>.py`` in
+    ``bench_dir``; a bare name resolves in the module ``default``."""
+    mod, _, fn = spec.rpartition(":")
+    return getattr(load_module(bench_dir, mod or default), fn)
+
+
 def find(entries, name, what):
     for e in entries:
         if e["name"] == name:
@@ -85,9 +92,11 @@ class Context:
     a few seconds of the steady window."""
 
     def __init__(self, root, bench_dir, config, traffic, seed, seconds,
-                 trace_dir=None, devices=(), compiles=None, t_start=None):
+                 trace_dir=None, devices=(), compiles=None, t_start=None,
+                 config_file=None):
         self.root, self.bench_dir = root, bench_dir
         self.config, self.traffic = config, traffic
+        self.config_file = config_file or config.get("name", "?")
         self.seed, self.seconds = seed, seconds
         self.trace_dir, self.devices = trace_dir, list(devices)
         self.compiles = compiles
@@ -99,6 +108,36 @@ class Context:
         self._trace_state = "off" if trace_dir is None else "armed"
         self._trace_len = min(TRACE_SECONDS, seconds / 4)
         self._trace_at = seconds - self._trace_len
+
+    def reference(self, key):
+        """The plain reference function the configuration's file names
+        under ``reference.<key>``: ``"module:function"`` is looked up in
+        ``<module>.py`` of the harness's directory (a new family brings
+        its own file), a bare name in ``reference.py``.  A driver calls
+        this before any set-up, so a name that resolves nowhere ends
+        the run at once."""
+        where = f"{self.config_file}: reference.{key}"
+        spec = self.config.get("reference", {}).get(key)
+        if not isinstance(spec, str):
+            raise SystemExit(f"{where} is not set")
+        try:
+            return load_function(self.bench_dir, spec, "reference")
+        except (OSError, AttributeError) as e:
+            raise SystemExit(f"{where} = {spec!r} resolves nowhere "
+                             f"({type(e).__name__}: {e})")
+
+    def reference_args(self):
+        """``reference.args`` maps the functions' keyword names to keys
+        of the configuration's file; a value there may be a number, a
+        string or a list (a layer pattern)."""
+        ref = self.config.get("reference", {})
+        return {a: self.config[k] for a, k in ref.get("args", {}).items()}
+
+    def mark(self, phase):
+        """Seconds from the start of the process to the end of a phase
+        of the set-up, under ``setup_phases`` in the notes."""
+        self.notes.setdefault("setup_phases", {})[phase] = \
+            time.monotonic() - self.t_start
 
     def begin_window(self):
         self.setup_s = time.monotonic() - self.t_start
@@ -158,13 +197,35 @@ def layer_metrics(bench_dir, manifest, cell, reader_ctx):
             continue
         spec = load_json(os.path.join(bench_dir, "layer_metrics",
                                       metric["name"] + ".json"))
-        mod, fn = spec["reader"].split(":")
-        value = getattr(load_module(bench_dir, mod), fn)(
+        value = load_function(bench_dir, spec["reader"])(
             reader_ctx, **spec.get("args", {}))
         if value is not None:
             out[metric["name"]] = {"value": float(value),
                                    "unit": metric["unit"]}
     return out
+
+
+def load_cell(root, bench_dir, workload):
+    """The manifest's entry of a cell with the files it names: the
+    configuration as it is run and the traffic mix."""
+    manifest = load_json(os.path.join(root, "BENCHMARK.json"))
+    cell = find(manifest["workloads"], workload, "workload")
+    config_file = find(manifest["configs"], cell["config"], "config")["file"]
+    return {"manifest": manifest, "cell": cell, "config_file": config_file,
+            "config": load_json(os.path.join(root, config_file)),
+            "traffic": load_json(os.path.join(
+                bench_dir, "traffic", cell["traffic"] + ".json"))}
+
+
+def reader_context(loaded, res, e2e, trace, peaks):
+    """What every reader is given: the trace, the driver's counters and
+    static shapes, the run's end-to-end numbers, the device's peaks, and
+    the cell's own files, so that a reader counts operations and bytes
+    from the published widths."""
+    return {"trace": trace, "counters": res["counters"],
+            "static": res["static"], "end_to_end": e2e, "peaks": peaks,
+            "chips": loaded["cell"]["chips"], "config": loaded["config"],
+            "traffic": loaded["traffic"]}
 
 
 def run_cell(ctx, kind):
@@ -180,16 +241,15 @@ def main(argv=None):
     p.add_argument("--trace", type=int, choices=[0, 1], default=0)
     args = p.parse_args(argv)
 
-    manifest = load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    cell = find(manifest["workloads"], args.workload, "workload")
-    config = load_json(os.path.join(
-        ROOT, find(manifest["configs"], cell["config"], "config")["file"]))
-    traffic = load_json(os.path.join(HERE, "traffic",
-                                     cell["traffic"] + ".json"))
+    loaded = load_cell(ROOT, HERE, args.workload)
+    manifest, cell = loaded["manifest"], loaded["cell"]
+    config, traffic = loaded["config"], loaded["traffic"]
     sys.path[:0] = [HERE, ROOT]
 
     import jax
+    t_imports = time.monotonic()
     devices = jax.devices()
+    t_devices = time.monotonic()
     dev = devices[0]
     if dev.platform != "tpu" or len(devices) < cell["chips"]:
         print(f"{args.workload}: needs {cell['chips']} TPU chip(s); JAX "
@@ -212,7 +272,13 @@ def main(argv=None):
         if args.trace else None
     ctx = Context(ROOT, HERE, config, traffic, args.seed, args.seconds,
                   trace_dir=trace_dir, devices=devices[:cell["chips"]],
-                  compiles=CompileCount(), t_start=T_START)
+                  compiles=CompileCount(), t_start=T_START,
+                  config_file=loaded["config_file"])
+    # where the set-up's seconds go: the TPU client's start-up is the
+    # machine's, what follows is the program's (the drivers mark
+    # ``engine_built`` and ``warm_up_done``)
+    ctx.notes["setup_phases"] = {"imports_done": t_imports - T_START,
+                                 "devices_found": t_devices - T_START}
     res = run_cell(ctx, traffic["kind"])
 
     res["checks"]["no_compile_in_window"] = ctx.window_compiles == 0
@@ -228,10 +294,9 @@ def main(argv=None):
     if args.trace:
         readers = load_module(HERE, "readers")
         trace = readers.load_trace(trace_dir)
-        line["metrics"] = layer_metrics(HERE, manifest, args.workload, {
-            "trace": trace, "counters": res["counters"],
-            "static": res["static"], "end_to_end": e2e,
-            "peaks": peaks[dev.device_kind], "chips": cell["chips"]})
+        line["metrics"] = layer_metrics(
+            HERE, manifest, args.workload,
+            reader_context(loaded, res, e2e, trace, peaks[dev.device_kind]))
         if trace is not None:
             device["busy_s"] = trace.busy_s()
             device["window_s"] = trace.window_s
@@ -242,15 +307,24 @@ def main(argv=None):
             m["name"]: {"value": float(e2e[m["name"]]), "unit": m["unit"]}
             for m in manifest["end_to_end"]
             if reports(m, args.workload) and e2e.get(m["name"]) is not None}
+    notes = dict(res["notes"], **ctx.notes)
+    line["notes"] = {"setup_phases": notes["setup_phases"]}
     print(json.dumps({"workload": args.workload, "seed": args.seed,
-                      "checks": res["checks"],
-                      "notes": dict(res["notes"], **ctx.notes),
+                      "checks": res["checks"], "notes": notes,
                       "end_to_end": e2e,
                       "window_compiles": ctx.window_compiles,
                       "compile_events": ctx.compiles.n,
                       "compile_s": ctx.compiles.secs,
                       "cache_dir": cache_dir}))
     print(json.dumps(line))
+    sys.stdout.flush()
+    # each check, and each number compared beside its limit, as the last
+    # lines of standard error
+    for name, (value, limit) in res.get("compared", {}).items():
+        print(f"compared {name}: {value!r} against {limit!r}",
+              file=sys.stderr)
+    print(f"checks {json.dumps(res['checks'])} window_compiles "
+          f"{ctx.window_compiles}", file=sys.stderr)
     return 0
 
 

@@ -19,6 +19,7 @@ T_START = time.monotonic()
 import argparse            # noqa: E402
 import importlib.util      # noqa: E402
 import json                # noqa: E402
+import math                # noqa: E402
 import os                  # noqa: E402
 import shutil              # noqa: E402
 import sys                 # noqa: E402
@@ -30,6 +31,8 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 # it falls after the window instead of stalling the loop in mid-window
 TRACE_SECONDS = 3.0
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# reference.routed: the names it must carry, and the one it may
+ROUTED_KEYS, ROUTED_OPTIONAL = ("layers", "experts", "per_token"), ("held",)
 
 
 def load_json(path):
@@ -133,6 +136,48 @@ class Context:
         ref = self.config.get("reference", {})
         return {a: self.config[k] for a, k in ref.get("args", {}).items()}
 
+    def reference_routed(self):
+        """``reference.routed`` declares the configuration's router as
+        data: each name maps to the key of the configuration's own file
+        that holds the size.  ``layers`` routed layers, ``experts`` the
+        router scores, ``per_token`` it chooses, and optionally ``held``
+        (the experts this chip holds; all of them without the name).
+        The served tokens of such a configuration are held to the
+        routed rule (``drive_serve.check_routed``).  None where the
+        block is absent: the dense rule.  A driver calls this before
+        any set-up, so a name the block lacks, or a key the file lacks,
+        ends the run at once."""
+        block = self.config.get("reference", {}).get("routed")
+        if block is None:
+            return None
+        where = f"{self.config_file}: reference.routed"
+        names = ROUTED_KEYS + ROUTED_OPTIONAL
+        if not isinstance(block, dict) or set(block) - set(names):
+            raise SystemExit(f"{where} is not a map of names among "
+                             f"{list(names)} (it is {block!r})")
+        out = {}
+        for name in names:
+            key = block.get(name)
+            if key is None and name in ROUTED_OPTIONAL:
+                continue
+            if not isinstance(key, str):
+                raise SystemExit(f"{where}.{name} is not set (it names "
+                                 f"the key of this file that holds it)")
+            value = self.config.get(key)
+            if isinstance(value, bool) or not isinstance(value, int) \
+                    or value < 1:
+                raise SystemExit(
+                    f"{where}.{name} = {key!r}: the file has no whole "
+                    f"number of 1 or more under {key!r} (it has "
+                    f"{value!r})")
+            out[name] = value
+        out.setdefault("held", out["experts"])
+        if not out["per_token"] <= out["experts"] >= out["held"]:
+            raise SystemExit(f"{where}: {out['experts']} experts cannot "
+                             f"give {out['per_token']} a token or hold "
+                             f"{out['held']}")
+        return out
+
     def mark(self, phase):
         """Seconds from the start of the process to the end of a phase
         of the set-up, under ``setup_phases`` in the notes."""
@@ -233,6 +278,41 @@ def run_cell(ctx, kind):
     return load_module(ctx.bench_dir, "drive_" + kind).run(ctx)
 
 
+def plain(value):
+    """A compared number as JSON holds it: one that is not finite goes
+    as its name, so that the line stays JSON."""
+    if isinstance(value, float) and not math.isfinite(value):
+        return repr(value)
+    return value
+
+
+def end_to_end_metrics(manifest, workload, e2e):
+    """The cell's end-to-end metrics as the result line holds them."""
+    return {m["name"]: {"value": float(e2e[m["name"]]), "unit": m["unit"]}
+            for m in manifest["end_to_end"]
+            if reports(m, workload) and e2e.get(m["name"]) is not None}
+
+
+def result_line(res, ctx, device, metrics, breakdown=None):
+    """The last line of standard output.  Its ``notes`` come last and
+    name what decided ``correct``: every check as 0 or 1, and each
+    number compared beside its limit."""
+    res["checks"]["no_compile_in_window"] = ctx.window_compiles == 0
+    res.setdefault("compared", {})["window_compiles"] = [
+        ctx.window_compiles, 0]
+    line = {"correct": all(res["checks"].values()),
+            "attempted": res["attempted"], "failed": res["failed"],
+            "device": device, "metrics": metrics}
+    if breakdown is not None:
+        line["breakdown"] = breakdown
+    line["notes"] = {
+        "setup_phases": ctx.notes["setup_phases"],
+        "checks": {k: int(bool(v)) for k, v in res["checks"].items()},
+        "compared": {k: [plain(v), plain(limit)]
+                     for k, (v, limit) in res["compared"].items()}}
+    return line
+
+
 def main(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--workload", required=True)
@@ -281,34 +361,28 @@ def main(argv=None):
                                  "devices_found": t_devices - T_START}
     res = run_cell(ctx, traffic["kind"])
 
-    res["checks"]["no_compile_in_window"] = ctx.window_compiles == 0
-    correct = all(res["checks"].values())
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices),
               "memory_peak_bytes": max(
                   d.memory_stats()["peak_bytes_in_use"]
                   for d in ctx.devices)}
     e2e = dict(res["end_to_end"], setup_s=ctx.setup_s)
-    line = {"correct": correct, "attempted": res["attempted"],
-            "failed": res["failed"], "device": device}
+    breakdown = None
     if args.trace:
         readers = load_module(HERE, "readers")
         trace = readers.load_trace(trace_dir)
-        line["metrics"] = layer_metrics(
+        metrics = layer_metrics(
             HERE, manifest, args.workload,
             reader_context(loaded, res, e2e, trace, peaks[dev.device_kind]))
         if trace is not None:
             device["busy_s"] = trace.busy_s()
             device["window_s"] = trace.window_s
-            line["breakdown"] = {"device_ops": trace.top_ops(),
-                                 "idle_gaps": trace.top_gaps()}
+            breakdown = {"device_ops": trace.top_ops(),
+                         "idle_gaps": trace.top_gaps()}
     else:
-        line["metrics"] = {
-            m["name"]: {"value": float(e2e[m["name"]]), "unit": m["unit"]}
-            for m in manifest["end_to_end"]
-            if reports(m, args.workload) and e2e.get(m["name"]) is not None}
+        metrics = end_to_end_metrics(manifest, args.workload, e2e)
+    line = result_line(res, ctx, device, metrics, breakdown)
     notes = dict(res["notes"], **ctx.notes)
-    line["notes"] = {"setup_phases": notes["setup_phases"]}
     print(json.dumps({"workload": args.workload, "seed": args.seed,
                       "checks": res["checks"], "notes": notes,
                       "end_to_end": e2e,
@@ -320,7 +394,7 @@ def main(argv=None):
     sys.stdout.flush()
     # each check, and each number compared beside its limit, as the last
     # lines of standard error
-    for name, (value, limit) in res.get("compared", {}).items():
+    for name, (value, limit) in res["compared"].items():
         print(f"compared {name}: {value!r} against {limit!r}",
               file=sys.stderr)
     print(f"checks {json.dumps(res['checks'])} window_compiles "

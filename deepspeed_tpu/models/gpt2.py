@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from deepspeed_tpu.ops.attention.reference import causal_mask, mha_reference
+from deepspeed_tpu.runtime.zero import gather as zero_gather
 
 
 @dataclasses.dataclass(unsafe_hash=True)
@@ -380,6 +381,11 @@ class Block(nn.Module):
     def __call__(self, x, deterministic=True, cache=None, positions=None,
                  pld_keep=None):
         cfg = self.cfg
+        # ZeRO-3 gather-at-use (runtime/zero/gather.py): the residual
+        # stream enters and leaves a block with its batch on `data`
+        plan = zero_gather.active() if cache is None else None
+        if plan is not None:
+            x = plan.pin_batch(x)
         x_in = x
         ad = cache.get("adapters") if cache is not None else None
         ad_rows = None
@@ -428,6 +434,8 @@ class Block(nn.Module):
             keep = jax.random.bernoulli(self.make_rng("pld"), pld_keep)
             scaled = x_in + (out - x_in) / pld_keep.astype(out.dtype)
             out = jnp.where(keep, scaled, x_in)
+        if plan is not None:
+            out = plan.pin_batch(out)
         return out, new_cache
 
 
@@ -449,24 +457,40 @@ def _make_embed_tables(mdl, cfg):
     return wte_v, wpe_v
 
 
-def _embed_tokens(wte_v, wpe_v, input_ids, cfg, positions=None):
+def _embed_tokens(wte_v, wpe_v, input_ids, cfg, positions=None,
+                  gather_at=None):
+    """``gather_at``: (ZeRO-3 gather plan, the path of the module that
+    owns the tables), where the tables come sharded over `data` and are
+    gathered at these lookups (runtime/zero/gather.py)."""
     b, l = input_ids.shape
-    x = wte_v.astype(cfg.dtype)[input_ids]
+    plan, path = gather_at or (None, ())
+
+    def take(w, i, name):
+        if plan is None:
+            return w[i]
+        return plan.pin_batch(plan.take(w, i, path + (name,)))
+
+    x = take(wte_v.astype(cfg.dtype), input_ids, "wte")
     if wpe_v is not None:
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
-        x = x + wpe_v.astype(cfg.dtype)[positions + cfg.pos_offset]
+        x = x + take(wpe_v.astype(cfg.dtype), positions + cfg.pos_offset,
+                     "wpe")
     return x
 
 
-def _head_logits(x, cfg, *, wte_v=None, dense_ctor=None):
+def _head_logits(x, cfg, *, wte_v=None, dense_ctor=None, gather_at=None):
     """ln_f + LM projection; tied path multiplies by wte, untied builds a
     lm_head Dense (caller supplies the constructors so params land on the
-    calling module)."""
+    calling module; ``gather_at`` as in :func:`_embed_tokens`)."""
     x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                      name="ln_f")(x)
     if cfg.tie_embeddings:
         assert wte_v is not None, "tied head needs the embedding table"
+        if gather_at is not None:
+            plan, path = gather_at
+            return plan.einsum("ble,ve->blv", x, wte_v.astype(cfg.dtype),
+                               path + ("wte",))
         return jnp.einsum("ble,ve->blv", x, wte_v.astype(cfg.dtype))
     return dense_ctor(cfg.vocab_size, cfg, ("embed", "vocab"),
                       name="lm_head", use_bias=cfg.lm_head_bias)(x)
@@ -514,7 +538,13 @@ class GPT2(nn.Module):
                                              (b, l))
 
         wte_v, wpe_v = _make_embed_tables(self, cfg)
-        x = _embed_tokens(wte_v, wpe_v, input_ids, cfg, positions)
+        # ZeRO-3 gather-at-use: under a plan the engine installed, the
+        # tables are gathered at the lookups and at the tied head, every
+        # QDense kernel at its matmul, and the batch stays on `data`
+        plan = zero_gather.active() if cache is None else None
+        gather_at = None if plan is None else (plan, self.path)
+        x = _embed_tokens(wte_v, wpe_v, input_ids, cfg, positions,
+                          gather_at=gather_at)
         if cfg.embed_layernorm:
             x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                              name="ln_embed")(x)
@@ -614,7 +644,10 @@ class GPT2(nn.Module):
             x = jnp.take_along_axis(
                 x, jnp.maximum(cache["n_valid"] - 1, 0)[:, None, None],
                 axis=1)
-        logits = _head_logits(x, cfg, wte_v=wte_v, dense_ctor=_dense)
+        logits = _head_logits(x, cfg, wte_v=wte_v, dense_ctor=_dense,
+                              gather_at=gather_at)
+        if plan is not None:
+            logits = plan.pin_batch(logits)
         if paged:
             if "slot" in cache:
                 lengths = cache["lengths"].at[cache["slot"]].add(
@@ -654,6 +687,9 @@ def gpt2_loss_fn(logits, batch):
     ll = jnp.take_along_axis(
         logits, safe_labels[..., None], axis=-1)[..., 0].astype(jnp.float32)
     nll = (logz - ll) * valid
+    plan = zero_gather.active()
+    if plan is not None:    # the per-token loss stays on `data` too
+        nll = plan.pin_batch(nll)
     return nll.sum() / jnp.maximum(valid.sum(), 1)
 
 

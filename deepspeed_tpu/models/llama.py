@@ -23,6 +23,7 @@ from jax import lax
 from deepspeed_tpu.ops.attention.reference import (apply_rotary_emb,
                                                    decode_attention_reference,
                                                    mha_reference)
+from deepspeed_tpu.runtime.zero import gather as zero_gather
 
 
 @dataclasses.dataclass(unsafe_hash=True)
@@ -293,6 +294,11 @@ class LlamaBlock(nn.Module):
     @nn.compact
     def __call__(self, x, positions, cache=None):
         cfg = self.cfg
+        # ZeRO-3 gather-at-use (runtime/zero/gather.py): the residual
+        # stream enters and leaves a block with its batch on `data`
+        plan = zero_gather.active() if cache is None else None
+        if plan is not None:
+            x = plan.pin_batch(x)
         ad = cache.get("adapters") if cache is not None else None
         ad_rows = None
         if ad is not None:
@@ -305,6 +311,8 @@ class LlamaBlock(nn.Module):
         x = x + LlamaMLP(cfg, name="mlp")(
             RMSNorm(cfg.rms_eps, cfg.dtype, name="post_attn_norm")(x),
             ad, ad_rows)
+        if plan is not None:
+            x = plan.pin_batch(x)
         return x, new_cache
 
 
@@ -342,7 +350,16 @@ class Llama(nn.Module):
             nn.initializers.normal(0.02), ("vocab", "embed")),
             (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
         embed_v = embed.value if hasattr(embed, "value") else embed
-        x = embed_v.astype(cfg.dtype)[input_ids]
+        # ZeRO-3 gather-at-use: under a plan the engine installed, the
+        # table is gathered at the lookup and at a tied head, every
+        # QDense kernel at its matmul, and the batch stays on `data`
+        plan = zero_gather.active() if cache is None else None
+        if plan is not None:
+            x = plan.pin_batch(plan.take(
+                embed_v.astype(cfg.dtype), input_ids,
+                self.path + ("embed_tokens",)))
+        else:
+            x = embed_v.astype(cfg.dtype)[input_ids]
 
         block = LlamaBlock
         if cfg.remat and cache is None:
@@ -372,11 +389,16 @@ class Llama(nn.Module):
                 x, jnp.maximum(cache["n_valid"] - 1, 0)[:, None, None],
                 axis=1)
         x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x)
-        if cfg.tie_embeddings:
+        if cfg.tie_embeddings and plan is not None:
+            logits = plan.einsum("ble,ve->blv", x, embed_v.astype(cfg.dtype),
+                                 self.path + ("embed_tokens",))
+        elif cfg.tie_embeddings:
             logits = jnp.einsum("ble,ve->blv", x, embed_v.astype(cfg.dtype))
         else:
             logits = _proj(cfg, cfg.vocab_size, ("embed", "vocab"),
                            "lm_head")(x)
+        if plan is not None:
+            logits = plan.pin_batch(logits)
         if paged:
             if "slot" in cache:
                 lengths = cache["lengths"].at[cache["slot"]].add(

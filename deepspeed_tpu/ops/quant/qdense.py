@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from flax import errors as flax_errors
 
 from deepspeed_tpu.ops.quant.quantizer import QTensor
+from deepspeed_tpu.runtime.zero import gather as zero_gather
 
 
 def _quant_impl(impl):
@@ -110,8 +111,18 @@ class QDense(nn.Module):
         # float path: exactly nn.Dense (promote + dot_general + bias)
         inputs, kernel, bias = nn.dtypes.promote_dtype(
             inputs, kernel, bias, dtype=self.dtype)
-        y = jax.lax.dot_general(inputs, kernel,
-                                (((inputs.ndim - 1,), (0,)), ((), ())))
+        plan = zero_gather.active()
+        if plan is not None:
+            # ZeRO-3 gather-at-use: the kernel comes sharded over `data`
+            # and is gathered for this matmul alone (same contraction)
+            lead = "abcdefgh"[:inputs.ndim - 1]
+            y = plan.einsum(f"{lead}k,kn->{lead}n", inputs, kernel,
+                            self.path + ("kernel",))
+            if bias is not None:
+                bias = plan.gather(bias, self.path + ("bias",))
+        else:
+            y = jax.lax.dot_general(inputs, kernel,
+                                    (((inputs.ndim - 1,), (0,)), ((), ())))
         if bias is not None:
             y = y + jnp.reshape(bias, (1,) * (y.ndim - 1) + (-1,))
         return y

@@ -3,9 +3,7 @@
 This module is the TPU replacement for the reference's partition bookkeeping
 (``runtime/zero/stage_1_and_2.py``, ``stage3.py``, ``partition_parameters.py``):
 instead of slicing flat buffers and tracking ownership, each array in the
-train state gets a ``NamedSharding`` and XLA materializes the all-gathers /
-reduce-scatters (reference `stage_1_and_2.py:894`, `stage3.py:1076`) as
-collectives over ICI.
+train state gets a ``NamedSharding``.
 
 Models annotate params with *logical* axis names (flax
 ``nn.with_partitioning``). ``logical_to_mesh_axes`` maps them through
@@ -14,6 +12,29 @@ t5x-style rules; ZeRO stages then add `data`-axis sharding:
   stage 1 — optimizer state sharded over `data`
   stage 2 — + gradient accumulator sharded over `data`
   stage 3 — + parameters sharded over `data` (fsdp)
+
+Which spec a stage-3 leaf has where, and where its collectives come from:
+
+* AT REST (the train state, between steps and on entry to the step) a
+  parameter has ``param_pspec(..., zero_stage=3)``: its tensor-parallel
+  axes plus `data` on its largest dimension that divides, unless it is
+  smaller than the persistence threshold or nothing divides (then it stays
+  replicated over `data`).  The engine's cast to the compute dtype is
+  elementwise and keeps that spec.
+* AT USE it has ``param_pspec(..., zero_stage=2)``: the tensor-parallel axes
+  alone.  These specs do not make the compiler gather anything by
+  themselves — left to GSPMD, a step keeps the weights sharded and moves the
+  batch instead (all-to-alls of activations around every matmul; PERF.md §6
+  PR 29).  The all-gather comes from ``runtime/zero/gather.py``: the matmul
+  or lookup that consumes a leaf constrains its cast shard to the at-use
+  spec where it runs (reference `stage3.py:1076`, "allgathered
+  just-in-time"), and pins the activations' batch to `data`.
+* THE GRADIENT of such a leaf is computed in float32 on each chip and
+  constrained to the at-rest spec by the same op's backward, which is the
+  reduce-scatter (reference `stage_1_and_2.py:894`); the engine's
+  ``grad_pspecs`` (the optimizer-state specs from stage 2 up) then hold it
+  for the update.  Leaves the plan does not hold (persisted, or consumed by
+  no such op) get whatever GSPMD derives from their specs, as before.
 """
 
 import jax

@@ -75,9 +75,33 @@ _COLLECTIVE_OPS = {
 }
 
 _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
-_INSTR_RE = re.compile(
-    r"^(?:ROOT\s+)?%(?P<name>[\w.\-]+)\s*=\s*(?P<shape>\([^)]*\)|\S+)\s+"
-    r"(?P<op>[\w\-]+)\(")
+_INSTR_HEAD_RE = re.compile(r"^(?:ROOT\s+)?%(?P<name>[\w.\-]+)\s*=\s*")
+_OP_RE = re.compile(r"\s*(?P<op>[\w\-]+)\(")
+
+
+def _match_instr(line):
+    """``(name, shape, op, index of the op's name in line)`` of an HLO
+    instruction line, or None.  A tuple shape is read to its matching
+    parenthesis: the chip compiler's layouts hold parentheses of their
+    own (``bf16[400,1600]{1,0:T(8,128)(2,1)}``)."""
+    head = _INSTR_HEAD_RE.match(line)
+    if head is None:
+        return None
+    i = end = head.end()
+    if line[i:i + 1] == "(":
+        depth = 0
+        for end in range(i, len(line)):
+            depth += (line[end] == "(") - (line[end] == ")")
+            if depth == 0:
+                break
+        end += 1
+    else:
+        while end < len(line) and not line[end].isspace():
+            end += 1
+    op = _OP_RE.match(line, end)
+    if op is None:
+        return None
+    return head.group("name"), line[i:end], op.group("op"), op.start("op")
 _OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
 _COMP_RE = re.compile(r"^(?:ENTRY\s+)?%?(?P<name>[\w.\-]+)\s*\(.*\{\s*$")
 _GROUPS_LITERAL_RE = re.compile(r"replica_groups=\{(\{[\d, ]*\}(?:, ?\{[\d, ]*\})*)\}")
@@ -92,6 +116,10 @@ _CALLEE_RE = {
     "branches": re.compile(r"branch_computations=\{([^}]*)\}"),
     "true": re.compile(r"true_computation=%?([\w.\-]+)"),
     "false": re.compile(r"false_computation=%?([\w.\-]+)"),
+    # a fusion or an async wrapper: the TPU compiler emits a
+    # reduce-scatter as a fusion ``calls=%all-reduce-scatter`` of pad +
+    # all-reduce + dynamic-slice, and wraps async collectives
+    "calls": re.compile(r"\bcalls=%?([\w.\-]+)"),
 }
 _CONST_RE = re.compile(r"constant\((\d+)\)")
 
@@ -159,14 +187,16 @@ def _split_operands(line, start):
 
 
 class _Collective:
-    __slots__ = ("op", "bytes_in", "bytes_out", "groups", "pairs")
+    __slots__ = ("op", "bytes_in", "bytes_out", "groups", "pairs",
+                 "shapes")
 
-    def __init__(self, op, bytes_in, bytes_out, groups, pairs):
+    def __init__(self, op, bytes_in, bytes_out, groups, pairs, shapes=""):
         self.op = op
         self.bytes_in = bytes_in
         self.bytes_out = bytes_out
         self.groups = groups      # list of lists of partition ids
         self.pairs = pairs        # collective-permute (src, dst) edges
+        self.shapes = shapes      # operand and result shape strings
 
 
 def _parse_module(text):
@@ -185,7 +215,8 @@ def _parse_module(text):
                 if raw.lstrip().startswith("ENTRY"):
                     entry = name
                 cur = {"collectives": [], "whiles": [], "calls": [],
-                       "constants": [], "root_lt": False}
+                       "constants": [], "root_lt": False,
+                       "parameters": ""}
                 shapes = {}
             continue
         line = raw.strip()
@@ -195,11 +226,15 @@ def _parse_module(text):
             continue
         if not line or " = " not in line:
             continue
-        m = _INSTR_RE.match(line)
+        m = _match_instr(line)
         if m is None:
             continue
-        op = m.group("op")
-        shapes[m.group("name")] = m.group("shape")
+        instr_name, instr_shape, op, op_at = m
+        shapes[instr_name] = instr_shape
+        if op == "parameter":
+            cur["parameters"] += " " + instr_shape
+        if "calls=" in line:
+            cur["calls"].append(_CALLEE_RE["calls"].search(line).group(1))
         if op == "constant" or "constant(" in line:
             cur["constants"] += [int(x) for x in _CONST_RE.findall(line)]
         if "compare(" in line and "direction=LT" in line and \
@@ -228,15 +263,16 @@ def _parse_module(text):
             continue
         if op not in _COLLECTIVE_OPS:
             continue
-        paren = line.find("(", m.start("op"))
+        paren = line.find("(", op_at)
         operands, attrs = _split_operands(line, paren)
         bytes_in = _shape_bytes(operands)
         if not bytes_in:
             # this XLA prints operands by name only (``all-reduce(%x)``):
             # their shapes are on the defining instructions, which
             # precede every use within a computation
-            bytes_in = sum(_shape_bytes(shapes.get(n, ""))
-                           for n in _OPERAND_NAME_RE.findall(operands))
+            operands = " ".join(shapes.get(n, "") for n in
+                                _OPERAND_NAME_RE.findall(operands))
+            bytes_in = _shape_bytes(operands)
         groups = None
         gm = _GROUPS_LITERAL_RE.search(attrs)
         if gm:
@@ -253,10 +289,11 @@ def _parse_module(text):
         pm = _PAIRS_RE.search(attrs)
         if pm:
             pairs = [tuple(p) for p in _parse_brace_groups(pm.group(1))]
-        out_bytes = _shape_bytes_max(m.group("shape")) \
-            if op.endswith("-start") else _shape_bytes(m.group("shape"))
+        out_bytes = _shape_bytes_max(instr_shape) \
+            if op.endswith("-start") else _shape_bytes(instr_shape)
         cur["collectives"].append(_Collective(
-            _COLLECTIVE_OPS[op], bytes_in, out_bytes, groups, pairs))
+            _COLLECTIVE_OPS[op], bytes_in, out_bytes, groups, pairs,
+            operands + " " + instr_shape))
     return comps, entry
 
 
@@ -400,6 +437,70 @@ def ledger_from_hlo(text, mesh=None):
             pa["wire_bytes"] += m * wire
             out["per_tier"][tier] += m * wire
     return out
+
+
+def _dims(s):
+    return tuple(int(d) for d in s.split(",") if d)
+
+
+def collective_census(text, param_shapes=(), mesh=None):
+    """Which collectives a compiled program holds, by the class of what
+    they move: ``param`` where an operand or a result has the dimensions
+    of one of ``param_shapes`` (an iterable of shape tuples: a
+    parameter's whole shape and its shards), ``other`` where none has —
+    an activation, a scalar.  Trip-weighted like the ledger.
+
+    Returns ``{"per_op": {op: {cls: {"count", "bytes", "dtypes",
+    "axes"}}}, "other_shapes": {"<op> <dtype>[dims]": bytes}}``:
+    ``bytes`` is the ledger's per-device payload, ``dtypes`` the payload
+    split by the element type of the LARGEST component (what a gather
+    moves, what a gradient is summed in), ``axes`` the payload by the
+    mesh axes the groups span (``mesh`` given), ``other_shapes`` the ten
+    heaviest movers that are no parameter's, by name."""
+    known = {tuple(int(d) for d in sh) for sh in param_shapes}
+    known.discard(())
+    comps, entry = _parse_module(text)
+    mult, _ = _multipliers(comps, entry) if entry is not None \
+        else ({c: 1 for c in comps}, 0)
+    per_op, others = {}, {}
+    if mesh is not None:
+        mesh_sizes = tuple(int(n) for n in mesh.devices.shape)
+        mesh_names = tuple(str(a) for a in mesh.axis_names)
+    for name, comp in comps.items():
+        m = mult.get(name, 0)
+        # the TPU compiler's reduce-scatter: a fusion of pad +
+        # all-reduce + dynamic-slice whose own parameter has the
+        # gradient's shape (the all-reduce's is padded to the tiling)
+        fused_rs = name.startswith("all-reduce-scatter")
+        for c in comp["collectives"] if m else ():
+            shapes = c.shapes + (comp["parameters"] if fused_rs else "")
+            parts = [(dt, _dims(dims)) for dt, dims in
+                     _SHAPE_RE.findall(shapes) if dt in _DTYPE_BYTES]
+            if not parts:
+                continue
+            cls = "param" if any(d in known for _, d in parts) else "other"
+            op = "reduce_scatter" if fused_rs and c.op == "all_reduce" \
+                else c.op
+            payload = c.bytes_out \
+                if op in ("all_gather", "broadcast") else c.bytes_in
+            dt, dims = max(parts, key=lambda p: int(np.prod(p[1] or (1,)))
+                           * _DTYPE_BYTES[p[0]])
+            slot = per_op.setdefault(op, {}).setdefault(
+                cls, {"count": 0, "bytes": 0, "dtypes": {}, "axes": {}})
+            slot["count"] += m
+            slot["bytes"] += m * payload
+            slot["dtypes"][dt] = slot["dtypes"].get(dt, 0) + m * payload
+            groups = c.groups or [list(p) for p in c.pairs or ()
+                                  if p[0] != p[1]]
+            if mesh is not None and groups:
+                axis = _group_axes(groups, mesh_sizes, mesh_names) \
+                    or "replicated"
+                slot["axes"][axis] = slot["axes"].get(axis, 0) + m * payload
+            if cls == "other":
+                key = f"{op} {dt}[{','.join(map(str, dims))}]"
+                others[key] = others.get(key, 0) + m * payload
+    top = sorted(others.items(), key=lambda kv: -kv[1])[:10]
+    return {"per_op": per_op, "other_shapes": dict(top)}
 
 
 def ledger_for(fn, *args, mesh=None, static_argnums=(), **kwargs):

@@ -47,6 +47,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu import comm as dist
 from deepspeed_tpu.parallel import sharding as shd
+from deepspeed_tpu.runtime.zero.gather import (GatherPlan,
+                                               scope as zero_gather_scope)
 from deepspeed_tpu.resilience import faults
 from deepspeed_tpu.parallel.topology import make_mesh
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
@@ -229,6 +231,8 @@ class DeepSpeedEngine:
         self.state: Optional[TrainState] = None
         self._grad_acc = None          # running grad sum (gas > 1 windows)
         self._pending = None           # forward() result awaiting backward()
+        self._census_probe = None      # first whole-step dispatch, by shapes
+        self._census = None            # its collective census, once read
         self._next_state = None        # boundary result awaiting step()
         self._next_metrics = None
         self._last_metrics = {}
@@ -517,6 +521,20 @@ class DeepSpeedEngine:
                                                    opt_param_pspecs)
         self.grad_pspecs = opt_param_pspecs if self.zero_stage >= 2 \
             else self.param_pspecs
+        # Stage 3: ``param_pspecs`` is a leaf's spec AT REST (sharded
+        # over `data`; ``cast()`` makes the compute-dtype copy on the
+        # shard).  AT USE a leaf has its stage-2 spec, the
+        # tensor-parallel axes alone: under the gather plan the matmul
+        # or lookup that consumes a leaf all-gathers the cast shard
+        # where it runs, and its backward computes the weight gradient
+        # in float32 and constrains it to the at-rest spec — the
+        # reduce-scatter (runtime/zero/gather.py).  The plan holds the
+        # leaves whose at-rest spec has the `data` axis, so it is empty
+        # (None) at stage <= 2 and on a `data` axis of size 1.
+        self._gather_plan = GatherPlan(
+            mesh, shapes, self.param_pspecs,
+            shd.tree_pspecs(mesh, shapes, logical,
+                            min(self.zero_stage, 2), kind="param")) or None
 
         param_sh = shd.tree_shardings(mesh, self.param_pspecs)
         opt_sh = shd.tree_shardings(mesh, self.opt_pspecs)
@@ -792,6 +810,12 @@ class DeepSpeedEngine:
                 lambda x: x.astype(compute_dtype)
                 if x.dtype == jnp.float32 and compute_dtype != jnp.float32 else x, p)
 
+        # ZeRO-3 gather-at-use: installed around the trace of the SPMD
+        # loss, never inside the 1-bit / sparse shard_maps, whose bodies
+        # hold whole local parameters already (there stage 3 keeps the
+        # program it had: the shard_map's own boundary gathers)
+        plan = self._gather_plan
+
         rltd_keep_static = self._rltd_keep
 
         # in-program param streaming (ZeRO-3 param offload): host-kind
@@ -862,7 +886,8 @@ class DeepSpeedEngine:
                 return loss, grads
 
             def scaled_loss(p):
-                loss = loss_fn(prep(p), batch, rng, **loss_kw)
+                with zero_gather_scope(plan):
+                    loss = loss_fn(prep(p), batch, rng, **loss_kw)
                 return loss.astype(jnp.float32) * scale / gas, loss
 
             (s_loss, loss), grads = jax.value_and_grad(
@@ -1434,6 +1459,65 @@ class DeepSpeedEngine:
             state.params, state.opt_state, rest, dev_batch, rng,
             lr).compile().as_text()
 
+    def _dispatch_step(self, name, *args):
+        """Run the whole-step executable ``name`` and, the first time,
+        keep what :meth:`collective_census` needs to find the program
+        again: the callable and its arguments' shapes and shardings."""
+        first = self._census_probe is None
+        if first:
+            self._census_probe = (name, jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, weak_type=x.weak_type,
+                    sharding=x.sharding if x.committed else None)
+                if isinstance(x, jax.Array) else x, args))
+        out = getattr(self, name)(*args)
+        if first and self.mesh.size > 1:    # one device: no collectives
+            self.collective_census()
+        return out
+
+    def collective_census(self):
+        """Collective census of the step program this engine compiled
+        and ran first — the count and per-device bytes of every
+        ``all_gather`` / ``reduce_scatter`` / ``all_reduce`` /
+        ``all_to_all`` / ``collective_permute`` by shape class (``param``:
+        an operand or result has a parameter's shape or a shard's;
+        ``other``: an activation, a scalar), the heaviest movers that
+        are no parameter's by name, and the leaves and bytes the ZeRO-3
+        plan gathers at use.  Under gather-at-use the all-gathers and
+        reduce-scatters are ``param`` and nothing large is ``other``.
+
+        Reads the executable the first dispatch compiled (lowering the
+        same callable for the same shapes and shardings finds it in
+        jit's own caches: no second compile); None before any whole
+        step ran.  Computed once, logged once."""
+        if self._census is None and self._census_probe is not None:
+            from deepspeed_tpu.profiling.comm_ledger import \
+                collective_census
+            name, args = self._census_probe
+            text = getattr(self, name).lower(*args).compile().as_text()
+            # a parameter's shapes: whole, one scanned layer's slice,
+            # and their shards under the at-rest and the gradient specs
+            shapes = set()
+            is_spec = lambda x: isinstance(x, P)
+            leaves = jax.tree.leaves(self.state.params)
+            for specs in (self.param_pspecs, self.grad_pspecs):
+                for leaf, spec in zip(
+                        leaves, jax.tree.leaves(specs, is_leaf=is_spec)):
+                    spec = tuple(spec) + (None,) * (leaf.ndim - len(spec))
+                    for sh, sp in ((leaf.shape, spec),
+                                   (leaf.shape[1:], spec[1:])):
+                        shapes.add(tuple(sh))
+                        shapes.add(NamedSharding(
+                            self.mesh, P(*sp)).shard_shape(tuple(sh)))
+            census = collective_census(text, shapes, mesh=self.mesh)
+            census["program"] = name.lstrip("_")
+            census["gather_at_use"] = self._gather_plan.summary() \
+                if self._gather_plan is not None else None
+            self._census = census
+            log_dist("collective census of " + census["program"] + ": " +
+                     json.dumps(census, sort_keys=True), ranks=[0])
+        return self._census
+
     def set_tracer(self, tracer):
         """Install a host-side span tracer (None restores the shared
         no-op singleton).  Tracing is host bookkeeping only — it can
@@ -1645,9 +1729,9 @@ class DeepSpeedEngine:
                 dev_batch, rng, float(self.get_lr()[0]))
             self._pending = ("commit", loss, new_state, metrics)
         elif self.gas == 1:
-            loss, new_state, metrics = self._step_gas1(
-                self.state.params, self.state.opt_state, rest,
-                dev_batch, rng, float(self.get_lr()[0]))
+            loss, new_state, metrics = self._dispatch_step(
+                "_step_gas1", self.state.params, self.state.opt_state,
+                rest, dev_batch, rng, float(self.get_lr()[0]))
             self._pending = ("commit", loss, new_state, metrics)
         elif boundary:
             loss, new_state, metrics = self._step_last(
@@ -2091,8 +2175,8 @@ class DeepSpeedEngine:
                         dev, rng, float(self.get_lr()[0]),
                         self._onebit_we, self._onebit_se)
             else:
-                mean_loss_dev, new_state, metrics = self._step_gasN(
-                    self.state.params, self.state.opt_state,
+                mean_loss_dev, new_state, metrics = self._dispatch_step(
+                    "_step_gasN", self.state.params, self.state.opt_state,
                     self.state.replace(params=None, opt_state=None),
                     dev, rng, float(self.get_lr()[0]))
         self.state = new_state
@@ -2174,8 +2258,8 @@ class DeepSpeedEngine:
             lrs.append(float(self.get_lr()[0]))     # schedule as it goes
             if self.lr_scheduler is not None:
                 self.lr_scheduler.step()
-        losses, new_state, metrics = self._step_loop(
-            self.state.params, self.state.opt_state,
+        losses, new_state, metrics = self._dispatch_step(
+            "_step_loop", self.state.params, self.state.opt_state,
             self.state.replace(params=None, opt_state=None),
             dev, rngs[1:], jnp.asarray(lrs, jnp.float32))
         self.state = new_state

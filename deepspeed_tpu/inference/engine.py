@@ -18,6 +18,7 @@ TPU redesign:
 """
 
 import os
+import sys
 import time
 from typing import Any, Optional
 
@@ -617,15 +618,16 @@ class InferenceEngine:
         return t
 
     # ---------------------------------------------------------------- generate
-    def _supports_cache(self):
-        from deepspeed_tpu.models.gpt2 import GPT2
-        from deepspeed_tpu.models.llama import Llama
-        return isinstance(self.module, (Llama, GPT2))
+    def _cache_module(self):
+        """The model family's file: one that follows the KV-cache
+        contract (ops/attention/kv_cache.py) exports ``init_kv_cache``
+        and ``init_paged_kv_cache`` at its own head geometry."""
+        mod = sys.modules.get(type(self.module).__module__)
+        return mod if hasattr(mod, "init_paged_kv_cache") else None
 
     def _init_cache(self, batch_size, max_len):
-        from deepspeed_tpu.models import gpt2, llama
         from deepspeed_tpu.ops.quant.kv import is_quantized_kv
-        mod = llama if isinstance(self.module, llama.Llama) else gpt2
+        mod = self._cache_module()
         # quantized kv_dtype applies to the PAGED serving pools only;
         # generate()'s dense cache stays fp32 — generate() is the
         # divergence oracle the quantized serving path is measured
@@ -689,14 +691,12 @@ class InferenceEngine:
     # so the serving loop never recompiles.
 
     def _paged_module(self):
-        from deepspeed_tpu.models import gpt2, llama
-        if isinstance(self.module, llama.Llama):
-            return llama
-        if isinstance(self.module, gpt2.GPT2):
-            return gpt2
-        raise ValueError(
-            "paged serving needs a KV-cache model contract (GPT2/Llama); "
-            f"got {type(self.module).__name__}")
+        mod = self._cache_module()
+        if mod is None:
+            raise ValueError(
+                "paged serving needs a KV-cache model contract "
+                f"(GPT2/Llama); got {type(self.module).__name__}")
+        return mod
 
     def init_paged_cache(self, num_pages, page_size, kv_dtype=None):
         """Device-resident per-layer K/V page pools, committed to the
@@ -775,55 +775,50 @@ class InferenceEngine:
                                  cfg.head_dim, page_size, dt)
 
     def _build_serving_fns(self):
+        from deepspeed_tpu.ops.attention import kv_cache
         module = self.module
         materialize = self._materialize
 
+        # every serving program names its step through a kv_cache
+        # constructor, built INSIDE the traced closure (mode and the
+        # sequence-parallel plan are static).  The multi-tenant LoRA
+        # side input ``adapters=None`` is a LEAFLESS pytree, so
+        # base-only traffic keeps the exact pre-tenancy signature and
+        # trace; a stacked adapter pack adds one signature per rank
+        # bucket (shapes), never per adapter (ids/weights are traced)
         def prefill(params, ids, slot, n_valid, page_table, lengths, pools,
-                    adapters):
-            cache = dict(pools, page_table=page_table, lengths=lengths,
-                         slot=slot, n_valid=n_valid)
-            # multi-tenant LoRA side input: None is a LEAFLESS pytree, so
-            # base-only traffic keeps the exact pre-tenancy signature and
-            # trace; a stacked adapter pack adds one signature per rank
-            # bucket (shapes), never per adapter (ids/weights are traced)
-            if adapters is not None:
-                cache["adapters"] = adapters
-            logits, cache = module.apply({"params": materialize(params)},
-                                         ids, cache=cache)
+                    adapters, seq_parallel=None):
+            logits, step = module.apply(
+                {"params": materialize(params)}, ids,
+                cache=kv_cache.prefill_step(
+                    pools["layers"], page_table, lengths, slot, n_valid,
+                    adapters=adapters, seq_parallel=seq_parallel))
             # the model already reduced each row to its chunk's boundary
             # position (the only one a scheduler ever samples from)
-            return logits[:, 0], {"layers": cache["layers"]}
+            return logits[:, 0], step.pools
 
         seq_plan = self.seq_parallel_plan()
 
         def prefill_sp(params, ids, slot, n_valid, page_table, lengths,
                        pools):
             # sequence-parallel twin of prefill: identical signature and
-            # paged landing, but the cache carries the static
-            # seq_axis/seq_impl markers (plain Python strings at trace
-            # time — the dict is built INSIDE the traced closure, same
-            # mechanism as the "slot" marker), so the model runs the
-            # chunk's attention distributed over the sequence axis.
-            # ids arrive sequence-sharded on dim 1 (the staging in
-            # prefill_sequence_parallel), which is what makes GSPMD
-            # shard the whole per-token pipeline and gather the KV
-            # scatter over the axis
-            cache = dict(pools, page_table=page_table, lengths=lengths,
-                         slot=slot, n_valid=n_valid,
-                         seq_axis=seq_plan.axis, seq_impl=seq_plan.impl)
-            logits, cache = module.apply({"params": materialize(params)},
-                                         ids, cache=cache)
-            return logits[:, 0], {"layers": cache["layers"]}
+            # paged landing, but the chunk's attention runs distributed
+            # over the sequence axis.  ids arrive sequence-sharded on
+            # dim 1 (the staging in prefill_sequence_parallel), which is
+            # what makes GSPMD shard the whole per-token pipeline and
+            # gather the KV scatter over the axis
+            return prefill(params, ids, slot, n_valid, page_table, lengths,
+                           pools, None, (seq_plan.axis, seq_plan.impl))
 
         def decode(params, toks, active, page_table, lengths, pools, rng,
                    do_sample, temperature, top_k, top_p):
-            cache = dict(pools, page_table=page_table, lengths=lengths,
-                         active=active)
-            logits, cache = module.apply({"params": materialize(params)},
-                                         toks[:, None], cache=cache)
+            logits, step = module.apply(
+                {"params": materialize(params)}, toks[:, None],
+                cache=kv_cache.decode_step(pools["layers"], page_table,
+                                           lengths, active))
             nxt = _sample_tokens(logits[:, 0], rng, do_sample, temperature,
                                  top_k, top_p)
-            return nxt.astype(jnp.int32), {"layers": cache["layers"]}
+            return nxt.astype(jnp.int32), step.pools
 
         def decode_multi(params, tok, active, page_table, lengths, pools,
                          emitted, budgets, eos_ids, rng, adapters, horizon,
@@ -845,24 +840,21 @@ class InferenceEngine:
             emit ``valid=False`` rows."""
             def body(carry, i):
                 tok, active, lengths, emitted, layers = carry
-                cache = {"layers": layers, "page_table": page_table,
-                         "lengths": lengths, "active": active}
                 # adapter factors are scan CONSTANTS (closure capture of
                 # the traced outer arg), never carries — each step
                 # re-gathers by the same per-slot ids
-                if adapters is not None:
-                    cache["adapters"] = adapters
                 logits, cache = module.apply(
                     {"params": materialize(params)}, tok[:, None],
-                    cache=cache)
+                    cache=kv_cache.decode_step(layers, page_table, lengths,
+                                               active, adapters=adapters))
                 nxt = _sample_tokens(logits[:, 0],
                                      jax.random.fold_in(rng, i), do_sample,
                                      temperature, top_k, top_p)
                 nxt = jnp.where(active, nxt.astype(jnp.int32), tok)
                 emitted = emitted + active.astype(jnp.int32)
                 new_active = active & (nxt != eos_ids) & (emitted < budgets)
-                return (nxt, new_active, cache["lengths"], emitted,
-                        cache["layers"]), (nxt, active)
+                return (nxt, new_active, cache.lengths, emitted,
+                        cache.layers), (nxt, active)
             (tok, active, lengths, emitted, layers), (toks, valid) = \
                 jax.lax.scan(body,
                              (tok, active, lengths, emitted,
@@ -895,12 +887,10 @@ class InferenceEngine:
             slots, K = drafts.shape
             x = jnp.concatenate([tok[:, None], drafts], axis=1)
             cols = jnp.where(active, widths + 1, 0)
-            cache = dict(pools, page_table=page_table, lengths=lengths,
-                         active=active, widths=cols)
-            if adapters is not None:
-                cache["adapters"] = adapters
-            logits, cache = module.apply({"params": materialize(params)},
-                                         x, cache=cache)
+            logits, step = module.apply(
+                {"params": materialize(params)}, x,
+                cache=kv_cache.verify_step(pools["layers"], page_table,
+                                           lengths, cols, adapters=adapters))
             # the greedy contract: fp32 argmax, ties to the lowest id
             g = jnp.argmax(logits.astype(jnp.float32),
                            axis=-1).astype(jnp.int32)       # [slots, K+1]
@@ -934,7 +924,7 @@ class InferenceEngine:
             lengths_end = lengths + n
             accepted = jnp.minimum(a, n)
             return (out_toks, valid, tok_end, active_end, lengths_end,
-                    emitted_end, accepted, {"layers": cache["layers"]})
+                    emitted_end, accepted, step.pools)
 
         def decode_multi_policy(params, tok, active, page_table, lengths,
                                 pools, emitted, budgets, eos_ids, keys,
@@ -958,11 +948,10 @@ class InferenceEngine:
 
             def body(carry, i):
                 tok, active, lengths, emitted, counts, layers = carry
-                cache = {"layers": layers, "page_table": page_table,
-                         "lengths": lengths, "active": active}
                 logits, cache = module.apply(
                     {"params": materialize(params)}, tok[:, None],
-                    cache=cache)
+                    cache=kv_cache.decode_step(layers, page_table, lengths,
+                                               active))
                 x = policy_pipeline.process_logits(
                     logits[:, 0], counts, mask, temps, top_ks, top_ps,
                     rep_pens, pres_pens, freq_pens)
@@ -973,8 +962,8 @@ class InferenceEngine:
                     active.astype(jnp.int32))
                 emitted = emitted + active.astype(jnp.int32)
                 new_active = active & (nxt != eos_ids) & (emitted < budgets)
-                return (nxt, new_active, cache["lengths"], emitted,
-                        counts, cache["layers"]), (nxt, active)
+                return (nxt, new_active, cache.lengths, emitted,
+                        counts, cache.layers), (nxt, active)
             (tok, active, lengths, emitted, counts, layers), \
                 (toks, valid) = jax.lax.scan(
                     body, (tok, active, lengths, emitted, counts,
@@ -1006,10 +995,10 @@ class InferenceEngine:
             slots, K = drafts.shape
             x_in = jnp.concatenate([tok[:, None], drafts], axis=1)
             cols = jnp.where(active, widths + 1, 0)
-            cache = dict(pools, page_table=page_table, lengths=lengths,
-                         active=active, widths=cols)
-            logits, cache = module.apply({"params": materialize(params)},
-                                         x_in, cache=cache)
+            logits, step = module.apply(
+                {"params": materialize(params)}, x_in,
+                cache=kv_cache.verify_step(pools["layers"], page_table,
+                                           lengths, cols))
             drafts_pad = jnp.concatenate(
                 [drafts, jnp.zeros((slots, 1), jnp.int32)], axis=1)
 
@@ -1058,8 +1047,7 @@ class InferenceEngine:
             lengths_end = lengths + n
             accepted = jnp.minimum(a, n)
             return (out_toks, valid, tok_end, active_end, lengths_end,
-                    emitted_end, accepted, counts,
-                    {"layers": cache["layers"]})
+                    emitted_end, accepted, counts, step.pools)
 
         # every in/out array family gets its serving sharding
         # (serving/sharding.py): pools shard kv_heads over `model`,
@@ -1871,7 +1859,7 @@ class InferenceEngine:
                 f"exceeds max_out_tokens={self._config.max_out_tokens}; "
                 "raise max_out_tokens in the inference config")
 
-        if not self._supports_cache():
+        if self._cache_module() is None:
             return self._generate_nocache(ids, max_new_tokens, do_sample,
                                           temperature, top_k, top_p,
                                           eos_token_id)

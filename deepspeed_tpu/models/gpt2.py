@@ -21,9 +21,9 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax import lax
 
-from deepspeed_tpu.ops.attention.reference import causal_mask, mha_reference
+from deepspeed_tpu.models.lora import layer_adapters, lora_delta
+from deepspeed_tpu.ops.attention import kv_cache
 from deepspeed_tpu.runtime.zero import gather as zero_gather
 
 
@@ -122,10 +122,7 @@ class SelfAttention(nn.Module):
         # multi-tenant serving: per-slot LoRA deltas ride the paged
         # cache as a stacked side input (models/lora.py); absent for
         # base-only traffic, so that path's trace is unchanged
-        ad = cache.get("adapters") if cache is not None else None
-        if ad is not None:
-            from deepspeed_tpu.models.lora import adapter_rows, lora_delta
-            ad_rows = adapter_rows(ad, cache)
+        ad, ad_rows = layer_adapters(cache)
         qkv = _dense(3 * cfg.hidden_size, cfg, ("embed", "kv"), name="qkv",
                      use_bias=qkv_bias)(x)
         if ad is not None and "qkv" in ad:
@@ -145,196 +142,14 @@ class SelfAttention(nn.Module):
             k = apply_partial_rotary(k, positions, cfg.rotary_dim,
                                      base=cfg.rope_base,
                                      interleaved=cfg.rotary_interleaved)
-
-        new_cache = None
-        if cache is not None and "k_pages" in cache:
-            # paged serving path (serving/ subsystem): K/V live in a
-            # shared fixed-page pool indexed through a per-slot page
-            # table — sequences of any length share one preallocated
-            # cache, and the jit signature is fixed by (slots, chunk,
-            # pool, table) shapes regardless of request churn.
-            assert self.window == 0, \
-                "paged serving does not support local attn_windows yet"
-            from deepspeed_tpu.ops.attention import (decode_attention,
-                                                     paged_decode_attention)
-            from deepspeed_tpu.ops.quant.kv import (paged_gather,
-                                                    paged_write)
-            k_pages, v_pages = cache["k_pages"], cache["v_pages"]
-            num_pages, ps = k_pages.shape[0], k_pages.shape[1]
-            pt = cache["page_table"]                     # [slots, maxp]
-            max_len = pt.shape[1] * ps
-            k_pos = jnp.arange(max_len)
-            alibi = None
-            if cfg.use_alibi:
-                alibi = (alibi_slopes(cfg.num_heads)[None, :, None, None]
-                         * k_pos[None, None, None, :])
-            if "slot" in cache:
-                # chunked prefill, one row per prefilling slot: row r
-                # carries the next chunk of slot[r] (b == rows, l ==
-                # chunk).  Columns past n_valid[r] are padding (a
-                # padding ROW has n_valid == 0) — their K/V writes drop
-                # (out-of-bounds page id) and their outputs are unused.
-                # Row r starts at lengths[slot[r]], which a prefix-
-                # cache hit seeds to the cached boundary (not 0, not
-                # page-aligned): writes only touch positions >= it, so
-                # shared read-only pages below the boundary stay
-                # immutable, and the write-before-gather order makes
-                # the copy-on-write tail page's stale region harmless
-                # (every stale position is either overwritten first or
-                # masked out by k_pos <= position)
-                slot = cache["slot"]                     # [rows]
-                pos = positions                          # [rows, l]
-                valid = jnp.arange(l)[None, :] < cache["n_valid"][:, None]
-                page_ids = jnp.where(valid, pt[slot[:, None], pos // ps],
-                                     num_pages)
-                # write through the (possibly int8/fp8-quantized) pool:
-                # quantized pools carry parallel per-row scale pools
-                # that the same masked page ids update atomically
-                # (ops/quant/kv.py); float pools take the byte-identical
-                # legacy path
-                pools_out = paged_write(cache, page_ids, pos % ps, k, v)
-                k_slot, v_slot = paged_gather(pools_out, pt[slot], q.dtype)
-                seq_ax = cache.get("seq_axis")
-                if seq_ax is not None:
-                    # sequence-parallel prefill (static trace-time
-                    # marker — the engine's seq-parallel closure builds
-                    # the cache with it; one row): the paged_write
-                    # above already landed the chunk's KV — with ids
-                    # sequence-sharded, GSPMD all-gathers k/v over the
-                    # axis for the pool scatter, the collective the
-                    # comm ledger prices — and attention runs
-                    # distributed over the axis against the pool
-                    # gather.  Pages in the pool are identical to the
-                    # chunked path's, so decode/COW/donation/handoff
-                    # downstream never notice.
-                    assert b == 1, "sequence-parallel prefill is one row"
-                    assert alibi is None, \
-                        "sequence-parallel prefill does not support alibi"
-                    from deepspeed_tpu import comm as dist
-                    from deepspeed_tpu.sequence.prefill import (
-                        paged_prefill_attention)
-                    out = paged_prefill_attention(
-                        q, k, v, k_slot, v_slot, positions[0, 0],
-                        dist.get_mesh(), axis=seq_ax,
-                        impl=cache["seq_impl"])
-                else:
-                    mask = k_pos[None, None, :] <= pos[:, :, None]
-                    bias = jnp.where(mask, 0.0,
-                                     jnp.finfo(jnp.float32).min)[:, None]
-                    if alibi is not None:
-                        bias = bias + alibi
-                    out = decode_attention(q, k_slot, v_slot, bias=bias)
-            elif "widths" in cache:
-                # teacher-forced multi-token verify (speculative decode):
-                # b == slots, l == K+1 candidate tokens per slot. Column
-                # j of slot s writes position lengths[s] + j when
-                # j < widths[s] (widths is already 0 for inactive slots)
-                # and attends causally through the page table — one
-                # batched forward scores every draft instead of one scan
-                # step per token. Columns the verifier later rejects
-                # leave stale K/V past the rolled-back length; that tail
-                # is either overwritten before any later gather reads it
-                # or masked out by the k_pos <= position bias.
-                widths = cache["widths"]
-                pos = positions                          # [slots, l]
-                write = jnp.arange(l)[None, :] < widths[:, None]
-                page_ids = jnp.where(
-                    write, pt[jnp.arange(b)[:, None], pos // ps], num_pages)
-                pools_out = paged_write(cache, page_ids, pos % ps, k, v)
-                k_slot, v_slot = paged_gather(pools_out, pt, q.dtype)
-                mask = k_pos[None, None, :] <= pos[:, :, None]
-                bias = jnp.where(mask, 0.0,
-                                 jnp.finfo(jnp.float32).min)[:, None]
-                if alibi is not None:
-                    bias = bias + alibi
-                out = decode_attention(q, k_slot, v_slot, bias=bias)
-            else:
-                # continuous-batch decode: b == slots, l == 1; inactive
-                # slots write nowhere and produce ignored outputs.
-                # paged_decode_attention owns the kernel-vs-reference
-                # dispatch (engine's paged_kernel mode rides the trace
-                # scope): on a multi-device mesh the Pallas kernel
-                # runs per-shard under shard_map — kv heads over
-                # `model`, slots over `data`, the page table global —
-                # so this call site never changes with the topology
-                active = cache["active"]
-                pos = positions[:, 0]                    # [slots]
-                page_ids = jnp.where(active,
-                                     pt[jnp.arange(b), pos // ps], num_pages)
-                pools_out = paged_write(cache, page_ids, pos % ps,
-                                        k[:, 0], v[:, 0])
-                out = paged_decode_attention(
-                    q, pools_out["k_pages"], pools_out["v_pages"], pt,
-                    pos, bias=alibi,
-                    k_scale=pools_out.get("k_scale"),
-                    v_scale=pools_out.get("v_scale"))
-            # multi-chip serving: pin the pools' kv-head sharding on the
-            # updated arrays so GSPMD keeps the scatter/gather split over
-            # the `model` axis (no-op on a single-device mesh); the
-            # quantized scale pools share the payload's [pages, ps,
-            # kv_heads, 1] axis family and pin identically
-            from deepspeed_tpu.serving.sharding import constrain_kv_pages
-            new_cache = {name: constrain_kv_pages(arr)
-                         for name, arr in pools_out.items()}
-        elif cache is not None:
-            # decode: append k/v at cache["index"], attend over the valid
-            # prefix with a positional mask (same scheme as models/llama.py)
-            k_cache = lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype),
-                (0, cache["index"], 0, 0))
-            v_cache = lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype),
-                (0, cache["index"], 0, 0))
-            new_cache = {"k": k_cache, "v": v_cache,
-                         "index": cache["index"] + l}
-            max_len = k_cache.shape[1]
-            k_pos = jnp.arange(max_len)
-            mask = k_pos[None, None, :] <= positions[:, :, None]  # [b,l,max]
-            if self.window > 0:
-                mask &= k_pos[None, None, :] > \
-                    positions[:, :, None] - self.window
-            bias = jnp.where(mask, 0.0, jnp.finfo(jnp.float32).min)[:, None]
-            if cfg.use_alibi:
-                # softmax is shift-invariant per query row, so
-                # slopes * key_pos == slopes * (key_pos - query_pos)
-                bias = bias + (alibi_slopes(cfg.num_heads)[None, :, None, None]
-                               * k_pos[None, None, None, :])
-            from deepspeed_tpu.ops.attention import decode_attention
-            out = decode_attention(q, k_cache, v_cache, bias=bias)
-        elif self.window > 0:
-            # local sliding-window causal attention (GPT-Neo "local"):
-            # query attends to keys in (q_pos - window, q_pos]
-            q_pos = jnp.arange(l)[:, None]
-            k_pos = jnp.arange(l)[None, :]
-            mask = (k_pos <= q_pos) & (k_pos > q_pos - self.window)
-            bias = jnp.where(mask, 0.0,
-                             jnp.finfo(jnp.float32).min)[None, None]
-            out = mha_reference(q, k, v, causal=False, bias=bias)
-        elif cfg.use_alibi:
-            k_pos = jnp.arange(l)
-            bias = (alibi_slopes(cfg.num_heads)[None, :, None, None] *
-                    k_pos[None, None, None, :])
-            out = mha_reference(q, k, v, causal=True, bias=bias)
-        else:
-            impl = cfg.attn_impl
-            if impl == "auto":
-                # Pallas kernel needs block-aligned seq lens; oracle otherwise
-                impl = "flash" if (jax.default_backend() == "tpu" and
-                                   l % 128 == 0) else "reference"
-            if impl == "flash":
-                from deepspeed_tpu.ops.attention import flash_attention
-                out = flash_attention(q, k, v, causal=True)
-            elif impl in ("ring", "ulysses"):
-                # sequence/context parallelism over the `sequence` mesh axis
-                from deepspeed_tpu import comm as dist
-                from deepspeed_tpu.sequence import DistributedAttention
-                mesh = dist.get_mesh()
-                assert mesh is not None and \
-                    mesh.shape.get("sequence", 1) > 1, \
-                    f"attn_impl={impl} needs a mesh with a sequence axis > 1"
-                out = DistributedAttention(mesh, impl=impl)(q, k, v)
-            else:
-                out = mha_reference(q, k, v, causal=True)
+        alibi = None
+        if cfg.use_alibi:
+            alibi = lambda k_pos: (
+                alibi_slopes(cfg.num_heads)[None, :, None, None]
+                * k_pos[None, None, None, :])
+        out, new_cache = kv_cache.attend(
+            q, k, v, positions, cache, impl=cfg.attn_impl,
+            window=self.window, key_bias=alibi)
         out = out.reshape(b, l, cfg.hidden_size)
         proj_in = out
         out = _dense(cfg.hidden_size, cfg, ("heads", "embed"), name="proj",
@@ -356,7 +171,6 @@ class MLP(nn.Module):
         h = _dense(cfg.mlp_ratio * cfg.hidden_size, cfg, ("embed", "mlp"),
                    name="fc_in")(x)
         if adapters is not None and "fc_in" in adapters:
-            from deepspeed_tpu.models.lora import lora_delta
             h = h + lora_delta(x, adapters["fc_in"], ad_rows,
                                adapters["scale"])
         h = nn.relu(h) if cfg.activation == "relu" else \
@@ -364,7 +178,6 @@ class MLP(nn.Module):
         mid = h
         h = _dense(cfg.hidden_size, cfg, ("mlp", "embed"), name="fc_out")(mid)
         if adapters is not None and "fc_out" in adapters:
-            from deepspeed_tpu.models.lora import lora_delta
             h = h + lora_delta(mid, adapters["fc_out"], ad_rows,
                                adapters["scale"])
         if cfg.dropout > 0:
@@ -387,11 +200,7 @@ class Block(nn.Module):
         if plan is not None:
             x = plan.pin_batch(x)
         x_in = x
-        ad = cache.get("adapters") if cache is not None else None
-        ad_rows = None
-        if ad is not None:
-            from deepspeed_tpu.models.lora import adapter_rows
-            ad_rows = adapter_rows(ad, cache)
+        ad, ad_rows = layer_adapters(cache)
         ln1 = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                            name="ln_1")(x)
         attn_out, new_cache = SelfAttention(cfg, self.window, name="attn")(
@@ -519,23 +328,8 @@ class GPT2(nn.Module):
                 "SUBsequence, where index distance != token distance — " \
                 "local attn_windows / ALiBi biases would silently " \
                 "change meaning; disable one of the two"
-        paged = cache is not None and "page_table" in cache
         if positions is None:
-            if paged:
-                lens = cache["lengths"]
-                if "slot" in cache:      # chunked prefill (row per slot)
-                    positions = lens[cache["slot"]][:, None] + \
-                        jnp.arange(l)[None, :]
-                elif "widths" in cache:  # teacher-forced verify (l == K+1)
-                    positions = lens[:, None] + jnp.arange(l)[None, :]
-                else:                    # continuous-batch decode (l == 1)
-                    positions = lens[:, None]
-                positions = jnp.broadcast_to(positions, (b, l))
-            else:
-                start = cache["layers"][0]["index"] if cache is not None \
-                    else 0
-                positions = jnp.broadcast_to(start + jnp.arange(l)[None],
-                                             (b, l))
+            positions = kv_cache.positions(cache, b, l)
 
         wte_v, wpe_v = _make_embed_tables(self, cfg)
         # ZeRO-3 gather-at-use: under a plan the engine installed, the
@@ -602,17 +396,7 @@ class GPT2(nn.Module):
                 use_moe = (cfg.moe_num_experts > 1 and
                            i % cfg.moe_every == cfg.moe_every - 1)
                 win = cfg.attn_windows[i] if i < len(cfg.attn_windows) else 0
-                layer_cache = cache["layers"][i] if cache is not None else None
-                if paged:
-                    layer_cache = dict(layer_cache,
-                                       page_table=cache["page_table"])
-                    for key in ("slot", "n_valid", "active", "widths",
-                                "seq_axis", "seq_impl"):
-                        if key in cache:
-                            layer_cache[key] = cache[key]
-                    if "adapters" in cache:
-                        from deepspeed_tpu.models.lora import layer_adapters
-                        layer_cache["adapters"] = layer_adapters(cache, i)
+                layer_cache = kv_cache.layer_view(cache, i)
                 pk = None if pld_keeps is None else pld_keeps[i]
                 # random layerwise token dropping (reference
                 # data_routing/basic_layer.py:14 RandomLayerTokenDrop):
@@ -637,35 +421,13 @@ class GPT2(nn.Module):
                     x, deterministic, layer_cache, positions, pk)
                 new_layer_caches.append(new_c)
 
-        if paged and "slot" in cache:
-            # chunked prefill consumes ONLY each row's boundary position
-            # — skip the full-vocab head for the chunk's other positions
-            # (~30% of a prefill step at gpt2-small shapes)
-            x = jnp.take_along_axis(
-                x, jnp.maximum(cache["n_valid"] - 1, 0)[:, None, None],
-                axis=1)
-        logits = _head_logits(x, cfg, wte_v=wte_v, dense_ctor=_dense,
-                              gather_at=gather_at)
+        logits = _head_logits(kv_cache.head_rows(cache, x), cfg, wte_v=wte_v,
+                              dense_ctor=_dense, gather_at=gather_at)
         if plan is not None:
             logits = plan.pin_batch(logits)
-        if paged:
-            if "slot" in cache:
-                lengths = cache["lengths"].at[cache["slot"]].add(
-                    cache["n_valid"])
-            elif "widths" in cache:
-                # verify: widths columns written per slot (already 0 for
-                # inactive slots); the engine's verify primitive rewinds
-                # this to the emitted-token count after acceptance
-                lengths = cache["lengths"] + cache["widths"]
-            else:
-                lengths = cache["lengths"] + \
-                    cache["active"].astype(jnp.int32)
-            out_cache = dict(cache, lengths=lengths,
-                             layers=new_layer_caches)
-            return logits, out_cache
-        if cache is not None:
-            return logits, {"layers": new_layer_caches}
-        return logits
+        if cache is None:
+            return logits
+        return logits, kv_cache.advance(cache, new_layer_caches)
 
 
 def gpt2_loss_fn(logits, batch):
@@ -739,32 +501,19 @@ def gpt2_pipeline(cfg, num_stages, num_microbatches=None, layer_weights=None,
 
 def init_kv_cache(cfg: GPTConfig, batch_size, max_len=None,
                   dtype=jnp.bfloat16):
-    """Empty KV cache pytree (reference inference_context.h workspace);
-    same contract as models/llama.py init_kv_cache."""
-    max_len = max_len or cfg.max_seq_len
-    layer = lambda: {
-        "k": jnp.zeros((batch_size, max_len, cfg.num_heads, cfg.head_dim),
-                       dtype),
-        "v": jnp.zeros((batch_size, max_len, cfg.num_heads, cfg.head_dim),
-                       dtype),
-        "index": jnp.int32(0),
-    }
-    return {"layers": [layer() for _ in range(cfg.num_layers)]}
+    """Empty dense KV cache of ``generate()`` at this model's head
+    geometry (ops/attention/kv_cache.py holds the contract)."""
+    return kv_cache.init_dense(cfg.num_layers, batch_size,
+                               max_len or cfg.max_seq_len, cfg.num_heads,
+                               cfg.head_dim, dtype)
 
 
 def init_paged_kv_cache(cfg: GPTConfig, num_pages, page_size,
                         dtype=jnp.bfloat16):
-    """Per-layer paged KV pools (serving/ subsystem): ``num_pages`` fixed
-    pages of ``page_size`` tokens shared by every live sequence through a
-    page table. The table/lengths/active arrays are host-owned (the
-    scheduler passes them per call); only the pools live here.
-    ``dtype`` may be a quantized kv-dtype name ("int8"/"fp8"): the
-    layer then carries int8/fp8 payload pools plus parallel per-row f32
-    scale pools (ops/quant/kv.py storage contract)."""
-    from deepspeed_tpu.ops.quant.kv import paged_pool_layer
-    layer = lambda: paged_pool_layer(num_pages, page_size, cfg.num_heads,
-                                     cfg.head_dim, dtype)
-    return {"layers": [layer() for _ in range(cfg.num_layers)]}
+    """Per-layer paged KV pools of the serving path at this model's
+    head geometry; ``dtype`` may be a quantized kv-dtype name."""
+    return kv_cache.init_paged(cfg.num_layers, num_pages, page_size,
+                               cfg.num_heads, cfg.head_dim, dtype)
 
 
 # canonical "HF GPT-2 small" hyperparameters

@@ -20,8 +20,9 @@ batch row gathers ITS adapter's factors and adds
   rank-5 adapter served in an 8-bucket produces bit-identical deltas
   to its unpadded math.
 
-The weight dict a layer sees (``cache["adapters"]`` after the model
-top-level fans it out per layer) is::
+The weight dict a layer sees (the ``adapters`` of its
+``kv_cache.layer_view``, which slices the per-layer factor stacks out
+of the model-level pack and shares ids/scale) is::
 
     {"ids":   int32 [num_slots]          (-1 = no adapter),
      "scale": float32 [n],
@@ -35,19 +36,20 @@ not injected — adapters may cover any subset.
 
 import jax.numpy as jnp
 
+from deepspeed_tpu.ops.attention import kv_cache
 
-def adapter_rows(adapters, cache):
-    """Per-batch-row adapter ids for the current paged-cache marker.
 
-    Chunked prefill runs one row per prefilling slot (the ``slot``
-    marker is int32 [rows]), so row r's id is ``ids[slot[r]]``; decode
-    (l == 1) and teacher-forced verify (``widths`` marker) run b ==
-    num_slots with one row per slot, so the ids array maps through
+def layer_adapters(cache):
+    """(this layer's weight dict, the adapter id of each batch row) out
+    of a layer's cache view; (None, None) off the serving path and for
+    base-only traffic.  Chunked prefill runs one row per prefilling
+    slot, so row r's id is ``ids[slot[r]]``; decode and teacher-forced
+    verify run one row per slot, so the ids array maps through
     unchanged."""
-    ids = adapters["ids"]
-    if "slot" in cache:
-        return ids[cache["slot"]]
-    return ids
+    ad, rows = kv_cache.adapters_of(cache)
+    if ad is None:
+        return None, None
+    return ad, ad["ids"] if rows is None else ad["ids"][rows]
 
 
 def lora_delta(x, pack, rows, scale):
@@ -68,16 +70,6 @@ def lora_delta(x, pack, rows, scale):
     d = jnp.einsum("b...r,bro->b...o", h, bm.astype(x.dtype))
     shape = (x.shape[0],) + (1,) * (x.ndim - 1)
     return d * coef.reshape(shape).astype(x.dtype)
-
-
-def layer_adapters(cache, layer_idx):
-    """Slice the model-level ``cache["adapters"]`` side input down to
-    ONE layer's injection dict (ids/scale shared, per-layer factor
-    stacks) — the per-layer cache fan-out in gpt2/llama calls this."""
-    ad = cache.get("adapters") if cache is not None else None
-    if ad is None:
-        return None
-    return dict(ad["layers"][layer_idx], ids=ad["ids"], scale=ad["scale"])
 
 
 def lora_targets(cfg):

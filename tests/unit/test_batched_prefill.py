@@ -20,6 +20,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.models.gpt2 import GPT2, gpt2_tiny
 from deepspeed_tpu.models.llama import Llama, llama_tiny
+from deepspeed_tpu.ops.attention import kv_cache
 from deepspeed_tpu.resilience import faults
 from deepspeed_tpu.serving import PagedKVManager, ServingScheduler
 
@@ -126,15 +127,16 @@ def test_model_advances_lengths_per_row(engine):
     kv = PagedKVManager(PAGES, PS, num_slots=SLOTS, max_pages_per_slot=MAXP)
     for slot in range(SLOTS):
         assert kv.ensure_capacity(slot, 32)
-    cache = dict(pools, page_table=jnp.asarray(kv.table),
-                 lengths=jnp.asarray([5, 0, 9, 0], jnp.int32),
-                 slot=jnp.asarray([2, 0, 2, 2], jnp.int32),
-                 n_valid=jnp.asarray([CHUNK, 3, 0, 0], jnp.int32))
+    cache = kv_cache.prefill_step(
+        pools["layers"], jnp.asarray(kv.table),
+        jnp.asarray([5, 0, 9, 0], jnp.int32),
+        slot=jnp.asarray([2, 0, 2, 2], jnp.int32),
+        n_valid=jnp.asarray([CHUNK, 3, 0, 0], jnp.int32))
     logits, out = engine.module.apply(
         {"params": engine._materialize(engine.params)},
         jnp.zeros((4, CHUNK), jnp.int32), cache=cache)
     assert logits.shape[:2] == (4, 1)
-    assert list(np.asarray(out["lengths"])) == [8, 0, 9 + CHUNK, 0]
+    assert list(np.asarray(out.lengths)) == [8, 0, 9 + CHUNK, 0]
 
 
 # ------------------------------------------------------------ scheduler

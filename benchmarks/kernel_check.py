@@ -14,8 +14,9 @@ the TPU platform on CPU, so a BlockSpec that Mosaic's lowering refuses
 is caught before chip time is spent.
 
 Geometries: GPT-2 small (12 heads, d 64, context 1024), Llama-2-7B (32
-heads, d 128), and Llama-2-7B heads with 8 kv heads for GQA; pages are
-128 tokens (the TPU default of ``serving/page_manager.py``).
+heads, d 128), and Llama-2-7B heads with 8 kv heads for GQA (Mistral-7B's
+too: its prefill case has the benchmark cell's 16 rows x 33 pages); pages
+are 128 tokens (the TPU default of ``serving/page_manager.py``).
 """
 
 import dataclasses
@@ -35,6 +36,7 @@ import jax.numpy as jnp  # noqa: E402
 from deepspeed_tpu.ops.attention.decode import (  # noqa: E402
     _repeat_kv, decode_attention, gather_pages, paged_decode_attention)
 from deepspeed_tpu.ops.attention.flash import flash_attention  # noqa: E402
+from deepspeed_tpu.ops.attention.paged_prefill import paged_prefill  # noqa: E402
 from deepspeed_tpu.ops.attention.reference import mha_reference  # noqa: E402
 from deepspeed_tpu.ops.quant.kernels import int8_matmul  # noqa: E402
 
@@ -50,7 +52,7 @@ class Case:
     name: str
     fn: callable        # the kernel, interpret=False
     ref: callable       # float32 jnp reference, same signature
-    args: list          # [(kind, shape, dtype)] — see _make
+    args: list          # [(kind or draw(rng, shape), shape, dtype)] — see _make
 
     def specs(self):
         return [jax.ShapeDtypeStruct(shape, dtype)
@@ -62,6 +64,8 @@ class Case:
 
 
 def _make(rng, kind, shape, dtype):
+    if callable(kind):
+        return jnp.asarray(kind(rng, shape), dtype)
     if kind == "normal":
         return jnp.asarray(rng.standard_normal(shape, np.float32), dtype)
     if kind == "int8":
@@ -102,6 +106,15 @@ def _flash_case(name, b, l, h, d, dtype, grad):
     return Case(name, kernel, ref, args)
 
 
+def _gathered_f32(kp, vp, pt, ks, vs):
+    """Each row's whole table gathered to float32 K/V, dequantized where
+    the pool carries scales."""
+    k, v = (gather_pages(x, pt).astype(jnp.float32) for x in (kp, vp))
+    if ks is not None:
+        k, v = k * gather_pages(ks, pt), v * gather_pages(vs, pt)
+    return k, v
+
+
 def _paged_case(name, h, kv_h, d, q_dtype, kv_dtype, slots=8):
     quant = kv_dtype == jnp.int8
     pool = ("int8" if quant else "normal", (PAGES, PAGE, kv_h, d), kv_dtype)
@@ -117,9 +130,7 @@ def _paged_case(name, h, kv_h, d, q_dtype, kv_dtype, slots=8):
             k_scale=ks, v_scale=vs)
 
     def ref(q, kp, vp, pt, pos, ks=None, vs=None):
-        k, v = (gather_pages(x, pt).astype(jnp.float32) for x in (kp, vp))
-        if quant:
-            k, v = k * gather_pages(ks, pt), v * gather_pages(vs, pt)
+        k, v = _gathered_f32(kp, vp, pt, ks, vs)
         live = jnp.arange(MAXP * PAGE)[None, None, None, :] <= \
             pos[:, None, None, None]
         bias = jnp.where(live, 0.0, jnp.finfo(jnp.float32).min)
@@ -127,6 +138,51 @@ def _paged_case(name, h, kv_h, d, q_dtype, kv_dtype, slots=8):
                              _repeat_kv(k, h // kv_h),
                              _repeat_kv(v, h // kv_h), causal=False,
                              bias=bias)
+    return Case(name, kernel, ref, args)
+
+
+def _prefill_case(name, h, kv_h, d, q_dtype, kv_dtype, rows=16, l=32,
+                  maxp=MAXP):
+    """The paged flash-prefill kernel: ``rows`` chunks of ``l`` tokens,
+    each at its own start (0, page-aligned, mid-page, the table's end)
+    with its own count of valid columns (one padding row).  Padding
+    columns are zeroed on both sides: the kernel reads no page past a
+    row's last written position, the reference all of them."""
+    quant = kv_dtype == jnp.int8
+    pool = ("int8" if quant else "normal", (PAGES, PAGE, kv_h, d), kv_dtype)
+
+    def starts(rng, shape):
+        st = rng.integers(0, maxp * PAGE - l + 1, shape)
+        st[:4] = 0, PAGE, PAGE + 37, maxp * PAGE - l
+        return st
+
+    def counts(rng, shape):
+        ct = rng.integers(1, l + 1, shape)
+        ct[:4] = l, l, 0, l
+        return ct
+    args = [("normal", (rows, l, h, d), q_dtype), pool, pool,
+            ("table", (rows, maxp), jnp.int32),
+            (starts, (rows,), jnp.int32), (counts, (rows,), jnp.int32)]
+    if quant:
+        args += [("scale", (PAGES, PAGE, kv_h, 1), jnp.float32)] * 2
+
+    def valid(out, ct):
+        return jnp.where((jnp.arange(l)[None, :] < ct[:, None])
+                         [:, :, None, None], out, 0)
+
+    def kernel(q, kp, vp, pt, st, ct, ks=None, vs=None):
+        return valid(paged_prefill(q, kp, vp, ks, vs, pt, st, ct,
+                                   scale=d ** -0.5, interpret=False), ct)
+
+    def ref(q, kp, vp, pt, st, ct, ks=None, vs=None):
+        k, v = _gathered_f32(kp, vp, pt, ks, vs)
+        live = jnp.arange(maxp * PAGE)[None, None, None, :] <= \
+            (st[:, None] + jnp.arange(l)[None, :])[:, None, :, None]
+        bias = jnp.where(live, 0.0, jnp.finfo(jnp.float32).min)
+        return valid(mha_reference(q.astype(jnp.float32),
+                                   _repeat_kv(k, h // kv_h),
+                                   _repeat_kv(v, h // kv_h), causal=False,
+                                   bias=bias), ct)
     return Case(name, kernel, ref, args)
 
 
@@ -174,6 +230,15 @@ CASES = [
     _paged_case("paged_mha_h32_d128_int8kv", 32, 32, 128, bf16, i8),
     _paged_case("paged_gqa_h32_kv8_d128_bf16kv", 32, 8, 128, bf16, bf16),
     _paged_case("paged_gqa_h32_kv8_d128_int8kv", 32, 8, 128, bf16, i8),
+    # chunked prefill and verify: Mistral-7B's cell geometry (16 rows x
+    # 33 pages, chunk 32), GPT-2's MHA d 64, a verify of K+1 = 9
+    _prefill_case("prefill_gqa_h32_kv8_d128_r16_p33_bf16kv", 32, 8, 128,
+                  bf16, bf16, maxp=33),
+    _prefill_case("prefill_gqa_h32_kv8_d128_int8kv", 32, 8, 128, bf16, i8),
+    _prefill_case("prefill_mha_h12_d64_bf16kv", 12, 12, 64, bf16, bf16),
+    _prefill_case("prefill_mha_h12_d64_f32kv", 12, 12, 64, f32, f32),
+    _prefill_case("verify_gqa_h32_kv8_d128_l9_bf16kv", 32, 8, 128, bf16,
+                  bf16, rows=32, l=9),
     _decode_case("decode_h12_d64_bf16", 12, 12, 64, bf16),
     _decode_case("decode_h32_d128_bf16", 32, 32, 128, bf16),
     _decode_case("decode_gqa_h32_kv8_d128_bf16", 32, 8, 128, bf16),

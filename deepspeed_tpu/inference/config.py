@@ -72,7 +72,8 @@ class DeepSpeedInferenceConfig(BaseModel):
     mesh_dcn: Optional[Dict[str, int]] = None
     kv_cache_dtype: str = "bfloat16"
     # paged-attention kernel dispatch policy (ops/attention/decode.py
-    # paged_kernel_decision): "auto" picks the Pallas kernel on TPU
+    # paged_kernel_decision): "auto" picks the Pallas kernels — paged
+    # decode, and paged flash-prefill for prefill/verify — on TPU
     # with 128-aligned pages (shard_mapped per-shard on a multi-device
     # mesh) and the jnp gather reference otherwise; "force" pins the
     # kernel (interpret mode off-TPU — the CI parity oracle);

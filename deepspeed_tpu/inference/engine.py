@@ -17,6 +17,7 @@ TPU redesign:
     single-token decode step, KV cache as a device-resident pytree.
 """
 
+import functools
 import os
 import sys
 import time
@@ -288,10 +289,12 @@ class InferenceEngine:
         """The paged-attention kernel-eligibility decision
         (``ops/attention/decode.paged_kernel_decision``) for THIS
         engine's model + mesh + configured mode: ``{"path", "dispatch",
-        "reason"}``.  ``page_size`` comes from the live pools when
-        given (the leaves' page dim), else from the argument; the
-        serving dispatch makes the IDENTICAL decision at trace time, so
-        what health() reports is what runs."""
+        "reason"}`` of single-token decode, and under ``"multi_token"``
+        the same three for the prefill / verify path.  ``page_size``
+        comes from the live pools when given (the leaves' page dim),
+        else from the argument; the serving dispatch makes the
+        IDENTICAL decisions at trace time, so what health() reports is
+        what runs."""
         from deepspeed_tpu.ops.attention import decode as _decode_ops
         heads, kv_heads = self._model_head_counts()
         if page_size is None and pools is not None:
@@ -300,11 +303,13 @@ class InferenceEngine:
             if layers:
                 page_size = int(layers[0]["k_pages"].shape[1])
         cfg = getattr(self.module, "cfg", None)
-        return _decode_ops.paged_kernel_decision(
+        decide = functools.partial(
+            _decode_ops.paged_kernel_decision,
             num_heads=heads or 1, num_kv_heads=kv_heads or heads or 1,
             page_size=page_size, mesh=self.mesh,
             mode=self.paged_kernel_mode,
             has_bias=bool(getattr(cfg, "use_alibi", False)))
+        return dict(decide(), multi_token=decide(multi_token=True))
 
     def serving_mesh_info(self, pools=None, num_slots=None):
         """Mesh topology + serving-sharding snapshot for operators
@@ -743,9 +748,11 @@ class InferenceEngine:
         dec = self.paged_kernel_decision(page_size=page_size)
         if not getattr(self, "_paged_kernel_logged", False):
             self._paged_kernel_logged = True
-            via = f" via {dec['dispatch']}" if dec.get("dispatch") else ""
-            log_dist(f"paged attention path: {dec['path']}{via} — "
-                     f"{dec['reason']}", ranks=[0])
+            for what, d in (("decode", dec),
+                            ("prefill/verify", dec["multi_token"])):
+                via = f" via {d['dispatch']}" if d.get("dispatch") else ""
+                log_dist(f"paged attention path, {what}: {d['path']}{via}"
+                         f" — {d['reason']}", ranks=[0])
         if dec.get("blocker") == "page_size":
             import warnings
             warnings.warn(

@@ -339,7 +339,7 @@ class kernel_mode_scope:
 
 def paged_kernel_decision(*, num_heads, num_kv_heads, page_size,
                           mesh=None, mode="auto", has_bias=False,
-                          backend=None):
+                          backend=None, multi_token=False):
     """THE paged-attention kernel-eligibility decision, as data: returns
     ``{"path": "kernel"|"reference", "dispatch": "shard_map"|"direct"|
     None, "reason": str}``.  :func:`paged_decode_attention` makes this
@@ -349,6 +349,15 @@ def paged_kernel_decision(*, num_heads, num_kv_heads, page_size,
     instead of silent — the decision depends only on static config
     (model head counts, page size, mesh, backend, mode), never on
     per-step data, so the two views cannot disagree.
+
+    ``multi_token`` answers for the prefill / verify path
+    (``kv_cache._paged_multi``) instead of single-token decode: the
+    same facts decide — the ``paged_prefill`` kernel
+    (ops/attention/paged_prefill.py) takes every chunk length (it pads
+    and tiles the chunk itself), quantized pools and the decode
+    kernel's ``shard_map`` axes — and the reason names the kernel or
+    the fallback of THAT path.  A sequence-parallel prefill dispatch
+    never asks: its attention is the distributed transport's.
 
     ``dispatch`` says HOW the kernel runs: "direct" is a plain
     ``pallas_call`` (single device), "shard_map" wraps it per-shard
@@ -365,7 +374,16 @@ def paged_kernel_decision(*, num_heads, num_kv_heads, page_size,
     disp = "shard_map" if multi else "direct"
 
     def ref(reason):
+        if multi_token:
+            reason += " — prefill and verify gather each row's whole " \
+                      "page table and attend in jnp"
         return {"path": "reference", "dispatch": None, "reason": reason}
+
+    def kernel(reason):
+        if multi_token:
+            reason += " — prefill and verify run the paged_prefill " \
+                      "kernel over each row's live pages"
+        return {"path": "kernel", "dispatch": disp, "reason": reason}
 
     if has_bias:
         return ref("additive bias (ALiBi) rides the gather reference "
@@ -377,9 +395,8 @@ def paged_kernel_decision(*, num_heads, num_kv_heads, page_size,
     if mode == "reference":
         return ref("paged_kernel='reference' pins the gather fallback")
     if mode == "force":
-        return {"path": "kernel", "dispatch": disp,
-                "reason": "paged_kernel='force' pins the kernel "
-                          "(interpret mode off-TPU)"}
+        return kernel("paged_kernel='force' pins the kernel "
+                      "(interpret mode off-TPU)")
     backend = jax.default_backend() if backend is None else backend
     if backend != "tpu":
         return ref(f"off-TPU backend {backend!r}: interpret-mode Pallas "
@@ -397,10 +414,29 @@ def paged_kernel_decision(*, num_heads, num_kv_heads, page_size,
                   "enable the kernel path")
         out["blocker"] = "page_size"
         return out
-    return {"path": "kernel", "dispatch": disp,
-            "reason": "TPU backend, 128-aligned pages"
-                      + (" — shard_mapped over the mesh" if multi
-                         else "")}
+    return kernel("TPU backend, 128-aligned pages"
+                  + (" — shard_mapped over the mesh" if multi else ""))
+
+
+def trace_time_decision(num_heads, num_kv_heads, page_size, *, has_bias,
+                        force_kernel=False, multi_token=False):
+    """(:func:`paged_kernel_decision` for the program being traced, the
+    mesh its ``shard_map`` dispatch runs over — None for every other
+    dispatch).  The engine's configured mode rides
+    :class:`kernel_mode_scope`.  Inside a ``shard_map`` body the mesh
+    axes are bound: no mesh is seen there and the decision resolves
+    "direct", so the per-shard kernel never re-triggers the multi-chip
+    dispatch."""
+    from deepspeed_tpu import comm as dist
+    mesh = dist.get_mesh()
+    if mesh is not None and _inside_shard_map(mesh):
+        mesh = None
+    mode = "force" if force_kernel else (_KERNEL_MODE or "auto")
+    decision = paged_kernel_decision(
+        num_heads=num_heads, num_kv_heads=num_kv_heads,
+        page_size=page_size, mesh=mesh, mode=mode, has_bias=has_bias,
+        multi_token=multi_token)
+    return decision, mesh if decision["dispatch"] == "shard_map" else None
 
 
 def _shard_map_axes(mesh, slots, h, kv_h):
@@ -531,20 +567,12 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, positions, *,
     # over its kv-head/slot shard
     # (GSPMD cannot partition a pallas_call, so this dispatch is the
     # ONLY multi-chip kernel path — the jnp reference below remains
-    # the GSPMD-partitionable correctness oracle).  Inside a shard_map
-    # body the mesh axes are bound, _multichip_mesh reports False, and
-    # the decision resolves "direct" — the per-shard kernel never
-    # re-triggers the bypass.
-    from deepspeed_tpu import comm as dist
-    mesh = dist.get_mesh()
-    if mesh is not None and _inside_shard_map(mesh):
-        mesh = None
-    mode = "force" if force_kernel else (_KERNEL_MODE or "auto")
-    decision = paged_kernel_decision(
-        num_heads=h, num_kv_heads=kv_h, page_size=page_size, mesh=mesh,
-        mode=mode, has_bias=bias is not None)
+    # the GSPMD-partitionable correctness oracle).
+    decision, mesh = trace_time_decision(
+        h, kv_h, page_size, has_bias=bias is not None,
+        force_kernel=force_kernel)
     if l == 1 and decision["path"] == "kernel":
-        if decision["dispatch"] == "shard_map":
+        if mesh is not None:
             return _paged_decode_shard_map(
                 q, k_pages, v_pages, page_table.astype(jnp.int32),
                 positions, scale=scale, interpret=interpret, mesh=mesh,

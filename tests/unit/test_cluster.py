@@ -390,8 +390,10 @@ HEALTH_SCHEMA = {
     "mesh": (dict, type(None)),
     "mesh_devices": (int, type(None)),
     "serving_axes": (dict, type(None)),
-    # the paged-attention dispatch decision (path/dispatch/reason) —
-    # kernel vs reference must be operator-visible, never silent
+    # the paged-attention dispatch decision (path/dispatch/reason of
+    # single-token decode, and the same three under "multi_token" for
+    # prefill/verify) — kernel vs reference must be operator-visible,
+    # never silent
     "paged_attention": (dict, type(None)),
     # quantized serving memory (kv_dtype in {float32, bfloat16, int8,
     # fp8}); the byte figures reflect the TRUE quantized footprint
@@ -545,6 +547,8 @@ def test_health_schema_pinned(engine):
     for key, types in HEALTH_SCHEMA.items():
         assert isinstance(h[key], types), \
             f"health()[{key!r}] = {h[key]!r} is not {types}"
+    assert {"path", "dispatch", "reason"} <= \
+        set(h["paged_attention"]) & set(h["paged_attention"]["multi_token"])
     # the specific fields admission/routing consume must be live values
     assert h["running"] == 0 and h["completed"] == 1
     assert 0.0 <= h["page_utilization"] <= 1.0

@@ -29,13 +29,15 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu import comm as dist
+from deepspeed_tpu.ops.ssm.state import RECURRENT_STATE_REFUSALS
 from deepspeed_tpu.parallel import sharding as shd
 from deepspeed_tpu.parallel.topology import make_mesh
 from deepspeed_tpu.serving.sampling import pipeline as policy_pipeline
 from deepspeed_tpu.serving.sharding import (ServingShardingConfig,
                                             config_scope,
                                             pool_bytes_per_device,
-                                            resolve_sequence_plan)
+                                            resolve_sequence_plan,
+                                            split_pools)
 from deepspeed_tpu.tracing import jit_cache_size
 from deepspeed_tpu.utils.logging import log_dist
 
@@ -257,16 +259,21 @@ class InferenceEngine:
                 log_dist(
                     f"serving slot sharding -> {fresh.slot_axis or 'replicated'}"
                     f" for num_slots={num_slots}; rebuilding serving fns")
-                self._paged_prefill_fn = None
-                self._paged_prefill_sp_fn = None
-                self._paged_decode_fn = None
-                self._paged_decode_multi_fn = None
-                self._paged_verify_fn = None
-                self._paged_decode_policy_fn = None
-                self._paged_verify_policy_fn = None
+                self._drop_serving_fns()
             self._serving_shd = fresh
             self._serving_shd_slots = num_slots
         return self._serving_shd
+
+    def _drop_serving_fns(self):
+        """The jitted serving programs pin their out_shardings: when
+        those change, the next dispatch builds them again."""
+        self._paged_prefill_fn = None
+        self._paged_prefill_sp_fn = None
+        self._paged_decode_fn = None
+        self._paged_decode_multi_fn = None
+        self._paged_verify_fn = None
+        self._paged_decode_policy_fn = None
+        self._paged_verify_policy_fn = None
 
     def _serving_scope(self):
         """Trace scope for the model-tracing serving primitives: the
@@ -300,8 +307,10 @@ class InferenceEngine:
         if page_size is None and pools is not None:
             layers = pools.get("layers") if isinstance(pools, dict) \
                 else None
-            if layers:
-                page_size = int(layers[0]["k_pages"].shape[1])
+            # a hybrid's first blocks may hold state, not pages
+            kv = [L for L in layers or () if "k_pages" in L]
+            if kv:
+                page_size = int(kv[0]["k_pages"].shape[1])
         cfg = getattr(self.module, "cfg", None)
         decide = functools.partial(
             _decode_ops.paged_kernel_decision,
@@ -340,9 +349,18 @@ class InferenceEngine:
                 "dcn": dict(self.mesh_dcn),
             }
         if pools is not None:
-            info["kv_pool_bytes_per_device"] = pool_bytes_per_device(pools)
+            # K/V pages and per-slot recurrent state are two pools with
+            # two units (a page, a slot): the page ledgers divide the
+            # first by num_pages, so the second is counted beside it
+            kv, state = split_pools(pools)
+            info["kv_pool_bytes_per_device"] = pool_bytes_per_device(kv)
             info["kv_pool_bytes_total"] = sum(
-                int(leaf.nbytes) for leaf in jax.tree.leaves(pools))
+                int(leaf.nbytes) for leaf in jax.tree.leaves(kv))
+            if self.recurrent_state:
+                info["state_pool_bytes_per_device"] = \
+                    pool_bytes_per_device(state)
+                info["state_pool_bytes_total"] = sum(
+                    int(leaf.nbytes) for leaf in jax.tree.leaves(state))
         return info
 
     # ------------------------------------------------------------------ params
@@ -699,11 +717,36 @@ class InferenceEngine:
         mod = self._cache_module()
         if mod is None:
             raise ValueError(
-                "paged serving needs a KV-cache model contract "
-                f"(GPT2/Llama); got {type(self.module).__name__}")
+                "paged serving needs the KV-cache model contract: "
+                "init_kv_cache and init_paged_kv_cache in the model's "
+                f"module (ops/attention/kv_cache.py); "
+                f"{type(self.module).__module__} has neither")
         return mod
 
-    def init_paged_cache(self, num_pages, page_size, kv_dtype=None):
+    @property
+    def recurrent_state(self):
+        """True for a model that keeps recurrent (conv/SSM) state per
+        slot beside the page pool (its module says so)."""
+        return bool(getattr(self.module, "recurrent_state", False))
+
+    def recurrent_state_refusal(self, feature):
+        """THE rule for a model with recurrent state: None where
+        ``feature`` (a key of ``RECURRENT_STATE_REFUSALS``) can serve
+        this engine's model, else the reason it cannot — one sentence
+        naming the model, for ``health()`` or an error."""
+        if not self.recurrent_state:
+            return None
+        return (f"{type(self.module).__name__} keeps recurrent state per "
+                f"slot, which {RECURRENT_STATE_REFUSALS[feature]}")
+
+    def refuse_recurrent_state(self, feature):
+        """Raise where :meth:`recurrent_state_refusal` has a reason."""
+        why = self.recurrent_state_refusal(feature)
+        if why is not None:
+            raise ValueError(f"{feature} cannot serve this model: {why}")
+
+    def init_paged_cache(self, num_pages, page_size, kv_dtype=None,
+                         num_slots=None):
         """Device-resident per-layer K/V page pools, committed to the
         serving pool sharding (kv_heads over ``model``, page ids
         global). The page table, lengths and active mask are host-owned
@@ -760,11 +803,53 @@ class InferenceEngine:
                 "OFF (pages must tile the 128-lane TPU layout): decode "
                 "runs the gather reference path — use page_size 128 or "
                 "256 for kernel-speed paged attention", stacklevel=2)
-        pool_sh = self._serving_shardings().pool
+        if not self.recurrent_state:
+            build = functools.partial(mod.init_paged_kv_cache, cfg,
+                                      num_pages, page_size, dtype=dt)
+        else:
+            # the family sizes its per-slot state by the slot count and
+            # its pools hold more than one kind of leaf: one sharding a
+            # leaf, by the leaf's name (serving/sharding.py)
+            build = functools.partial(mod.init_paged_kv_cache, cfg,
+                                      num_pages, page_size, dtype=dt,
+                                      num_slots=num_slots)
+            struct = jax.eval_shape(build)
+            if jax.tree.structure(struct) != jax.tree.structure(
+                    getattr(self, "_pool_struct", None)):
+                self._pool_struct = struct
+                self._drop_serving_fns()
+        pool_sh = self._pool_shardings(num_slots)
         with dist.mesh_scope(self.mesh):
-            return jax.jit(lambda: mod.init_paged_kv_cache(
-                cfg, num_pages, page_size, dtype=dt),
-                out_shardings=pool_sh)()
+            return jax.jit(build, out_shardings=pool_sh)()
+
+    def _pool_shardings(self, num_slots=None):
+        """What pins the pools pytree: ONE sharding for a family whose
+        pools are K/V pages alone, one a leaf where the family keeps
+        recurrent state too."""
+        shd = self._serving_shardings(num_slots=num_slots)
+        struct = getattr(self, "_pool_struct", None)
+        return shd.pool if struct is None else shd.pool_tree(struct)
+
+    def state_bytes_per_slot(self, kv_dtype=None):
+        """Exact bytes of recurrent state ONE slot costs across all
+        layers (0 for a model without any) — beside ``kv_page_bytes``,
+        the second unit the capacity arithmetic bills in."""
+        if not self.recurrent_state:
+            return 0
+        dt = self.kv_dtype if kv_dtype is None else kv_dtype
+        if isinstance(dt, str) and dt in DTYPES:
+            dt = DTYPES[dt]
+        return self._paged_module().state_bytes_per_slot(self.module.cfg,
+                                                         dt)
+
+    def routing_counters(self, pools):
+        """The routed layers' counters riding the pools (uint32, mod
+        2**32; ``moe/held_experts.routing_stats`` summed over layers and
+        dispatches), or None for a model that routes nothing."""
+        mod = self._cache_module()
+        if mod is None or not hasattr(mod, "routing_counters"):
+            return None
+        return mod.routing_counters(pools)
 
     def kv_page_bytes(self, page_size, kv_dtype=None):
         """Exact bytes ONE paged-KV page costs across all layers (K+V
@@ -778,7 +863,8 @@ class InferenceEngine:
         dt = self.kv_dtype if kv_dtype is None else kv_dtype
         if isinstance(dt, str) and dt in DTYPES:
             dt = DTYPES[dt]
-        return kvq.kv_page_bytes(cfg.num_layers, kv_heads or heads,
+        return kvq.kv_page_bytes(getattr(cfg, "num_kv_layers",
+                                         cfg.num_layers), kv_heads or heads,
                                  cfg.head_dim, page_size, dt)
 
     def _build_serving_fns(self):
@@ -1066,7 +1152,7 @@ class InferenceEngine:
         # call — same invariant as the replicated PR-1 design, now per
         # axis family
         shd = self._serving_shardings()
-        slot, block, pool = shd.slot, shd.block, shd.pool
+        slot, block, pool = shd.slot, shd.block, self._pool_shardings()
         self._paged_prefill_fn = jax.jit(prefill, donate_argnums=(6,),
                                          out_shardings=(shd.logits, pool))
         # the sequence-parallel twin only exists when the mesh has a
@@ -1115,6 +1201,7 @@ class InferenceEngine:
         may append to it).  Page ids are traced scalars, so churn in
         which pages get copied never adds a jit signature — ONE compile
         per serving config, like the other paged primitives."""
+        self.refuse_recurrent_state("prefix_cache")
         if getattr(self, "_copy_page_fn", None) is None:
             # a page copy moves one index of the GLOBAL page dim; the
             # kv-head shards copy in place on their own devices (no
@@ -1168,6 +1255,7 @@ class InferenceEngine:
         conventional; the extra gathered page is trimmed on host).  Ids
         are a traced operand, so churn in WHICH pages transfer never
         adds a signature: exactly one compile per bucket length."""
+        self.refuse_recurrent_state("handoff")
         if getattr(self, "_chain_export_fn", None) is None:
             def export(pools, ids):
                 return [{name: arr[ids] for name, arr in L.items()}
@@ -1194,6 +1282,7 @@ class InferenceEngine:
         the padded writes, the same out-of-range discipline every paged
         write primitive rides.  Donates the pools like every other
         pool-mutating primitive; one compile per bucket length."""
+        self.refuse_recurrent_state("handoff")
         if getattr(self, "_chain_import_fn", None) is None:
             def imp(pools, payload, ids):
                 return {"layers": [
@@ -1423,6 +1512,7 @@ class InferenceEngine:
         in the standard pool, so decode / prefix-cache donation / COW /
         spec verify / handoff downstream never notice which path
         prefilled them."""
+        self.refuse_recurrent_state("seq_parallel_prefill")
         assert self.params is not None, "set_params/init_params first"
         plan = self.seq_parallel_plan()
         assert plan.usable, \
@@ -1581,6 +1671,7 @@ class InferenceEngine:
         follow-up dispatch can run straight off them; the host mirrors
         the rollback with ``PagedKVManager.truncate_slot``.  One
         compiled signature per K (the scheduler's spec-K bucket set)."""
+        self.refuse_recurrent_state("spec_decode")
         assert self.params is not None, "set_params/init_params first"
         shd = self._serving_shardings(num_slots=int(np.shape(budgets)[0]))
         if getattr(self, "_paged_verify_fn", None) is None:
@@ -1688,6 +1779,7 @@ class InferenceEngine:
         counts_end, pools)``.  One compiled signature per K bucket —
         sampling params are traced, so sampled+spec composes without
         recompiles (the gate ``ds_serve`` used to force off)."""
+        self.refuse_recurrent_state("spec_decode")
         assert self.params is not None, "set_params/init_params first"
         shd = self._serving_shardings(num_slots=int(np.shape(budgets)[0]))
         if getattr(self, "_paged_verify_policy_fn", None) is None:

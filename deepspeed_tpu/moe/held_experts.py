@@ -1,0 +1,88 @@
+"""Dropless top-k routing over a HELD share of the experts.
+
+The layer expert parallelism asks for, on the chip that holds experts
+``first .. first + held - 1`` of ``num_experts``: the router scores ALL
+experts and chooses ``k`` of them a token; this chip computes its own
+experts' part of the result for the (token, choice) pairs that landed
+on them; what the absent experts would have added is left out — that
+is the other chips' part of the sum, and nothing here stands in for
+them or for their exchange.  ``moe/sharded_moe.py`` keeps the
+capacity-factor top-1/top-2 gate that ``models/gpt2.py`` trains with.
+
+No pair is dropped whatever the imbalance: the pairs are sorted by
+expert and ONE grouped matmul pair (``jax.lax.ragged_dot``) runs over
+the groups, with the static row bound tokens x k; the rows past the
+held pairs belong to no group and cost nothing.
+"""
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+F32 = jnp.float32
+
+
+def sigmoid_topk_router(x, router_w, score_bias, k, scale, normalize=True):
+    """DeepSeek-V3-style router (no expert groups).  ``x`` [t, hidden],
+    ``router_w`` [hidden, experts], ``score_bias`` [experts].  Scores
+    ``s = sigmoid(x @ W)`` in float32; the ``k`` largest of ``s + bias``
+    are chosen (the bias takes part in the CHOICE only); weights
+    ``s[chosen] / (sum + 1e-20) * scale``.  Returns (chosen [t, k]
+    int32, weights [t, k] float32)."""
+    with jax.named_scope("router"):
+        s = jax.nn.sigmoid(jnp.dot(x.astype(F32), router_w.astype(F32),
+                                   preferred_element_type=F32))
+        _, chosen = lax.top_k(s + score_bias.astype(F32), k)
+        w = jnp.take_along_axis(s, chosen, axis=-1)
+        if normalize:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return chosen.astype(jnp.int32), w * scale
+
+
+def relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def held_experts_ffn(x, chosen, weights, w_up, w_down, first, live=None,
+                     activation=relu2):
+    """The held experts' part of ``sum_k w_k * down_k(act(up_k(x)))``.
+
+    ``x`` [t, hidden]; ``chosen``/``weights`` [t, k] from the router
+    over all experts; ``w_up`` [held, hidden, inter], ``w_down`` [held,
+    inter, hidden] are experts ``first ..``; ``live`` [t] bool marks
+    the tokens that exist (padding columns and idle slots route
+    nowhere).  Returns (out [t, hidden] in x's dtype, group sizes
+    [held] int32: the pairs each held expert computed)."""
+    t, k = chosen.shape
+    held = w_up.shape[0]
+    local = chosen - first
+    mine = (local >= 0) & (local < held)
+    if live is not None:
+        mine &= live[:, None]
+    # pairs sorted by held expert; every other pair sorts to the end
+    key = jnp.where(mine, local, held).reshape(t * k)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    with jax.named_scope("experts"):
+        up = lax.ragged_dot(x[order // k], w_up.astype(x.dtype), sizes)
+        down = lax.ragged_dot(activation(up), w_down.astype(x.dtype), sizes)
+    # back in (token, choice) order the k parts of a token add up in
+    # float32; a row outside every group was not written by the grouped
+    # matmul, so it is masked, not multiplied by a zero weight
+    down = down[jnp.argsort(order)].reshape(t, k, -1)
+    out = jnp.sum(jnp.where(mine[..., None], down.astype(F32) *
+                            weights[..., None], 0.0), axis=1)
+    return out.astype(x.dtype), sizes
+
+
+def routing_stats(chosen, sizes, live=None):
+    """uint32 [4] of one layer call: (token, choice) pairs routed, pairs
+    that landed on held experts, round(1024 x busiest held expert's
+    pairs / mean) (0 with no held pair), and 1 (the call)."""
+    t, k = chosen.shape
+    n = t if live is None else jnp.sum(live)
+    held = jnp.sum(sizes)
+    ratio = jnp.where(held > 0, jnp.max(sizes) * sizes.shape[0] /
+                      jnp.maximum(held, 1), 0.0)
+    return jnp.stack([n * k, held, jnp.round(ratio * 1024), 1]).astype(
+        jnp.uint32)

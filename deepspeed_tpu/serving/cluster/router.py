@@ -1406,6 +1406,10 @@ def make_disaggregated_group(engine, *, name="g0", num_prefill=1,
     pages handoff like any others on every path — their scale pools
     ride the same page ids, and the chunk payloads carry the scale
     leaves so transferred pages land with their own scales)."""
+    # a prefill/decode pair hands page chains over; a model that keeps
+    # recurrent state per slot has nothing to hand its state over with
+    getattr(engine, "refuse_recurrent_state", lambda feature: None)(
+        "handoff")
     if transport not in ("shared_pool", "device_put"):
         raise ValueError(f"unknown in-process transport {transport!r}")
     reps = []

@@ -86,6 +86,16 @@ class ServingMetrics:
         # online autotuner (OnlineTuner drives these; all 0 when off)
         self.tune_nudges = 0           # knob nudges applied
         self.tune_log = deque(maxlen=64)   # (step, knob, value)
+        # recurrent state beside the page pool, and routed layers (all
+        # 0 for a model with neither)
+        self.state_pool_bytes = 0      # per-slot conv/SSM state allocated
+        self.state_resets = 0          # prefill rows that began at 0
+        self.prefix_cache_refused = 0  # a prefix cache asked for, refused
+        self.moe_assignments = 0       # (token, choice) pairs routed
+        self.moe_held_assignments = 0  # of those, on experts held here
+        self.moe_load_ratio_q10 = 0    # sum of 1024 x busiest/mean, a call
+        self.moe_calls = 0             # routed-layer calls
+        self._routing_seen = None      # last raw device counters
         self.mesh_info = {}            # serving topology (record_mesh)
         self._events = []
 
@@ -166,6 +176,52 @@ class ServingMetrics:
         self._write([("serving/prefill/rows", rows, step),
                      ("serving/prefill/padded_rows", padded_rows, step),
                      ("serving/prefill/tokens", tokens, step)])
+
+    def record_state_pool(self, nbytes, step=0):
+        """One-shot gauge at scheduler construction: bytes of per-slot
+        recurrent state allocated beside the page pool."""
+        self.state_pool_bytes = int(nbytes)
+        self._write([("serving/state/pool_bytes", int(nbytes), step)])
+
+    def record_state_resets(self, step, rows):
+        """``rows`` prefill rows of one dispatch began at position 0:
+        their slots' recurrent state started from zeros."""
+        self.state_resets += int(rows)
+        self._write([("serving/state/resets", int(rows), step)])
+
+    def record_prefix_refused(self, step=0):
+        """A prefix cache was asked for and refused: the model keeps
+        recurrent state, which pages cannot share."""
+        self.prefix_cache_refused += 1
+        self._write([("serving/prefix_cache/refused", 1, step)])
+
+    def record_routing(self, step, counters):
+        """The routed layers' device counters as they stand (uint32 [4],
+        moe/held_experts.routing_stats summed over layers and calls,
+        wrapping at 2**32): the differences since the last reading are
+        added up here."""
+        now = [int(c) for c in counters]
+        seen = self._routing_seen or [0] * len(now)
+        self._routing_seen = now
+        d_all, d_held, d_ratio, d_calls = (
+            (a - b) % (1 << 32) for a, b in zip(now, seen))
+        if not d_calls:
+            return
+        self.moe_assignments += d_all
+        self.moe_held_assignments += d_held
+        self.moe_load_ratio_q10 += d_ratio
+        self.moe_calls += d_calls
+        self._write([
+            ("serving/moe/assignments", d_all, step),
+            ("serving/moe/held_assignments", d_held, step),
+            ("serving/moe/held_load_max_over_mean",
+             d_ratio / 1024.0 / d_calls, step)])
+
+    def moe_held_load_max_over_mean(self):
+        """The busiest held expert's pairs over the mean held expert's,
+        averaged over routed-layer calls."""
+        return self.moe_load_ratio_q10 / 1024.0 / self.moe_calls \
+            if self.moe_calls else 0.0
 
     def prefill_rows_per_dispatch(self):
         """Mean prefilling slots per shared prefill dispatch — how often
@@ -559,6 +615,14 @@ class ServingMetrics:
             "policy_dispatches": self.policy_dispatches,
             "grammar_violations": self.grammar_violations,
             "tune_nudges": self.tune_nudges,
+            "state_pool_bytes": self.state_pool_bytes,
+            "state_resets": self.state_resets,
+            "prefix_cache_refused": self.prefix_cache_refused,
+            "moe_assignments": self.moe_assignments,
+            "moe_held_assignments": self.moe_held_assignments,
+            "moe_calls": self.moe_calls,
+            "moe_held_load_max_over_mean":
+            round(self.moe_held_load_max_over_mean(), 4),
         }
         if wall_s:
             out["tokens_per_sec"] = round(self.tokens_emitted / wall_s, 2)

@@ -319,6 +319,31 @@ class ServingScheduler:
             max_pages_per_slot = -(-num_pages // 2) or 1
         self.kv = PagedKVManager(num_pages, page_size, num_slots,
                                  max_pages_per_slot, pool=shared_pool)
+        # THE rule for a model that keeps recurrent state per slot
+        # (engine.refuse_recurrent_state; ops/ssm/state.py says why):
+        # what cannot carry a state is refused BY NAME, never served
+        # wrong.  A prefix cache is on by default (bin/ds_serve), so it
+        # is switched off with its reason in health(); speculation, the
+        # sequence-parallel prefill and a page-chain hand-off were asked
+        # for and raise.  Continuous batching, chunked prefill, fused
+        # horizons, overlap, slot reuse and recompute-preemption carry a
+        # state as they carry pages.
+        self.recurrent_state = bool(getattr(engine, "recurrent_state",
+                                            False))
+        self._refuse = getattr(engine, "refuse_recurrent_state",
+                               lambda feature: None)
+        self.prefix_cache_refused = \
+            engine.recurrent_state_refusal("prefix_cache") \
+            if prefix_cache and self.recurrent_state else None
+        if self.prefix_cache_refused is not None:
+            prefix_cache = False
+        for feature, asked in (
+                ("spec_decode", spec_drafter is not None or
+                 spec_decode not in (None, False, "off")),
+                ("seq_parallel_prefill", bool(seq_parallel_threshold)),
+                ("handoff", on_handoff is not None)):
+            if asked:
+                self._refuse(feature)
         # radix prefix cache: finished requests donate their full pages
         # to a token-keyed index; admissions longest-prefix match and
         # share the chain read-only. Cached pages are reclaimable
@@ -336,8 +361,12 @@ class ServingScheduler:
             # engine.  int8/fp8 pools carry parallel per-row f32 scale
             # pools; every host mechanism (COW, donation, truncate,
             # handoff) is dtype-blind because it moves page IDS
+            # per-slot recurrent state is sized by the slot count; a
+            # family without any never sees the argument
+            slots = {"num_slots": self.num_slots} \
+                if self.recurrent_state else {}
             pools_ref = _PoolsRef(engine.init_paged_cache(
-                num_pages, page_size, kv_dtype=kv_dtype))
+                num_pages, page_size, kv_dtype=kv_dtype, **slots))
         elif kv_dtype is not None:
             raise ValueError(
                 "kv_dtype cannot be set on a scheduler adopting shared "
@@ -347,8 +376,9 @@ class ServingScheduler:
         # live truth for health()/operators: derived from the allocated
         # leaves, not from config (a shared pool reports what it IS)
         from deepspeed_tpu.ops.quant.kv import kv_dtype_name
-        self.kv_dtype_name = kv_dtype_name(
-            self._pools_ref.pools["layers"][0])
+        self.kv_dtype_name = kv_dtype_name(next(
+            entry for entry in self._pools_ref.pools["layers"]
+            if "k_pages" in entry))
         # prefill-worker hook: a request submitted with handoff=True
         # finishes its prompt, emits the boundary token, and hands its
         # page chain to this callback instead of decoding on
@@ -463,6 +493,11 @@ class ServingScheduler:
         self._comm_summary = None       # comm_ledger()'s health cache
         if self.mesh_info:
             self.metrics.record_mesh(self.mesh_info)
+        if self.recurrent_state:
+            self.metrics.record_state_pool(
+                self.mesh_info.get("state_pool_bytes_total", 0))
+        if self.prefix_cache_refused is not None:
+            self.metrics.record_prefix_refused()
         self.step_idx = 0
         self._ema_step_s = None      # EWMA of step wall time (health)
         # admission feasibility uses the MEDIAN of a recent window, not
@@ -682,6 +717,8 @@ class ServingScheduler:
             raise ValueError(
                 f"request of {need} tokens exceeds per-slot capacity {cap} "
                 "(min(max_pages_per_slot, num_pages) * page_size)")
+        if handoff:
+            self._refuse("handoff")
         req = Request(prompt, max_new_tokens, eos_token_id, on_token,
                       deadline_s=deadline_s)
         req.handoff = bool(handoff)
@@ -1708,6 +1745,12 @@ class ServingScheduler:
         self.metrics.record_prefill_dispatch(
             self.step_idx, rows=len(rows), padded_rows=padded,
             tokens=tokens)
+        if self.recurrent_state:
+            # a row whose first position is 0 started from zeros
+            # whatever its slot held (ops/ssm/state.py)
+            fresh = sum(1 for slot, _, _ in rows if self.lengths[slot] == 0)
+            if fresh:
+                self.metrics.record_state_resets(self.step_idx, fresh)
         return logits
 
     def _prefill_seq_parallel(self, slot, req):
@@ -1838,6 +1881,7 @@ class ServingScheduler:
         waits in ``_pending_attach`` still holding its pages (bounded:
         the cluster router only hands off what the decode side's queue
         can absorb)."""
+        self._refuse("handoff")
         if self.draining:
             raise QueueFull("scheduler is draining; handoff refused")
         t_cfg, adapter_id = self._resolve_tenant(tenant, adapter)
@@ -2873,6 +2917,7 @@ class ServingScheduler:
         # heartbeat cadence, not the hot loop), so health() reports the
         # split whether or not per-step telemetry is on.  Per-device
         # bytes derive from the existing pool_bytes_per_device figure.
+        self._pull_routing()
         mem_counts = memtel.classify(self)
         bpp = None
         per_dev = self.mesh_info.get("kv_pool_bytes_per_device")
@@ -2905,7 +2950,21 @@ class ServingScheduler:
                 self.mesh_info.get("kv_pool_bytes_per_device"),
             "kv_pool_bytes_total":
                 self.mesh_info.get("kv_pool_bytes_total"),
+            # per-slot recurrent state beside the page pool (0 / None
+            # for a model without any), and the routed layers' counters
+            "state_pool_bytes_per_device":
+                self.mesh_info.get("state_pool_bytes_per_device", 0),
+            "state_pool_bytes_total":
+                self.mesh_info.get("state_pool_bytes_total", 0),
+            "state_resets": m.state_resets,
+            "moe_assignments": m.moe_assignments,
+            "moe_held_assignments": m.moe_held_assignments,
+            "moe_held_load_max_over_mean":
+                round(m.moe_held_load_max_over_mean(), 4),
             "prefix_cache": pc is not None,
+            # why a prefix cache that was asked for is off (the rule
+            # for a model with recurrent state), else None
+            "prefix_cache_refused": self.prefix_cache_refused,
             "prefix_hit_rate": None if pc is None
             else round(pc.hit_rate(), 4),
             "tokens_reused": 0 if pc is None else pc.tokens_reused,
@@ -3035,7 +3094,17 @@ class ServingScheduler:
             "quota_shed": m.quota_shed,
         }
 
+    def _pull_routing(self):
+        """The routed layers' counters ride the pools every dispatch
+        returns; a snapshot or a summary reads them (it waits for the
+        dispatch in flight) and the metrics keep the differences."""
+        read = getattr(self.engine, "routing_counters", None)
+        counters = None if read is None else read(self.pools)
+        if counters is not None:
+            self.metrics.record_routing(self.step_idx, counters)
+
     def summary(self):
+        self._pull_routing()
         out = self.metrics.summary(getattr(self, "_wall_s", None))
         if self.mem.enabled:
             # per-request memory attribution aggregates: page-seconds

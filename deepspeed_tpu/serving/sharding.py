@@ -17,6 +17,11 @@ over a multi-chip topology is a config change, not a rewrite:
                                  GLOBAL: the host-side free list / page
                                  table / radix cache never know the mesh
   vocab        model             boundary logits a prefill chunk returns
+  ssm_heads    model             a recurrent layer's state [SLOTS, SSM_H, p, n]
+                                 (its conv tail [SLOTS, k-1, c] shards slots
+                                 alone)
+  experts      expert            the held experts of a routed layer (their
+                                 stacked weights' leading dim)
   ===========  ================  =============================================
 
 Weights already shard over ``model`` through the engine's
@@ -53,7 +58,18 @@ SERVING_AXIS_RULES = (
     ("pages", None),
     ("vocab", "model"),
     ("sequence", "sequence"),
+    ("ssm_heads", "model"),
+    ("experts", "expert"),
 )
+
+# the logical axes of a pools leaf that is not a K/V page array, by the
+# leaf's name in its layer's entry (ops/ssm/state.py; the routing
+# counters of moe/held_experts.py); every other leaf is a page array
+POOL_LEAF_AXES = {
+    "conv": ("slots", None, None),
+    "ssm": ("slots", "ssm_heads", None, None),
+    "routing": (None,),
+}
 
 
 def _mesh_axis_size(mesh, axis):
@@ -214,6 +230,28 @@ class ServingShardings:
         return NamedSharding(
             self.mesh, P(self.page_axis, None, self.kv_axis, None))
 
+    def leaf(self, logical, shape):
+        """The sharding of one array from its logical axes: ``slots``
+        as resolved for this scheduler, any other name through the rule
+        table, replicated where the mesh axis is trivial or does not
+        divide the dim."""
+        spec = []
+        for name, dim in zip(logical, shape):
+            ax = self.slot_axis if name == "slots" else \
+                self.config.axis(name) if name is not None else None
+            size = _mesh_axis_size(self.mesh, ax)
+            spec.append(ax if size > 1 and dim % size == 0 else None)
+        return NamedSharding(self.mesh, P(*spec))
+
+    def pool_tree(self, pools):
+        """One sharding a leaf for a pools pytree (arrays or their
+        ShapeDtypeStructs) that holds more than K/V pages."""
+        return {"layers": [
+            {name: self.leaf(POOL_LEAF_AXES[name], leaf.shape)
+             if name in POOL_LEAF_AXES else self.pool
+             for name, leaf in entry.items()}
+            for entry in pools["layers"]]}
+
     @property
     def slot(self):
         return NamedSharding(self.mesh, P(self.slot_axis))
@@ -228,8 +266,26 @@ class ServingShardings:
 
     def describe(self):
         """Logical-axis -> resolved mesh axis map (health()/logs)."""
-        return {"kv_heads": self.kv_axis, "slots": self.slot_axis,
-                "pages": self.page_axis, "vocab": self.vocab_axis}
+        out = {"kv_heads": self.kv_axis, "slots": self.slot_axis,
+               "pages": self.page_axis, "vocab": self.vocab_axis}
+        for name in ("ssm_heads", "experts"):
+            ax = self.config.axis(name)
+            if _mesh_axis_size(self.mesh, ax) > 1:
+                out[name] = ax
+        return out
+
+
+def split_pools(pools):
+    """(the K/V page leaves, the other leaves) of a pools pytree, each
+    as a list of per-layer dicts: pages are billed by the page, per-slot
+    state and counters are not."""
+    kv, other = [], []
+    for entry in pools["layers"]:
+        kv.append({n: a for n, a in entry.items()
+                   if n not in POOL_LEAF_AXES})
+        other.append({n: a for n, a in entry.items()
+                      if n in POOL_LEAF_AXES})
+    return kv, other
 
 
 def pool_bytes_per_device(pools):

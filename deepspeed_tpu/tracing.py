@@ -90,6 +90,21 @@ EVENT_TAXONOMY = {
         "cumulative prefill tokens not computed",
     "serving/prefix_cache/evicted_pages":
         "cached pages drained under pool pressure",
+    "serving/prefix_cache/refused":
+        "a prefix cache was asked for and refused (recurrent-state model)",
+    # recurrent state beside the page pool; routed (held-expert) layers
+    "serving/state/pool_bytes":
+        "bytes of per-slot conv/SSM state allocated beside the page pool",
+    "serving/state/resets":
+        "prefill rows of one dispatch that began at position 0 (state "
+        "started from zeros)",
+    "serving/moe/assignments":
+        "(token, choice) pairs routed since the last reading",
+    "serving/moe/held_assignments":
+        "of those, pairs on experts this chip holds",
+    "serving/moe/held_load_max_over_mean":
+        "busiest held expert's pairs over the mean, averaged over "
+        "routed-layer calls since the last reading",
     # speculative decoding
     "serving/spec/k": "draft K of one verify round",
     "serving/spec/proposed": "draft tokens scored in one round",

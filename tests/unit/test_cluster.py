@@ -390,6 +390,15 @@ HEALTH_SCHEMA = {
     "mesh": (dict, type(None)),
     "mesh_devices": (int, type(None)),
     "serving_axes": (dict, type(None)),
+    # per-slot recurrent state beside the page pool and the routed
+    # layers' counters (0 / None for a model with neither)
+    "state_pool_bytes_per_device": (int,),
+    "state_pool_bytes_total": (int,),
+    "state_resets": (int,),
+    "moe_assignments": (int,),
+    "moe_held_assignments": (int,),
+    "moe_held_load_max_over_mean": (float,),
+    "prefix_cache_refused": (str, type(None)),
     # the paged-attention dispatch decision (path/dispatch/reason of
     # single-token decode, and the same three under "multi_token" for
     # prefill/verify) — kernel vs reference must be operator-visible,

@@ -148,6 +148,10 @@ def _drive_all_serving_events(m):
     m.record_seq_prefill_route(1, 256, 16)
     m.record_seq_prefill_chunk(1, 128)
     m.record_seq_prefill_degrade(1)
+    m.record_state_pool(4096)
+    m.record_state_resets(1, 2)
+    m.record_prefix_refused()
+    m.record_routing(1, [768, 96, 3 * 1024, 1])
     m.record_seq_prefill_shed(1, 33)
     m.record_mem(1, {"slot": 3, "prefix_shared": 2, "prefix_sole": 1,
                      "handoff": 0, "draft": 0, "unattributed": 0,

@@ -41,7 +41,7 @@ from deepspeed_tpu.ops.quant.kv import is_quantized_kv
 from deepspeed_tpu.ops.ssm import mamba2, state as ssm_state
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
-ROUTING_STATS = 4       # moe/held_experts.routing_stats' length
+ROUTING_STATS = 5       # moe/held_experts.routing_stats' length
 
 
 @dataclasses.dataclass(unsafe_hash=True)
@@ -411,7 +411,7 @@ def state_bytes_per_slot(cfg: NemotronHConfig, dtype=jnp.bfloat16):
 
 
 def routing_counters(pools):
-    """uint32 [4] host array: the ``E`` layers' counters summed (mod
+    """uint32 [5] host array: the ``E`` layers' counters summed (mod
     2**32; a reader takes differences)."""
     stats = [np.asarray(entry["routing"]) for entry in pools["layers"]
              if "routing" in entry]

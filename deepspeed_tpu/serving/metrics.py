@@ -95,6 +95,7 @@ class ServingMetrics:
         self.moe_held_assignments = 0  # of those, on experts held here
         self.moe_load_ratio_q10 = 0    # sum of 1024 x busiest/mean, a call
         self.moe_calls = 0             # routed-layer calls
+        self.moe_dense_calls = 0       # of those, in the dense form
         self._routing_seen = None      # last raw device counters
         self.mesh_info = {}            # serving topology (record_mesh)
         self._events = []
@@ -196,14 +197,14 @@ class ServingMetrics:
         self._write([("serving/prefix_cache/refused", 1, step)])
 
     def record_routing(self, step, counters):
-        """The routed layers' device counters as they stand (uint32 [4],
+        """The routed layers' device counters as they stand (uint32 [5],
         moe/held_experts.routing_stats summed over layers and calls,
         wrapping at 2**32): the differences since the last reading are
         added up here."""
         now = [int(c) for c in counters]
         seen = self._routing_seen or [0] * len(now)
         self._routing_seen = now
-        d_all, d_held, d_ratio, d_calls = (
+        d_all, d_held, d_ratio, d_calls, d_dense = (
             (a - b) % (1 << 32) for a, b in zip(now, seen))
         if not d_calls:
             return
@@ -211,6 +212,7 @@ class ServingMetrics:
         self.moe_held_assignments += d_held
         self.moe_load_ratio_q10 += d_ratio
         self.moe_calls += d_calls
+        self.moe_dense_calls += d_dense
         self._write([
             ("serving/moe/assignments", d_all, step),
             ("serving/moe/held_assignments", d_held, step),
@@ -621,6 +623,7 @@ class ServingMetrics:
             "moe_assignments": self.moe_assignments,
             "moe_held_assignments": self.moe_held_assignments,
             "moe_calls": self.moe_calls,
+            "moe_dense_calls": self.moe_dense_calls,
             "moe_held_load_max_over_mean":
             round(self.moe_held_load_max_over_mean(), 4),
         }

@@ -16,3 +16,37 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
 jax.config.update("jax_threefry_partitionable", True)
+
+import sys  # noqa: E402
+
+import pytest  # noqa: E402
+
+_BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks", "chip")
+_HARNESS = {}
+
+
+def pytest_collection_finish(session):
+    """The harness's modules as the test files imported them."""
+    for f in os.listdir(_BENCH):
+        if f.endswith(".py"):
+            _HARNESS[f[:-3]] = sys.modules.get(f[:-3])
+
+
+@pytest.hookimpl(tryfirst=True)
+def pytest_runtest_setup(item):
+    """The benchmark's harness loads its modules by file and keeps them
+    in ``sys.modules`` under bare names (``drive_serve``, ``readers``).
+    A test that runs a COPY of the harness from a temporary directory
+    leaves the copy's modules there, and the next test in the same
+    worker then monkeypatches the ``drive_serve`` its file imported
+    while the harness loads and runs a third: which tests passed
+    depended on which files shared a worker.  Every test starts from
+    the modules the collection found."""
+    for name, mod in _HARNESS.items():
+        now = sys.modules.get(name)
+        if mod is not None:
+            sys.modules[name] = mod
+        elif now is not None and not (
+                getattr(now, "__file__", None) or "").startswith(_BENCH):
+            del sys.modules[name]

@@ -151,7 +151,7 @@ def _drive_all_serving_events(m):
     m.record_state_pool(4096)
     m.record_state_resets(1, 2)
     m.record_prefix_refused()
-    m.record_routing(1, [768, 96, 3 * 1024, 1])
+    m.record_routing(1, [768, 96, 3 * 1024, 1, 1])
     m.record_seq_prefill_shed(1, 33)
     m.record_mem(1, {"slot": 3, "prefix_shared": 2, "prefix_sole": 1,
                      "handoff": 0, "draft": 0, "unattributed": 0,
@@ -172,6 +172,27 @@ def _drive_all_serving_events(m):
     m.record_token(1, 0.01)
     for state in ("failed", "shed", "cancelled"):
         m.record_terminal(1, state, rid=1, reason="x")
+
+
+def test_record_routing_adds_up_differences_modulo_2_32():
+    """Two readings of the routed layers' wrapping uint32 counters: the
+    second lies past the wrap in every entry, and the metrics add the
+    differences, the dense calls beside the calls."""
+    m = ServingMetrics(None)
+    wrap = 1 << 32
+    m.record_routing(1, [wrap - 700, wrap - 90, wrap - 2048, wrap - 11,
+                         wrap - 8])
+    first = (m.moe_assignments, m.moe_held_assignments, m.moe_calls,
+             m.moe_dense_calls)
+    assert first == (wrap - 700, wrap - 90, wrap - 11, wrap - 8)
+    m.record_routing(2, [68, 6, 1024, 88, 80])   # 99 calls on, 88 dense
+    s = m.summary()
+    assert s["moe_assignments"] - first[0] == 768
+    assert s["moe_held_assignments"] - first[1] == 96
+    assert s["moe_calls"] - first[2] == 99
+    assert s["moe_dense_calls"] - first[3] == 88
+    m.record_routing(3, [68, 6, 1024, 88, 80])   # nothing ran since
+    assert m.summary()["moe_dense_calls"] == s["moe_dense_calls"]
 
 
 _CLUSTER_TAGS = ("heartbeat_miss", "failover", "replay", "retry",

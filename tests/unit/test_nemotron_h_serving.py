@@ -160,7 +160,7 @@ def test_the_state_pool_and_the_routing_counters_are_reported(served):
     cfg = sched.engine.module.cfg
     per_slot = sched.engine.state_bytes_per_slot()
     assert per_slot == 3 * (3 * cfg.conv_dim * 4 + 8 * 8 * 16 * 4)
-    routing = 3 * 4 * 4           # three E layers' uint32 [4] counters
+    routing = 3 * 5 * 4           # three E layers' uint32 [5] counters
     assert h["state_pool_bytes_total"] == 3 * per_slot + routing
     assert s["state_pool_bytes"] == h["state_pool_bytes_total"]
     # K/V pages are counted apart: one attention layer's two pools
@@ -176,6 +176,8 @@ def test_the_state_pool_and_the_routing_counters_are_reported(served):
     assert 0 < s["moe_held_assignments"] < s["moe_assignments"]
     assert s["moe_held_load_max_over_mean"] >= 1.0
     assert h["moe_assignments"] == s["moe_assignments"]
+    # every dispatch of this fixture is under the constant's token count
+    assert s["moe_dense_calls"] == s["moe_calls"] > 0
 
 
 def test_the_prefix_cache_is_refused_with_its_reason(served):

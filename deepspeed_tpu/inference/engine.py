@@ -296,8 +296,9 @@ class InferenceEngine:
         """The paged-attention kernel-eligibility decision
         (``ops/attention/decode.paged_kernel_decision``) for THIS
         engine's model + mesh + configured mode: ``{"path", "dispatch",
-        "reason"}`` of single-token decode, and under ``"multi_token"``
-        the same three for the prefill / verify path.  ``page_size``
+        "reason"}`` of single-token decode, under ``"multi_token"`` the
+        same three for the prefill / verify path, and under ``"heads"``
+        the ``[num_heads, num_kv_heads]`` decided for.  ``page_size``
         comes from the live pools when given (the leaves' page dim),
         else from the argument; the serving dispatch makes the
         IDENTICAL decisions at trace time, so what health() reports is
@@ -318,7 +319,10 @@ class InferenceEngine:
             page_size=page_size, mesh=self.mesh,
             mode=self.paged_kernel_mode,
             has_bias=bool(getattr(cfg, "use_alibi", False)))
-        return dict(decide(), multi_token=decide(multi_token=True))
+        # the head geometry decided for (20 / 4 is a query group of 5),
+        # so a fall-back is read beside what it fell back FOR
+        return dict(decide(), multi_token=decide(multi_token=True),
+                    heads=[heads, kv_heads])
 
     def serving_mesh_info(self, pools=None, num_slots=None):
         """Mesh topology + serving-sharding snapshot for operators

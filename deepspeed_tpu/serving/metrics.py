@@ -89,6 +89,7 @@ class ServingMetrics:
         # recurrent state beside the page pool, and routed layers (all
         # 0 for a model with neither)
         self.state_pool_bytes = 0      # per-slot conv/SSM state allocated
+        self.kv_pool_bytes = 0         # K/V pages allocated, beside it
         self.state_resets = 0          # prefill rows that began at 0
         self.prefix_cache_refused = 0  # a prefix cache asked for, refused
         self.moe_assignments = 0       # (token, choice) pairs routed
@@ -97,6 +98,11 @@ class ServingMetrics:
         self.moe_calls = 0             # routed-layer calls
         self.moe_dense_calls = 0       # of those, in the dense form
         self._routing_seen = None      # last raw device counters
+        # what the decode steps NEEDED (the programs compute every slot
+        # and every page of capacity): host-side sums at harvest
+        self.decode_steps = 0          # steps of the harvested horizons
+        self.decode_live_rows = 0      # sum over steps: slots that emit
+        self.decode_kv_tokens = 0      # sum over those: the slot's length
         self.mesh_info = {}            # serving topology (record_mesh)
         self._events = []
 
@@ -120,6 +126,7 @@ class ServingMetrics:
         ``health()``.  Fires before the first live step — the central
         clamp in ``_write`` lands it at step 1."""
         self.mesh_info = mesh_info
+        self.kv_pool_bytes = int(mesh_info.get("kv_pool_bytes_total") or 0)
         events = [(f"serving/mesh/{ax}", size, step)
                   for ax, size in
                   (mesh_info.get("mesh_shape") or {}).items()]
@@ -302,11 +309,18 @@ class ServingMetrics:
         self._write(
                 [("serving/tbt_ms", gap_s * 1e3, step)])
 
-    def record_horizon(self, step, horizon, tokens, device_wait_s):
+    def record_horizon(self, step, horizon, tokens, device_wait_s,
+                       live_rows=0, kv_tokens=0):
         """One fused decode horizon was harvested: its step count, the
         tokens it delivered, and how long the host blocked waiting for
-        the device (0 when the overlapped copy had already landed)."""
+        the device (0 when the overlapped copy had already landed).
+        ``live_rows`` sums, over its steps, the slots that emitted a
+        token; ``kv_tokens`` sums, over those, the slot's length at
+        that step (the keys its attention needed)."""
         self.horizons.append(horizon)
+        self.decode_steps += int(horizon)
+        self.decode_live_rows += int(live_rows)
+        self.decode_kv_tokens += int(kv_tokens)
         self._write([
                 ("serving/horizon", horizon, step),
                 ("serving/horizon_tokens", tokens, step),
@@ -618,6 +632,10 @@ class ServingMetrics:
             "grammar_violations": self.grammar_violations,
             "tune_nudges": self.tune_nudges,
             "state_pool_bytes": self.state_pool_bytes,
+            "kv_pool_bytes": self.kv_pool_bytes,
+            "decode_steps": self.decode_steps,
+            "decode_live_rows": self.decode_live_rows,
+            "decode_kv_tokens": self.decode_kv_tokens,
             "state_resets": self.state_resets,
             "prefix_cache_refused": self.prefix_cache_refused,
             "moe_assignments": self.moe_assignments,

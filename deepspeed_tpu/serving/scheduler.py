@@ -2597,7 +2597,7 @@ class ServingScheduler:
                                  cat="device", track="device",
                                  args={"horizon": rec["horizon"],
                                        "spec": bool(rec.get("spec"))})
-        pulled = 0
+        pulled = live_rows = kv_tokens = 0
         for slot in rec["slots"]:
             req = rec["reqs"][slot]
             if req.state in TERMINAL or self.slot_req[slot] is not req:
@@ -2612,6 +2612,10 @@ class ServingScheduler:
                                           "deadline expired mid-flight")
                 continue
             n = int(valid[slot].sum())
+            # step j of the n this slot emits at attends over the
+            # length it began the horizon with and its j + 1 new tokens
+            live_rows += n
+            kv_tokens += n * int(self.lengths[slot]) + n * (n + 1) // 2
             if n and req.t_last is not None:
                 # horizon-granularity time-between-tokens: the client-
                 # visible burst cadence (per-token gaps within a burst
@@ -2671,7 +2675,7 @@ class ServingScheduler:
             self.metrics.record_spec_wait(self.step_idx, wait)
         else:
             self.metrics.record_horizon(self.step_idx, rec["horizon"],
-                                        pulled, wait)
+                                        pulled, wait, live_rows, kv_tokens)
         if self.tracer.enabled:
             # host bookkeeping share of the harvest (emit callbacks,
             # retire, rollback) — the counterpart of device_wait above

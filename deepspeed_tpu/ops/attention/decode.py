@@ -9,7 +9,8 @@ score tensors in HBM, with additive bias (position mask, ALiBi).
 Design:
   * caches stay in their storage layout [batch, max_len, kv_heads, dim] —
     BlockSpecs index directly into it, no transpose copies per token.
-  * grid = (batch, k_blocks) / (slots, pages); every block spans ALL kv
+  * grid = (batch, k_blocks) / (the live (slot, page) pairs of the active
+    slots, a dynamic bound); every block spans ALL kv
     heads (Mosaic refuses a block whose last two dims are neither
     (8,128)-divisible nor the array's own), and the k axis is innermost
     so the online-softmax state lives in VMEM scratch across grid steps
@@ -177,34 +178,71 @@ def _multichip_mesh():
     return not _inside_shard_map(mesh)
 
 
-def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                         scale, page_size, np_, quantized):
+def _live_pairs(page_table, positions, active, page_size):
+    """The decode kernel's grid, from the step's own inputs: the live
+    (slot, page) pairs of the ACTIVE slots in slot order.  A slot holds
+    ``positions // page_size + 1`` live pages (its cursor's page
+    included) if it is active and none if not.  Returns (``pair`` int32
+    [slots * max_pages]: entry i is ``slot * max_pages + k`` of the i-th
+    live pair, the flat index of its page-table entry; ``pages`` int32
+    [slots * max_pages]: that entry's page id, 0 past the live entries;
+    ``n`` int32 [1]: how many entries are live).  Nothing here depends
+    on a layer, so XLA computes it once a decode step for all of them."""
+    slots, maxp = page_table.shape
+    live = jnp.minimum(positions // page_size + 1, maxp)
+    if active is not None:
+        live = jnp.where(active, live, 0)
+    ends = jnp.cumsum(live)
+    i = jnp.arange(slots * maxp, dtype=jnp.int32)
+    slot = jnp.minimum(
+        jnp.searchsorted(ends, i, side="right", method="compare_all"),
+        slots - 1).astype(jnp.int32)
+    pair = slot * maxp + jnp.minimum(i - (ends - live)[slot], maxp - 1)
+    pair = pair.astype(jnp.int32)
+    pages = jnp.where(i < ends[-1], page_table.reshape(-1)[pair], 0)
+    return pair, pages, ends[-1:].astype(jnp.int32)
+
+
+def _paged_decode_kernel(pair_ref, page_ref, pos_ref, n_ref, q_ref, k_ref,
+                         v_ref, *rest, scale, page_size, maxp, quantized):
     """Paged variant of ``_decode_kernel``: one grid step is ALL kv heads
-    of one slot against ONE cache page, fetched through the prefetched
-    page table (the BlockSpec index_map picks the page id, so K/V stream
-    page-by-page from the shared pool — the gathered [slots, max_len]
-    copy of the jnp fallback never exists).  ``q`` arrives grouped
-    [slots, kv_heads, group, d] (query head kv*group + g belongs to kv
-    head kv — the contiguous grouping ``_repeat_kv`` spells out), so MHA
-    is the group == 1 case of the same kernel and a GQA pool is never
-    expanded to full heads.  Every block spans the pool's trailing
-    (kv_heads, d) dims whole: Mosaic's lowering refuses a per-kv-head
-    block (its last two dims must be (8,128)-divisible or equal the
-    array's).  The validity mask is computed in-kernel from the
-    prefetched per-slot position: key position ``page * page_size +
-    offset`` is live iff <= the slot's current position.
+    of one slot against ONE cache page, and the grid is the step's LIVE
+    (slot, page) pairs in slot order (:func:`_live_pairs`, prefetched;
+    its length is the grid's dynamic bound) — a slot that is idle, still
+    prefilling or finished appears in no pair, and a page past a slot's
+    cursor in none either, so the kernel's work is what the batch holds
+    and not ``slots * max_pages``.  The BlockSpec index maps pick the
+    pair's slot (q, output) and page id (K/V), so K/V stream page-by-page
+    from the shared pool — the gathered [slots, max_len] copy of the jnp
+    fallback never exists — and the next pair's page is in flight while
+    this one is computed, across slot boundaries too.  The
+    online-softmax state (float32 scratch) is reset at a slot's first
+    page and the slot's output row written at its last; a slot in no
+    pair keeps the zeros its row of the output starts from.
+
+    ``q`` arrives grouped [slots, kv_heads, group, d] (query head
+    kv*group + g belongs to kv head kv — the contiguous grouping
+    ``_repeat_kv`` spells out), so MHA is the group == 1 case of the
+    same kernel and a GQA pool is never expanded to full heads.  Every
+    block spans the pool's trailing (kv_heads, d) dims whole: Mosaic's
+    lowering refuses a per-kv-head block (its last two dims must be
+    (8,128)-divisible or equal the array's).  The validity mask is
+    computed in-kernel from the prefetched per-slot position: key
+    position ``page * page_size + offset`` is live iff <= the slot's
+    current position (only the cursor's page has dead ones).
 
     ``quantized`` appends the per-row scale refs ([1, page_size,
     kv_heads, 1] blocks of the parallel scale pool, fetched through the
-    SAME page-table index map, so a page and its scales are one unit)
+    SAME page-id index map, so a page and its scales are one unit)
     and dequantizes in VMEM right before the dot — only quantized bytes
     ever stream from HBM."""
     if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        ks_ref, vs_ref, _, o_ref, m_scr, l_scr, acc_scr = rest
     else:
-        o_ref, m_scr, l_scr, acc_scr = rest
-    si = pl.program_id(0)
-    ki = pl.program_id(1)
+        _, o_ref, m_scr, l_scr, acc_scr = rest
+    i = pl.program_id(0)
+    ki = jax.lax.rem(pair_ref[i], maxp)
+    pos = pos_ref[jax.lax.div(pair_ref[i], maxp)]
 
     @pl.when(ki == 0)
     def _init():
@@ -212,57 +250,56 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    pos = len_ref[si]
+    q = q_ref[0]                                          # [kv_h, g, d]
+    k = k_ref[0]                                          # [ps, kv_h, d]
+    v = v_ref[0]
+    if quantized:
+        k = (k.astype(jnp.float32) *
+             ks_ref[0].astype(jnp.float32)).astype(q.dtype)
+        v = (v.astype(jnp.float32) *
+             vs_ref[0].astype(jnp.float32)).astype(q.dtype)
+    # leading-batch dot over kv heads (Mosaic supports batch dims
+    # only at position 0 on both sides)
+    k = k.transpose(1, 0, 2)                              # [kv_h, ps, d]
+    v = v.transpose(1, 0, 2)
+    s = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * scale       # [kv_h, g, ps]
+    k_pos = ki * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (1, 1, page_size), 2)
+    s = jnp.where(k_pos <= pos, s, NEG_INF)
 
-    # skip pages entirely past the slot's live prefix (their state
-    # contribution is exactly zero); the page the cursor sits in still
-    # runs with the in-kernel mask
-    @pl.when(ki * page_size <= pos)
-    def _compute():
-        q = q_ref[0]                                      # [kv_h, g, d]
-        k = k_ref[0]                                      # [ps, kv_h, d]
-        v = v_ref[0]
-        if quantized:
-            k = (k.astype(jnp.float32) *
-                 ks_ref[0].astype(jnp.float32)).astype(q.dtype)
-            v = (v.astype(jnp.float32) *
-                 vs_ref[0].astype(jnp.float32)).astype(q.dtype)
-        # leading-batch dot over kv heads (Mosaic supports batch dims
-        # only at position 0 on both sides)
-        k = k.transpose(1, 0, 2)                          # [kv_h, ps, d]
-        v = v.transpose(1, 0, 2)
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale   # [kv_h, g, ps]
-        k_pos = ki * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page_size), 2)
-        s = jnp.where(k_pos <= pos, s, NEG_INF)
+    m_prev = m_scr[:, :, :1]                              # [kv_h, g, 1]
+    l_prev = l_scr[:, :, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    # position 0 of page 0 is live for every listed slot, so m_new is
+    # finite from a slot's first page on and exp() needs no
+    # fully-masked-row guard
+    p = jnp.exp(s - m_new)
+    l_new = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)               # [kv_h, g, d]
+    acc_scr[:] = acc_scr[:] * alpha + pv
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-        m_prev = m_scr[:, :, :1]                          # [kv_h, g, 1]
-        l_prev = l_scr[:, :, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # position 0 of page 0 is live for every slot, so m_new is
-        # finite from the first computed page on and exp() needs no
-        # fully-masked-row guard
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)           # [kv_h, g, d]
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    # the slot's last listed page: its cursor's.  With no live pair at
+    # all the grid is one step over entry 0, whose row must read zeros
+    live = i < n_ref[0]
 
-    @pl.when(ki == np_ - 1)
+    @pl.when(jnp.logical_or(
+        ki == jnp.minimum(jax.lax.div(pos, page_size), maxp - 1),
+        jnp.logical_not(live)))
     def _finalize():
-        l = l_scr[:, :, :1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+        o_ref[0] = jnp.where(live, acc_scr[:] / l_scr[:, :, :1],
+                             0.0).astype(o_ref.dtype)
 
 
 def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
-                         scale, interpret, k_scale=None, v_scale=None):
+                         scale, interpret, k_scale=None, v_scale=None,
+                         active=None):
     slots, one, h, d = q.shape
     page_size, kv_h = k_pages.shape[1], k_pages.shape[2]
     maxp = page_table.shape[1]
@@ -271,12 +308,15 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
     # [slots, 1, h, d] -> [slots, kv_h, group, d]: head kv*group + g is
     # kv head kv's g-th query head (the _repeat_kv grouping)
     q_g = q.reshape(slots, kv_h, group, d)
+    pair, pages, n = _live_pairs(page_table, positions, active, page_size)
 
-    def page_index(si, ki, pt, ln):
-        return (pt[si, ki], 0, 0, 0)
+    def slot_index(i, pair, pages, pos, n):
+        return (pair[i] // maxp, 0, 0, 0)
 
-    q_spec = pl.BlockSpec((1, kv_h, group, d),
-                          lambda si, ki, pt, ln: (si, 0, 0, 0))
+    def page_index(i, pair, pages, pos, n):
+        return (pages[i], 0, 0, 0)
+
+    q_spec = pl.BlockSpec((1, kv_h, group, d), slot_index)
     page_spec = pl.BlockSpec((1, page_size, kv_h, d), page_index)
     in_specs = [q_spec, page_spec, page_spec]
     operands = [q_g, k_pages, v_pages]
@@ -284,12 +324,16 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
         scale_spec = pl.BlockSpec((1, page_size, kv_h, 1), page_index)
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
+    # the output starts as zeros (aliased in, never fetched): the rows
+    # of slots in no pair are never visited
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+    operands.append(jnp.zeros(q_g.shape, q.dtype))
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                               page_size=page_size, np_=maxp,
+                               page_size=page_size, maxp=maxp,
                                quantized=quantized)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(slots, maxp),
+        num_scalar_prefetch=4,
+        grid=(jnp.maximum(n[0], 1),),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
@@ -301,8 +345,9 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, kv_h, group, d), q.dtype),
+        input_output_aliases={4 + len(operands) - 1: 0},
         interpret=interpret,
-    )(page_table, positions, *operands)
+    )(pair, pages, positions, n, *operands)
     return out.reshape(slots, 1, h, d)
 
 
@@ -461,14 +506,14 @@ def _shard_map_axes(mesh, slots, h, kv_h):
 
 def _paged_decode_shard_map(q, k_pages, v_pages, page_table, positions,
                             *, scale, interpret, mesh, k_scale=None,
-                            v_scale=None):
+                            v_scale=None, active=None):
     """Run the paged kernel per-shard over the serving mesh: kv pools
     enter sharded [pages, ps, KV_H/model, dim] (each device holds its
     kv-head slice of EVERY page — page ids are global, the host-side
-    page table needs no translation), q/page_table/positions shard
-    their slot dim over ``data``, and each shard runs the ordinary
-    kernel on its local arrays — so per-shard BlockSpecs need no new
-    indexing, and GQA groups stay intact (the q-head group belonging
+    page table needs no translation), q/page_table/positions/active
+    shard their slot dim over ``data``, and each shard runs the ordinary
+    kernel on its local arrays — its work list is its own slots' live
+    pages — and GQA groups stay intact (the q-head group belonging
     to the local kv shard rides in; a sharded MHA model sees grouped
     heads the same way).  Inside the body ``_multichip_mesh`` reports
     False (the axis names are bound), so nothing re-triggers the mesh
@@ -479,18 +524,20 @@ def _paged_decode_shard_map(q, k_pages, v_pages, page_table, positions,
     head_ax, slot_ax = _shard_map_axes(mesh, slots, h, kv_h)
     q_spec = P(slot_ax, None, head_ax, None)
     pool_spec = P(None, None, head_ax, None)
+    if active is None:
+        active = jnp.ones((slots,), bool)
     in_specs = [q_spec, pool_spec, pool_spec, P(slot_ax, None),
-                P(slot_ax)]
-    args = [q, k_pages, v_pages, page_table, positions]
+                P(slot_ax), P(slot_ax)]
+    args = [q, k_pages, v_pages, page_table, positions, active]
     if k_scale is not None:
         in_specs += [pool_spec, pool_spec]
         args += [k_scale, v_scale]
 
-    def body(q_, kp_, vp_, pt_, pos_, *scales):
+    def body(q_, kp_, vp_, pt_, pos_, act_, *scales):
         ks, vs = scales if scales else (None, None)
         return _paged_decode_pallas(q_, kp_, vp_, pt_, pos_, scale=scale,
                                     interpret=interpret, k_scale=ks,
-                                    v_scale=vs)
+                                    v_scale=vs, active=act_)
 
     return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                          out_specs=q_spec, check_vma=False)(*args)
@@ -509,12 +556,19 @@ def gather_pages(pages, page_table):
 def paged_decode_attention(q, k_pages, v_pages, page_table, positions, *,
                            scale=None, bias=None, interpret=None,
                            force_kernel=False, k_scale=None,
-                           v_scale=None):
+                           v_scale=None, active=None):
     """Single-token attention of ``q`` [slots, 1, heads, d] over a PAGED
     cache: a shared pool ``k_pages``/``v_pages`` [num_pages, page_size,
     kv_heads, d] indexed through ``page_table`` [slots, max_pages] with
     per-slot query ``positions`` [slots] (key positions <= position are
     live — the current token's k/v must already be written).
+
+    ``active`` (optional, bool [slots]; None = every slot) marks the
+    slots whose output the step uses.  The kernel walks the live pages
+    of those alone — its work is proportional to what the batch holds,
+    not to ``slots * max_pages`` — and writes an inactive slot's row as
+    zeros; the fallback computes every row (an inactive row's output is
+    finite and ignored).
 
     ``k_scale``/``v_scale`` (optional, [num_pages, page_size, kv_heads,
     1] f32) mark a QUANTIZED pool (int8/fp8 payload + per-row scales,
@@ -576,11 +630,12 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, positions, *,
             return _paged_decode_shard_map(
                 q, k_pages, v_pages, page_table.astype(jnp.int32),
                 positions, scale=scale, interpret=interpret, mesh=mesh,
-                k_scale=k_scale, v_scale=v_scale)
+                k_scale=k_scale, v_scale=v_scale, active=active)
         return _paged_decode_pallas(q, k_pages, v_pages,
                                     page_table.astype(jnp.int32), positions,
                                     scale=scale, interpret=interpret,
-                                    k_scale=k_scale, v_scale=v_scale)
+                                    k_scale=k_scale, v_scale=v_scale,
+                                    active=active)
 
     k_full = gather_pages(k_pages, page_table)
     v_full = gather_pages(v_pages, page_table)

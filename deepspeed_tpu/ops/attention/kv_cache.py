@@ -223,7 +223,8 @@ def attend(q, k, v, positions, cache, *, impl="auto", window=0,
         pools = paged_write(pools, page_ids, pos % ps, k[:, 0], v[:, 0])
         out = paged_decode_attention(
             q, pools["k_pages"], pools["v_pages"], pt, pos, bias=bias,
-            k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"))
+            k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"),
+            active=cache.count)
     # multi-chip serving: pin the pools' kv-head sharding on the updated
     # arrays so GSPMD keeps the scatter/gather split over the `model`
     # axis (no-op on a single-device mesh; GQA pools shard num_kv_heads,

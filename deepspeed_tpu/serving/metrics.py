@@ -103,6 +103,8 @@ class ServingMetrics:
         self.decode_steps = 0          # steps of the harvested horizons
         self.decode_live_rows = 0      # sum over steps: slots that emit
         self.decode_kv_tokens = 0      # sum over those: the slot's length
+        self.decode_live_pages = 0     # sum over those: the pages it spans
+        self.decode_table_pages = 0    # sum over steps: slots x max_pages
         self.mesh_info = {}            # serving topology (record_mesh)
         self._events = []
 
@@ -310,17 +312,23 @@ class ServingMetrics:
                 [("serving/tbt_ms", gap_s * 1e3, step)])
 
     def record_horizon(self, step, horizon, tokens, device_wait_s,
-                       live_rows=0, kv_tokens=0):
+                       live_rows=0, kv_tokens=0, live_pages=0,
+                       table_pages=0):
         """One fused decode horizon was harvested: its step count, the
         tokens it delivered, and how long the host blocked waiting for
         the device (0 when the overlapped copy had already landed).
         ``live_rows`` sums, over its steps, the slots that emitted a
         token; ``kv_tokens`` sums, over those, the slot's length at
-        that step (the keys its attention needed)."""
+        that step (the keys its attention needed) and ``live_pages`` the
+        pages that length spans (what the paged decode kernel walks);
+        ``table_pages`` is one step's whole page table, slots x pages a
+        slot."""
         self.horizons.append(horizon)
         self.decode_steps += int(horizon)
         self.decode_live_rows += int(live_rows)
         self.decode_kv_tokens += int(kv_tokens)
+        self.decode_live_pages += int(live_pages)
+        self.decode_table_pages += int(horizon) * int(table_pages)
         self._write([
                 ("serving/horizon", horizon, step),
                 ("serving/horizon_tokens", tokens, step),
@@ -636,6 +644,9 @@ class ServingMetrics:
             "decode_steps": self.decode_steps,
             "decode_live_rows": self.decode_live_rows,
             "decode_kv_tokens": self.decode_kv_tokens,
+            "decode_live_page_share":
+            round(self.decode_live_pages / self.decode_table_pages, 4)
+            if self.decode_table_pages else None,
             "state_resets": self.state_resets,
             "prefix_cache_refused": self.prefix_cache_refused,
             "moe_assignments": self.moe_assignments,

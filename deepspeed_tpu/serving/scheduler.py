@@ -2597,7 +2597,7 @@ class ServingScheduler:
                                  cat="device", track="device",
                                  args={"horizon": rec["horizon"],
                                        "spec": bool(rec.get("spec"))})
-        pulled = live_rows = kv_tokens = 0
+        pulled = live_rows = kv_tokens = live_pages = 0
         for slot in rec["slots"]:
             req = rec["reqs"][slot]
             if req.state in TERMINAL or self.slot_req[slot] is not req:
@@ -2613,9 +2613,13 @@ class ServingScheduler:
                 continue
             n = int(valid[slot].sum())
             # step j of the n this slot emits at attends over the
-            # length it began the horizon with and its j + 1 new tokens
+            # length it began the horizon with and its j + 1 new tokens,
+            # i.e. the pages up to its cursor at position length + j
+            length = int(self.lengths[slot])
             live_rows += n
-            kv_tokens += n * int(self.lengths[slot]) + n * (n + 1) // 2
+            kv_tokens += n * length + n * (n + 1) // 2
+            live_pages += sum((length + j) // self.kv.page_size + 1
+                              for j in range(n))
             if n and req.t_last is not None:
                 # horizon-granularity time-between-tokens: the client-
                 # visible burst cadence (per-token gaps within a burst
@@ -2675,7 +2679,8 @@ class ServingScheduler:
             self.metrics.record_spec_wait(self.step_idx, wait)
         else:
             self.metrics.record_horizon(self.step_idx, rec["horizon"],
-                                        pulled, wait, live_rows, kv_tokens)
+                                        pulled, wait, live_rows, kv_tokens,
+                                        live_pages, self.kv.table.size)
         if self.tracer.enabled:
             # host bookkeeping share of the harvest (emit callbacks,
             # retire, rollback) — the counterpart of device_wait above

@@ -195,6 +195,32 @@ def test_record_routing_adds_up_differences_modulo_2_32():
     assert m.summary()["moe_dense_calls"] == s["moe_dense_calls"]
 
 
+def test_decode_live_page_share_is_live_pages_over_the_tables_walked():
+    """Hand-made horizons over a table of 4 slots x 6 pages: the share
+    is the pages the emitting slots' lengths span over steps x 24; no
+    harvested step reads None, never a division error; a spec harvest
+    (no horizon recorded) leaves it alone."""
+    m = ServingMetrics(None)
+    assert m.summary()["decode_live_page_share"] is None
+    m.record_spec_wait(1, 0.001)
+    assert m.summary()["decode_live_page_share"] is None
+    # 8 steps; two slots emit 8 tokens each: one inside its first page,
+    # one crossing from its second page into its third after 3 steps
+    m.record_horizon(1, 8, 16, 0.0, live_rows=16, kv_tokens=400,
+                     live_pages=8 * 1 + (3 * 2 + 5 * 3), table_pages=24)
+    assert m.summary()["decode_live_page_share"] == round(29 / (8 * 24), 4)
+    # 4 steps in which every slot is at capacity: the whole table
+    m.record_horizon(2, 4, 16, 0.0, live_rows=16, kv_tokens=16 * 96,
+                     live_pages=4 * 24, table_pages=24)
+    s = m.summary()
+    assert s["decode_steps"] == 12
+    assert s["decode_live_page_share"] == round((29 + 96) / (12 * 24), 4)
+    # a horizon in which nothing emitted adds steps and no pages
+    m.record_horizon(3, 8, 0, 0.0, table_pages=24)
+    assert m.summary()["decode_live_page_share"] == \
+        round(125 / (20 * 24), 4)
+
+
 _CLUSTER_TAGS = ("heartbeat_miss", "failover", "replay", "retry",
                  "handoff", "handoff_degrade", "drain", "restart")
 

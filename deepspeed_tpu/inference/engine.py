@@ -38,7 +38,7 @@ from deepspeed_tpu.serving.sharding import (ServingShardingConfig,
                                             pool_bytes_per_device,
                                             resolve_sequence_plan,
                                             split_pools)
-from deepspeed_tpu.tracing import jit_cache_size
+from deepspeed_tpu.tracing import annotation, jit_cache_size
 from deepspeed_tpu.utils.logging import log_dist
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16,
@@ -1328,15 +1328,16 @@ class InferenceEngine:
         call (jit compiles synchronously at dispatch, so this call's
         wall time IS compile + dispatch)."""
         wd = self._compile_watchdog
-        if wd is None:
-            return fn(*args)
-        n0 = jit_cache_size(fn)
-        t0 = time.monotonic()
-        out = fn(*args)
-        n1 = jit_cache_size(fn)
-        if n1 > n0:
-            wd.on_compile(name, n1 - n0, t0, time.monotonic(),
-                          detail=detail)
+        if wd is not None:
+            n0 = jit_cache_size(fn)
+            t0 = time.monotonic()
+        with annotation("ds.engine.launch", program=name):
+            out = fn(*args)
+        if wd is not None:
+            n1 = jit_cache_size(fn)
+            if n1 > n0:
+                wd.on_compile(name, n1 - n0, t0, time.monotonic(),
+                              detail=detail)
         return out
 
     def enable_comm_telemetry(self, enabled=True):
@@ -1591,10 +1592,11 @@ class InferenceEngine:
         (zero-copy on CPU) or read it asynchronously (TPU), and the
         scheduler mutates its live ``lengths``/page-table state right
         after the dispatch returns."""
-        staged = [x if isinstance(x, jax.Array) and x.dtype == dt
-                  else np.array(x, dt) for x, dt, _ in triples]
-        return jax.device_put(tuple(staged),
-                              tuple(sh for _, _, sh in triples))
+        with annotation("ds.engine.stage"):
+            staged = [x if isinstance(x, jax.Array) and x.dtype == dt
+                      else np.array(x, dt) for x, dt, _ in triples]
+            return jax.device_put(tuple(staged),
+                                  tuple(sh for _, _, sh in triples))
 
     def decode_multi(self, toks, active, page_table, lengths, pools, *,
                      horizon, budgets, eos_ids, emitted=None,

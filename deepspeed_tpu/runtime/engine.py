@@ -58,7 +58,7 @@ from deepspeed_tpu.runtime.fp16.loss_scaler import (LossScaleState, has_overflow
                                                     update_scale)
 from deepspeed_tpu.runtime.lr_schedules import LRScheduler, get_lr_schedule
 from deepspeed_tpu.runtime.optimizers import build_optimizer
-from deepspeed_tpu.tracing import NULL_TRACER, jit_cache_size
+from deepspeed_tpu.tracing import NULL_TRACER, annotation, jit_cache_size
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (BACKWARD_GLOBAL_TIMER,
                                        FORWARD_GLOBAL_TIMER, STEP_GLOBAL_TIMER,
@@ -2236,43 +2236,57 @@ class DeepSpeedEngine:
             "scan-fused train_loop yet; drive it through " \
             "forward()/backward()/step()"
         k = len(batches) // self.gas
-        self.tput_timer.start()
-        self._last_batch = batches[0]
-        if self.gas == 1:
-            dev = self._stack_batches(batches)
-        else:
-            # [K, gas, ...]: scan axis over windows, unrolled micro axis
-            stacked = jax.tree.map(
-                lambda *xs: np.stack(xs).reshape(
-                    (k, self.gas) + np.shape(xs[0])), *batches)
-            base = self._batch_sharding(batches[0])
-            dev = jax.tree.map(
-                lambda x, s: jax.device_put(
-                    jnp.asarray(x),
-                    NamedSharding(self.mesh, P(None, None, *s.spec))),
-                stacked, base)
-        rngs = jax.random.split(self._rng, k + 1)
-        self._rng = rngs[0]
-        lrs = []
-        for _ in range(k):   # the loop really takes k steps: advance the
-            lrs.append(float(self.get_lr()[0]))     # schedule as it goes
-            if self.lr_scheduler is not None:
-                self.lr_scheduler.step()
-        losses, new_state, metrics = self._dispatch_step(
-            "_step_loop", self.state.params, self.state.opt_state,
-            self.state.replace(params=None, opt_state=None),
-            dev, rngs[1:], jnp.asarray(lrs, jnp.float32))
-        self.state = new_state
-        self.micro_steps += k * self.gas
-        self.global_steps += k
-        self.global_samples += self.train_micro_batch_size_per_gpu() * \
-            self.dp_world_size * k * self.gas
-        self._last_metrics = metrics
-        self.tput_timer.stop(global_step=True, steps=k)
-        self._maybe_log_flops()
-        if self.global_steps % self._config.steps_per_print == 0:
-            self._log_train_step(float(jax.device_get(losses[-1])), metrics)
-        return jax.device_get(losses) if sync else losses
+        # host phases of one call as events of a device profile: the
+        # whole call, staging the batches, launching the fused steps,
+        # and (sync=True) the blocking pull of the losses
+        with annotation("ds.train.loop", steps=k):
+            self.tput_timer.start()
+            self._last_batch = batches[0]
+            with annotation("ds.train.stage"):
+                if self.gas == 1:
+                    dev = self._stack_batches(batches)
+                else:
+                    # [K, gas, ...]: scan axis over windows, unrolled
+                    # micro axis
+                    stacked = jax.tree.map(
+                        lambda *xs: np.stack(xs).reshape(
+                            (k, self.gas) + np.shape(xs[0])), *batches)
+                    base = self._batch_sharding(batches[0])
+                    dev = jax.tree.map(
+                        lambda x, s: jax.device_put(
+                            jnp.asarray(x),
+                            NamedSharding(self.mesh,
+                                          P(None, None, *s.spec))),
+                        stacked, base)
+            rngs = jax.random.split(self._rng, k + 1)
+            self._rng = rngs[0]
+            lrs = []
+            # the loop really takes k steps: advance the schedule as it
+            # goes
+            for _ in range(k):
+                lrs.append(float(self.get_lr()[0]))
+                if self.lr_scheduler is not None:
+                    self.lr_scheduler.step()
+            with annotation("ds.train.launch"):
+                losses, new_state, metrics = self._dispatch_step(
+                    "_step_loop", self.state.params, self.state.opt_state,
+                    self.state.replace(params=None, opt_state=None),
+                    dev, rngs[1:], jnp.asarray(lrs, jnp.float32))
+            self.state = new_state
+            self.micro_steps += k * self.gas
+            self.global_steps += k
+            self.global_samples += self.train_micro_batch_size_per_gpu() * \
+                self.dp_world_size * k * self.gas
+            self._last_metrics = metrics
+            self.tput_timer.stop(global_step=True, steps=k)
+            self._maybe_log_flops()
+            if self.global_steps % self._config.steps_per_print == 0:
+                self._log_train_step(float(jax.device_get(losses[-1])),
+                                     metrics)
+            if not sync:
+                return losses
+            with annotation("ds.train.sync"):
+                return jax.device_get(losses)
 
     def eval_batch(self, batch, _retried=False):
         """Loss-only forward (no grads). Compression-aware training

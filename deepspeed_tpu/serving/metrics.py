@@ -27,6 +27,7 @@ class ServingMetrics:
     def __init__(self, monitor=None):
         self.monitor = monitor        # MonitorMaster-compatible (or None)
         self.ttft_s = []              # submit -> first token, per request
+        self.queue_wait_s = []        # submit -> first admission, per request
         self.tpot_s = []              # inter-token gaps, per token
         self.tbt_s = []               # horizon-boundary gaps, per request
         self.completed = 0
@@ -543,6 +544,11 @@ class ServingMetrics:
         self.handoff_aborted += 1
         self._write([("serving/handoff/aborted", 1, step)])
 
+    def record_queue_wait(self, wait_s):
+        """Submit -> first admission of one request: the part of its
+        TTFT spent in the queue (the rest is prefill steps)."""
+        self.queue_wait_s.append(wait_s)
+
     def record_first_token(self, step, ttft_s):
         self.ttft_s.append(ttft_s)
         self.tokens_emitted += 1
@@ -585,6 +591,10 @@ class ServingMetrics:
             "ttft_ms_p50": round(_percentile(self.ttft_s, 50) * 1e3, 3),
             "ttft_ms_p90": round(_percentile(self.ttft_s, 90) * 1e3, 3),
             "ttft_ms_p99": round(_percentile(self.ttft_s, 99) * 1e3, 3),
+            "queue_wait_ms_p50":
+            round(_percentile(self.queue_wait_s, 50) * 1e3, 3),
+            "queue_wait_ms_p90":
+            round(_percentile(self.queue_wait_s, 90) * 1e3, 3),
             "tpot_ms_p50": round(_percentile(self.tpot_s, 50) * 1e3, 3),
             "tpot_ms_p90": round(_percentile(self.tpot_s, 90) * 1e3, 3),
             "tpot_ms_p99": round(_percentile(self.tpot_s, 99) * 1e3, 3),

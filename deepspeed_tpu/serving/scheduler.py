@@ -147,6 +147,8 @@ from deepspeed_tpu.serving.sampling import (GREEDY, GrammarConstraintError,
                                             SamplingParams, compile_grammar,
                                             request_key)
 from deepspeed_tpu.serving.trace import NULL_TRACER
+from deepspeed_tpu.tracing import Phases
+from deepspeed_tpu.utils.logging import logger
 
 WAITING, PREFILL, RUNNING, FINISHED = "waiting", "prefill", "running", \
     "finished"
@@ -156,6 +158,21 @@ CANCELLED, FAILED, SHED = "cancelled", "failed", "shed"
 # THIS scheduler, live for the cluster request it belongs to
 HANDOFF = "handoff"
 TERMINAL = (FINISHED, CANCELLED, FAILED, SHED, HANDOFF)
+
+# the phases of one step() at depth one inside "step": exhaustive and
+# disjoint, so their seconds sum to the step's wall (docs/observability.md)
+STEP_PHASES = ("chain", "device_wait", "harvest", "sweep", "admit",
+               "prefill", "spec_dispatch", "horizon_dispatch", "observe")
+# the two phases in which the host only waits for the device
+BLOCKED_PHASES = ("device_wait", "first_token_wait")
+# (cat, track) of the phases that were SpanTracer spans before they were
+# phases: a Perfetto trace keeps its rows
+PHASE_SPANS = {"device_wait": ("device", "device"),
+               "harvest": ("dispatch", "scheduler"),
+               "horizon_dispatch": ("dispatch", "scheduler"),
+               "prefill_chunk": ("dispatch", "scheduler")}
+# a step longer than this is logged with its split by phase
+SLOW_STEP_S = 1.0
 
 
 def _geometric_buckets(lo, hi, factor=2):
@@ -298,7 +315,16 @@ class ServingScheduler:
         # signatures and the hot loop are byte-identical (pinned by
         # tests/unit/test_trace.py).  Tracing is pure host bookkeeping:
         # no device op, no new jit signature, ever.
+        # the named phases of step(): each is an event of a device
+        # profile, seconds summary() reads, and a span of the tracer
+        # (the ``tracer`` setter hands them the tracer, so a replica
+        # that swaps tracers swaps theirs too)
+        self.phases = Phases(prefix="ds.sched.", spans=PHASE_SPANS)
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        self._slow_steps = 0
+        self._slow_step_max_s = 0.0
+        self._slow_step_max_blocked_s = 0.0
+        self._slow_step_log = deque(maxlen=8)
         self._t_start = time.monotonic()
         self.num_slots = int(num_slots)
         self.prefill_chunk = int(prefill_chunk)
@@ -662,6 +688,14 @@ class ServingScheduler:
         # PATH): echoed through health() so an operator can tell a
         # hand-set config from a searched one
         self.tuned_from = tuned_from
+
+    @property
+    def tracer(self):
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, value):
+        self._tracer = self.phases.tracer = value
 
     @property
     def pools(self):
@@ -1343,100 +1377,143 @@ class ServingScheduler:
         i.e. every step that is not a purely chained continuation of an
         in-flight horizon."""
         self.step_idx += 1
-        t_step = time.monotonic()
-        # fault point: slow-step / loop-level fault injection. Fires per
-        # HORIZON since the fused-decode change — with
-        # decode_horizon_steps > 1 a "step" covers up to that many
-        # tokens (docs/resilience.md documents the timing change).
-        faults.fire("serve.step", step=self.step_idx)
+        phases = self.phases
+        before = dict(phases.seconds)
+        with phases("step") as ph_step:
+            # fault point: slow-step / loop-level fault injection. Fires
+            # per HORIZON since the fused-decode change — with
+            # decode_horizon_steps > 1 a "step" covers up to that many
+            # tokens (docs/resilience.md documents the timing change).
+            faults.fire("serve.step", step=self.step_idx)
 
-        t_wait, pulled = 0.0, 0
-        chained = False
-        if self._inflight:
-            if self.overlap:
-                # overlap: put the NEXT horizon on the device before
-                # doing this one's host bookkeeping
-                chained = self._try_chain()
-            w, n = self._harvest()
-            t_wait += w
-            pulled += n
-        if not chained:
-            # conservative barrier: membership may change below, so no
-            # horizon may remain in flight (its page-table snapshot
-            # would go stale and eviction could corrupt live pages)
-            while self._inflight:
+            t_wait, pulled = 0.0, 0
+            chained = False
+            if self._inflight:
+                if self.overlap:
+                    # overlap: put the NEXT horizon on the device before
+                    # doing this one's host bookkeeping
+                    with phases("chain"):
+                        chained = self._try_chain()
                 w, n = self._harvest()
                 t_wait += w
                 pulled += n
-            now = time.monotonic()
-            # 1. cancellations + deadlines leave at the boundary
-            self._sweep(now)
-            # 2. admit waiting requests into free slots (retirement
-            # happens at harvest, so slots are already recycled);
-            # handoff chains go first — their pages are already held
-            self._admit_attached(now)
-            self._admit(now)
-            # 3. one prompt chunk per prefilling slot (chunked prefill)
-            self._prefill()
-            # 4. dispatch ONE fused decode horizon over running slots
-            self._dispatch()
-            if not self.overlap and self._inflight:
-                w, n = self._harvest()
-                t_wait += w
-                pulled += n
+            if not chained:
+                # conservative barrier: membership may change below, so
+                # no horizon may remain in flight (its page-table
+                # snapshot would go stale and eviction could corrupt
+                # live pages)
+                while self._inflight:
+                    w, n = self._harvest()
+                    t_wait += w
+                    pulled += n
+                # 1. cancellations + deadlines leave at the boundary
+                with phases("sweep") as ph:
+                    now = ph.t0
+                    self._sweep(now)
+                # 2. admit waiting requests into free slots (retirement
+                # happens at harvest, so slots are already recycled);
+                # handoff chains go first — their pages are already held
+                with phases("admit"):
+                    self._admit_attached(now)
+                    self._admit(now)
+                # 3. one prompt chunk per prefilling slot (chunked
+                # prefill)
+                with phases("prefill"):
+                    self._prefill()
+                # 4. dispatch ONE fused decode horizon over running slots
+                self._dispatch()
+                if not self.overlap and self._inflight:
+                    w, n = self._harvest()
+                    t_wait += w
+                    pulled += n
 
-        # 5. observability
-        dt = time.monotonic() - t_step
-        self._step_window.append(dt)
-        if pulled:
-            self._tok_window.append(dt / pulled)
-        self._ema_step_s = dt if self._ema_step_s is None \
-            else 0.8 * self._ema_step_s + 0.2 * dt
-        n_running = sum(r is not None for r in self.slot_req)
-        self.metrics.record_step(
-            self.step_idx, queue_depth=len(self.waiting),
-            running=n_running, waiting=len(self.waiting),
-            page_utilization=self.kv.utilization(),
-            device_wait_s=t_wait, host_s=max(0.0, dt - t_wait),
-            cached_pages=None if self.prefix_cache is None
-            else self.prefix_cache.cached_pages)
-        if self.mem.enabled:
-            # rolling page-state attribution + per-request page-seconds
-            # + sustained-pressure detection (one host sweep per step)
-            self.mem.on_step(self)
-        if self.tenancy is not None and not chained:
-            # scalar tenancy gauges per barrier step; the per-tenant
-            # split rides health()["tenants"] (scalar-only sinks)
-            pages = {t: self._tenant_pages(t)
-                     for t in self.tenancy.tenants}
-            self.metrics.record_tenants(
-                self.step_idx,
-                active=sum(1 for p in pages.values() if p),
-                page_seconds=sum(u.page_seconds for u in
-                                 self.tenancy.usage.values()),
-                max_share=max(pages.values()) / self.kv.pool.num_pages)
-        if self.audit_every and not chained and \
-                self.step_idx % self.audit_every == 0:
-            # barrier steps only: a chained step's host view is not
-            # authoritative, but page refcounts are — we still skip it
-            # to keep audit cadence aligned with host-authoritative
-            # bookkeeping (and off the overlap hot path)
-            self.audit()
-        if self.compile_watchdog is not None:
-            # auto-steady ticker: after steady_after_steps quiet steps
-            # the watchdog arms and further signature churn is a
-            # detection, not warmup (owner-gated: on a shared engine
-            # only the current owner's steps advance the counter)
-            self.compile_watchdog.step(owner=self.metrics)
-        if self.online is not None and not chained:
-            # online tuner nudges ride BARRIER steps only: knob changes
-            # must land on host-authoritative state, never while a
-            # chained horizon's stale snapshot is in flight.  Every
-            # nudge stays inside the construction-time bucket sets, so
-            # the compiled-signature story is untouched.
-            self.online.on_step(self)
+            # 5. observability
+            with phases("observe") as ph:
+                dt = ph.t0 - ph_step.t0
+                self._step_window.append(dt)
+                if pulled:
+                    self._tok_window.append(dt / pulled)
+                self._ema_step_s = dt if self._ema_step_s is None \
+                    else 0.8 * self._ema_step_s + 0.2 * dt
+                n_running = sum(r is not None for r in self.slot_req)
+                self.metrics.record_step(
+                    self.step_idx, queue_depth=len(self.waiting),
+                    running=n_running, waiting=len(self.waiting),
+                    page_utilization=self.kv.utilization(),
+                    device_wait_s=t_wait, host_s=max(0.0, dt - t_wait),
+                    cached_pages=None if self.prefix_cache is None
+                    else self.prefix_cache.cached_pages)
+                if self.mem.enabled:
+                    # rolling page-state attribution + per-request
+                    # page-seconds + sustained-pressure detection (one
+                    # host sweep per step)
+                    self.mem.on_step(self)
+                if self.tenancy is not None and not chained:
+                    # scalar tenancy gauges per barrier step; the
+                    # per-tenant split rides health()["tenants"]
+                    # (scalar-only sinks)
+                    pages = {t: self._tenant_pages(t)
+                             for t in self.tenancy.tenants}
+                    self.metrics.record_tenants(
+                        self.step_idx,
+                        active=sum(1 for p in pages.values() if p),
+                        page_seconds=sum(u.page_seconds for u in
+                                         self.tenancy.usage.values()),
+                        max_share=max(pages.values())
+                        / self.kv.pool.num_pages)
+                if self.audit_every and not chained and \
+                        self.step_idx % self.audit_every == 0:
+                    # barrier steps only: a chained step's host view is
+                    # not authoritative, but page refcounts are — we
+                    # still skip it to keep audit cadence aligned with
+                    # host-authoritative bookkeeping (and off the
+                    # overlap hot path)
+                    self.audit()
+                if self.compile_watchdog is not None:
+                    # auto-steady ticker: after steady_after_steps quiet
+                    # steps the watchdog arms and further signature
+                    # churn is a detection, not warmup (owner-gated: on
+                    # a shared engine only the current owner's steps
+                    # advance the counter)
+                    self.compile_watchdog.step(owner=self.metrics)
+                if self.online is not None and not chained:
+                    # online tuner nudges ride BARRIER steps only: knob
+                    # changes must land on host-authoritative state,
+                    # never while a chained horizon's stale snapshot is
+                    # in flight.  Every nudge stays inside the
+                    # construction-time bucket sets, so the
+                    # compiled-signature story is untouched.
+                    self.online.on_step(self)
+        if ph_step.last_s > min(self._slow_step_max_s, SLOW_STEP_S):
+            self._note_long_step(ph_step.last_s, before)
         return bool(self.waiting) or n_running > 0 or \
             bool(self._inflight) or bool(self._pending_attach)
+
+    def _note_long_step(self, wall_s, before):
+        """The longest step so far, and every step over SLOW_STEP_S,
+        with the step's own split by phase (the accumulators' growth
+        since ``before``): whether the host was blocked on the device
+        (``device_wait`` + ``first_token_wait``), in its own loop, or
+        in neither (``other_s``: inside ``step()`` and in no phase)."""
+        secs = self.phases.seconds
+        split = {k: v - before.get(k, 0.0) for k, v in secs.items()
+                 if k != "step" and v > before.get(k, 0.0)}
+        blocked = sum(split.get(k, 0.0) for k in BLOCKED_PHASES)
+        if wall_s > self._slow_step_max_s:
+            self._slow_step_max_s = wall_s
+            self._slow_step_max_blocked_s = blocked
+        if wall_s <= SLOW_STEP_S:
+            return
+        self._slow_steps += 1
+        rec = {"step": self.step_idx, "wall_s": round(wall_s, 4),
+               "blocked_s": round(blocked, 4),
+               "other_s": round(wall_s - sum(
+                   split.get(k, 0.0) for k in STEP_PHASES), 4),
+               "phases_s": {k: round(v, 4) for k, v in split.items()}}
+        self._slow_step_log.append(rec)
+        logger.warning("slow scheduler step %s", json.dumps(rec))
+        if self.tracer.enabled:
+            self.tracer.instant("slow_step", args=rec)
 
     # ------------------------------------------------- boundary phases
     def _admit(self, now):
@@ -1528,6 +1605,8 @@ class ServingScheduler:
             req.state = PREFILL
             # one timestamp per phase: admission decisions within a step
             # price time identically (no per-slot clock reads)
+            if req.t_admit is None:
+                self.metrics.record_queue_wait(now - req.t_submit)
             req.t_admit = now
             if self.tracer.enabled:
                 # the queue-wait phase closes at admission: submit->admit
@@ -1733,11 +1812,8 @@ class ServingScheduler:
             slots[i] = slot
             n_valid[i] = len(chunk)
         tokens = int(n_valid.sum())
-        with self.tracer.span(
-                "prefill_chunk", cat="dispatch",
-                args={"rows": len(rows), "padded_rows": padded,
-                      "tokens": tokens}
-                if self.tracer.enabled else None):
+        with self.phases("prefill_chunk", rows=len(rows),
+                         padded_rows=padded, tokens=tokens):
             a_ids, a_pack = self._adapter_args()
             logits, self.pools = self.engine.prefill_into_slots(
                 ids, slots, n_valid, self.kv.table, self.lengths,
@@ -1765,11 +1841,9 @@ class ServingScheduler:
             return None       # self-preempted: back in the queue
         ids = np.zeros((1, width), np.int32)
         ids[0, :n_valid] = chunk
-        with self.tracer.span(
-                "prefill_chunk", track=slot, rid=req.trace_rid,
-                args={"tokens": n_valid, "pos": req.prefill_pos,
-                      "seq_parallel": True}
-                if self.tracer.enabled else None):
+        with self.phases("prefill_chunk", track=slot, rid=req.trace_rid,
+                         tokens=n_valid, pos=req.prefill_pos,
+                         seq_parallel=1):
             logits, self.pools = self.engine.prefill_sequence_parallel(
                 ids, slot, n_valid, self.kv.table, self.lengths,
                 self.pools)
@@ -1809,33 +1883,42 @@ class ServingScheduler:
             for i, s, r in finishing:
                 sl[i] = s
                 idx[i] = r.sample_offset + len(r.out_tokens)
-            toks = self.engine.sample_from_logits_policy(
+            sample = self.engine.sample_from_logits_policy
+            args, kw = (
                 logits, self._samp_keys[sl], idx, self._samp_temps[sl],
                 self._samp_topk[sl], self._samp_topp[sl],
                 self._samp_rep[sl], self._samp_pres[sl],
                 self._samp_freq[sl], self._tok_counts[sl],
-                self._grammar_masks[sl])
+                self._grammar_masks[sl]), {}
         else:
-            toks = self.engine.sample_from_logits(logits, **self.sampling)
-        for i, slot, req in finishing:
-            if self.slot_req[slot] is not req or req.state != PREFILL:
-                continue   # closed by an earlier row's emit epilogue
-            tok = toks[i]
-            try:
-                self._emit(req, tok)
-                self._note_emitted(slot, req, tok)
-            except Exception as e:
-                self._close_slot(slot, FAILED, f"{type(e).__name__}: {e}")
-                continue
-            if req._finished_by(tok) or self._grammar_finished(req):
-                self._retire(slot)
-            elif req.handoff and self.on_handoff is not None:
-                self._do_handoff(slot, req, tok)
-            else:
-                self.last_tok[slot] = tok
-                req.state = RUNNING
-                if req.grammar is not None:
-                    self._grammar_masks[slot] = req.grammar.token_mask()
+            sample = self.engine.sample_from_logits
+            args, kw = (logits,), self.sampling
+        # the sample's launch and the blocking pull of its tokens: the
+        # host waits here until the prefill dispatch is done
+        with self.phases("first_token_wait", rows=len(finishing)):
+            toks = sample(*args, **kw)
+        with self.phases("first_token"):
+            for i, slot, req in finishing:
+                if self.slot_req[slot] is not req or req.state != PREFILL:
+                    continue   # closed by an earlier row's emit epilogue
+                tok = toks[i]
+                try:
+                    self._emit(req, tok)
+                    self._note_emitted(slot, req, tok)
+                except Exception as e:
+                    self._close_slot(slot, FAILED,
+                                     f"{type(e).__name__}: {e}")
+                    continue
+                if req._finished_by(tok) or self._grammar_finished(req):
+                    self._retire(slot)
+                elif req.handoff and self.on_handoff is not None:
+                    self._do_handoff(slot, req, tok)
+                else:
+                    self.last_tok[slot] = tok
+                    req.state = RUNNING
+                    if req.grammar is not None:
+                        self._grammar_masks[slot] = \
+                            req.grammar.token_mask()
 
     # ------------------------------------------------ disaggregated KV
     def _do_handoff(self, slot, req, tok):
@@ -1958,6 +2041,7 @@ class ServingScheduler:
             if self.tenancy is not None:
                 self._adapter_ids[slot] = req.adapter_id
                 self.tenancy.note(req.tenant, "admitted")
+            self.metrics.record_queue_wait(now - req.t_submit)
             req.t_admit = now
             req.state = RUNNING
             if self.tracer.enabled:
@@ -2358,52 +2442,51 @@ class ServingScheduler:
                    self.slot_req[s].state == RUNNING]
         if not running:
             return
-        if self._spec is not None and self._dispatch_spec(running):
-            return
+        if self._spec is not None:
+            with self.phases("spec_dispatch"):
+                took = self._dispatch_spec(running)
+            if took:
+                return
         running = [s for s in running if self.slot_req[s] is not None and
                    self.slot_req[s].state == RUNNING]
         if not running:
             return
-        t_disp = time.monotonic()
-        horizon, running = self._reserve(
-            running, self._pick_horizon(running, t_disp))
-        if not running:
-            return
-        active = np.zeros(self.num_slots, bool)
-        active[running] = True
-        budgets = np.zeros(self.num_slots, np.int32)
-        for s in running:
-            budgets[s] = self.slot_req[s].remaining_new
-        # budgets baseline for any chained continuation: the device's
-        # `emitted` carry counts from THIS dispatch
-        self._chain_budgets = budgets
-        if self._batch_needs_policy(running):
-            pol = self._policy_args(running)
-            out = self.engine.decode_multi_policy(
-                self.last_tok, active, self.kv.table, self.lengths,
-                self.pools, horizon=horizon, budgets=budgets,
-                eos_ids=self._eos_ids, **pol)
-            self.metrics.record_policy_dispatch(self.step_idx,
-                                                len(running))
-        else:
-            pol = None
-            a_ids, a_pack = self._adapter_args()
-            out = self.engine.decode_multi(
-                self.last_tok, active, self.kv.table, self.lengths,
-                self.pools, horizon=horizon, budgets=budgets,
-                eos_ids=self._eos_ids, adapter_ids=a_ids,
-                adapters=a_pack, **self.sampling)
-        self._commit_dispatch(out, running, horizon,
-                              {s: self.slot_req[s] for s in running},
-                              policy=pol)
-        if self.tracer.enabled:
-            # host side of the dispatch: page reservation + argument
-            # staging + launching the fused scan (the device's share of
-            # the horizon shows up as device_wait at harvest)
-            self.tracer.complete("horizon_dispatch", t_disp,
-                                 time.monotonic(), cat="dispatch",
-                                 args={"horizon": horizon,
-                                       "slots": len(running)})
+        # host side of the dispatch: page reservation + argument staging
+        # + launching the fused scan (the device's share of the horizon
+        # shows up as device_wait at harvest)
+        with self.phases("horizon_dispatch") as ph:
+            horizon, running = self._reserve(
+                running, self._pick_horizon(running, ph.t0))
+            if not running:
+                return
+            ph.note(horizon=horizon, slots=len(running))
+            active = np.zeros(self.num_slots, bool)
+            active[running] = True
+            budgets = np.zeros(self.num_slots, np.int32)
+            for s in running:
+                budgets[s] = self.slot_req[s].remaining_new
+            # budgets baseline for any chained continuation: the
+            # device's `emitted` carry counts from THIS dispatch
+            self._chain_budgets = budgets
+            if self._batch_needs_policy(running):
+                pol = self._policy_args(running)
+                out = self.engine.decode_multi_policy(
+                    self.last_tok, active, self.kv.table, self.lengths,
+                    self.pools, horizon=horizon, budgets=budgets,
+                    eos_ids=self._eos_ids, **pol)
+                self.metrics.record_policy_dispatch(self.step_idx,
+                                                    len(running))
+            else:
+                pol = None
+                a_ids, a_pack = self._adapter_args()
+                out = self.engine.decode_multi(
+                    self.last_tok, active, self.kv.table, self.lengths,
+                    self.pools, horizon=horizon, budgets=budgets,
+                    eos_ids=self._eos_ids, adapter_ids=a_ids,
+                    adapters=a_pack, **self.sampling)
+            self._commit_dispatch(out, running, horizon,
+                                  {s: self.slot_req[s] for s in running},
+                                  policy=pol)
 
     def _commit_dispatch(self, out, running, horizon, reqs, policy=None):
         if policy is not None:
@@ -2583,112 +2666,105 @@ class ServingScheduler:
         horizon, and release any deferred pages parked on this horizon.
         Returns (device_wait_s, tokens_delivered)."""
         rec = self._inflight.popleft()
-        t0 = time.monotonic()
-        toks = np.asarray(rec["toks"])    # blocks until the device (and
-        valid = np.asarray(rec["valid"])  # async host copy) catch up
-        wait = time.monotonic() - t0
-        now = time.monotonic()
-        if self.tracer.enabled:
-            # the host/device split the device_wait instrumentation
-            # already measures: time blocked pulling the token block is
-            # the device's (+ copy's) share of this horizon; 0 means the
-            # overlapped copy had already landed
-            self.tracer.complete("device_wait", t0, t0 + wait,
-                                 cat="device", track="device",
-                                 args={"horizon": rec["horizon"],
-                                       "spec": bool(rec.get("spec"))})
-        pulled = live_rows = kv_tokens = live_pages = 0
-        for slot in rec["slots"]:
-            req = rec["reqs"][slot]
-            if req.state in TERMINAL or self.slot_req[slot] is not req:
-                continue       # closed at an earlier boundary (zombie)
-            if req.cancelled:
-                # tokens generated past the cancel are dropped: honored
-                # at the horizon boundary, like the legacy step boundary
-                self._close_slot_or_defer(slot, CANCELLED, "cancelled")
-                continue
-            if req.past_deadline(now):
-                self._close_slot_or_defer(slot, SHED,
-                                          "deadline expired mid-flight")
-                continue
-            n = int(valid[slot].sum())
-            # step j of the n this slot emits at attends over the
-            # length it began the horizon with and its j + 1 new tokens,
-            # i.e. the pages up to its cursor at position length + j
-            length = int(self.lengths[slot])
-            live_rows += n
-            kv_tokens += n * length + n * (n + 1) // 2
-            live_pages += sum((length + j) // self.kv.page_size + 1
-                              for j in range(n))
-            if n and req.t_last is not None:
-                # horizon-granularity time-between-tokens: the client-
-                # visible burst cadence (per-token gaps within a burst
-                # are ~0 and still land in tpot)
-                self.metrics.record_tbt(self.step_idx, now - req.t_last)
-            for i in range(rec["horizon"]):
-                if not valid[slot, i]:
+        spec = int(bool(rec.get("spec")))
+        # time blocked pulling the token block is the device's (+ the
+        # copy's) share of this horizon; 0 means the overlapped copy
+        # had already landed
+        with self.phases("device_wait", horizon=rec["horizon"],
+                         spec=spec) as ph:
+            toks = np.asarray(rec["toks"])    # blocks until the device
+            valid = np.asarray(rec["valid"])  # (and async copy) catch up
+        wait = ph.last_s
+        # host bookkeeping share of the harvest (emit callbacks, retire,
+        # rollback) — the counterpart of device_wait above
+        with self.phases("harvest", horizon=rec["horizon"],
+                         spec=spec) as ph:
+            now = ph.t0
+            pulled = live_rows = kv_tokens = live_pages = 0
+            for slot in rec["slots"]:
+                req = rec["reqs"][slot]
+                if req.state in TERMINAL or self.slot_req[slot] is not req:
+                    continue       # closed at an earlier boundary (zombie)
+                if req.cancelled:
+                    # tokens generated past the cancel are dropped: honored
+                    # at the horizon boundary, like the legacy step boundary
+                    self._close_slot_or_defer(slot, CANCELLED, "cancelled")
                     continue
-                tok = int(toks[slot, i])
-                try:
-                    self._emit(req, tok)
-                    pulled += 1   # only tokens actually DELIVERED count
-                    # policy bookkeeping rides the same containment: a
-                    # grammar rejection of a delivered token fails THIS
-                    # request (the device mask should make it
-                    # impossible — reaching it means corrupted state)
-                    self._note_emitted(slot, req, tok)
-                except Exception as e:  # per-request emit/callback fault
-                    self._close_slot_or_defer(
-                        slot, FAILED, f"{type(e).__name__}: {e}")
-                    break
-                if req._finished_by(tok) or self._grammar_finished(req):
-                    # the device froze the slot at this same token, so
-                    # its pages are read-only in any chained horizon:
-                    # immediate release is safe.  A grammar cursor with
-                    # no continuation (done) finishes the request even
-                    # without eos — the constrained output is complete.
-                    self._retire(slot)
-                    break
-            if self.slot_req[slot] is req and req.state == RUNNING and \
-                    req.grammar is not None:
-                # refresh the staged mask for the next (barrier)
-                # dispatch — constrained slots run horizon-1 unchained,
-                # so the mask is always exactly one token fresh
-                self._grammar_masks[slot] = req.grammar.token_mask()
-            if n and self.tracer.enabled:
-                # one span per (slot, horizon) burst on the slot's own
-                # track: dispatch -> harvest, n tokens delivered.  This
-                # is the per-request timeline row (rid-keyed), emitted
-                # even when the request just retired/closed above.
-                self.tracer.complete(
-                    "decode_burst" if not rec.get("spec")
-                    else "spec_round", rec["t_dispatch"], now,
-                    cat="decode", track=slot, rid=req.trace_rid,
-                    args={"tokens": n, "horizon": rec["horizon"]})
-            if self.slot_req[slot] is req and req.state == RUNNING:
-                self.lengths[slot] += n
-                if n:
-                    self.last_tok[slot] = int(toks[slot][valid[slot]][-1])
-        if rec.get("spec"):
-            self._harvest_spec(rec, valid)
-        for slot in rec["release_after"]:
-            self.kv.release_slot(slot)
-            self.lengths[slot] = 0
-            self._zombies.discard(slot)
-        if rec.get("spec"):
-            self.metrics.record_spec_wait(self.step_idx, wait)
-        else:
-            self.metrics.record_horizon(self.step_idx, rec["horizon"],
-                                        pulled, wait, live_rows, kv_tokens,
-                                        live_pages, self.kv.table.size)
-        if self.tracer.enabled:
-            # host bookkeeping share of the harvest (emit callbacks,
-            # retire, rollback) — the counterpart of device_wait above
-            self.tracer.complete("harvest", now, time.monotonic(),
-                                 cat="dispatch",
-                                 args={"tokens": pulled,
-                                       "horizon": rec["horizon"],
-                                       "spec": bool(rec.get("spec"))})
+                if req.past_deadline(now):
+                    self._close_slot_or_defer(slot, SHED,
+                                              "deadline expired mid-flight")
+                    continue
+                n = int(valid[slot].sum())
+                # step j of the n this slot emits at attends over the
+                # length it began the horizon with and its j + 1 new tokens,
+                # i.e. the pages up to its cursor at position length + j
+                length = int(self.lengths[slot])
+                live_rows += n
+                kv_tokens += n * length + n * (n + 1) // 2
+                live_pages += sum((length + j) // self.kv.page_size + 1
+                                  for j in range(n))
+                if n and req.t_last is not None:
+                    # horizon-granularity time-between-tokens: the client-
+                    # visible burst cadence (per-token gaps within a burst
+                    # are ~0 and still land in tpot)
+                    self.metrics.record_tbt(self.step_idx, now - req.t_last)
+                for i in range(rec["horizon"]):
+                    if not valid[slot, i]:
+                        continue
+                    tok = int(toks[slot, i])
+                    try:
+                        self._emit(req, tok)
+                        pulled += 1   # only tokens actually DELIVERED count
+                        # policy bookkeeping rides the same containment: a
+                        # grammar rejection of a delivered token fails THIS
+                        # request (the device mask should make it
+                        # impossible — reaching it means corrupted state)
+                        self._note_emitted(slot, req, tok)
+                    except Exception as e:  # per-request emit/callback fault
+                        self._close_slot_or_defer(
+                            slot, FAILED, f"{type(e).__name__}: {e}")
+                        break
+                    if req._finished_by(tok) or self._grammar_finished(req):
+                        # the device froze the slot at this same token, so
+                        # its pages are read-only in any chained horizon:
+                        # immediate release is safe.  A grammar cursor with
+                        # no continuation (done) finishes the request even
+                        # without eos — the constrained output is complete.
+                        self._retire(slot)
+                        break
+                if self.slot_req[slot] is req and req.state == RUNNING and \
+                        req.grammar is not None:
+                    # refresh the staged mask for the next (barrier)
+                    # dispatch — constrained slots run horizon-1 unchained,
+                    # so the mask is always exactly one token fresh
+                    self._grammar_masks[slot] = req.grammar.token_mask()
+                if n and self.tracer.enabled:
+                    # one span per (slot, horizon) burst on the slot's own
+                    # track: dispatch -> harvest, n tokens delivered.  This
+                    # is the per-request timeline row (rid-keyed), emitted
+                    # even when the request just retired/closed above.
+                    self.tracer.complete(
+                        "decode_burst" if not rec.get("spec")
+                        else "spec_round", rec["t_dispatch"], now,
+                        cat="decode", track=slot, rid=req.trace_rid,
+                        args={"tokens": n, "horizon": rec["horizon"]})
+                if self.slot_req[slot] is req and req.state == RUNNING:
+                    self.lengths[slot] += n
+                    if n:
+                        self.last_tok[slot] = int(toks[slot][valid[slot]][-1])
+            if rec.get("spec"):
+                self._harvest_spec(rec, valid)
+            for slot in rec["release_after"]:
+                self.kv.release_slot(slot)
+                self.lengths[slot] = 0
+                self._zombies.discard(slot)
+            if rec.get("spec"):
+                self.metrics.record_spec_wait(self.step_idx, wait)
+            else:
+                self.metrics.record_horizon(self.step_idx, rec["horizon"],
+                                            pulled, wait, live_rows, kv_tokens,
+                                            live_pages, self.kv.table.size)
+            ph.note(tokens=pulled)
         return wait, pulled
 
     def _harvest_spec(self, rec, valid):
@@ -2940,6 +3016,9 @@ class ServingScheduler:
             "uptime_s": round(uptime, 3),
             "steps_per_s": round(self.step_idx / uptime, 3),
             "tracing": self.tracer.enabled,
+            # the last steps over SLOW_STEP_S, each with its split by
+            # phase (blocked on the device, in the host loop, or neither)
+            "slow_steps": list(self._slow_step_log),
             "mesh": self.mesh_info.get("mesh_shape"),
             "mesh_devices": self.mesh_info.get("mesh_devices"),
             "serving_axes": self.mesh_info.get("serving_axes"),
@@ -3112,9 +3191,30 @@ class ServingScheduler:
         if counters is not None:
             self.metrics.record_routing(self.step_idx, counters)
 
+    def _phase_summary(self):
+        """What the phases of step() add up to: seconds by depth-one
+        phase (they sum to ``step_wall_s``), the host's share of the
+        steps' wall with BOTH device waits taken out (``device_wait_frac``
+        books the first-token wait as host time), and the slow steps."""
+        total = self.phases.total
+        wall = total("step")
+        out = {f"phase_{k}_s": round(total(k), 6)
+               for k in STEP_PHASES + ("first_token_wait",)}
+        out["step_wall_s"] = round(wall, 6)
+        out["host_busy_frac"] = round(
+            (wall - total(*BLOCKED_PHASES)) / wall, 4) if wall else 0.0
+        out["first_token_wait_frac"] = round(
+            total("first_token_wait") / wall, 4) if wall else 0.0
+        out["slow_steps"] = self._slow_steps
+        out["slow_step_max_s"] = round(self._slow_step_max_s, 4)
+        out["slow_step_max_blocked_s"] = round(
+            self._slow_step_max_blocked_s, 4)
+        return out
+
     def summary(self):
         self._pull_routing()
         out = self.metrics.summary(getattr(self, "_wall_s", None))
+        out.update(self._phase_summary())
         if self.mem.enabled:
             # per-request memory attribution aggregates: page-seconds
             # is the unit the autotuner's cost model bills capacity in

@@ -41,6 +41,8 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 from deepspeed_tpu.resilience import faults
 
 # ---------------------------------------------------------------------
@@ -476,6 +478,110 @@ class _NullTracer(SpanTracer):
 
 
 NULL_TRACER = _NullTracer()
+
+
+# --------------------------------------------------------------- phases
+# ONE primitive for "this stretch of host code has a name".  A phase is
+# an event on the host plane of a device profile (the profiler's clock,
+# beside the "XLA Ops" line), seconds in a flat accumulator that
+# ``summary()`` reads, and a span of the SpanTracer under the name its
+# site always had.  Sites use it as a ``with`` block at their own call
+# depth: no decorator and no wrapper, so the Python frames above a
+# kernel's trace are the same with it as without.
+
+def annotation(name, **stats):
+    """A bare ``jax.profiler.TraceAnnotation`` for code that owns no
+    tracer (the engines): ``with tracing.annotation("ds.engine.launch",
+    program=name):``.  Without a profiler session it is a no-op of well
+    under a microsecond; under one it is an event of the ``.xplane.pb``
+    whose keyword arguments arrive as the event's stats."""
+    return TraceAnnotation(name, **stats)
+
+
+class _Phase:
+    """One named phase, made once: the context object its site enters
+    every time (so a name never nests in itself)."""
+
+    __slots__ = ("owner", "name", "label", "cat", "home", "track", "rid",
+                 "stats", "t0", "last_s", "_ann")
+
+    def __init__(self, owner, name, cat, home):
+        self.owner = owner
+        self.name = name
+        self.label = owner.prefix + name
+        self.cat = cat
+        self.home = home        # the track of a pass that names none
+        self.track = None
+        self.rid = None
+        self.stats = {}
+        self.t0 = 0.0
+        self.last_s = 0.0       # seconds of the newest pass
+        self._ann = None
+
+    def note(self, **stats):
+        """Numbers known only once the phase is under way (the
+        horizon a dispatch settled on, the tokens a harvest
+        delivered)."""
+        self.stats.update(stats)
+        self._ann.set_metadata(**stats)
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(self.label, **self.stats)
+        self._ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.monotonic()
+        self._ann.__exit__(*exc)
+        self._ann = None
+        self.last_s = t1 - self.t0
+        owner = self.owner
+        owner.seconds[self.name] += self.last_s
+        owner.counts[self.name] += 1
+        if owner.tracer.enabled:
+            owner.tracer.complete(
+                self.name, self.t0, t1, cat=self.cat,
+                track=self.home if self.track is None else self.track,
+                rid=self.rid, args=self.stats or None)
+        return False
+
+
+class Phases:
+    """The named phases of one owner (a scheduler): ``with
+    self.phases("admit"):``, or with small whole numbers that describe
+    the pass, ``with self.phases("prefill_chunk", rows=3, tokens=24):``.
+
+    Entering and leaving a phase (1) opens and closes a
+    ``TraceAnnotation(prefix + name, **stats)`` — always, there is no
+    switch; (2) adds the pass's ``time.monotonic()`` seconds and a
+    count to ``seconds[name]`` / ``counts[name]``; (3) where the tracer
+    is enabled, records ``tracer.complete(name, t0, t1)`` with the
+    stats as its args.  ``spans`` gives the (cat, track) under which a
+    name has always been recorded; other names take ``("phase",
+    "scheduler")``."""
+
+    def __init__(self, tracer=None, prefix="", spans=None):
+        self.tracer = tracer if tracer is not None else NULL_TRACER
+        self.prefix = prefix
+        self.spans = dict(spans or {})
+        self.seconds = {}
+        self.counts = {}
+        self._phases = {}
+
+    def __call__(self, name, track=None, rid=None, **stats):
+        ph = self._phases.get(name)
+        if ph is None:
+            cat, trk = self.spans.get(name, ("phase", "scheduler"))
+            ph = self._phases[name] = _Phase(self, name, cat, trk)
+            self.seconds[name] = 0.0
+            self.counts[name] = 0
+        ph.stats, ph.track, ph.rid = stats, track, rid
+        return ph
+
+    def total(self, *names):
+        """Seconds accumulated under ``names`` so far."""
+        return sum(self.seconds.get(n, 0.0) for n in names)
 
 
 # ------------------------------------------------ compile observability

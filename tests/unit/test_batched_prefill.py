@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu
+from deepspeed_tpu import comm as dist
 from deepspeed_tpu.models.gpt2 import GPT2, gpt2_tiny
 from deepspeed_tpu.models.llama import Llama, llama_tiny
 from deepspeed_tpu.ops.attention import kv_cache
@@ -132,9 +133,13 @@ def test_model_advances_lengths_per_row(engine):
         jnp.asarray([5, 0, 9, 0], jnp.int32),
         slot=jnp.asarray([2, 0, 2, 2], jnp.int32),
         n_valid=jnp.asarray([CHUNK, 3, 0, 0], jnp.int32))
-    logits, out = engine.module.apply(
-        {"params": engine._materialize(engine.params)},
-        jnp.zeros((4, CHUNK), jnp.int32), cache=cache)
+    # under the engine's own mesh, as every serving trace runs: the
+    # paged attention code reads the installed mesh at trace time, and
+    # a mesh another file of this worker left installed is not ours
+    with dist.mesh_scope(engine.mesh):
+        logits, out = engine.module.apply(
+            {"params": engine._materialize(engine.params)},
+            jnp.zeros((4, CHUNK), jnp.int32), cache=cache)
     assert logits.shape[:2] == (4, 1)
     assert list(np.asarray(out.lengths)) == [8, 0, 9 + CHUNK, 0]
 

@@ -387,6 +387,8 @@ HEALTH_SCHEMA = {
     "uptime_s": (float,),
     "steps_per_s": (float,),
     "tracing": (bool,),
+    # the last steps over 1.0 s, each with its split by phase
+    "slow_steps": (list,),
     "mesh": (dict, type(None)),
     "mesh_devices": (int, type(None)),
     "serving_axes": (dict, type(None)),

@@ -495,3 +495,235 @@ def test_live_loop_emits_only_documented_tags(engine):
     assert emitted <= set(EVENT_TAXONOMY), \
         emitted - set(EVENT_TAXONOMY)
     assert all(step >= 1 for _, _, step in rb.events)
+
+
+# ------------------------------------------------- phases of one step()
+# (tracing.Phases: one primitive feeds a device profile, summary() and
+# the SpanTracer)
+
+DEPTH_ONE = ("chain", "device_wait", "harvest", "sweep", "admit",
+             "prefill", "spec_dispatch", "horizon_dispatch", "observe")
+NEW_KEYS = tuple(f"phase_{k}_s" for k in DEPTH_ONE) + (
+    "phase_first_token_wait_s", "step_wall_s", "host_busy_frac",
+    "first_token_wait_frac", "queue_wait_ms_p50", "queue_wait_ms_p90",
+    "slow_steps", "slow_step_max_s", "slow_step_max_blocked_s")
+# the span names a plain traced run recorded before the phases, and the
+# ones the phases add
+OLD_SPANS = {"queued", "prefill_chunk", "horizon_dispatch", "device_wait",
+             "harvest", "decode_burst", "request"}
+NEW_SPANS = {"step", "chain", "sweep", "admit", "prefill",
+             "first_token_wait", "first_token", "observe"}
+
+
+def _workload(seed, n=4):
+    rng = np.random.default_rng(seed)
+    prompts = [rng.integers(0, 256, 7).astype(np.int32) for _ in range(n)]
+    return prompts, [6, 5, 6, 5][:n]
+
+
+def test_summary_holds_the_phase_counters_with_tracing_off(engine):
+    import time as _time
+    prompts, max_new = _workload(0)
+    _serve(engine, prompts, max_new)      # a step that compiles is slow
+    sched = ServingScheduler(engine, **CFG)
+    # a client that takes 2 ms a token: the steps are long beside the
+    # few microseconds between two phases, whatever the machine does
+    reqs = [sched.submit(p, max_new_tokens=m,
+                         on_token=lambda req, tok: _time.sleep(0.002))
+            for p, m in zip(prompts, max_new)]
+    sched.run()
+    assert sched.tracer is NULL_TRACER and len(NULL_TRACER.events) == 0
+    s = sched.summary()
+    for key in NEW_KEYS:
+        assert isinstance(s[key], (int, float)), key
+    wall = s["step_wall_s"]
+    assert wall > 0
+    # exhaustive and disjoint at depth one: the phases are the step
+    assert sum(s[f"phase_{k}_s"] for k in DEPTH_ONE) == \
+        pytest.approx(wall, rel=0.02)
+    # the host's share and the two waits' shares are the whole
+    assert s["host_busy_frac"] + (s["phase_device_wait_s"] +
+                                  s["phase_first_token_wait_s"]) / wall \
+        == pytest.approx(1.0, abs=2e-4)
+    assert s["first_token_wait_frac"] == pytest.approx(
+        s["phase_first_token_wait_s"] / wall, abs=1e-4)
+    assert s["phase_first_token_wait_s"] > 0     # four prompts finished
+    assert s["phase_first_token_wait_s"] <= s["phase_prefill_s"]
+    # device_wait_frac keeps its definition: the harvest's pull over the
+    # steps' wall up to the bookkeeping
+    m = sched.metrics
+    assert s["device_wait_frac"] == round(
+        m.device_wait_s / (m.device_wait_s + m.host_s), 4)
+    assert m.device_wait_s == pytest.approx(s["phase_device_wait_s"],
+                                            abs=1e-5)
+    # every request was admitted once, from a queue it hardly stood in
+    assert len(m.queue_wait_s) == len(reqs)
+    assert 0 <= s["queue_wait_ms_p50"] <= s["queue_wait_ms_p90"] \
+        <= s["ttft_ms_p99"]
+    assert s["slow_steps"] == 0 and 0 < s["slow_step_max_s"] < 1.0
+    assert sched.health()["slow_steps"] == []
+
+
+def test_phases_feed_the_span_tracer_under_the_old_names(engine):
+    """Tokens and compile counts are those of the untraced run, and the
+    recorded names are the old set plus the new phases."""
+    prompts, max_new = _workload(0)
+    want = _oracle(engine, prompts, max_new)
+    _serve(engine, prompts, max_new)
+
+    def compiles():
+        return (engine.serving_decode_multi_compile_count(),
+                engine.serving_prefill_compile_count(),
+                engine.serving_decode_compile_count(),
+                engine.serving_verify_compile_count(),
+                engine.serving_page_copy_compile_count())
+    before = compiles()
+    tracer = SpanTracer(process="t")
+    sched, reqs, _ = _serve(engine, prompts, max_new, tracer=tracer)
+    assert compiles() == before
+    assert [r.out_tokens for r in reqs] == want
+    assert {e[1] for e in tracer.events} == OLD_SPANS | NEW_SPANS
+    by_name = {}
+    for e in tracer.events:
+        by_name.setdefault(e[1], []).append(e)
+    # the four sites keep their category, track and args
+    assert {(e[2], e[5]) for e in by_name["device_wait"]} == \
+        {("device", "device")}
+    assert {(e[2], e[5]) for e in by_name["harvest"]} == \
+        {("dispatch", "scheduler")}
+    assert all(set(e[7]) == {"horizon", "spec", "tokens"}
+               for e in by_name["harvest"])
+    assert all(set(e[7]) == {"horizon", "slots"}
+               for e in by_name["horizon_dispatch"])
+    assert all(set(e[7]) == {"rows", "padded_rows", "tokens"}
+               for e in by_name["prefill_chunk"])
+    assert sum(e[7]["tokens"] for e in by_name["harvest"]) + \
+        len(by_name["request"]) == sum(len(w) for w in want)
+    # the tracer's spans and the accumulators are one measurement
+    for name in ("device_wait", "admit", "first_token_wait"):
+        assert sum(e[4] for e in by_name[name]) == pytest.approx(
+            sched.phases.seconds[name], abs=1e-9)
+        assert len(by_name[name]) == sched.phases.counts[name]
+    # a replica that swaps the scheduler's tracer swaps the phases' too
+    other = SpanTracer(process="u")
+    sched.tracer = other
+    assert sched.phases.tracer is other
+
+
+def test_a_slow_step_is_recorded_with_its_phase_split(engine):
+    import logging
+
+    from deepspeed_tpu.utils.logging import logger as ds_logger
+    prompts, max_new = _workload(3, n=2)
+    _serve(engine, prompts, max_new)      # compile outside the record
+    tracer = SpanTracer(process="t")
+    sched = ServingScheduler(engine, tracer=tracer, **CFG)
+    for p, m in zip(prompts, max_new):
+        sched.submit(p, max_new_tokens=m)
+    inj = faults.FaultInjector(seed=0)
+    inj.on("serve.step", step=2, action=faults.sleep_s(1.05))
+    lines = []
+    handler = logging.Handler()
+    handler.emit = lambda record: lines.append(record.getMessage())
+    ds_logger.addHandler(handler)    # it does not propagate to caplog
+    try:
+        with faults.injected(inj):
+            sched.run()
+    finally:
+        ds_logger.removeHandler(handler)
+    s = sched.summary()
+    assert s["slow_steps"] == 1
+    assert 1.05 <= s["slow_step_max_s"] < 2.0
+    # the step slept in the fault point: neither blocked nor in a phase
+    assert s["slow_step_max_blocked_s"] < 0.5
+    (rec,) = sched.health()["slow_steps"]
+    assert rec["step"] == 2 and rec["wall_s"] == s["slow_step_max_s"]
+    assert rec["other_s"] >= 1.0 and rec["blocked_s"] < 0.5
+    assert set(rec["phases_s"]) <= set(DEPTH_ONE) | {
+        "prefill_chunk", "first_token_wait", "first_token"}
+    assert sum(rec["phases_s"].get(k, 0.0) for k in DEPTH_ONE) == \
+        pytest.approx(rec["wall_s"] - rec["other_s"], abs=1e-3)
+    slow = [e for e in tracer.events if e[1] == "slow_step"]
+    assert len(slow) == 1 and slow[0][0] == "i" and slow[0][7] == rec
+    lines = [ln for ln in lines if "slow scheduler step" in ln]
+    assert len(lines) == 1 and json.loads(
+        lines[0].split("step ", 1)[1]) == rec
+
+
+# nesting of the annotations in a device profile: child -> parent
+NESTING = {
+    "ds.sched.chain": "ds.sched.step",
+    "ds.sched.device_wait": "ds.sched.step",
+    "ds.sched.harvest": "ds.sched.step",
+    "ds.sched.sweep": "ds.sched.step",
+    "ds.sched.admit": "ds.sched.step",
+    "ds.sched.prefill": "ds.sched.step",
+    "ds.sched.horizon_dispatch": "ds.sched.step",
+    "ds.sched.observe": "ds.sched.step",
+    "ds.sched.prefill_chunk": "ds.sched.prefill",
+    "ds.sched.first_token_wait": "ds.sched.prefill",
+    "ds.sched.first_token": "ds.sched.prefill",
+}
+
+
+def test_the_phases_are_events_of_a_device_profile(engine, tmp_path):
+    """jax.profiler with the chip benchmark's own options around three
+    steps: every name of the table is in the .xplane.pb, nested as the
+    table says, on the profiler's clock."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    prompts, max_new = _workload(5, n=3)
+    sched = ServingScheduler(engine, **CFG)
+    for p in prompts:
+        sched.submit(p, max_new_tokens=12)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.enable_hlo_proto = False
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for _ in range(3):
+            sched.step()
+    finally:
+        jax.profiler.stop_trace()
+    sched.run()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                            "*.xplane.pb"))
+    evs = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("ds."):
+                    s = int(ev.start_ns)
+                    evs.append((ev.name, s, s + int(ev.duration_ns),
+                                dict(ev.stats)))
+    names = {e[0] for e in evs}
+    assert names == set(NESTING) | {"ds.sched.step", "ds.engine.stage",
+                                    "ds.engine.launch"}
+    assert sum(e[0] == "ds.sched.step" for e in evs) == 3
+
+    def inside(child, parents):
+        return any(p[1] <= child[1] and child[2] <= p[2] for p in parents)
+    for child, parent in NESTING.items():
+        parents = [e for e in evs if e[0] == parent]
+        for ev in (e for e in evs if e[0] == child):
+            assert inside(ev, parents), (child, parent)
+    # the engine's staging and launch lie inside a dispatching phase
+    dispatching = [e for e in evs if e[0] in (
+        "ds.sched.prefill_chunk", "ds.sched.horizon_dispatch",
+        "ds.sched.chain")]
+    for ev in (e for e in evs if e[0].startswith("ds.engine.")):
+        assert inside(ev, dispatching), ev[0]
+    # depth-one phases of one step do not overlap
+    one = sorted(e for e in evs if NESTING.get(e[0]) == "ds.sched.step")
+    one.sort(key=lambda e: e[1])
+    assert all(a[2] <= b[1] for a, b in zip(one, one[1:]))
+    # small whole numbers ride as the events' stats
+    chunk = next(e for e in evs if e[0] == "ds.sched.prefill_chunk")
+    assert {"rows", "padded_rows", "tokens"} <= set(chunk[3])
+    launch = {e[3].get("program") for e in evs
+              if e[0] == "ds.engine.launch"}
+    assert {"prefill", "decode_multi"} <= launch
+    disp = [e for e in evs if e[0] == "ds.sched.horizon_dispatch"]
+    assert all({"horizon", "slots"} <= set(e[3]) for e in disp)

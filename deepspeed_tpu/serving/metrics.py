@@ -9,7 +9,7 @@ cannot produce negative or wild TTFT/ITL values.  Terminal outcomes are
 counted distinctly (completed / failed / shed / cancelled): an operator
 must be able to tell "we errored" from "we refused load"."""
 
-from collections import deque
+from collections import Counter, deque
 
 import numpy as np
 
@@ -68,6 +68,7 @@ class ServingMetrics:
         self.prefill_dispatches = 0    # shared [rows, chunk] dispatches
         self.prefill_rows = 0          # slot chunks those carried
         self.prefill_padded_rows = 0   # rows dispatched incl. bucket pad
+        self.prefill_by_bucket = Counter()  # row bucket -> dispatches
         self.prefill_tokens = 0        # prompt tokens those landed
         self.seq_prefill_routed = 0    # prompts routed onto the sp path
         self.seq_prefill_chunks = 0    # sp chunk dispatches
@@ -183,6 +184,7 @@ class ServingMetrics:
         self.prefill_dispatches += 1
         self.prefill_rows += rows
         self.prefill_padded_rows += padded_rows
+        self.prefill_by_bucket[padded_rows] += 1
         self.prefill_tokens += tokens
         self._write([("serving/prefill/rows", rows, step),
                      ("serving/prefill/padded_rows", padded_rows, step),
@@ -245,6 +247,12 @@ class ServingMetrics:
         """Share of dispatched prefill rows that were bucket padding."""
         return 1.0 - self.prefill_rows / self.prefill_padded_rows \
             if self.prefill_padded_rows else 0.0
+
+    def prefill_dispatches_by_bucket(self):
+        """Shared prefill dispatches per row bucket they rode in, as
+        ``{"16": n, "32": n, ...}`` (JSON keys; buckets never used are
+        absent) — which programs of the bucket set the traffic runs."""
+        return {str(b): n for b, n in sorted(self.prefill_by_bucket.items())}
 
     def record_seq_prefill_route(self, step, prompt_tokens, reserved_pages):
         """One admission routed onto the sequence-parallel prefill path:
@@ -639,6 +647,8 @@ class ServingMetrics:
             "prefill_rows_per_dispatch":
             round(self.prefill_rows_per_dispatch(), 3),
             "prefill_pad_share": round(self.prefill_pad_share(), 4),
+            "prefill_dispatches_by_bucket":
+            self.prefill_dispatches_by_bucket(),
             "seq_prefill_routed": self.seq_prefill_routed,
             "seq_prefill_chunks": self.seq_prefill_chunks,
             "seq_prefill_tokens": self.seq_prefill_tokens,

@@ -12,7 +12,7 @@ granularity.  Each ``step()`` is one scheduler iteration:
   3. advance every admitted-but-unprefilled slot by ONE prompt chunk
      (chunked prefill — long prompts never stall running decoders for
      more than a chunk), all of them in ONE ``[rows, prefill_chunk]``
-     dispatch (rows padded to a power-of-four bucket),
+     dispatch (rows padded to a row bucket: x4 to 16 rows, x2 above),
   4. run ONE fused multi-step decode ("horizon") over all running
      slots: up to ``decode_horizon_steps`` tokens per slot in a single
      ``decode_multi`` dispatch, with token feedback, EOS detection and
@@ -185,6 +185,22 @@ def _geometric_buckets(lo, hi, factor=2):
         b = min(b * factor, hi)
         buckets.append(b)
     return buckets
+
+
+# rows up to which a prefill dispatch sits near its weight-read floor
+# (16 rows x a 32-token chunk = 512 tokens; a v5e's FLOPs meet a
+# weight's bytes at ~240)
+PREFILL_COARSE_ROWS = 16
+
+
+def _prefill_row_buckets(num_slots):
+    """Row buckets of the batched prefill dispatch: powers of four up
+    to ``PREFILL_COARSE_ROWS``, powers of two above, ``num_slots``
+    always last -- no dispatch of more than 16 rows pads by over 2x
+    (why: the comment where ``ServingScheduler`` builds the set)."""
+    coarse = _geometric_buckets(
+        1, min(num_slots, PREFILL_COARSE_ROWS), factor=4)
+    return coarse + _geometric_buckets(coarse[-1], num_slots)[1:]
 
 
 def _bucket_ceil(buckets, n):
@@ -574,14 +590,19 @@ class ServingScheduler:
             1, self.decode_horizon_steps)
         # batched prefill: every prefilling slot's next chunk rides ONE
         # [rows, prefill_chunk] dispatch per boundary step; the row
-        # count pads up to a power-of-FOUR bucket (the last is
-        # num_slots), so the compile count is pinned by the bucket set
-        # exactly like decode horizons.  Four, not two: every bucket is
-        # one more trace + load of the whole model at start-up (~1.4 s
-        # each for a 16-layer 7B on a v5e host, PERF.md PR 28), which
-        # cost more than the padding rows of the coarser set
-        self.prefill_row_buckets = _geometric_buckets(
-            1, self.num_slots, factor=4)
+        # count pads up to a bucket (the last is num_slots), so the
+        # compile count is pinned by the bucket set exactly like decode
+        # horizons.  The set bounds two costs.  Signatures: every
+        # bucket is one more trace + load of the whole model at
+        # start-up (1.1-2.0 s each on a v5e host, PERF.md PR 28), so up
+        # to 16 rows the step is x4 -- 16 rows x 32 = 512 tokens is
+        # ~20 ms on a 16-layer 7B against a 9 ms weight read, and the
+        # worst padding (5 -> 16 rows) wastes ~10 ms.  A padding row:
+        # above 512 tokens the dispatch is compute-bound and a padding
+        # row costs what a prompt row costs (17 rows in a 64-row
+        # bucket wasted 50 of Falcon-H1's 105 ms, every step, PERF.md
+        # PR 41), so from 16 rows up the step is x2
+        self.prefill_row_buckets = _prefill_row_buckets(self.num_slots)
         # ---- sequence-parallel prefill routing (long-context path) ----
         # prompts with >= seq_parallel_threshold tokens left to prefill
         # route through engine.prefill_sequence_parallel: the chunk
@@ -3091,6 +3112,8 @@ class ServingScheduler:
             "prefill_rows_per_dispatch":
             round(m.prefill_rows_per_dispatch(), 3),
             "prefill_pad_share": round(m.prefill_pad_share(), 4),
+            "prefill_dispatches_by_bucket":
+            m.prefill_dispatches_by_bucket(),
             "prefill_reserve_cap": self.prefill_reserve_cap,
             "seq_prefill_routed": m.seq_prefill_routed,
             "seq_prefill_chunks": m.seq_prefill_chunks,

@@ -9,7 +9,10 @@
   the number of prefilling slots, a slot evicted by a later row's growth
   is not in the dispatch, a per-row host failure closes that slot only,
   token streams equal ``generate()``;
-* compile-set pin — prefill signatures stay within the row bucket set.
+* compile-set pin — prefill signatures stay within the row bucket set;
+* the row bucket set — powers of four up to 16 rows, powers of two
+  above: 17-32 prefilling rows ride a 32-row dispatch, and the tokens
+  served do not depend on the bucket a chunk rode in.
 """
 
 import jax
@@ -20,10 +23,14 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu import comm as dist
 from deepspeed_tpu.models.gpt2 import GPT2, gpt2_tiny
+from deepspeed_tpu.models.falcon_h1 import FalconH1, falcon_h1_tiny
 from deepspeed_tpu.models.llama import Llama, llama_tiny
+from deepspeed_tpu.models.nemotron_h import NemotronH, nemotron_h_tiny
 from deepspeed_tpu.ops.attention import kv_cache
 from deepspeed_tpu.resilience import faults
 from deepspeed_tpu.serving import PagedKVManager, ServingScheduler
+from deepspeed_tpu.serving.scheduler import (_bucket_ceil,
+                                             _prefill_row_buckets)
 
 PS, CHUNK, SLOTS, MAXP, PAGES = 16, 8, 4, 4, 16
 # float32 compute on CPU: a row's matmuls do not depend on its batch
@@ -278,3 +285,74 @@ def test_prefill_compiles_bounded_by_row_buckets(model):
     assert engine.serving_prefill_compile_count() == n0
     assert engine.serving_prefill_compile_count() == \
         deepspeed_tpu.tracing.jit_cache_size(engine._paged_prefill_fn)
+
+
+# ------------------------------------------------------ row bucket set
+
+ROW_BUCKETS = {1: [1], 3: [1, 3], 4: [1, 4], 16: [1, 4, 16],
+               32: [1, 4, 16, 32], 33: [1, 4, 16, 32, 33],
+               64: [1, 4, 16, 32, 64], 128: [1, 4, 16, 32, 64, 128],
+               256: [1, 4, 16, 32, 64, 128, 256]}
+
+
+@pytest.mark.parametrize("num_slots", sorted(ROW_BUCKETS))
+def test_row_buckets_step_by_four_to_16_rows_and_by_two_above(num_slots):
+    """The set bounds the signatures (x4 while a dispatch is near its
+    weight-read floor) AND what a padding row can cost (x2 above 16
+    rows, where a padding row costs what a prompt row costs)."""
+    buckets = _prefill_row_buckets(num_slots)
+    assert buckets == ROW_BUCKETS[num_slots]
+    assert buckets == sorted(set(buckets)) and buckets[-1] == num_slots
+    for n in range(1, num_slots + 1):
+        padded = _bucket_ceil(buckets, n)
+        assert padded >= n and padded in buckets
+        assert n <= 16 or padded < 2 * n, (n, padded)
+        assert padded <= 4 * n
+    if num_slots >= 64:
+        assert {_bucket_ceil(buckets, n) for n in range(17, 33)} == {32}
+        assert {_bucket_ceil(buckets, n) for n in range(33, 65)} == {64}
+
+
+BUCKET_MODELS = {
+    "llama": lambda: Llama(llama_tiny(num_layers=2)),
+    "falcon-h1": lambda: FalconH1(falcon_h1_tiny()),
+    "nemotron-h": lambda: NemotronH(nemotron_h_tiny(first_held_expert=4)),
+}
+
+
+@pytest.mark.parametrize("model", sorted(BUCKET_MODELS))
+def test_twenty_prompts_on_64_slots_ride_one_32_row_dispatch(model):
+    """20 short prompts admitted together: one [32, chunk] dispatch
+    (12 padding rows with ``n_valid == 0``, which write nothing), and
+    every request's tokens are what it gets served alone through the
+    1-row bucket -- a dense model, a parallel hybrid (pages AND state
+    in every layer) and a Mamba-2 / routed-experts hybrid alike."""
+    engine = deepspeed_tpu.init_inference(
+        model=BUCKET_MODELS[model](), dtype="float32", kv_cache_dtype="float32")
+    engine.init_params(seed=3)
+    cfg = dict(num_slots=64, num_pages=128, page_size=8,
+               max_pages_per_slot=2, prefill_chunk=8,
+               decode_horizon_steps=2, prefix_cache=False)
+    rng = np.random.default_rng(41)
+    prompts = [rng.integers(0, 256, int(n)).astype(np.int32)
+               for n in rng.integers(2, 9, 20)]
+    before = engine.serving_prefill_compile_count()
+
+    together = ServingScheduler(engine, audit_every=1, **cfg)
+    assert together.prefill_row_buckets == [1, 4, 16, 32, 64]
+    reqs = [together.submit(p, max_new_tokens=4) for p in prompts]
+    got = together.run()
+    s, h = together.summary(), together.health()
+    assert s["prefill_dispatches_by_bucket"] == {"32": 1}
+    assert h["prefill_dispatches_by_bucket"] == {"32": 1}
+    assert (s["prefill_rows"], s["prefill_padded_rows"]) == (20, 32)
+    assert s["prefill_pad_share"] == pytest.approx(12 / 32)
+
+    alone = ServingScheduler(engine, audit_every=1, **cfg)
+    for p, r in zip(prompts, reqs):
+        one = alone.submit(p, max_new_tokens=4)
+        assert alone.run()[one.rid] == got[r.rid]
+        assert r.state == one.state == "finished"
+    assert alone.summary()["prefill_dispatches_by_bucket"] == {"1": 20}
+    compiled = engine.serving_prefill_compile_count() - before
+    assert compiled == 2 <= len(together.prefill_row_buckets)

@@ -524,6 +524,9 @@ HEALTH_SCHEMA = {
     "prefill_tokens": (int,),
     "prefill_rows_per_dispatch": (int, float),
     "prefill_pad_share": (int, float),
+    # dispatches per row bucket they rode in, {"16": n, "32": n, ...}
+    # (PR 41): which programs of the bucket set the traffic runs
+    "prefill_dispatches_by_bucket": (dict,),
     "prefill_reserve_cap": (int,),
     "seq_prefill_routed": (int,),
     "seq_prefill_chunks": (int,),

@@ -23,9 +23,12 @@ Contracts the observability tier rides on:
 """
 
 import csv
+import json
 import math
 import os
 import types
+
+import pytest
 
 from deepspeed_tpu.monitor.config import get_monitor_config
 from deepspeed_tpu.monitor.monitor import (MonitorMaster,
@@ -219,6 +222,30 @@ def test_decode_live_page_share_is_live_pages_over_the_tables_walked():
     m.record_horizon(3, 8, 0, 0.0, table_pages=24)
     assert m.summary()["decode_live_page_share"] == \
         round(125 / (20 * 24), 4)
+
+
+@pytest.mark.parametrize("dispatches,by_bucket,pad_share", [
+    ([], {}, 0.0),
+    ([(20, 32)], {"32": 1}, 12 / 32),
+    ([(3, 4), (17, 32), (30, 32), (5, 16), (33, 64), (1, 1)],
+     {"1": 1, "4": 1, "16": 1, "32": 2, "64": 1}, 1 - 89 / 149),
+])
+def test_prefill_dispatches_are_counted_by_the_bucket_they_rode_in(
+        dispatches, by_bucket, pad_share):
+    """(rows, padded_rows) of each dispatch: ``summary()`` holds the
+    count per bucket under JSON keys in the buckets' order, never a
+    bucket that was not used, beside the pad share of the same rows."""
+    m = ServingMetrics(None)
+    for step, (rows, padded) in enumerate(dispatches, 1):
+        m.record_prefill_dispatch(step, rows=rows, padded_rows=padded,
+                                  tokens=8 * rows)
+    s = m.summary()
+    assert s["prefill_dispatches_by_bucket"] == by_bucket
+    assert list(s["prefill_dispatches_by_bucket"]) == \
+        sorted(by_bucket, key=int)
+    assert sum(by_bucket.values()) == s["prefill_dispatches"]
+    assert s["prefill_pad_share"] == pytest.approx(pad_share, abs=1e-4)
+    json.dumps(s["prefill_dispatches_by_bucket"])
 
 
 _CLUSTER_TAGS = ("heartbeat_miss", "failover", "replay", "retry",

@@ -29,7 +29,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu import comm as dist
-from deepspeed_tpu.ops.ssm.state import RECURRENT_STATE_REFUSALS
+from deepspeed_tpu.ops.ssm.state import SLOT_STATE_REFUSALS
 from deepspeed_tpu.parallel import sharding as shd
 from deepspeed_tpu.parallel.topology import make_mesh
 from deepspeed_tpu.serving.sampling import pipeline as policy_pipeline
@@ -360,7 +360,7 @@ class InferenceEngine:
             info["kv_pool_bytes_per_device"] = pool_bytes_per_device(kv)
             info["kv_pool_bytes_total"] = sum(
                 int(leaf.nbytes) for leaf in jax.tree.leaves(kv))
-            if self.recurrent_state:
+            if self.slot_state:
                 info["state_pool_bytes_per_device"] = \
                     pool_bytes_per_device(state)
                 info["state_pool_bytes_total"] = sum(
@@ -728,24 +728,25 @@ class InferenceEngine:
         return mod
 
     @property
-    def recurrent_state(self):
-        """True for a model that keeps recurrent (conv/SSM) state per
-        slot beside the page pool (its module says so)."""
-        return bool(getattr(self.module, "recurrent_state", False))
+    def slot_state(self):
+        """What the model keeps per SLOT beside the page pool, in its
+        module's words — "recurrent state" (conv/SSM, ops/ssm/state.py),
+        "a window ring" (ops/attention/window.py) — or None."""
+        return getattr(self.module, "slot_state", None)
 
-    def recurrent_state_refusal(self, feature):
-        """THE rule for a model with recurrent state: None where
-        ``feature`` (a key of ``RECURRENT_STATE_REFUSALS``) can serve
-        this engine's model, else the reason it cannot — one sentence
-        naming the model, for ``health()`` or an error."""
-        if not self.recurrent_state:
+    def slot_state_refusal(self, feature):
+        """THE rule for a model that keeps per-slot state: None where
+        ``feature`` (a key of ``SLOT_STATE_REFUSALS``) can serve this
+        engine's model, else the reason it cannot — one sentence naming
+        the model and what it keeps, for ``health()`` or an error."""
+        if not self.slot_state:
             return None
-        return (f"{type(self.module).__name__} keeps recurrent state per "
-                f"slot, which {RECURRENT_STATE_REFUSALS[feature]}")
+        return (f"{type(self.module).__name__} keeps {self.slot_state} per "
+                f"slot, which {SLOT_STATE_REFUSALS[feature]}")
 
-    def refuse_recurrent_state(self, feature):
-        """Raise where :meth:`recurrent_state_refusal` has a reason."""
-        why = self.recurrent_state_refusal(feature)
+    def refuse_slot_state(self, feature):
+        """Raise where :meth:`slot_state_refusal` has a reason."""
+        why = self.slot_state_refusal(feature)
         if why is not None:
             raise ValueError(f"{feature} cannot serve this model: {why}")
 
@@ -807,7 +808,7 @@ class InferenceEngine:
                 "OFF (pages must tile the 128-lane TPU layout): decode "
                 "runs the gather reference path — use page_size 128 or "
                 "256 for kernel-speed paged attention", stacklevel=2)
-        if not self.recurrent_state:
+        if not self.slot_state:
             build = functools.partial(mod.init_paged_kv_cache, cfg,
                                       num_pages, page_size, dtype=dt)
         else:
@@ -834,17 +835,32 @@ class InferenceEngine:
         struct = getattr(self, "_pool_struct", None)
         return shd.pool if struct is None else shd.pool_tree(struct)
 
-    def state_bytes_per_slot(self, kv_dtype=None):
-        """Exact bytes of recurrent state ONE slot costs across all
-        layers (0 for a model without any) — beside ``kv_page_bytes``,
-        the second unit the capacity arithmetic bills in."""
-        if not self.recurrent_state:
-            return 0
+    def _kv_dtype_of(self, kv_dtype=None):
+        """``kv_dtype`` (default: the engine's) as a jnp dtype where it
+        names a float one; a quantized kv-dtype name stays a name."""
         dt = self.kv_dtype if kv_dtype is None else kv_dtype
-        if isinstance(dt, str) and dt in DTYPES:
-            dt = DTYPES[dt]
+        return DTYPES[dt] if isinstance(dt, str) and dt in DTYPES else dt
+
+    def state_bytes_per_slot(self, kv_dtype=None):
+        """Exact bytes of per-slot state (recurrent state, window
+        rings) ONE slot costs across all layers (0 for a model without
+        any) — beside ``kv_page_bytes``, the second unit the capacity
+        arithmetic bills in."""
+        if not self.slot_state:
+            return 0
+        dt = self._kv_dtype_of(kv_dtype)
         return self._paged_module().state_bytes_per_slot(self.module.cfg,
                                                          dt)
+
+    def window_ring(self, kv_dtype=None):
+        """(the window a sliding-window layer's ring holds, the bytes of
+        ring ONE slot costs across all such layers); (0, 0) for a model
+        without one.  Its module exports ``window_ring(cfg, dtype)``."""
+        mod = self._cache_module()
+        if mod is None or not hasattr(mod, "window_ring"):
+            return 0, 0
+        dt = self._kv_dtype_of(kv_dtype)
+        return mod.window_ring(self.module.cfg, dt)
 
     def routing_counters(self, pools):
         """The routed layers' counters riding the pools (uint32, mod
@@ -860,13 +876,16 @@ class InferenceEngine:
         payload + the f32 scale rows of a quantized pool) — the unit
         the capacity ledgers and the autotuner's feasibility arithmetic
         bill in.  Agrees with the allocated leaves' nbytes to the byte
-        (pinned by tests/unit/test_kv_quant.py)."""
+        (pinned by tests/unit/test_kv_quant.py).  A family whose pages
+        are not ``head_dim`` wide for K and V alike exports its own
+        ``kv_page_bytes(cfg, page_size, dtype)``."""
         from deepspeed_tpu.ops.quant import kv as kvq
         cfg = self.module.cfg
+        dt = self._kv_dtype_of(kv_dtype)
+        mod = self._cache_module()
+        if hasattr(mod, "kv_page_bytes"):
+            return mod.kv_page_bytes(cfg, page_size, dt)
         heads, kv_heads = self._model_head_counts()
-        dt = self.kv_dtype if kv_dtype is None else kv_dtype
-        if isinstance(dt, str) and dt in DTYPES:
-            dt = DTYPES[dt]
         return kvq.kv_page_bytes(getattr(cfg, "num_kv_layers",
                                          cfg.num_layers), kv_heads or heads,
                                  cfg.head_dim, page_size, dt)
@@ -1205,7 +1224,7 @@ class InferenceEngine:
         may append to it).  Page ids are traced scalars, so churn in
         which pages get copied never adds a jit signature — ONE compile
         per serving config, like the other paged primitives."""
-        self.refuse_recurrent_state("prefix_cache")
+        self.refuse_slot_state("prefix_cache")
         if getattr(self, "_copy_page_fn", None) is None:
             # a page copy moves one index of the GLOBAL page dim; the
             # kv-head shards copy in place on their own devices (no
@@ -1259,7 +1278,7 @@ class InferenceEngine:
         conventional; the extra gathered page is trimmed on host).  Ids
         are a traced operand, so churn in WHICH pages transfer never
         adds a signature: exactly one compile per bucket length."""
-        self.refuse_recurrent_state("handoff")
+        self.refuse_slot_state("handoff")
         if getattr(self, "_chain_export_fn", None) is None:
             def export(pools, ids):
                 return [{name: arr[ids] for name, arr in L.items()}
@@ -1286,7 +1305,7 @@ class InferenceEngine:
         the padded writes, the same out-of-range discipline every paged
         write primitive rides.  Donates the pools like every other
         pool-mutating primitive; one compile per bucket length."""
-        self.refuse_recurrent_state("handoff")
+        self.refuse_slot_state("handoff")
         if getattr(self, "_chain_import_fn", None) is None:
             def imp(pools, payload, ids):
                 return {"layers": [
@@ -1517,7 +1536,7 @@ class InferenceEngine:
         in the standard pool, so decode / prefix-cache donation / COW /
         spec verify / handoff downstream never notice which path
         prefilled them."""
-        self.refuse_recurrent_state("seq_parallel_prefill")
+        self.refuse_slot_state("seq_parallel_prefill")
         assert self.params is not None, "set_params/init_params first"
         plan = self.seq_parallel_plan()
         assert plan.usable, \
@@ -1677,7 +1696,7 @@ class InferenceEngine:
         follow-up dispatch can run straight off them; the host mirrors
         the rollback with ``PagedKVManager.truncate_slot``.  One
         compiled signature per K (the scheduler's spec-K bucket set)."""
-        self.refuse_recurrent_state("spec_decode")
+        self.refuse_slot_state("spec_decode")
         assert self.params is not None, "set_params/init_params first"
         shd = self._serving_shardings(num_slots=int(np.shape(budgets)[0]))
         if getattr(self, "_paged_verify_fn", None) is None:
@@ -1785,7 +1804,7 @@ class InferenceEngine:
         counts_end, pools)``.  One compiled signature per K bucket —
         sampling params are traced, so sampled+spec composes without
         recompiles (the gate ``ds_serve`` used to force off)."""
-        self.refuse_recurrent_state("spec_decode")
+        self.refuse_slot_state("spec_decode")
         assert self.params is not None, "set_params/init_params first"
         shd = self._serving_shardings(num_slots=int(np.shape(budgets)[0]))
         if getattr(self, "_paged_verify_policy_fn", None) is None:

@@ -295,7 +295,7 @@ class FalconH1(nn.Module):
     qtensor_params = True   # QDense consumes QTensor kernels
     # recurrent per-slot state: no prefix-cache match, no speculative
     # verify, no sequence-parallel prefill, no page-chain hand-off
-    recurrent_state = True
+    slot_state = "recurrent state"
 
     @nn.compact
     def __call__(self, input_ids, deterministic=True, positions=None,

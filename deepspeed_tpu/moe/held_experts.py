@@ -69,13 +69,24 @@ def relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
+def swiglu(x):
+    """A gated expert through the one ``activation`` seam: ``w_up``
+    holds gate and up side by side, [held, hidden, 2 x inter], and the
+    activation of the packed product is ``silu(gate) * up`` at ``inter``
+    — so ``held_experts_ffn`` is the same two matmuls for either kind
+    of expert."""
+    inter = x.shape[-1] // 2
+    return jax.nn.silu(x[..., :inter]) * x[..., inter:]
+
+
 def held_experts_ffn(x, chosen, weights, w_up, w_down, first, live=None,
                      activation=relu2):
     """The held experts' part of ``sum_k w_k * down_k(act(up_k(x)))``.
 
     ``x`` [t, hidden]; ``chosen``/``weights`` [t, k] from the router
     over all experts; ``w_up`` [held, hidden, inter], ``w_down`` [held,
-    inter, hidden] are experts ``first ..``; ``live`` [t] bool marks
+    inter, hidden] are experts ``first ..`` (``w_up`` twice as wide
+    under :func:`swiglu`); ``live`` [t] bool marks
     the tokens that exist (padding columns and idle slots route
     nowhere).  Returns (out [t, hidden] in x's dtype, group sizes
     [held] int32: the pairs each held expert computed)."""

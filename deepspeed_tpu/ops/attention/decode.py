@@ -152,7 +152,7 @@ def _gqa_reference(q, k, v, bias, scale):
     weights = jnp.exp(logits - logits.max(axis=-1, keepdims=True))
     weights = weights / weights.sum(axis=-1, keepdims=True)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", weights.astype(v.dtype), v)
-    return out.reshape(b, l, h, d).astype(q.dtype)
+    return out.reshape(b, l, h, v.shape[-1]).astype(q.dtype)
 
 
 def _multichip_mesh():
@@ -302,6 +302,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
                          active=None):
     slots, one, h, d = q.shape
     page_size, kv_h = k_pages.shape[1], k_pages.shape[2]
+    d_v = v_pages.shape[3]      # a value may be narrower than a key
     maxp = page_table.shape[1]
     group = h // kv_h
     quantized = k_scale is not None
@@ -317,8 +318,10 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
         return (pages[i], 0, 0, 0)
 
     q_spec = pl.BlockSpec((1, kv_h, group, d), slot_index)
-    page_spec = pl.BlockSpec((1, page_size, kv_h, d), page_index)
-    in_specs = [q_spec, page_spec, page_spec]
+    out_spec = pl.BlockSpec((1, kv_h, group, d_v), slot_index)
+    in_specs = [q_spec,
+                pl.BlockSpec((1, page_size, kv_h, d), page_index),
+                pl.BlockSpec((1, page_size, kv_h, d_v), page_index)]
     operands = [q_g, k_pages, v_pages]
     if quantized:
         scale_spec = pl.BlockSpec((1, page_size, kv_h, 1), page_index)
@@ -327,7 +330,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
     # the output starts as zeros (aliased in, never fetched): the rows
     # of slots in no pair are never visited
     in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-    operands.append(jnp.zeros(q_g.shape, q.dtype))
+    operands.append(jnp.zeros((slots, kv_h, group, d_v), q.dtype))
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                page_size=page_size, maxp=maxp,
                                quantized=quantized)
@@ -335,20 +338,20 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
         num_scalar_prefetch=4,
         grid=(jnp.maximum(n[0], 1),),
         in_specs=in_specs,
-        out_specs=q_spec,
+        out_specs=out_spec,
         scratch_shapes=[
             pltpu.VMEM((kv_h, group, 128), jnp.float32),
             pltpu.VMEM((kv_h, group, 128), jnp.float32),
-            pltpu.VMEM((kv_h, group, d), jnp.float32),
+            pltpu.VMEM((kv_h, group, d_v), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, kv_h, group, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((slots, kv_h, group, d_v), q.dtype),
         input_output_aliases={4 + len(operands) - 1: 0},
         interpret=interpret,
     )(pair, pages, positions, n, *operands)
-    return out.reshape(slots, 1, h, d)
+    return out.reshape(slots, 1, h, d_v)
 
 
 _KERNEL_MODE = None       # None -> "auto"; see kernel_mode_scope
@@ -684,6 +687,7 @@ def decode_attention(q, k_cache, v_cache, *, bias, scale=None,
         interpret = jax.default_backend() != "tpu"
 
     if l == 1 and h % kv_h == 0 and max_len % (block_k or 128) == 0 and \
+            v_cache.shape[-1] == d and \
             (force_kernel or not (interpret or _multichip_mesh())):
         # the K and V blocks span ALL heads and are double-buffered (4
         # resident copies): cap one block at 2 MiB so the set stays

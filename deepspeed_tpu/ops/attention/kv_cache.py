@@ -2,7 +2,10 @@
 
 A model file owns its projections (and their LoRA deltas), rotary /
 ALiBi and the head geometry; everything between ``q, k, v`` and the
-attention output is :func:`attend`.  Three caches exist:
+attention output is :func:`attend`.  Three caches exist (and a serving
+dispatch's pools may hold three kinds of per-layer entry: K/V PAGES,
+here; a recurrent layer's per-slot STATE, ops/ssm/state.py; a
+sliding-window layer's per-slot RING, ops/attention/window.py):
 
 * ``None`` — training / full forward: ``attn_impl`` picks the kernel.
 * the dense cache of ``generate()`` (:func:`init_dense`): per layer
@@ -39,6 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deepspeed_tpu.ops.attention import window as window_ops
 from deepspeed_tpu.ops.attention.decode import (_repeat_kv,
                                                 decode_attention,
                                                 paged_decode_attention,
@@ -115,7 +119,10 @@ def init_paged(num_layers, num_pages, page_size, kv_heads, head_dim, dtype):
     table (host-owned, passed per step; only the pools live here).
     GQA pools are sized to the kv heads and stay grouped.  ``dtype``
     may be a quantized kv-dtype name ("int8"/"fp8"): payload pools plus
-    parallel per-row f32 scale pools (ops/quant/kv.py)."""
+    parallel per-row f32 scale pools (ops/quant/kv.py).  A model whose
+    layers differ (in head counts, in kind of cache) builds its entries
+    itself: ``paged_pool_layer`` takes one layer's geometry, a value
+    width beside the key's included."""
     return {"layers": [paged_pool_layer(num_pages, page_size, kv_heads,
                                         head_dim, dtype)
                        for _ in range(num_layers)]}
@@ -186,22 +193,43 @@ def advance(cache, new_layers):
 # ------------------------------------------------------ inside ``attn``
 
 def attend(q, k, v, positions, cache, *, impl="auto", window=0,
-           key_bias=None):
-    """Attention of q [b, l, h, d] over k/v [b, l, kv_h, d] and what
-    ``cache`` (a :func:`layer_view`) already holds.  Returns (out
-    [b, l, h, d], the layer's updated cache or None).  ``window`` > 0
-    is local sliding-window attention; ``key_bias`` maps key positions
-    [n] to an additive bias broadcastable to [b, h, l, n] (ALiBi:
-    softmax is shift-invariant per query row, so slopes * key_pos ==
-    slopes * (key_pos - query_pos))."""
+           key_bias=None, sink=None, scale=None):
+    """Attention of q [b, l, h, d] over k [b, l, kv_h, d] / v [b, l,
+    kv_h, d_v] and what ``cache`` (a :func:`layer_view`) already holds.
+    Returns (out [b, l, h, d_v], the layer's updated cache or None).
+    ``window`` > 0 is local sliding-window attention (a query sees the
+    last ``window`` positions, its own included); on a
+    :class:`PagedStep` such a layer's entry is a ring a slot, not pages
+    (ops/attention/window.py).  ``key_bias`` maps key positions [n] to
+    an additive bias broadcastable to [b, h, l, n] (ALiBi: softmax is
+    shift-invariant per query row, so slopes * key_pos == slopes *
+    (key_pos - query_pos)).  ``sink`` [h] is one logit a query head
+    that joins the softmax and whose column is dropped.  A sink, or a
+    value narrower than a key, takes the jnp forms of window.py
+    wherever no page pool is involved; over pages the two paged
+    kernels take ``d != d_v`` as they are.  ``scale`` (over pages only)
+    replaces ``1 / sqrt(d)`` where q and k arrive zero-padded to the
+    width the pool stores."""
+    if sink is not None or q.shape[-1] != v.shape[-1]:
+        assert key_bias is None, "no key bias beside a sink or d != d_v"
+        if cache is None:
+            return window_ops.attend_fresh(q, k, v, window=window,
+                                           sink=sink), None
+        if not isinstance(cache, PagedStep):
+            return window_ops.attend_dense(q, k, v, positions, cache,
+                                           window=window, sink=sink)
     if cache is None:
         return _attend_fresh(q, k, v, impl, window, key_bias), None
     if not isinstance(cache, PagedStep):
         return _attend_dense(q, k, v, positions, cache, window, key_bias)
-    assert window == 0, \
-        "paged serving does not support local attn_windows yet"
+    if window > 0:
+        assert key_bias is None, "a window ring takes no key bias"
+        return window_ops.attend_ring(q, k, v, positions, cache,
+                                      window=window, sink=sink)
+    assert sink is None, "a sink logit over pages: no kernel takes one"
     if cache.mode != "decode":
-        out, pools = _paged_multi(q, k, v, positions, cache, key_bias)
+        out, pools = _paged_multi(q, k, v, positions, cache, key_bias,
+                                  scale)
     else:
         # single-token decode, written out HERE and not behind a call of
         # its own: the Pallas kernel's body is traced below this frame
@@ -224,7 +252,7 @@ def attend(q, k, v, positions, cache, *, impl="auto", window=0,
         out = paged_decode_attention(
             q, pools["k_pages"], pools["v_pages"], pt, pos, bias=bias,
             k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"),
-            active=cache.count)
+            active=cache.count, scale=scale)
     # multi-chip serving: pin the pools' kv-head sharding on the updated
     # arrays so GSPMD keeps the scatter/gather split over the `model`
     # axis (no-op on a single-device mesh; GQA pools shard num_kv_heads,
@@ -244,7 +272,7 @@ def _causal_bias(k_pos, pos, key_bias, window=0):
     return bias if key_bias is None else bias + key_bias(k_pos)
 
 
-def _paged_multi(q, k, v, pos, step, key_bias):
+def _paged_multi(q, k, v, pos, step, key_bias, scale=None):
     """Prefill and verify: write the ``count[r]`` valid columns of each
     row through its row of the page table, then attend causally over
     the row's pages — the ``paged_prefill`` kernel over the LIVE pages
@@ -271,11 +299,14 @@ def _paged_multi(q, k, v, pos, step, key_bias):
             multi_token=True)
         if decision["path"] == "kernel":
             return paged_flash_prefill(q, pools, pt_rows, pos[:, 0],
-                                       step.count, mesh=mesh), pools
+                                       step.count, mesh=mesh,
+                                       scale=scale), pools
     k_slot, v_slot = paged_gather(pools, pt_rows, q.dtype)
     if step.seq_parallel is None:
         bias = _causal_bias(jnp.arange(pt.shape[1] * ps), pos, key_bias)
-        return decode_attention(q, k_slot, v_slot, bias=bias), pools
+        return decode_attention(q, k_slot, v_slot, bias=bias,
+                                scale=scale), pools
+    assert scale is None, "sequence-parallel prefill takes no scale"
     # sequence-parallel prefill: the write above already landed the
     # chunk's KV — with ids sequence-sharded, GSPMD all-gathers k/v over
     # the axis for the pool scatter, the collective the comm ledger

@@ -131,6 +131,7 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
     model's ``attn`` scope never count this one."""
     b, l, h, d = q.shape
     page_size, kv_h = k_pages.shape[1], k_pages.shape[2]
+    d_v = v_pages.shape[3]      # a value may be narrower than a key
     maxp = page_table.shape[1]
     group = h // kv_h
     quantized = k_scale is not None
@@ -149,10 +150,13 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
         live = _tile_last(ri, ti, st, ls, cols) // page_size
         return (pt[ri, jnp.minimum(ki, live)], 0, 0, 0)
 
-    q_spec = pl.BlockSpec((1, kv_h, tq, d),
-                          lambda ri, ti, ki, pt, st, ls: (ri, 0, ti, 0))
-    page_spec = pl.BlockSpec((1, page_size, kv_h, d), page_index)
-    in_specs = [q_spec, page_spec, page_spec]
+    def tile_index(ri, ti, ki, pt, st, ls):
+        return (ri, 0, ti, 0)
+
+    q_spec = pl.BlockSpec((1, kv_h, tq, d), tile_index)
+    in_specs = [q_spec,
+                pl.BlockSpec((1, page_size, kv_h, d), page_index),
+                pl.BlockSpec((1, page_size, kv_h, d_v), page_index)]
     operands = [q_g, k_pages, v_pages]
     if quantized:
         scale_spec = pl.BlockSpec((1, page_size, kv_h, 1), page_index)
@@ -165,20 +169,20 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
         num_scalar_prefetch=3,
         grid=(b, l_pad // cols, maxp),
         in_specs=in_specs,
-        out_specs=q_spec,
+        out_specs=pl.BlockSpec((1, kv_h, tq, d_v), tile_index),
         scratch_shapes=[
             pltpu.VMEM((kv_h, tq, 128), jnp.float32),
             pltpu.VMEM((kv_h, tq, 128), jnp.float32),
-            pltpu.VMEM((kv_h, tq, d), jnp.float32),
+            pltpu.VMEM((kv_h, tq, d_v), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec, name="paged_prefill",
-        out_shape=jax.ShapeDtypeStruct(q_g.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_g.shape[:3] + (d_v,), q.dtype),
         interpret=interpret,
     )(page_table.astype(jnp.int32), start, last, *operands)
-    return out.reshape(b, kv_h, l_pad, group, d).transpose(0, 2, 1, 3, 4) \
-        .reshape(b, l_pad, h, d)[:, :l]
+    return out.reshape(b, kv_h, l_pad, group, d_v).transpose(0, 2, 1, 3, 4) \
+        .reshape(b, l_pad, h, d_v)[:, :l]
 
 
 def paged_flash_prefill(q, pools, page_table, start, count, *,

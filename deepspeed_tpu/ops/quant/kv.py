@@ -120,17 +120,21 @@ def dequantize_kv_rows(q, scale, dtype):
         .astype(dtype)
 
 
-def paged_pool_layer(num_pages, page_size, kv_heads, head_dim, dtype):
+def paged_pool_layer(num_pages, page_size, kv_heads, head_dim, dtype,
+                     v_dim=None):
     """One layer's pool leaves: two float pools classically, four
     leaves (int8/fp8 payload + f32 scale pools) when ``dtype`` is a
-    quantized kv-dtype name."""
+    quantized kv-dtype name.  ``head_dim`` is the width of a key and,
+    unless ``v_dim`` says otherwise, of a value (MiMo-V2's keys are 192
+    wide beside values of 128)."""
+    v_dim = head_dim if v_dim is None else v_dim
     if is_quantized_kv(dtype):
         st = kv_storage_dtype(dtype)
         return {
             "k_pages": jnp.zeros((num_pages, page_size, kv_heads,
                                   head_dim), st),
             "v_pages": jnp.zeros((num_pages, page_size, kv_heads,
-                                  head_dim), st),
+                                  v_dim), st),
             "k_scale": jnp.zeros((num_pages, page_size, kv_heads, 1),
                                  jnp.float32),
             "v_scale": jnp.zeros((num_pages, page_size, kv_heads, 1),
@@ -139,7 +143,7 @@ def paged_pool_layer(num_pages, page_size, kv_heads, head_dim, dtype):
     return {
         "k_pages": jnp.zeros((num_pages, page_size, kv_heads, head_dim),
                              dtype),
-        "v_pages": jnp.zeros((num_pages, page_size, kv_heads, head_dim),
+        "v_pages": jnp.zeros((num_pages, page_size, kv_heads, v_dim),
                              dtype),
     }
 
@@ -205,16 +209,19 @@ def paged_gather(pools, page_table, dtype):
     return k, v
 
 
-def kv_page_bytes(num_layers, kv_heads, head_dim, page_size, dtype):
+def kv_page_bytes(num_layers, kv_heads, head_dim, page_size, dtype,
+                  v_dim=None):
     """Exact bytes one KV page costs across ALL layers (K + V payload
-    plus, for quantized dtypes, the f32 scale rows).  This is the
+    plus, for quantized dtypes, the f32 scale rows; ``v_dim`` where a
+    value is not as wide as a key).  This is the
     page-arithmetic unit the capacity ledgers and the autotuner's
     feasibility pruning bill in; it must agree with the allocated
     leaves' ``nbytes`` to the byte (pinned by tests/unit/
     test_kv_quant.py against real device pools)."""
+    width = head_dim + (head_dim if v_dim is None else v_dim)
     if is_quantized_kv(dtype):
-        per_row = head_dim * jnp.dtype(kv_storage_dtype(dtype)).itemsize \
-            + 4                                  # + one f32 scale
+        per_token = width * jnp.dtype(kv_storage_dtype(dtype)).itemsize \
+            + 2 * 4                              # + one f32 scale each
     else:
-        per_row = head_dim * jnp.dtype(dtype).itemsize
-    return 2 * int(num_layers) * int(page_size) * int(kv_heads) * per_row
+        per_token = width * jnp.dtype(dtype).itemsize
+    return int(num_layers) * int(page_size) * int(kv_heads) * per_token

@@ -28,17 +28,20 @@ holds the same two leaves with a batch row where the slot is.
 
 import jax.numpy as jnp
 
-# What a model with recurrent state cannot use, and why: the serving
-# layer's ONE rule (``InferenceEngine.recurrent_state_refusal``) ends
-# its sentence "<Model> keeps recurrent state per slot, which ..." here.
-RECURRENT_STATE_REFUSALS = {
+# What a model that keeps something per SLOT beside the page pool — a
+# recurrent layer's state here, a sliding-window layer's ring in
+# ops/attention/window.py — cannot use, and why: the serving layer's ONE
+# rule (``InferenceEngine.slot_state_refusal``) ends its sentence
+# "<Model> keeps <recurrent state | a window ring> per slot, which ..."
+# here, and each ending reads true of either.
+SLOT_STATE_REFUSALS = {
     "prefix_cache": "cannot be shared by pages: a cached prefix's pages "
-                    "come without the state after its last token",
+                    "come without what the slot held after its last token",
     "spec_decode": "cannot be rolled back past the drafts a verify step "
                    "rejects",
     "seq_parallel_prefill": "is carried from chunk to chunk in order, not "
                             "over a sequence axis",
-    "handoff": "does not travel with a page chain (no state snapshot "
+    "handoff": "does not travel with a page chain (no snapshot of it "
                "exists to hand over)",
 }
 

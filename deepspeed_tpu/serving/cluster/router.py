@@ -1408,7 +1408,7 @@ def make_disaggregated_group(engine, *, name="g0", num_prefill=1,
     leaves so transferred pages land with their own scales)."""
     # a prefill/decode pair hands page chains over; a model that keeps
     # recurrent state per slot has nothing to hand its state over with
-    getattr(engine, "refuse_recurrent_state", lambda feature: None)(
+    getattr(engine, "refuse_slot_state", lambda feature: None)(
         "handoff")
     if transport not in ("shared_pool", "device_put"):
         raise ValueError(f"unknown in-process transport {transport!r}")

@@ -88,9 +88,10 @@ class ServingMetrics:
         # online autotuner (OnlineTuner drives these; all 0 when off)
         self.tune_nudges = 0           # knob nudges applied
         self.tune_log = deque(maxlen=64)   # (step, knob, value)
-        # recurrent state beside the page pool, and routed layers (all
-        # 0 for a model with neither)
-        self.state_pool_bytes = 0      # per-slot conv/SSM state allocated
+        # per-slot state beside the page pool (a recurrent layer's, a
+        # window layer's ring), and routed layers (all 0 for a model
+        # with neither)
+        self.state_pool_bytes = 0      # per-slot state / rings allocated
         self.kv_pool_bytes = 0         # K/V pages allocated, beside it
         self.state_resets = 0          # prefill rows that began at 0
         self.prefix_cache_refused = 0  # a prefix cache asked for, refused
@@ -107,6 +108,16 @@ class ServingMetrics:
         self.decode_kv_tokens = 0      # sum over those: the slot's length
         self.decode_live_pages = 0     # sum over those: the pages it spans
         self.decode_table_pages = 0    # sum over steps: slots x max_pages
+        self.decode_window_tokens = 0  # decode_kv_tokens, each length
+        #                                cut to the window ring (0: none)
+        # what the prefill dispatches needed of the PAGED layers: over
+        # the rows, the keys a chunk reads (its start + its columns) and
+        # the (query, key) pairs it scores
+        self.prefill_kv_tokens = 0
+        self.prefill_kv_pairs = 0
+        # what a token costs in pages and a slot in rings (gauges)
+        self.kv_paged_bytes_per_token = 0
+        self.kv_window_bytes_per_slot = 0
         self.mesh_info = {}            # serving topology (record_mesh)
         self._events = []
 
@@ -177,11 +188,16 @@ class ServingMetrics:
                  self.prefill_tokens_saved, step),
             ])
 
-    def record_prefill_dispatch(self, step, *, rows, padded_rows, tokens):
+    def record_prefill_dispatch(self, step, *, rows, padded_rows, tokens,
+                                kv_tokens=0, kv_pairs=0):
         """One shared prefill dispatch carried the next chunk of
         ``rows`` prefilling slots (``tokens`` prompt tokens) in a
-        ``padded_rows``-row bucket."""
+        ``padded_rows``-row bucket; over the rows its chunks read
+        ``kv_tokens`` keys of the paged layers and scored ``kv_pairs``
+        (query, key) pairs."""
         self.prefill_dispatches += 1
+        self.prefill_kv_tokens += int(kv_tokens)
+        self.prefill_kv_pairs += int(kv_pairs)
         self.prefill_rows += rows
         self.prefill_padded_rows += padded_rows
         self.prefill_by_bucket[padded_rows] += 1
@@ -190,15 +206,20 @@ class ServingMetrics:
                      ("serving/prefill/padded_rows", padded_rows, step),
                      ("serving/prefill/tokens", tokens, step)])
 
-    def record_state_pool(self, nbytes, step=0):
-        """One-shot gauge at scheduler construction: bytes of per-slot
-        recurrent state allocated beside the page pool."""
+    def record_state_pool(self, nbytes, step=0, *, paged_bytes_per_token=0,
+                          window_bytes_per_slot=0):
+        """One-shot gauges at scheduler construction: bytes of per-slot
+        state (recurrent state, window rings) allocated beside the page
+        pool; what a token costs in the paged layers and a slot in the
+        window layers' rings."""
         self.state_pool_bytes = int(nbytes)
+        self.kv_paged_bytes_per_token = int(paged_bytes_per_token)
+        self.kv_window_bytes_per_slot = int(window_bytes_per_slot)
         self._write([("serving/state/pool_bytes", int(nbytes), step)])
 
     def record_state_resets(self, step, rows):
         """``rows`` prefill rows of one dispatch began at position 0:
-        their slots' recurrent state started from zeros."""
+        their slots' state started from zeros (a ring: unseen)."""
         self.state_resets += int(rows)
         self._write([("serving/state/resets", int(rows), step)])
 
@@ -322,7 +343,7 @@ class ServingMetrics:
 
     def record_horizon(self, step, horizon, tokens, device_wait_s,
                        live_rows=0, kv_tokens=0, live_pages=0,
-                       table_pages=0):
+                       table_pages=0, window_tokens=0):
         """One fused decode horizon was harvested: its step count, the
         tokens it delivered, and how long the host blocked waiting for
         the device (0 when the overlapped copy had already landed).
@@ -331,13 +352,15 @@ class ServingMetrics:
         that step (the keys its attention needed) and ``live_pages`` the
         pages that length spans (what the paged decode kernel walks);
         ``table_pages`` is one step's whole page table, slots x pages a
-        slot."""
+        slot; ``window_tokens`` is ``kv_tokens`` with each length cut to
+        the window a ring holds (0 for a model without one)."""
         self.horizons.append(horizon)
         self.decode_steps += int(horizon)
         self.decode_live_rows += int(live_rows)
         self.decode_kv_tokens += int(kv_tokens)
         self.decode_live_pages += int(live_pages)
         self.decode_table_pages += int(horizon) * int(table_pages)
+        self.decode_window_tokens += int(window_tokens)
         self._write([
                 ("serving/horizon", horizon, step),
                 ("serving/horizon_tokens", tokens, step),
@@ -664,6 +687,11 @@ class ServingMetrics:
             "decode_steps": self.decode_steps,
             "decode_live_rows": self.decode_live_rows,
             "decode_kv_tokens": self.decode_kv_tokens,
+            "decode_window_tokens": self.decode_window_tokens,
+            "prefill_kv_tokens": self.prefill_kv_tokens,
+            "prefill_kv_pairs": self.prefill_kv_pairs,
+            "kv_paged_bytes_per_token": self.kv_paged_bytes_per_token,
+            "kv_window_bytes_per_slot": self.kv_window_bytes_per_slot,
             "decode_live_page_share":
             round(self.decode_live_pages / self.decode_table_pages, 4)
             if self.decode_table_pages else None,

@@ -361,8 +361,9 @@ class ServingScheduler:
             max_pages_per_slot = -(-num_pages // 2) or 1
         self.kv = PagedKVManager(num_pages, page_size, num_slots,
                                  max_pages_per_slot, pool=shared_pool)
-        # THE rule for a model that keeps recurrent state per slot
-        # (engine.refuse_recurrent_state; ops/ssm/state.py says why):
+        # THE rule for a model that keeps state per slot beside the
+        # page pool — a recurrent layer's, a window layer's ring —
+        # (engine.refuse_slot_state; ops/ssm/state.py says why):
         # what cannot carry a state is refused BY NAME, never served
         # wrong.  A prefix cache is on by default (bin/ds_serve), so it
         # is switched off with its reason in health(); speculation, the
@@ -370,13 +371,12 @@ class ServingScheduler:
         # for and raise.  Continuous batching, chunked prefill, fused
         # horizons, overlap, slot reuse and recompute-preemption carry a
         # state as they carry pages.
-        self.recurrent_state = bool(getattr(engine, "recurrent_state",
-                                            False))
-        self._refuse = getattr(engine, "refuse_recurrent_state",
+        self.slot_state = bool(getattr(engine, "slot_state", None))
+        self._refuse = getattr(engine, "refuse_slot_state",
                                lambda feature: None)
         self.prefix_cache_refused = \
-            engine.recurrent_state_refusal("prefix_cache") \
-            if prefix_cache and self.recurrent_state else None
+            engine.slot_state_refusal("prefix_cache") \
+            if prefix_cache and self.slot_state else None
         if self.prefix_cache_refused is not None:
             prefix_cache = False
         for feature, asked in (
@@ -403,10 +403,10 @@ class ServingScheduler:
             # engine.  int8/fp8 pools carry parallel per-row f32 scale
             # pools; every host mechanism (COW, donation, truncate,
             # handoff) is dtype-blind because it moves page IDS
-            # per-slot recurrent state is sized by the slot count; a
-            # family without any never sees the argument
+            # per-slot state (recurrent, a ring) is sized by the slot
+            # count; a family without any never sees the argument
             slots = {"num_slots": self.num_slots} \
-                if self.recurrent_state else {}
+                if self.slot_state else {}
             pools_ref = _PoolsRef(engine.init_paged_cache(
                 num_pages, page_size, kv_dtype=kv_dtype, **slots))
         elif kv_dtype is not None:
@@ -535,9 +535,14 @@ class ServingScheduler:
         self._comm_summary = None       # comm_ledger()'s health cache
         if self.mesh_info:
             self.metrics.record_mesh(self.mesh_info)
-        if self.recurrent_state:
+        # the window a ring holds and its bytes a slot, (0, 0) without
+        self._window, ring_bytes = getattr(
+            engine, "window_ring", lambda: (0, 0))()
+        if self.slot_state:
             self.metrics.record_state_pool(
-                self.mesh_info.get("state_pool_bytes_total", 0))
+                self.mesh_info.get("state_pool_bytes_total", 0),
+                paged_bytes_per_token=engine.kv_page_bytes(page_size)
+                // page_size, window_bytes_per_slot=ring_bytes)
         if self.prefix_cache_refused is not None:
             self.metrics.record_prefix_refused()
         self.step_idx = 0
@@ -1839,13 +1844,19 @@ class ServingScheduler:
             logits, self.pools = self.engine.prefill_into_slots(
                 ids, slots, n_valid, self.kv.table, self.lengths,
                 self.pools, adapter_ids=a_ids, adapters=a_pack)
+        # a chunk of n columns from position s reads s + n keys of a
+        # paged layer and scores n * s + n * (n + 1) / 2 pairs
+        starts = [int(self.lengths[slot]) for slot, _, _ in rows]
         self.metrics.record_prefill_dispatch(
             self.step_idx, rows=len(rows), padded_rows=padded,
-            tokens=tokens)
-        if self.recurrent_state:
+            tokens=tokens,
+            kv_tokens=sum(starts) + tokens,
+            kv_pairs=sum(s * len(c) + len(c) * (len(c) + 1) // 2
+                         for s, (_, _, c) in zip(starts, rows)))
+        if self.slot_state:
             # a row whose first position is 0 started from zeros
             # whatever its slot held (ops/ssm/state.py)
-            fresh = sum(1 for slot, _, _ in rows if self.lengths[slot] == 0)
+            fresh = starts.count(0)
             if fresh:
                 self.metrics.record_state_resets(self.step_idx, fresh)
         return logits
@@ -2701,7 +2712,7 @@ class ServingScheduler:
         with self.phases("harvest", horizon=rec["horizon"],
                          spec=spec) as ph:
             now = ph.t0
-            pulled = live_rows = kv_tokens = live_pages = 0
+            pulled = live_rows = kv_tokens = live_pages = win_tokens = 0
             for slot in rec["slots"]:
                 req = rec["reqs"][slot]
                 if req.state in TERMINAL or self.slot_req[slot] is not req:
@@ -2724,6 +2735,9 @@ class ServingScheduler:
                 kv_tokens += n * length + n * (n + 1) // 2
                 live_pages += sum((length + j) // self.kv.page_size + 1
                                   for j in range(n))
+                if self._window:
+                    win_tokens += sum(min(length + j + 1, self._window)
+                                      for j in range(n))
                 if n and req.t_last is not None:
                     # horizon-granularity time-between-tokens: the client-
                     # visible burst cadence (per-token gaps within a burst
@@ -2784,7 +2798,8 @@ class ServingScheduler:
             else:
                 self.metrics.record_horizon(self.step_idx, rec["horizon"],
                                             pulled, wait, live_rows, kv_tokens,
-                                            live_pages, self.kv.table.size)
+                                            live_pages, self.kv.table.size,
+                                            window_tokens=win_tokens)
             ph.note(tokens=pulled)
         return wait, pulled
 

@@ -63,11 +63,14 @@ SERVING_AXIS_RULES = (
 )
 
 # the logical axes of a pools leaf that is not a K/V page array, by the
-# leaf's name in its layer's entry (ops/ssm/state.py; the routing
-# counters of moe/held_experts.py); every other leaf is a page array
+# leaf's name in its layer's entry (ops/ssm/state.py; a window layer's
+# ring, ops/attention/window.py; the routing counters of
+# moe/held_experts.py); every other leaf is a page array
 POOL_LEAF_AXES = {
     "conv": ("slots", None, None),
     "ssm": ("slots", "ssm_heads", None, None),
+    "k_ring": ("slots", None, "kv_heads", None),
+    "v_ring": ("slots", None, "kv_heads", None),
     "routing": (None,),
 }
 

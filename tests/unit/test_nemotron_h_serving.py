@@ -198,7 +198,7 @@ def test_a_model_without_state_keeps_its_prefix_cache():
     h = sched.health()
     assert sched.prefix_cache is not None
     assert h["prefix_cache_refused"] is None
-    assert h["state_pool_bytes_total"] == 0 and not eng.recurrent_state
+    assert h["state_pool_bytes_total"] == 0 and not eng.slot_state
     assert eng.state_bytes_per_slot() == 0
     assert eng.routing_counters(sched.pools) is None
 

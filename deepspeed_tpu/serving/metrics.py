@@ -39,6 +39,8 @@ class ServingMetrics:
         self.page_util = []           # pool utilization per step
         self.queue_depths = []
         self.horizons = []            # fused decode horizon per harvest
+        self.horizon_turnover_picks = 0   # of them, chosen under the
+        # configured pick by the slot-bound rule (_turnover_horizon)
         self.device_wait_s = 0.0      # step time blocked on the device
         self.host_s = 0.0             # step time doing host bookkeeping
         # prefix-cache aggregates (admission-time KV reuse)
@@ -360,7 +362,7 @@ class ServingMetrics:
 
     def record_horizon(self, step, horizon, tokens, device_wait_s,
                        live_rows=0, kv_tokens=0, live_pages=0,
-                       table_pages=0, window_tokens=0):
+                       table_pages=0, window_tokens=0, turnover=False):
         """One fused decode horizon was harvested: its step count, the
         tokens it delivered, and how long the host blocked waiting for
         the device (0 when the overlapped copy had already landed).
@@ -370,8 +372,11 @@ class ServingMetrics:
         pages that length spans (what the paged decode kernel walks);
         ``table_pages`` is one step's whole page table, slots x pages a
         slot; ``window_tokens`` is ``kv_tokens`` with each length cut to
-        the window a ring holds (0 for a model without one)."""
+        the window a ring holds (0 for a model without one);
+        ``turnover`` says the scheduler was slot-bound and chose this
+        horizon below its configured pick to turn slots over faster."""
         self.horizons.append(horizon)
+        self.horizon_turnover_picks += bool(turnover)
         self.decode_steps += int(horizon)
         self.decode_live_rows += int(live_rows)
         self.decode_kv_tokens += int(kv_tokens)
@@ -383,6 +388,12 @@ class ServingMetrics:
                 ("serving/horizon_tokens", tokens, step),
                 ("serving/horizon_wait_ms", device_wait_s * 1e3, step),
             ])
+
+    def horizon_turnover_share(self):
+        """Share of the harvested horizons that the slot-bound rule
+        chose below the configured pick (0.0 before any horizon)."""
+        return round(self.horizon_turnover_picks / len(self.horizons), 4) \
+            if self.horizons else 0.0
 
     def record_spec(self, step, *, proposed, accepted, emitted, rollbacks,
                     rollback_tokens, k, slot_rounds=0):
@@ -651,6 +662,8 @@ class ServingMetrics:
             "tbt_ms_p99": round(_percentile(self.tbt_s, 99) * 1e3, 3),
             "horizon_mean": round(float(np.mean(self.horizons)), 3)
             if self.horizons else 0.0,
+            "horizon_turnover_picks": self.horizon_turnover_picks,
+            "horizon_turnover_share": self.horizon_turnover_share(),
             "device_wait_frac": round(
                 self.device_wait_s / (self.device_wait_s + self.host_s), 4)
             if (self.device_wait_s + self.host_s) > 0 else 0.0,

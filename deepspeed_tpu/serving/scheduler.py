@@ -211,6 +211,61 @@ def _bucket_ceil(buckets, n):
     return buckets[-1]
 
 
+# what the slot-bound horizon rule did in a step it did not run in:
+# (chose under the configured pick, P seconds, D seconds)
+_NO_TURNOVER = (False, 0.0, 0.0)
+
+
+class _StepCost:
+    """What a scheduler step has been costing by the decode horizon it
+    carried: the recent walls of the cycles in which a prefill dispatch
+    and a horizon of ``h`` steps rode together (boundary work + the
+    prefill's device time + ``h`` decode passes), a short deque a
+    horizon bucket.  ``estimate()`` reads them as ``wall = P + h * D``:
+    ``D`` (one decode pass) is the median slope between the buckets'
+    medians, ``P`` (everything else a step costs) what that leaves of
+    the bucket sampled last.  Measured, never configured: P / D is 3.5
+    for one model and traffic and 10 for another."""
+
+    KEEP = 16   # walls kept a bucket: the newest push the oldest out
+    ENOUGH = 3  # walls from which a bucket's median counts (one stalled
+                # step among three does not move it)
+
+    def __init__(self):
+        self.walls = {}     # horizon -> deque of recent cycle walls, s
+        self.last = None    # the horizon sampled last
+
+    def add(self, horizon, wall_s):
+        self.walls.setdefault(
+            horizon, deque(maxlen=self.KEEP)).append(wall_s)
+        self.last = horizon
+
+    def estimate(self):
+        """``(P, D)`` in seconds, or None while fewer than two buckets
+        have ``ENOUGH`` samples or their medians do not rise with the
+        horizon."""
+        med = {h: float(np.median(w)) for h, w in self.walls.items()
+               if len(w) >= self.ENOUGH}
+        if len(med) < 2:
+            return None
+        hs = sorted(med)
+        d = float(np.median([(med[b] - med[a]) / (b - a)
+                             for i, a in enumerate(hs)
+                             for b in hs[i + 1:]]))
+        if d <= 0:
+            return None
+        last = self.last if self.last in med else hs[-1]
+        return max(0.0, med[last] - last * d), d
+
+    def wanting(self, buckets):
+        """The first of ``buckets`` with fewer than ``ENOUGH`` samples,
+        or None."""
+        for b in buckets:
+            if len(self.walls.get(b, ())) < self.ENOUGH:
+                return b
+        return None
+
+
 class _PoolsRef:
     """Mutable holder for the device-resident KV pools.  The jitted
     primitives are functional — every dispatch consumes the pools and
@@ -552,6 +607,15 @@ class ServingScheduler:
         # dominate the estimate for dozens of steps and shed perfectly
         # serviceable deadline-bearing requests after every cold start
         self._step_window = deque(maxlen=16)
+        # the decode horizon under slot-bound load (_pick_horizon): what
+        # a step has been costing by horizon, whether this step's
+        # admission left requests waiting, and the clock its samples are
+        # cut by (the last harvest's end, or the step's start)
+        self._step_cost = _StepCost()
+        self._slot_bound = False
+        self._cycle_t0 = 0.0
+        self._prefill_rode = False
+        self._turnover = _NO_TURNOVER   # the newest pick
         self._last_error = None
         # Router-HA fence state, set by the owning replica/worker:
         # the highest router epoch this scheduler has served under and
@@ -1330,11 +1394,15 @@ class ServingScheduler:
         now: remaining prefill chunks + one decode horizon per
         ``decode_horizon_steps`` remaining tokens (ignores queueing
         ahead of it — a deliberately optimistic bound, so shedding only
-        fires on certainly-hopeless requests).  With the prefix cache
-        on, tokens a hit would skip are subtracted — a request the
-        cache makes feasible must not be shed for the prefill it will
-        never run (match() is a pure host trie walk, cheap enough to
-        price in here)."""
+        fires on certainly-hopeless requests).  ``decode_horizon_steps``
+        is what a step carries while nothing waits for a slot and the
+        most it carries when something does (``_pick_horizon`` then
+        asks ``_service_steps`` what each smaller bucket would cost),
+        so this stays the optimistic bound under both.  With the prefix
+        cache on, tokens a hit would skip are subtracted — a request
+        the cache makes feasible must not be shed for the prefill it
+        will never run (match() is a pure host trie walk, cheap enough
+        to price in here)."""
         pending = max(0, len(req.prompt) - req.prefill_pos)
         if self.prefix_cache is not None and req.prefill_pos == 0 \
                 and pending > 1:
@@ -1343,15 +1411,20 @@ class ServingScheduler:
                 ns=self._req_ns(req))
             pending = max(1, pending - len(full) * self.kv.page_size
                           - plen)
+        return self._service_steps(pending, req.remaining_new,
+                                   self.decode_horizon_steps)
+
+    def _service_steps(self, pending, new_tokens, horizon):
+        """Scheduler iterations that ``pending`` prompt tokens and
+        ``new_tokens`` output tokens take at decode horizon
+        ``horizon``: one a prefill chunk, one a horizon."""
         chunk = self.prefill_chunk
         if self.seq_plan is not None and self.seq_parallel_threshold > 0 \
                 and pending >= self.seq_parallel_threshold:
             # priced at the widest sp bucket: routed prompts retire
             # axis_size x prefill_chunk tokens per step
             chunk = self.sp_chunk_buckets[-1]
-        prefill = -(-pending // chunk)
-        horizons = -(-max(1, req.remaining_new) // self.decode_horizon_steps)
-        return prefill + horizons
+        return -(-pending // chunk) + -(-max(1, new_tokens) // horizon)
 
     def _step_s_estimate(self):
         """Robust per-step wall-time estimate for admission decisions:
@@ -1406,6 +1479,10 @@ class ServingScheduler:
         phases = self.phases
         before = dict(phases.seconds)
         with phases("step") as ph_step:
+            if not self._inflight:
+                # nothing on the device: the cycle _step_cost times
+                # starts with this step (else at the last harvest's end)
+                self._cycle_t0 = ph_step.t0
             # fault point: slow-step / loop-level fault injection. Fires
             # per HORIZON since the fused-decode change — with
             # decode_horizon_steps > 1 a "step" covers up to that many
@@ -1657,6 +1734,12 @@ class ServingScheduler:
                                      f"{type(e).__name__}: {e}")
             if self.slot_req[slot] is req:
                 self._route_seq_parallel(slot, req)
+        # slot-bound: admission left requests waiting for want of a slot
+        # or of pages (a tenant parked at its quota waits for pages
+        # too), so their time to a first token is set by how fast slots
+        # turn over.  Recorded HERE, where admission knows it: a submit
+        # racing the rest of the step has not been refused anything
+        self._slot_bound = bool(self.waiting)
 
     def _attach_prefix(self, slot, req, hit):
         """Map a matched cached chain into the admitted slot: full pages
@@ -1783,6 +1866,7 @@ class ServingScheduler:
         wide sharded chunk.  Slots finishing their prompt this step
         sample their first token in ONE batched device call over the
         dispatch's whole logits block."""
+        self._prefill_rode = False   # until _prefill_dispatch says so
         rows = []        # (slot, req, chunk) riding the shared dispatch
         blocks = []      # (logits [n, vocab], [(row, slot, req)] finishing)
         for slot in range(self.num_slots):
@@ -1838,6 +1922,7 @@ class ServingScheduler:
             slots[i] = slot
             n_valid[i] = len(chunk)
         tokens = int(n_valid.sum())
+        self._prefill_rode = True
         with self.phases("prefill_chunk", rows=len(rows),
                          padded_rows=padded, tokens=tokens):
             a_ids, a_pack = self._adapter_args()
@@ -2151,14 +2236,34 @@ class ServingScheduler:
         return out
 
     def _pick_horizon(self, running, now):
-        """Largest useful horizon, quantized to the bucket set: capped
-        by the largest remaining token budget among running slots (scan
-        steps past every budget are pure waste) and by the tightest live
-        deadline (a horizon overshooting a deadline generates tokens the
-        sweep will throw away).  A grammar-constrained slot pins the
-        batch to horizon 1: its allowed-token mask is a host-compiled
-        function of the tokens emitted so far, so the device may take
-        at most one constrained step per staged mask."""
+        """The decode horizon of this step's dispatch, under one of two
+        regimes the scheduler reads off its own state.
+
+        **Nothing waits for a slot** (``_admit`` admitted everything):
+        the rows decoding are the ones a user watches, and the horizon
+        is the largest useful one, quantized to the bucket set: capped
+        by ``decode_horizon_steps``, by the largest remaining token
+        budget among running slots (scan steps past every budget are
+        pure waste) and by the tightest live deadline (a horizon
+        overshooting a deadline generates tokens the sweep will throw
+        away).  A grammar-constrained slot pins the batch to horizon 1:
+        its allowed-token mask is a host-compiled function of the
+        tokens emitted so far, so the device may take at most one
+        constrained step per staged mask.
+
+        **Slot-bound** (``_admit`` left requests waiting for a slot or
+        for pages): every waiting request's time to a first token is
+        set by how fast slots turn over, so the pick above is only a
+        cap, and the horizon is the bucket under it that finishes the
+        requests now in slots in the least time (``_turnover_horizon``):
+        a step carries one prefill dispatch for every prefilling row
+        and ``h`` weight passes for the few rows decoding, and with
+        long prompts the passes are most of the step.
+
+        The same tokens come out in the same order either way (a
+        horizon is a scan of single steps); ``_reserve`` may still
+        shrink the horizon under page pressure afterwards."""
+        self._turnover = _NO_TURNOVER
         if any(self.slot_req[s].grammar is not None for s in running):
             return 1
         h = min(self.decode_horizon_steps,
@@ -2170,7 +2275,41 @@ class ServingScheduler:
             if per_tok > 0:
                 slack = min(deadlines) - now
                 h = max(1, min(h, int(slack / per_tok)))
-        return self._bucket_floor(h)
+        h = self._bucket_floor(h)
+        return self._turnover_horizon(h) if self._slot_bound else h
+
+    def _turnover_horizon(self, cap):
+        """The horizon bucket no larger than ``cap`` that turns slots
+        over fastest.  The requests now in slots are the sample of the
+        traffic the scheduler has: a slot serves one of them in
+        ``steps_i(h)`` steps of ``T(h)`` seconds, so the bucket with
+        the least ``T(h) x sum_i steps_i(h)`` serves such requests at
+        the highest rate.  ``steps_i(h)`` is request i's whole life,
+        its prompt's chunks plus ``ceil(max_new_i / h)``
+        (``_service_steps``, the arithmetic admission prices a request
+        with), and ``T(h) = P + h * D`` is ``_step_cost``'s measured
+        wall of a step that carries ``h`` decode passes.  Long prompts
+        and short outputs pull it down (``sqrt(o * P / (c * D))`` for
+        ``c`` chunks and ``o`` tokens a request), chat lengths leave it
+        at 4 to 8.  A tie goes to the larger bucket.  While there is no
+        estimate the step rides the largest bucket that still wants
+        samples (``cap`` itself on a scheduler that has just started,
+        then the next one down: six horizons give two buckets their
+        medians, and no shorter horizon is tried than that takes); with
+        every bucket sampled and no estimate, ``cap`` stands."""
+        buckets = [b for b in reversed(self.horizon_buckets) if b <= cap]
+        est = self._step_cost.estimate()
+        if est is None:
+            best, p, d = self._step_cost.wanting(buckets) or cap, 0.0, 0.0
+        else:
+            p, d = est
+            live = [(len(r.orig_prompt) - r.cached_prefix_tokens,
+                     r.max_new_tokens)
+                    for r in self.slot_req if r is not None]
+            best = min(buckets, key=lambda b: (p + b * d) * sum(
+                self._service_steps(n, o, b) for n, o in live))
+        self._turnover = (best < cap, p, d)
+        return best
 
     def _reserve(self, running, horizon):
         """Pre-reserve every running slot's pages for the whole horizon
@@ -2491,7 +2630,10 @@ class ServingScheduler:
                 running, self._pick_horizon(running, ph.t0))
             if not running:
                 return
-            ph.note(horizon=horizon, slots=len(running))
+            picked, p_s, d_s = self._turnover
+            ph.note(horizon=horizon, slots=len(running),
+                    slot_bound=int(self._slot_bound),
+                    p_ms=round(p_s * 1e3, 3), d_ms=round(d_s * 1e3, 3))
             active = np.zeros(self.num_slots, bool)
             active[running] = True
             budgets = np.zeros(self.num_slots, np.int32)
@@ -2516,11 +2658,14 @@ class ServingScheduler:
                     self.pools, horizon=horizon, budgets=budgets,
                     eos_ids=self._eos_ids, adapter_ids=a_ids,
                     adapters=a_pack, **self.sampling)
-            self._commit_dispatch(out, running, horizon,
-                                  {s: self.slot_req[s] for s in running},
-                                  policy=pol)
+            self._commit_dispatch(
+                out, running, horizon,
+                {s: self.slot_req[s] for s in running}, policy=pol,
+                turnover=picked,
+                cycle_t0=self._cycle_t0 if self._prefill_rode else None)
 
-    def _commit_dispatch(self, out, running, horizon, reqs, policy=None):
+    def _commit_dispatch(self, out, running, horizon, reqs, policy=None,
+                         turnover=False, cycle_t0=None):
         if policy is not None:
             # the policy twin returns a counts carry before the pools:
             # a chained continuation stages IT (device truth mid-chain)
@@ -2548,6 +2693,10 @@ class ServingScheduler:
             "active_end": active_end, "lengths_end": lengths_end,
             "emitted_end": emitted_end, "release_after": set(),
             "policy": policy, "t_dispatch": time.monotonic(),
+            # whether the slot-bound rule chose this horizon below the
+            # configured pick, and where the cycle _step_cost times
+            # began (None: no prefill dispatch rode it, or it is chained)
+            "turnover": turnover, "cycle_t0": cycle_t0,
         })
 
     def _try_chain(self):
@@ -2707,6 +2856,11 @@ class ServingScheduler:
             toks = np.asarray(rec["toks"])    # blocks until the device
             valid = np.asarray(rec["valid"])  # (and async copy) catch up
         wait = ph.last_s
+        # this horizon is done: one cycle ends here and the next begins
+        self._cycle_t0 = ph.t0 + wait
+        if rec.get("cycle_t0") is not None:
+            self._step_cost.add(rec["horizon"],
+                                self._cycle_t0 - rec["cycle_t0"])
         # host bookkeeping share of the harvest (emit callbacks, retire,
         # rollback) — the counterpart of device_wait above
         with self.phases("harvest", horizon=rec["horizon"],
@@ -2799,7 +2953,8 @@ class ServingScheduler:
                 self.metrics.record_horizon(self.step_idx, rec["horizon"],
                                             pulled, wait, live_rows, kv_tokens,
                                             live_pages, self.kv.table.size,
-                                            window_tokens=win_tokens)
+                                            window_tokens=win_tokens,
+                                            turnover=rec["turnover"])
             ph.note(tokens=pulled)
         return wait, pulled
 
@@ -3108,6 +3263,10 @@ class ServingScheduler:
             else round(self._ema_step_s * 1e3, 3),
             "decode_horizon_steps": self.decode_horizon_steps,
             "horizon_buckets": list(self.horizon_buckets),
+            # horizons the slot-bound rule chose below the configured
+            # pick (_turnover_horizon), and their share of all horizons
+            "horizon_turnover_picks": m.horizon_turnover_picks,
+            "horizon_turnover_share": m.horizon_turnover_share(),
             "overlap": self.overlap,
             # sequence-parallel prefill: the resolved transport (or why
             # it degraded), the routing threshold, and the fairness cap

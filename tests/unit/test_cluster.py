@@ -430,6 +430,10 @@ HEALTH_SCHEMA = {
     "ema_step_ms": (float, type(None)),
     "decode_horizon_steps": (int,),
     "horizon_buckets": (list,),
+    # horizons the slot-bound rule chose below the configured pick
+    # (PR 47, _turnover_horizon) and their share of all horizons
+    "horizon_turnover_picks": (int,),
+    "horizon_turnover_share": (float,),
     "overlap": (bool,),
     "spec_decode": (str,),
     "spec_k": (int, type(None)),

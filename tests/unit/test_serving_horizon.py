@@ -15,6 +15,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.models.gpt2 import GPT2, gpt2_tiny
 from deepspeed_tpu.serving import ServingScheduler
+from deepspeed_tpu.serving.scheduler import Request
 
 CFG = dict(num_slots=3, num_pages=16, page_size=16, max_pages_per_slot=8,
            prefill_chunk=8)
@@ -165,6 +166,244 @@ def test_decode_compile_count_bounded_by_horizon_buckets(engine):
     # the fused path IS the decode path: the single-step primitive never
     # compiles in serving anymore
     assert engine.serving_decode_compile_count() == 0
+
+
+# ------------------------------------------- the horizon under load
+
+
+def _seat(sched, shapes, deadline_in=None, grammar=False):
+    """Fill ``sched``'s slots with running requests of ``(prompt
+    tokens, max_new, already emitted)`` without serving them (the rule
+    reads the scheduler's own state and nothing of the device)."""
+    reqs = []
+    for n, new, emitted in shapes:
+        r = Request(np.zeros(n, np.int32), new)
+        r.out_tokens = [0] * emitted
+        r.state = "running"
+        reqs.append(r)
+    if deadline_in is not None:
+        reqs[0].deadline = NOW + deadline_in
+    if grammar:
+        reqs[0].grammar = object()
+    sched.slot_req = reqs
+    return list(range(len(reqs)))
+
+
+def _seed_walls(sched, p_ms, d_ms, horizons, n=5):
+    """Step walls as a program whose step costs ``p_ms + h * d_ms``
+    would have left them, in the buckets ``horizons``."""
+    for h in horizons:
+        for _ in range(n):
+            sched._step_cost.add(h, (p_ms + h * d_ms) / 1e3)
+
+
+NOW = 100.0
+# (slots, per-token median s, deadline slack s, grammar) -> the pick
+# when nothing waits: the budget cap, the deadline cap, grammar -> 1
+UNENGAGED = {
+    "budget_over_cap": ([(9, 40, 1), (9, 40, 30)], None, None, False, 8),
+    "budget_7": ([(9, 40, 33), (9, 40, 38)], None, None, False, 4),
+    "budget_3": ([(9, 40, 37)], None, None, False, 2),
+    "budget_1": ([(9, 40, 39), (9, 3, 2)], None, None, False, 1),
+    "deadline_5_tokens": ([(9, 40, 1)], 0.01, 0.055, False, 4),
+    "deadline_passed": ([(9, 40, 1)], 0.01, -1.0, False, 1),
+    "deadline_far": ([(9, 40, 1)], 0.01, 9.0, False, 8),
+    "grammar": ([(9, 40, 1), (9, 40, 1)], None, None, True, 1),
+}
+
+
+@pytest.mark.parametrize("slot_bound", [False, True])
+@pytest.mark.parametrize("case", sorted(UNENGAGED))
+def test_pick_horizon_unengaged_is_the_configured_pick(engine, case,
+                                                       slot_bound):
+    """Nothing waiting: ``_pick_horizon`` returns what it returned
+    before the slot-bound rule existed, over the budget cap, the
+    deadline cap and the grammar pin, whatever the step walls say.
+    Slot-bound: the same caps are applied first, so the pick is a
+    bucket no larger than that."""
+    shapes, per_tok, slack, grammar, want = UNENGAGED[case]
+    sched = ServingScheduler(engine, decode_horizon_steps=8, **CFG)
+    running = _seat(sched, shapes, deadline_in=slack, grammar=grammar)
+    if per_tok is not None:
+        sched._tok_window.append(per_tok)
+    _seed_walls(sched, 30.0, 8.75, (2, 8))
+    sched._slot_bound = slot_bound
+    got = sched._pick_horizon(running, NOW)
+    if not slot_bound:
+        assert got == want
+        assert sched._turnover == (False, 0.0, 0.0)
+    else:
+        assert got in sched.horizon_buckets and got <= want
+
+
+# the two closed loops of the benchmark: a step's cost as their traces
+# read it (prefill dispatch ms, one decode pass ms) and the requests
+# their mixes put in the slots (prompts log-uniform, outputs uniform)
+LOOPS = {
+    # P / D = 3.4: 16 slots, prompts 1024-4096, outputs 32-128
+    "mistral_longprompt": (30.0, 8.75, 16, (1024, 4096), (32, 128), (2,)),
+    # P / D = 10.7: 32 slots, prompts 2k-16k, outputs 64-256 (h = 2
+    # and h = 4 cost the same there to the model's precision)
+    "mimo_longctx": (43.5, 4.05, 32, (2048, 16384), (64, 256), (2, 4)),
+    # chat lengths over their knee keep a long horizon
+    "chat_overload": (30.0, 17.0, 32, (100, 400), (64, 384), (4, 8)),
+}
+
+
+@pytest.mark.parametrize("cap", [1, 2, 4, 8])
+@pytest.mark.parametrize("loop", sorted(LOOPS))
+def test_turnover_horizon_of_the_two_closed_loops(engine, loop, cap):
+    """Slot-bound with step walls at the two cells' P / D and their
+    row mixes: the bucket ``(c + o / h)(P + h D)`` is least at, never a
+    value outside ``horizon_buckets`` or above the un-engaged pick."""
+    p_ms, d_ms, slots, prompts, outs, best = LOOPS[loop]
+    rng = np.random.default_rng(7)
+    sched = ServingScheduler(engine, decode_horizon_steps=8, **CFG)
+    sched.prefill_chunk = 32
+    n = np.exp(rng.uniform(*np.log(prompts), slots)).astype(int)
+    new = rng.integers(outs[0], outs[1] + 1, slots)
+    # prefilling rows have emitted nothing; the two rows decoding have
+    # 1 and ``cap`` tokens left, which is the un-engaged pick
+    done = [0] * (slots - 2) + [int(new[-2]) - 1, int(new[-1]) - cap]
+    running = _seat(sched, list(zip(n, new, done)))[-2:]
+    _seed_walls(sched, p_ms, d_ms, (1, 2, 4, 8))
+    sched._slot_bound = True
+    got = sched._pick_horizon(running, NOW)
+    assert got in sched.horizon_buckets and got <= cap
+    assert got in ([b for b in best if b <= cap] or [cap])
+    chose_lower, p, d = sched._turnover
+    assert chose_lower == (got < cap)
+    assert (p * 1e3, d * 1e3) == pytest.approx((p_ms, d_ms))
+
+
+# walls already sampled, as {horizon: (how many, ms)} -> the pick of a
+# slot-bound step whose un-engaged pick is 8
+NO_ESTIMATE = {
+    # a scheduler that has just started rides the configured horizon
+    "none": ({}, 8),
+    # then the next bucket down, for its samples
+    "one_bucket": ({8: (5, 100.0)}, 4),
+    "first_bucket_thin": ({8: (2, 100.0)}, 8),
+    "second_bucket_thin": ({8: (5, 100.0), 4: (2, 65.0)}, 4),
+    # medians that do not rise with the horizon are no estimate: the
+    # unsampled buckets first, and with all sampled the configured one
+    "falling": ({8: (5, 50.0), 2: (5, 80.0)}, 4),
+    "falling_all_sampled": ({8: (3, 50.0), 4: (3, 60.0), 2: (3, 80.0),
+                             1: (3, 90.0)}, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(NO_ESTIMATE))
+def test_turnover_horizon_without_an_estimate(engine, case):
+    """Fewer than two buckets with enough samples (a scheduler that
+    has just started), or medians that do not rise with the horizon:
+    there is no estimate, and a slot-bound step rides the largest
+    bucket that still wants samples: the configured horizon first, and
+    again once every bucket has its samples."""
+    walls, want = NO_ESTIMATE[case]
+    sched = ServingScheduler(engine, decode_horizon_steps=8, **CFG)
+    sched.prefill_chunk = 32
+    running = _seat(sched, [(2048, 80, 0)] * 15 + [(2048, 80, 20)])[-1:]
+    for h, (n, ms) in walls.items():
+        for _ in range(n):
+            sched._step_cost.add(h, ms / 1e3)
+    sched._slot_bound = True
+    assert sched._step_cost.estimate() is None
+    assert sched._pick_horizon(running, NOW) == want
+    assert sched._turnover == (want < 8, 0.0, 0.0)
+    # nothing waiting: the configured horizon, whatever was sampled
+    sched._slot_bound = False
+    assert sched._pick_horizon(running, NOW) == 8
+
+
+def test_step_cost_prefers_the_recent_walls(engine):
+    """A bucket keeps its newest walls only, one stalled step among a
+    bucket's samples does not move its median, and P is read off the
+    bucket sampled last."""
+    sched = ServingScheduler(engine, decode_horizon_steps=8, **CFG)
+    sched.prefill_chunk = 32
+    running = _seat(sched, [(2048, 80, 0)] * 15 + [(2048, 80, 20)])[-1:]
+    sched._slot_bound = True
+    _seed_walls(sched, 500.0, 100.0, (2, 8), n=3)
+    _seed_walls(sched, 30.0, 8.75, (8, 2), n=sched._step_cost.KEEP - 1)
+    sched._step_cost.add(8, 2.4)       # a step that stood still
+    sched._step_cost.add(2, 0.0475)
+    p, d = sched._step_cost.estimate()
+    assert (p * 1e3, d * 1e3) == pytest.approx((30.0, 8.75))
+    assert sched._pick_horizon(running, NOW) == 2
+    assert sched._turnover == (True, p, d)
+
+
+def test_slot_bound_closed_loop_token_exact(engine):
+    """More clients than slots, a finished request replaced at once:
+    ``_admit`` leaves requests waiting in every step, the rule engages
+    and picks under the configured horizon — and every stream still
+    equals the oracle's, every page comes back, and decode compiled no
+    signature outside the horizon bucket set."""
+    rng = np.random.default_rng(11)
+    shapes = [(40, 6), (33, 9), (48, 5), (25, 12), (40, 7), (36, 10),
+              (44, 4), (30, 8), (41, 6)]
+    prompts = [rng.integers(0, 256, n).astype(np.int32) for n, _ in shapes]
+    max_new = [m for _, m in shapes]
+    want = _oracle(engine, prompts, max_new)
+
+    sched = ServingScheduler(engine, decode_horizon_steps=8, audit_every=1,
+                             **CFG)
+    # a step that costs 30 + 8.75 h ms, held there: the tiny engine's
+    # own walls on a CPU would make the pick a matter of the machine
+    _seed_walls(sched, 30.0, 8.75, (2, 8))
+    sched._step_cost.add = lambda horizon, wall_s: None
+    clients, nxt, reqs = 6, 0, []
+    while nxt < clients:
+        reqs.append(sched.submit(prompts[nxt], max_new_tokens=max_new[nxt]))
+        nxt += 1
+    bound = []
+    while sched.step():
+        bound.append(sched._slot_bound)
+        live = sum(r.state not in ("finished",) for r in reqs)
+        while live < clients and nxt < len(prompts):
+            reqs.append(sched.submit(prompts[nxt],
+                                     max_new_tokens=max_new[nxt]))
+            nxt, live = nxt + 1, live + 1
+    for r, w in zip(reqs, want):
+        assert r.state == "finished" and r.out_tokens == w, \
+            f"rid={r.rid} diverged under the slot-bound horizon"
+    assert sched.kv.pool.pages_in_use == 0
+    assert any(bound) and not bound[-1], \
+        "slot-bound while clients waited, not once the queue drained"
+    summary, health = sched.summary(), sched.health()
+    assert summary["horizon_turnover_picks"] > 0
+    assert summary["horizon_turnover_picks"] == \
+        health["horizon_turnover_picks"]
+    assert 0 < summary["horizon_turnover_share"] <= 1
+    assert summary["horizon_turnover_share"] == pytest.approx(
+        summary["horizon_turnover_picks"] / len(sched.metrics.horizons),
+        abs=1e-4)
+    assert summary["horizon_mean"] < 8
+    assert all(h in sched.horizon_buckets for h in sched.metrics.horizons)
+    assert engine.serving_decode_multi_compile_count() <= \
+        len(sched.horizon_buckets)
+
+
+def test_step_cost_samples_the_cycles_a_prefill_rode(engine):
+    """The scheduler's own step walls, by horizon: a cycle in which a
+    prefill dispatch and a horizon rode together leaves one sample in
+    that horizon's bucket; a step that only decodes leaves none."""
+    rng = np.random.default_rng(5)
+    sched = ServingScheduler(engine, decode_horizon_steps=8, **CFG)
+    sched.submit(rng.integers(0, 256, 9).astype(np.int32),
+                 max_new_tokens=1 + 4)
+    sched.run()
+    assert list(sched._step_cost.walls) == [4]
+    assert all(0 < w < 60 for w in sched._step_cost.walls[4])
+    assert sched.summary()["horizon_turnover_picks"] == 0
+    assert sched.health()["horizon_turnover_share"] == 0.0
+    # 1 + 16 tokens alone: the second horizon's step prefills nothing
+    sched.submit(rng.integers(0, 256, 9).astype(np.int32),
+                 max_new_tokens=1 + 16)
+    sched.run()
+    assert sorted(sched._step_cost.walls) == [4, 8]
+    assert len(sched._step_cost.walls[8]) == 1
 
 
 # ------------------------------------------------- host-input staging

@@ -593,7 +593,10 @@ def test_phases_feed_the_span_tracer_under_the_old_names(engine):
         {("dispatch", "scheduler")}
     assert all(set(e[7]) == {"horizon", "spec", "tokens"}
                for e in by_name["harvest"])
-    assert all(set(e[7]) == {"horizon", "slots"}
+    # since PR 47 beside whether admission left requests waiting and the
+    # step cost the slot-bound horizon rule read (0.0 un-engaged)
+    assert all(set(e[7]) == {"horizon", "slots", "slot_bound", "p_ms",
+                             "d_ms"}
                for e in by_name["horizon_dispatch"])
     assert all(set(e[7]) == {"rows", "padded_rows", "tokens"}
                for e in by_name["prefill_chunk"])

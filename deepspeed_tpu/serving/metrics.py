@@ -72,6 +72,14 @@ class ServingMetrics:
         self.prefill_padded_rows = 0   # rows dispatched incl. bucket pad
         self.prefill_by_bucket = Counter()  # row bucket -> dispatches
         self.prefill_tokens = 0        # prompt tokens those landed
+        # decoding slots that took their next token as a one-token row
+        # of a prefill dispatch (the scheduler's _plan_ride), the
+        # dispatches that carried one, and the slot-bound steps: all,
+        # and those whose decode pass was the dispatch and nothing else
+        self.ride_rows = 0
+        self.ride_dispatches = 0
+        self.slot_bound_steps = 0
+        self.horizon_none_steps = 0
         self.seq_prefill_routed = 0    # prompts routed onto the sp path
         self.seq_prefill_chunks = 0    # sp chunk dispatches
         self.seq_prefill_tokens = 0    # prompt tokens landed via sp chunks
@@ -193,13 +201,17 @@ class ServingMetrics:
             ])
 
     def record_prefill_dispatch(self, step, *, rows, padded_rows, tokens,
-                                kv_tokens=0, kv_pairs=0):
+                                kv_tokens=0, kv_pairs=0, riders=0):
         """One shared prefill dispatch carried the next chunk of
         ``rows`` prefilling slots (``tokens`` prompt tokens) in a
-        ``padded_rows``-row bucket; over the rows its chunks read
-        ``kv_tokens`` keys of the paged layers and scored ``kv_pairs``
-        (query, key) pairs."""
+        ``padded_rows``-row bucket, and beside them the next token of
+        ``riders`` decoding slots (one-token rows: in neither ``rows``
+        nor ``tokens``); over all of them its rows read ``kv_tokens``
+        keys of the paged layers and scored ``kv_pairs`` (query, key)
+        pairs."""
         self.prefill_dispatches += 1
+        self.ride_rows += int(riders)
+        self.ride_dispatches += riders > 0
         self.prefill_kv_tokens += int(kv_tokens)
         self.prefill_kv_pairs += int(kv_pairs)
         self.prefill_rows += rows
@@ -276,6 +288,24 @@ class ServingMetrics:
         experts (the others computed every held expert at once)."""
         return self.moe_walk_calls / self.moe_dense_calls \
             if self.moe_dense_calls else 0.0
+
+    def ride_steps_share(self):
+        """Share of the shared prefill dispatches that carried a
+        decoding slot's next token beside the prompt rows."""
+        return self.ride_dispatches / self.prefill_dispatches \
+            if self.prefill_dispatches else 0.0
+
+    def record_slot_bound_step(self, no_horizon):
+        """A barrier step whose admission left requests waiting;
+        ``no_horizon``: its decoding slots rode the prefill dispatch and
+        no decode horizon followed."""
+        self.slot_bound_steps += 1
+        self.horizon_none_steps += bool(no_horizon)
+
+    def horizon_none_share(self):
+        """Share of the slot-bound steps that launched no horizon."""
+        return self.horizon_none_steps / self.slot_bound_steps \
+            if self.slot_bound_steps else 0.0
 
     def prefill_rows_per_dispatch(self):
         """Mean prefilling slots per shared prefill dispatch — how often
@@ -700,6 +730,9 @@ class ServingMetrics:
             "prefill_rows_per_dispatch":
             round(self.prefill_rows_per_dispatch(), 3),
             "prefill_pad_share": round(self.prefill_pad_share(), 4),
+            "ride_rows": self.ride_rows,
+            "ride_steps_share": round(self.ride_steps_share(), 4),
+            "horizon_none_share": round(self.horizon_none_share(), 4),
             "prefill_dispatches_by_bucket":
             self.prefill_dispatches_by_bucket(),
             "seq_prefill_routed": self.seq_prefill_routed,

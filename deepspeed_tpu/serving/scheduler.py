@@ -12,7 +12,10 @@ granularity.  Each ``step()`` is one scheduler iteration:
   3. advance every admitted-but-unprefilled slot by ONE prompt chunk
      (chunked prefill — long prompts never stall running decoders for
      more than a chunk), all of them in ONE ``[rows, prefill_chunk]``
-     dispatch (rows padded to a row bucket: x4 to 16 rows, x2 above),
+     dispatch (rows padded to a row bucket: x4 to 16 rows, x2 above);
+     while requests wait for a slot the decoding slots may ride it as
+     one-token rows, where the measured step walls say that pays
+     (``_plan_ride``),
   4. run ONE fused multi-step decode ("horizon") over all running
      slots: up to ``decode_horizon_steps`` tokens per slot in a single
      ``decode_multi`` dispatch, with token feedback, EOS detection and
@@ -214,38 +217,56 @@ def _bucket_ceil(buckets, n):
 # what the slot-bound horizon rule did in a step it did not run in:
 # (chose under the configured pick, P seconds, D seconds)
 _NO_TURNOVER = (False, 0.0, 0.0)
+# the first half of a _StepCost form in which the decoding rows rode
+# the prefill dispatch: (RIDE, the horizon that followed)
+RIDE = "ride"
 
 
 class _StepCost:
-    """What a scheduler step has been costing by the decode horizon it
-    carried: the recent walls of the cycles in which a prefill dispatch
-    and a horizon of ``h`` steps rode together (boundary work + the
-    prefill's device time + ``h`` decode passes), a short deque a
-    horizon bucket.  ``estimate()`` reads them as ``wall = P + h * D``:
-    ``D`` (one decode pass) is the median slope between the buckets'
-    medians, ``P`` (everything else a step costs) what that leaves of
-    the bucket sampled last.  Measured, never configured: P / D is 3.5
-    for one model and traffic and 10 for another."""
+    """What a scheduler step has been costing by its form: the recent
+    walls of the cycles in which a prefill dispatch rode, a short deque
+    a form.  A form is the decode horizon ``h`` the step carried beside
+    the dispatch (boundary work + the prefill's device time + ``h``
+    decode passes), or ``(RIDE, h)`` where the rows decoding rode the
+    dispatch as one-token rows and a horizon of ``h`` followed (0:
+    none did).  ``estimate()`` reads the plain forms as ``wall = P +
+    h * D``: ``D`` (one decode pass) is the median slope between the
+    buckets' medians, ``P`` (everything else a step costs) what that
+    leaves of the bucket sampled last; a ride form is read by its own
+    median (``median``), since what a rider costs the dispatch is not
+    a decode pass.  Measured, never configured: P / D is 3.5 for one
+    model and traffic and 10 for another."""
 
-    KEEP = 16   # walls kept a bucket: the newest push the oldest out
-    ENOUGH = 3  # walls from which a bucket's median counts (one stalled
+    KEEP = 16   # walls kept a form: the newest push the oldest out
+    ENOUGH = 3  # walls from which a form's median counts (one stalled
                 # step among three does not move it)
+    MARGIN = 0.05   # the share by which a ride form has to undercut the
+                    # best plain one: a form's median moves by 2-3%
+                    # between runs of one program, and forms that tie
+                    # within that must not trade places by the run
 
     def __init__(self):
-        self.walls = {}     # horizon -> deque of recent cycle walls, s
-        self.last = None    # the horizon sampled last
+        self.walls = {}     # form -> deque of recent cycle walls, s
+        self.last = None    # the horizon sampled last (plain forms)
 
-    def add(self, horizon, wall_s):
+    def add(self, form, wall_s):
         self.walls.setdefault(
-            horizon, deque(maxlen=self.KEEP)).append(wall_s)
-        self.last = horizon
+            form, deque(maxlen=self.KEEP)).append(wall_s)
+        if not isinstance(form, tuple):
+            self.last = form
+
+    def median(self, form):
+        """The median wall of ``form``, or None under ``ENOUGH``."""
+        walls = self.walls.get(form, ())
+        return float(np.median(walls)) if len(walls) >= self.ENOUGH \
+            else None
 
     def estimate(self):
         """``(P, D)`` in seconds, or None while fewer than two buckets
         have ``ENOUGH`` samples or their medians do not rise with the
         horizon."""
         med = {h: float(np.median(w)) for h, w in self.walls.items()
-               if len(w) >= self.ENOUGH}
+               if len(w) >= self.ENOUGH and not isinstance(h, tuple)}
         if len(med) < 2:
             return None
         hs = sorted(med)
@@ -257,12 +278,12 @@ class _StepCost:
         last = self.last if self.last in med else hs[-1]
         return max(0.0, med[last] - last * d), d
 
-    def wanting(self, buckets):
-        """The first of ``buckets`` with fewer than ``ENOUGH`` samples,
+    def wanting(self, forms):
+        """The first of ``forms`` with fewer than ``ENOUGH`` samples,
         or None."""
-        for b in buckets:
-            if len(self.walls.get(b, ())) < self.ENOUGH:
-                return b
+        for f in forms:
+            if len(self.walls.get(f, ())) < self.ENOUGH:
+                return f
         return None
 
 
@@ -607,15 +628,22 @@ class ServingScheduler:
         # dominate the estimate for dozens of steps and shed perfectly
         # serviceable deadline-bearing requests after every cold start
         self._step_window = deque(maxlen=16)
-        # the decode horizon under slot-bound load (_pick_horizon): what
-        # a step has been costing by horizon, whether this step's
-        # admission left requests waiting, and the clock its samples are
-        # cut by (the last harvest's end, or the step's start)
+        # the step's form under slot-bound load (_plan_ride,
+        # _pick_horizon): what a step has been costing by form, whether
+        # this step's admission left requests waiting, and the clock its
+        # samples are cut by (the last harvest's end, the end of a step
+        # whose decode pass was its prefill dispatch -- _cycle_open says
+        # so to the next step --, or the step's start)
         self._step_cost = _StepCost()
         self._slot_bound = False
         self._cycle_t0 = 0.0
+        self._cycle_open = False
         self._prefill_rode = False
         self._turnover = _NO_TURNOVER   # the newest pick
+        # the horizon that follows if this step's decoding rows ride its
+        # prefill dispatch (None: they do not), and the rows that did
+        self._ride = None
+        self._riders = 0
         self._last_error = None
         # Router-HA fence state, set by the owning replica/worker:
         # the highest router epoch this scheduler has served under and
@@ -1479,10 +1507,13 @@ class ServingScheduler:
         phases = self.phases
         before = dict(phases.seconds)
         with phases("step") as ph_step:
-            if not self._inflight:
+            if not self._inflight and not self._cycle_open:
                 # nothing on the device: the cycle _step_cost times
-                # starts with this step (else at the last harvest's end)
+                # starts with this step (else at the last harvest's
+                # end, or the end of a step that rode and carried no
+                # horizon)
                 self._cycle_t0 = ph_step.t0
+            self._cycle_open = False
             # fault point: slow-step / loop-level fault injection. Fires
             # per HORIZON since the fused-decode change — with
             # decode_horizon_steps > 1 a "step" covers up to that many
@@ -1519,12 +1550,30 @@ class ServingScheduler:
                 with phases("admit"):
                     self._admit_attached(now)
                     self._admit(now)
+                    self._plan_ride(now)
+                bound, free = self._slot_bound, self.slot_req.count(None)
                 # 3. one prompt chunk per prefilling slot (chunked
-                # prefill)
+                # prefill), and where _plan_ride said so the next token
+                # of every decoding slot as a one-token row beside them
                 with phases("prefill"):
                     self._prefill()
+                if self._riders and self.waiting and \
+                        self.slot_req.count(None) > free:
+                    # a rider's last token retired it at the boundary:
+                    # its slot is admitted into now, as after a harvest,
+                    # so no slot stands empty between steps while
+                    # requests wait (the admitted prompt's first chunk
+                    # is the next step's either way)
+                    with phases("admit"):
+                        self._admit(now)
                 # 4. dispatch ONE fused decode horizon over running slots
-                self._dispatch()
+                # (none where the rows rode and the plan was no horizon)
+                launched = self._dispatch()
+                if self._riders and not launched:
+                    self._close_ride_cycle()
+                if bound:
+                    self.metrics.record_slot_bound_step(
+                        bool(self._riders) and not launched)
                 if not self.overlap and self._inflight:
                     w, n = self._harvest()
                     t_wait += w
@@ -1865,17 +1914,25 @@ class ServingScheduler:
         sequence-parallel prefill keep their own one-row dispatch of a
         wide sharded chunk.  Slots finishing their prompt this step
         sample their first token in ONE batched device call over the
-        dispatch's whole logits block."""
+        dispatch's whole logits block.
+
+        Where ``_plan_ride`` said so, the decoding slots ride the same
+        dispatch (``_riders``): a slot's next token is a row of one
+        valid column, ``last_tok[slot]`` at position ``lengths[slot]``,
+        and its boundary logits are that token's -- one decode step
+        computed by the prefill program, sampled with the finishing
+        rows.  A rider moves ``lengths`` and never ``prefill_pos``: its
+        token is an emitted token, not a prompt token."""
         self._prefill_rode = False   # until _prefill_dispatch says so
+        self._riders = 0
         rows = []        # (slot, req, chunk) riding the shared dispatch
-        blocks = []      # (logits [n, vocab], [(row, slot, req)] finishing)
+        blocks = []      # (logits [n, vocab], [(row, slot, req)] to sample)
         for slot in range(self.num_slots):
             req = self.slot_req[slot]
             if req is None or req.state != PREFILL:
                 continue
             try:
-                if getattr(req, "seq_parallel", False) \
-                        and self.seq_plan is not None:
+                if self._sp_routed(req):
                     logits = self._prefill_seq_parallel(slot, req)
                     if logits is not None:
                         blocks.append((logits, [(0, slot, req)]))
@@ -1890,16 +1947,23 @@ class ServingScheduler:
             except Exception as e:   # containment: fail one, not all
                 self._close_slot(slot, FAILED,
                                  f"{type(e).__name__}: {e}")
+        if rows and self._ride is not None:
+            rows += self._ride_rows()
         # a later row's growth may have evicted an earlier row's slot:
         # its pages are gone (maybe already another row's), so it must
         # not ride the dispatch
         rows = [(s, r, c) for s, r, c in rows
-                if self.slot_req[s] is r and r.state == PREFILL]
+                if self.slot_req[s] is r and r.state in (PREFILL, RUNNING)]
+        if all(r.state == RUNNING for _, r, _ in rows):
+            rows = []    # no prompt row is left: no dispatch to ride
         if rows:
             logits = self._prefill_dispatch(rows)
             done = []
             for i, (slot, req, chunk) in enumerate(rows):
                 self.lengths[slot] += len(chunk)
+                if req.state == RUNNING:
+                    done.append((i, slot, req))
+                    continue
                 req.prefill_pos += len(chunk)
                 if req.prefill_pos == len(req.prompt):
                     done.append((i, slot, req))
@@ -1908,11 +1972,50 @@ class ServingScheduler:
         for logits, done in blocks:
             self._sample_boundary(logits, done)
 
+    def _sp_routed(self, req):
+        """Whether ``req``'s prompt takes the sequence-parallel path's
+        own dispatches and not a row of the shared one."""
+        return getattr(req, "seq_parallel", False) and \
+            self.seq_plan is not None
+
+    def _may_ride(self, req):
+        """Whether a RUNNING request's next token can be a row of the
+        prefill dispatch: a plain decode step and nothing else -- no
+        grammar mask to restage, no hand-off owed, not a prompt the
+        sequence-parallel path prefilled."""
+        return req.grammar is None and not req.handoff and \
+            not getattr(req, "seq_parallel", False) and \
+            req.remaining_new >= 1
+
+    def _ride_rows(self):
+        """The decoding slots as rows of this step's prefill dispatch,
+        ``(slot, req, [last_tok[slot]])``, each after its page for that
+        one token is there, under the per-slot containment of a prompt
+        row: a rider whose growth fails is shed alone."""
+        rows = []
+        for slot in range(self.num_slots):
+            req = self.slot_req[slot]
+            if req is None or req.state != RUNNING or \
+                    not self._may_ride(req):
+                continue
+            try:
+                if self._grow_or_evict(slot, int(self.lengths[slot]) + 1):
+                    rows.append((slot, req, [int(self.last_tok[slot])]))
+            except PagePoolExhausted as e:
+                self._close_slot(slot, SHED, f"page capacity: {e}")
+            except Exception as e:   # containment: fail one, not all
+                self._close_slot(slot, FAILED,
+                                 f"{type(e).__name__}: {e}")
+        return rows
+
     def _prefill_dispatch(self, rows):
         """Pack ``rows`` into the smallest row bucket and launch the
         shared prefill dispatch; returns its [bucket, vocab] boundary
         logits (row i belongs to ``rows[i]``).  Padding rows carry
-        ``n_valid == 0`` and a live slot id: they write nothing."""
+        ``n_valid == 0`` and a live slot id: they write nothing.  The
+        counters' ``rows`` and ``tokens`` are PROMPT rows and prompt
+        tokens; riders are counted beside them (``riders``), and in the
+        keys and pairs the paged layers' attention really handles."""
         padded = _bucket_ceil(self.prefill_row_buckets, len(rows))
         ids = np.zeros((padded, self.prefill_chunk), np.int32)
         slots = np.full(padded, rows[0][0], np.int32)
@@ -1921,10 +2024,13 @@ class ServingScheduler:
             ids[i, :len(chunk)] = chunk
             slots[i] = slot
             n_valid[i] = len(chunk)
-        tokens = int(n_valid.sum())
+        riders = sum(req.state == RUNNING for _, req, _ in rows)
+        tokens = int(n_valid.sum()) - riders
         self._prefill_rode = True
-        with self.phases("prefill_chunk", rows=len(rows),
-                         padded_rows=padded, tokens=tokens):
+        self._riders = riders
+        with self.phases("prefill_chunk", rows=len(rows) - riders,
+                         padded_rows=padded, tokens=tokens,
+                         riders=riders):
             a_ids, a_pack = self._adapter_args()
             logits, self.pools = self.engine.prefill_into_slots(
                 ids, slots, n_valid, self.kv.table, self.lengths,
@@ -1933,9 +2039,9 @@ class ServingScheduler:
         # paged layer and scores n * s + n * (n + 1) / 2 pairs
         starts = [int(self.lengths[slot]) for slot, _, _ in rows]
         self.metrics.record_prefill_dispatch(
-            self.step_idx, rows=len(rows), padded_rows=padded,
-            tokens=tokens,
-            kv_tokens=sum(starts) + tokens,
+            self.step_idx, rows=len(rows) - riders, padded_rows=padded,
+            tokens=tokens, riders=riders,
+            kv_tokens=sum(starts) + tokens + riders,
             kv_pairs=sum(s * len(c) + len(c) * (len(c) + 1) // 2
                          for s, (_, _, c) in zip(starts, rows)))
         if self.slot_state:
@@ -1971,17 +2077,20 @@ class ServingScheduler:
 
     def _sample_boundary(self, logits, finishing):
         """First tokens of the requests whose prompt finished in one
-        prefill dispatch: ``logits`` is the dispatch's whole [n, vocab]
-        block and ``finishing`` lists ``(row, slot, req)``.  The sample
-        runs over EVERY row of the block (one program per row bucket,
-        never per finishing count or row index); non-finishing and
-        padding rows' tokens are dropped on the host."""
+        prefill dispatch, and the next tokens of the slots that rode it
+        (``_ride_rows``; RUNNING, where a finishing row is PREFILL):
+        ``logits`` is the dispatch's whole [n, vocab] block and
+        ``finishing`` lists ``(row, slot, req)``.  The sample runs over
+        EVERY row of the block (one program per row bucket, never per
+        finishing count or row index); non-finishing and padding rows'
+        tokens are dropped on the host."""
         # a later slot's growth (a sequence-parallel reservation) may
         # have evicted an earlier finishing slot — drop stale entries
         # BEFORE the batched sample (the policy-table gathers index by
         # slot, so a vacated slot must not reach them)
         finishing = [(i, s, r) for i, s, r in finishing
-                     if self.slot_req[s] is r and r.state == PREFILL]
+                     if self.slot_req[s] is r
+                     and r.state in (PREFILL, RUNNING)]
         if not finishing:
             return
         # the batched sample is shared work (like the decode dispatch);
@@ -2016,10 +2125,15 @@ class ServingScheduler:
             toks = sample(*args, **kw)
         with self.phases("first_token"):
             for i, slot, req in finishing:
-                if self.slot_req[slot] is not req or req.state != PREFILL:
+                if self.slot_req[slot] is not req or \
+                        req.state not in (PREFILL, RUNNING):
                     continue   # closed by an earlier row's emit epilogue
                 tok = toks[i]
                 try:
+                    if req.state == RUNNING:
+                        # a rider's token is a burst of one
+                        self.metrics.record_tbt(
+                            self.step_idx, time.monotonic() - req.t_last)
                     self._emit(req, tok)
                     self._note_emitted(slot, req, tok)
                 except Exception as e:
@@ -2235,35 +2349,16 @@ class ServingScheduler:
                 out = b
         return out
 
-    def _pick_horizon(self, running, now):
-        """The decode horizon of this step's dispatch, under one of two
-        regimes the scheduler reads off its own state.
-
-        **Nothing waits for a slot** (``_admit`` admitted everything):
-        the rows decoding are the ones a user watches, and the horizon
-        is the largest useful one, quantized to the bucket set: capped
-        by ``decode_horizon_steps``, by the largest remaining token
-        budget among running slots (scan steps past every budget are
-        pure waste) and by the tightest live deadline (a horizon
-        overshooting a deadline generates tokens the sweep will throw
-        away).  A grammar-constrained slot pins the batch to horizon 1:
-        its allowed-token mask is a host-compiled function of the
-        tokens emitted so far, so the device may take at most one
-        constrained step per staged mask.
-
-        **Slot-bound** (``_admit`` left requests waiting for a slot or
-        for pages): every waiting request's time to a first token is
-        set by how fast slots turn over, so the pick above is only a
-        cap, and the horizon is the bucket under it that finishes the
-        requests now in slots in the least time (``_turnover_horizon``):
-        a step carries one prefill dispatch for every prefilling row
-        and ``h`` weight passes for the few rows decoding, and with
-        long prompts the passes are most of the step.
-
-        The same tokens come out in the same order either way (a
-        horizon is a scan of single steps); ``_reserve`` may still
-        shrink the horizon under page pressure afterwards."""
-        self._turnover = _NO_TURNOVER
+    def _horizon_cap(self, running, now):
+        """The largest useful horizon over ``running``, quantized to
+        the bucket set: capped by ``decode_horizon_steps``, by the
+        largest remaining token budget among running slots (scan steps
+        past every budget are pure waste) and by the tightest live
+        deadline (a horizon overshooting a deadline generates tokens
+        the sweep will throw away).  A grammar-constrained slot pins
+        the batch to horizon 1: its allowed-token mask is a
+        host-compiled function of the tokens emitted so far, so the
+        device may take at most one constrained step per staged mask."""
         if any(self.slot_req[s].grammar is not None for s in running):
             return 1
         h = min(self.decode_horizon_steps,
@@ -2275,28 +2370,75 @@ class ServingScheduler:
             if per_tok > 0:
                 slack = min(deadlines) - now
                 h = max(1, min(h, int(slack / per_tok)))
-        h = self._bucket_floor(h)
-        return self._turnover_horizon(h) if self._slot_bound else h
+        return self._bucket_floor(h)
 
-    def _turnover_horizon(self, cap):
-        """The horizon bucket no larger than ``cap`` that turns slots
-        over fastest.  The requests now in slots are the sample of the
-        traffic the scheduler has: a slot serves one of them in
-        ``steps_i(h)`` steps of ``T(h)`` seconds, so the bucket with
-        the least ``T(h) x sum_i steps_i(h)`` serves such requests at
-        the highest rate.  ``steps_i(h)`` is request i's whole life,
-        its prompt's chunks plus ``ceil(max_new_i / h)``
+    def _pick_horizon(self, running, now):
+        """The decode horizon of this step's dispatch, under one of two
+        regimes the scheduler reads off its own state.
+
+        **Nothing waits for a slot** (``_admit`` admitted everything):
+        the rows decoding are the ones a user watches, and the horizon
+        is the largest useful one (``_horizon_cap``).
+
+        **Slot-bound** (``_admit`` left requests waiting for a slot or
+        for pages): every waiting request's time to a first token is
+        set by how fast slots turn over, so the pick above is only a
+        cap, and the horizon is the bucket under it that finishes the
+        requests now in slots in the least time (``_turnover_horizon``):
+        a step carries one prefill dispatch for every prefilling row
+        and ``h`` weight passes for the few rows decoding, and with
+        long prompts the passes are most of the step.  Where the rows
+        decoding rode this step's prefill dispatch (``_riders``) the
+        horizon is the one ``_plan_ride`` chose to follow the ride
+        with: 0, no horizon at all, where every running slot has had
+        its token of this step at the boundary.
+
+        The same tokens come out in the same order either way (a
+        horizon is a scan of single steps); ``_reserve`` may still
+        shrink the horizon under page pressure afterwards."""
+        cap = self._horizon_cap(running, now)
+        if self._riders:
+            # P and D stay as _plan_ride's pick read them
+            self._turnover = (self._ride < cap,) + self._turnover[1:]
+            return self._ride
+        self._turnover = _NO_TURNOVER
+        return self._turnover_horizon(cap)[1] if self._slot_bound else cap
+
+    def _turnover_horizon(self, cap, ride=()):
+        """The form ``(rode, h)`` of a slot-bound step that turns slots
+        over fastest: ``h`` a horizon bucket no larger than ``cap``
+        beside the prefill dispatch, or, where rows can ride it, one of
+        the horizons ``ride`` after the ride (0: none; in the order they
+        are sampled in).  The requests now in slots are the sample of
+        the traffic the scheduler has: a slot serves one of them in
+        ``steps_i`` steps of ``T`` seconds, so the form with the least
+        ``T x sum_i steps_i`` serves such requests at the highest
+        rate.  ``steps_i`` is request i's whole
+        life, its prompt's chunks plus ``ceil(max_new_i / (h + rode))``
         (``_service_steps``, the arithmetic admission prices a request
-        with), and ``T(h) = P + h * D`` is ``_step_cost``'s measured
-        wall of a step that carries ``h`` decode passes.  Long prompts
-        and short outputs pull it down (``sqrt(o * P / (c * D))`` for
-        ``c`` chunks and ``o`` tokens a request), chat lengths leave it
-        at 4 to 8.  A tie goes to the larger bucket.  While there is no
+        with; a ride is one token of every step).  ``T(h) = P + h * D``
+        is ``_step_cost``'s measured wall of a step that carries ``h``
+        decode passes; a ride form's ``T`` is the median of its own
+        cycles, because what a rider costs is the prefill kernel's
+        (a 128-row query tile against every live page of its slot, and
+        a first-token pull in every step) and no multiple of ``D``.
+        Long prompts and short outputs pull the horizon down (``sqrt(o
+        * P / (c * D))`` for ``c`` chunks and ``o`` tokens a request)
+        and make the second weight pass of a step worth dropping; chat
+        lengths leave it at 4 to 8; where riders hold many pages and a
+        decode pass is cheap the ride loses, and its walls say so.  A
+        tie goes to the larger bucket, and a ride form has to undercut
+        the best plain one by ``_StepCost.MARGIN`` (forms within the
+        walls' noise of each other would trade places by the run, and
+        the plain ones are the older program).  While there is no
         estimate the step rides the largest bucket that still wants
         samples (``cap`` itself on a scheduler that has just started,
         then the next one down: six horizons give two buckets their
         medians, and no shorter horizon is tried than that takes); with
-        every bucket sampled and no estimate, ``cap`` stands."""
+        every bucket sampled and no estimate, ``cap`` stands.  With an
+        estimate, a ride form that wants samples is sampled the same
+        way, the smallest bucket after the ride first: two forms, so
+        learning that riding loses costs six steps."""
         buckets = [b for b in reversed(self.horizon_buckets) if b <= cap]
         est = self._step_cost.estimate()
         if est is None:
@@ -2306,10 +2448,74 @@ class ServingScheduler:
             live = [(len(r.orig_prompt) - r.cached_prefix_tokens,
                      r.max_new_tokens)
                     for r in self.slot_req if r is not None]
-            best = min(buckets, key=lambda b: (p + b * d) * sum(
-                self._service_steps(n, o, b) for n, o in live))
+
+            def life(per_step):
+                return sum(self._service_steps(n, o, per_step)
+                           for n, o in live)
+            forms = [(RIDE, h) for h in ride]
+            best = self._step_cost.wanting(forms)
+            if best is None:
+                cost = {b: (p + b * d) * life(b) for b in buckets}
+                best = min(cost, key=cost.get)
+                rides = {f: self._step_cost.median(f) * life(1 + f[1])
+                         for f in forms}
+                cheapest = min(rides, key=rides.get, default=None)
+                if cheapest is not None and rides[cheapest] < \
+                        (1 - self._step_cost.MARGIN) * cost[best]:
+                    best = cheapest
+        if isinstance(best, tuple):
+            self._turnover = (best[1] < cap, p, d)
+            return True, best[1]
         self._turnover = (best < cap, p, d)
-        return best
+        return False, best
+
+    def _plan_ride(self, now):
+        """Once a step, after ``_admit`` has said whether anyone waits
+        and before ``_prefill``: whether the slots decoding take their
+        next token as one-token rows of this step's prefill dispatch
+        (``_ride`` = the horizon that then follows, else None).  A
+        ``[rows, prefill_chunk]`` dispatch reads every weight once
+        whatever its rows hold, and the rows its bucket pads with are
+        the slots that decode: riding makes the step's second read of
+        the weights shorter by a pass, or drops it.  It is one more
+        choice of the slot-bound rule (``_turnover_horizon``), made
+        from the walls this scheduler measured, and only there: with
+        nothing waiting, no prompt row to ride beside, a drafter
+        configured or no slot that may ride (``_may_ride``), the step
+        is what it was.  No horizon at all is a choice only where every
+        running slot rides: a step gives each at least one token."""
+        self._ride = None
+        if not self._slot_bound or self._spec is not None:
+            return
+        running = self._running_slots()
+        riders = [s for s in running if self._may_ride(self.slot_req[s])]
+        if not riders or not any(
+                r is not None and r.state == PREFILL
+                and not self._sp_routed(r) for r in self.slot_req):
+            return
+        after = self.horizon_buckets[:1] + \
+            [0] * (len(riders) == len(running))
+        rode, h = self._turnover_horizon(
+            self._horizon_cap(running, now), ride=after)
+        if rode:
+            self._ride = h
+
+    def _running_slots(self, among=None):
+        """The slots of ``among`` (all of them) that hold a RUNNING
+        request."""
+        return [s for s in (range(self.num_slots) if among is None
+                            else among)
+                if self.slot_req[s] is not None
+                and self.slot_req[s].state == RUNNING]
+
+    def _close_ride_cycle(self):
+        """The end of a step whose decode pass was its prefill dispatch
+        and that launched no horizon: the device is done (the boundary
+        sample's pull waited for it), so the cycle ``_step_cost`` times
+        ends here, as a harvest ends one that carried a horizon."""
+        now = time.monotonic()
+        self._step_cost.add((RIDE, 0), now - self._cycle_t0)
+        self._cycle_t0, self._cycle_open = now, True
 
     def _reserve(self, running, horizon):
         """Pre-reserve every running slot's pages for the whole horizon
@@ -2363,8 +2569,7 @@ class ServingScheduler:
                 self._close_slot(slot, FAILED,  # growth is per-slot work
                                  f"{type(e).__name__}: {e}")
         # a later slot's growth can evict an earlier kept slot too
-        return horizon, [s for s in kept if self.slot_req[s] is not None
-                         and self.slot_req[s].state == RUNNING]
+        return horizon, self._running_slots(kept)
 
     # --------------------------------------------- speculative decoding
     def _spec_bucket(self, k):
@@ -2538,8 +2743,7 @@ class ServingScheduler:
                 self._close_slot(slot, SHED, f"page capacity: {e}")
             except Exception as e:
                 self._close_slot(slot, FAILED, f"{type(e).__name__}: {e}")
-        running = [s for s in kept if self.slot_req[s] is not None and
-                   self.slot_req[s].state == RUNNING]
+        running = self._running_slots(kept)
         if not running:
             return True
         try:
@@ -2606,34 +2810,34 @@ class ServingScheduler:
 
     def _dispatch(self):
         """Reserve pages and launch one fused horizon over every running
-        slot.  The batched dispatch is shared — an error here is NOT
-        attributable to one request and must surface loudly."""
-        running = [s for s in range(self.num_slots)
-                   if self.slot_req[s] is not None and
-                   self.slot_req[s].state == RUNNING]
+        slot; returns whether a dispatch was launched (none where the
+        rows rode the prefill dispatch and no horizon follows).  The
+        batched dispatch is shared — an error here is NOT attributable
+        to one request and must surface loudly."""
+        running = self._running_slots()
         if not running:
-            return
+            return False
         if self._spec is not None:
             with self.phases("spec_dispatch"):
                 took = self._dispatch_spec(running)
             if took:
-                return
-        running = [s for s in running if self.slot_req[s] is not None and
-                   self.slot_req[s].state == RUNNING]
+                return True
+        running = self._running_slots(running)
         if not running:
-            return
+            return False
         # host side of the dispatch: page reservation + argument staging
         # + launching the fused scan (the device's share of the horizon
         # shows up as device_wait at harvest)
         with self.phases("horizon_dispatch") as ph:
-            horizon, running = self._reserve(
-                running, self._pick_horizon(running, ph.t0))
-            if not running:
-                return
+            horizon = self._pick_horizon(running, ph.t0)
+            if horizon:
+                horizon, running = self._reserve(running, horizon)
             picked, p_s, d_s = self._turnover
             ph.note(horizon=horizon, slots=len(running),
-                    slot_bound=int(self._slot_bound),
+                    slot_bound=int(self._slot_bound), riders=self._riders,
                     p_ms=round(p_s * 1e3, 3), d_ms=round(d_s * 1e3, 3))
+            if not running or not horizon:
+                return False
             active = np.zeros(self.num_slots, bool)
             active[running] = True
             budgets = np.zeros(self.num_slots, np.int32)
@@ -2662,10 +2866,12 @@ class ServingScheduler:
                 out, running, horizon,
                 {s: self.slot_req[s] for s in running}, policy=pol,
                 turnover=picked,
-                cycle_t0=self._cycle_t0 if self._prefill_rode else None)
+                cycle_t0=self._cycle_t0 if self._prefill_rode else None,
+                form=(RIDE, horizon) if self._riders else horizon)
+        return True
 
     def _commit_dispatch(self, out, running, horizon, reqs, policy=None,
-                         turnover=False, cycle_t0=None):
+                         turnover=False, cycle_t0=None, form=None):
         if policy is not None:
             # the policy twin returns a counts carry before the pools:
             # a chained continuation stages IT (device truth mid-chain)
@@ -2694,9 +2900,10 @@ class ServingScheduler:
             "emitted_end": emitted_end, "release_after": set(),
             "policy": policy, "t_dispatch": time.monotonic(),
             # whether the slot-bound rule chose this horizon below the
-            # configured pick, and where the cycle _step_cost times
-            # began (None: no prefill dispatch rode it, or it is chained)
-            "turnover": turnover, "cycle_t0": cycle_t0,
+            # configured pick, where the cycle _step_cost times began
+            # (None: no prefill dispatch rode it, or it is chained) and
+            # the form it files the cycle under
+            "turnover": turnover, "cycle_t0": cycle_t0, "form": form,
         })
 
     def _try_chain(self):
@@ -2859,7 +3066,7 @@ class ServingScheduler:
         # this horizon is done: one cycle ends here and the next begins
         self._cycle_t0 = ph.t0 + wait
         if rec.get("cycle_t0") is not None:
-            self._step_cost.add(rec["horizon"],
+            self._step_cost.add(rec["form"],
                                 self._cycle_t0 - rec["cycle_t0"])
         # host bookkeeping share of the harvest (emit callbacks, retire,
         # rollback) — the counterpart of device_wait above

@@ -9,13 +9,15 @@ max_pages, chunk) constants, so fused-decode jit signatures differ only
 by horizon bucket — the compile-count test's bound covers the whole
 module by design (same scheme as test_serving.py)."""
 
+import time
+
 import numpy as np
 import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.models.gpt2 import GPT2, gpt2_tiny
 from deepspeed_tpu.serving import ServingScheduler
-from deepspeed_tpu.serving.scheduler import Request
+from deepspeed_tpu.serving.scheduler import RIDE, Request
 
 CFG = dict(num_slots=3, num_pages=16, page_size=16, max_pages_per_slot=8,
            prefill_chunk=8)
@@ -404,6 +406,183 @@ def test_step_cost_samples_the_cycles_a_prefill_rode(engine):
     sched.run()
     assert sorted(sched._step_cost.walls) == [4, 8]
     assert len(sched._step_cost.walls[8]) == 1
+
+
+# ------------------------------------------- the ride as a form
+
+
+def _seat_loop(engine, loop, decoding=5):
+    """A slot-bound scheduler whose slots hold ``loop``'s row mix, the
+    last ``decoding`` of them running with 40 tokens left and the
+    others prefilling, and ``loop``'s no-ride walls in every bucket."""
+    p_ms, d_ms, slots, prompts, outs, _ = LOOPS[loop]
+    rng = np.random.default_rng(7)
+    sched = ServingScheduler(engine, decode_horizon_steps=8, **CFG)
+    sched.prefill_chunk, sched.num_slots = 32, slots
+    n = np.exp(rng.uniform(*np.log(prompts), slots)).astype(int)
+    new = rng.integers(outs[0], outs[1] + 1, slots)
+    done = [0] * (slots - decoding) + [int(o) - 40 for o in new[-decoding:]]
+    running = _seat(sched, list(zip(n, new, done)))[-decoding:]
+    for r in sched.slot_req[:-decoding]:
+        r.state = "prefill"
+    _seed_walls(sched, p_ms, d_ms, (1, 2, 4, 8))
+    sched._slot_bound = True
+    return sched, running
+
+
+def _ride_walls(sched, none_ms, one_ms, n=3):
+    for _ in range(n):
+        sched._step_cost.add((RIDE, 0), none_ms / 1e3)
+        sched._step_cost.add((RIDE, 1), one_ms / 1e3)
+
+
+# loop, wall of a ride with no horizon / with a horizon of 1 (ms) ->
+# the horizon after the ride (None: no row rides), the no-ride picks
+RIDES = {
+    # the long-prompt cell by issue 48's arithmetic on LOOPS' walls: a
+    # rider adds ~2 ms to the dispatch, a step that rides and carries a
+    # horizon has a second idle gap of ~3.5 ms
+    "longprompt_none": ("mistral_longprompt", 32.0, 44.25, 0, (2,)),
+    # ... and where the step without a horizon came out dearer
+    "longprompt_one": ("mistral_longprompt", 40.0, 44.25, 1, (2,)),
+    # a ride form 2% under the best plain one (47.5 ms at the same two
+    # tokens a step) is within the walls' noise: no row rides
+    "longprompt_within_noise": ("mistral_longprompt", 100.0, 46.5, None,
+                                (2,)),
+    # MiMo's: riders hold ~55 pages each, a decode pass is cheap and
+    # the life is prompt chunks -- PR 47's bucket stands
+    "mimo_declines": ("mimo_longctx", 54.0, 62.0, None, (2, 4)),
+    # chat lengths over their knee: two tokens a step lose to 4-8
+    "chat_declines": ("chat_overload", 32.0, 52.5, None, (4, 8)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RIDES))
+def test_the_ride_is_one_more_form_of_the_slot_bound_rule(engine, case):
+    """Walls shaped like the long-prompt cell's pick a ride form, walls
+    shaped like MiMo's and chat's leave PR 47's no-ride bucket; with
+    nothing waiting no row rides whatever the walls say."""
+    loop, none_ms, one_ms, want, plain = RIDES[case]
+    sched, running = _seat_loop(engine, loop)
+    _ride_walls(sched, none_ms, one_ms)
+    sched._plan_ride(NOW)
+    assert sched._ride == want
+    # the step's horizon: the plan's where rows rode, PR 47's where not
+    sched._riders = 0 if want is None else len(running)
+    got = sched._pick_horizon(running, NOW)
+    assert got == want if want is not None else got in plain
+    assert sched._turnover[0] == (got < 8)
+    sched._slot_bound = False
+    sched._plan_ride(NOW)
+    assert sched._ride is None
+
+
+@pytest.mark.parametrize("run", range(6))
+@pytest.mark.parametrize("loop, none_ms, one_ms, want", [
+    # MiMo's forms by the rule's own product (PERF §6 PR 49): the ride
+    # followed by 1 ties bucket 2 and 4, the ride alone loses
+    ("mimo_longctx", 54.0, 51.6, None),
+    # a ride alone that undercuts LOOPS' long-prompt walls by 14% (the
+    # cell's own read 11% under: 35.9 ms x 150 steps to 55.0 x 110)
+    ("mistral_longprompt", 30.0, 44.25, 0),
+])
+def test_forms_within_the_walls_noise_do_not_trade_places(
+        engine, loop, none_ms, one_ms, want, run):
+    """Every wall of every form moved by up to 3% either way, as a
+    form's median moves between runs of one program: forms that tie
+    keep PR 47's bucket run after run, and a ride that wins by more
+    than the margin wins in every run."""
+    p_ms, d_ms = LOOPS[loop][:2]
+    sched, running = _seat_loop(engine, loop)
+    rng = np.random.default_rng(run)
+    sched._step_cost.walls.clear()
+    for form, ms in [(h, p_ms + h * d_ms) for h in (1, 2, 4, 8)] + \
+            [((RIDE, 0), none_ms), ((RIDE, 1), one_ms)]:
+        # a run's program is uniformly faster or slower, and each of
+        # its forms' medians moves on top of that
+        for _ in range(5):
+            sched._step_cost.add(form, ms / 1e3 * rng.uniform(0.97, 1.03))
+    assert sched._step_cost.estimate() is not None
+    sched._plan_ride(NOW)
+    assert sched._ride == want
+
+
+def test_at_most_two_ride_forms_are_sampled_three_steps_each(engine):
+    """No estimate: PR 47's buckets are sampled first and no row rides.
+    With one, the ride followed by the smallest bucket is sampled three
+    times, then the ride alone three times -- and where both lose, no
+    row rides again: six steps."""
+    sched, running = _seat_loop(engine, "mimo_longctx")
+    walls = sched._step_cost.walls
+    kept = dict(walls)
+    walls.clear()
+    sched._plan_ride(NOW)
+    assert sched._ride is None and sched._step_cost.estimate() is None
+    walls.update(kept)
+    tried = []
+    for _ in range(20):
+        sched._plan_ride(NOW)
+        if sched._ride is None:
+            break
+        tried.append(sched._ride)
+        sched._step_cost.add((RIDE, sched._ride), 0.5)   # it loses
+    assert tried == [1, 1, 1, 0, 0, 0]
+    assert sorted(k for k in walls if isinstance(k, tuple)) == \
+        [(RIDE, 0), (RIDE, 1)]
+    # P and D are still read off the no-ride buckets alone
+    p, d = sched._step_cost.estimate()
+    assert (p * 1e3, d * 1e3) == pytest.approx((43.5, 4.05))
+    assert sched._pick_horizon(running, NOW) in (2, 4)
+
+
+def test_no_horizon_is_never_chosen_without_a_ride(engine):
+    """A ride with no horizon that costs next to nothing: ``h = 0`` is
+    still no pick of a step no row rode in, at any cap, and no plan
+    while a running slot cannot ride."""
+    sched, running = _seat_loop(engine, "mistral_longprompt")
+    _ride_walls(sched, 1e-3, 1.0)
+    for cap in (1, 2, 4, 8):
+        assert sched._turnover_horizon(cap)[1] >= 1
+        assert sched._turnover_horizon(cap, ride=[1])[1] >= 1
+    assert sched._pick_horizon(running, NOW) >= 1
+    sched._plan_ride(NOW)
+    assert sched._ride == 0
+    sched.slot_req[running[0]].grammar = object()
+    sched._plan_ride(NOW)
+    assert sched._ride == 1, "the constrained slot is owed its token"
+
+
+def test_a_step_with_no_horizon_still_closes_its_cycle(engine):
+    """Rows ride and no horizon follows: the step files its cycle under
+    ``(RIDE, 0)`` at its end, and the next cycle starts THERE, so what
+    the host does between two such steps is in the second one's wall
+    (as it is between two harvests)."""
+    rng = np.random.default_rng(5)
+    sched = ServingScheduler(engine, decode_horizon_steps=8, **CFG)
+    _seed_walls(sched, 30.0, 8.75, (2, 8))
+    _ride_walls(sched, 1e-3, 2e-3)
+    filed = []
+    sched._step_cost.add = lambda form, wall_s: filed.append((form, wall_s))
+    for n, new in [(40, 9), (33, 9), (48, 9), (25, 9), (40, 9), (36, 9)]:
+        sched.submit(rng.integers(0, 256, n).astype(np.int32),
+                     max_new_tokens=new)
+    pause, after_ride_only = 0.03, []
+    busy = True
+    while busy:
+        was_open = sched._cycle_open
+        seen = len(filed)
+        busy = sched.step()
+        ride_only = bool(sched._riders) and not sched._inflight
+        assert sched._cycle_open == ride_only
+        if ride_only:
+            assert filed[seen:] and filed[-1][0] == (RIDE, 0)
+            if was_open:
+                after_ride_only.append(filed[-1][1])
+            time.sleep(pause)
+    assert after_ride_only and min(after_ride_only) >= pause
+    assert all(0 < w < 60 for _, w in filed)
+    s = sched.summary()
+    assert s["horizon_none_share"] > 0 and s["ride_rows"] > 0
 
 
 # ------------------------------------------------- host-input staging

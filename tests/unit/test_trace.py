@@ -594,12 +594,16 @@ def test_phases_feed_the_span_tracer_under_the_old_names(engine):
     assert all(set(e[7]) == {"horizon", "spec", "tokens"}
                for e in by_name["harvest"])
     # since PR 47 beside whether admission left requests waiting and the
-    # step cost the slot-bound horizon rule read (0.0 un-engaged)
-    assert all(set(e[7]) == {"horizon", "slots", "slot_bound", "p_ms",
-                             "d_ms"}
+    # step cost the slot-bound horizon rule read (0.0 un-engaged); since
+    # PR 49 beside the decoding slots that rode the prefill dispatch (0
+    # while nothing waits)
+    assert all(set(e[7]) == {"horizon", "slots", "slot_bound", "riders",
+                             "p_ms", "d_ms"}
                for e in by_name["horizon_dispatch"])
-    assert all(set(e[7]) == {"rows", "padded_rows", "tokens"}
+    assert all(set(e[7]) == {"rows", "padded_rows", "tokens", "riders"}
                for e in by_name["prefill_chunk"])
+    assert {e[7]["riders"] for name in ("horizon_dispatch", "prefill_chunk")
+            for e in by_name[name]} == {0}
     assert sum(e[7]["tokens"] for e in by_name["harvest"]) + \
         len(by_name["request"]) == sum(len(w) for w in want)
     # the tracer's spans and the accumulators are one measurement

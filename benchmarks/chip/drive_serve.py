@@ -489,6 +489,30 @@ def reference_compared(notes):
                                          notes["reference_min_positions"]]}
 
 
+def generator_lateness(m, step_s):
+    """The on-time rule (issue 53, in place of issue 25's rule on the
+    mean) and the notes' lateness numbers.  The generator submits
+    between scheduler steps, so a request is late by half a step on
+    average; a MEDIAN lateness over one whole mean step means the loop
+    did not offer the load the mix names: a starved generator, a
+    ``submit()`` that blocks, or an open loop that has in effect closed
+    (every request late, so the median is).  One or two steps in which
+    the host stood still for seconds are late for the requests due in
+    them alone, and no longer make the run incorrect, as they did under
+    the mean (one stall of ~2.5 s in a 40 s window was enough, and the
+    tokens of every run so refused matched the reference).  The stall
+    stays in the numbers: the tails are timed from the due time and
+    carry it in full, the notes keep the mean and the maximum, say with
+    ``late_runs_mean_over_step`` whether the old rule would have failed
+    the run, and hold the longest step's wall, CPU, GC and
+    heartbeat-silence seconds.  Returns (on time, notes)."""
+    return m["lateness_median_s"] <= step_s, {
+        "lateness_mean_s": m["lateness_mean_s"],
+        "lateness_median_s": m["lateness_median_s"],
+        "lateness_max_s": m["lateness_max_s"],
+        "late_runs_mean_over_step": int(m["lateness_mean_s"] > step_s)}
+
+
 def run(ctx):
     config, mix = ctx.config, ctx.traffic
     ref_hidden, ref_logits = ctx.reference("hidden"), ctx.reference("logits")
@@ -531,16 +555,10 @@ def run(ctx):
         "all_finished": m["failed"] == 0,
         "paged_path": pa.get("path") == want_path,
     }
+    on_time, lateness = generator_lateness(m, step_s)
     if not ctx.traced():
-        # issue 25's rule: the generator submits between scheduler steps,
-        # so its mean lateness is about half a step; a mean over one
-        # whole step means the loop did not offer the load it names (a
-        # starved generator, or a step that stalled for seconds: one
-        # stall of ~2.5 s in a 40 s window is enough).  The stall is in
-        # the tails as well, which are timed from the due time; the
-        # longest step's wall, CPU, GC and heartbeat-silence seconds are in the
-        # notes.  A traced run's loop stalls at the profiler's stop.
-        checks["generator_on_time"] = m["lateness_mean_s"] <= step_s
+        # a traced run's loop stalls at the profiler's stop
+        checks["generator_on_time"] = on_time
     cap = cli.max_pages_per_slot * sched.kv.page_size
     del sched
     gc.collect()
@@ -549,8 +567,7 @@ def run(ctx):
                               routed)
     checks["reference"] = ok
     notes.update(counts["step_clock"])
-    notes.update(lateness_mean_s=m["lateness_mean_s"],
-                 lateness_max_s=m["lateness_max_s"], step_mean_s=step_s,
+    notes.update(lateness, step_mean_s=step_s,
                  page_util_mean=summary.get("page_util_mean"),
                  slot_occupancy=counts["slot_occupancy"],
                  paged_attention=pa, ttft_p50_ms=m.get("ttft_p50_ms"),
@@ -566,7 +583,7 @@ def run(ctx):
     compared = reference_compared(notes)
     compared["failed_requests"] = [m["failed"], 0]
     if "generator_on_time" in checks:
-        compared["lateness_mean_s"] = [m["lateness_mean_s"], step_s]
+        compared["lateness_median_s"] = [m["lateness_median_s"], step_s]
     return {"checks": checks, "attempted": m["attempted"],
             "failed": m["failed"], "end_to_end": m, "counters": counters,
             "compared": compared, "static": {}, "notes": notes}

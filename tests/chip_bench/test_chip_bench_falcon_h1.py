@@ -16,6 +16,7 @@ import drive_serve
 import readers
 import readers_falcon_h1
 import run as harness
+import test_chip_bench_manifest as contract
 
 SEED = 2 ** 31 + 37
 
@@ -100,6 +101,43 @@ def test_the_cell_is_the_issues():
                 "output_len", "sharing", "sampling", "drain_s"):
         assert MIX[key] == chat[key], key
     assert isinstance(MIX["rate_per_s"], float) and "knee" in MIX["rate_note"]
+
+
+CELL = "falcon-h1.chat"
+
+
+def manifest_holds(manifest, root):
+    """What a manifest has to say of THIS family's cell, whatever else
+    it holds (test_chip_bench_family.py runs this against a manifest
+    that has grown by a cell, a configuration and metrics)."""
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("falcon-h1-34b-l6-v8", "chat-steady-s128-fh1", 1)
+    config = next(c for c in manifest["configs"]
+                  if c["name"] == cell["config"])
+    assert config["reduced"] == CONFIG["reduced"] == ["num_hidden_layers",
+                                                      "vocab_size"]
+    e2e = {m["name"]: m for m in manifest["end_to_end"]}
+    for name in ("ttft_p90_ms", "tpot_p90_ms"):
+        assert CELL in e2e[name]["workloads"]
+    # under the knee the rate is the schedule's and spread over half its
+    # bound here (PERF.md section 2): the cell does not report it
+    assert CELL not in e2e["req_tokens_per_s"]["workloads"]
+    # one entry a layer_metrics/*.par.json on disk, each the cell's alone
+    own = contract.cell_metrics(manifest, root, ".par", CELL)
+    assert {m["name"] for m in own} >= {
+        "ssm.state_update.roofline.par", "kernel.paged_decode.roofline.par",
+        "ssm.prefill_state.time_share.par"}
+    for m in own:
+        assert m["moves"] in ("tpot_p90_ms", "ttft_p90_ms")
+        if "roofline" in m["name"]:
+            assert m["reader"].startswith("readers_falcon_h1:")
+    shared = [m for m in manifest["per_layer"] if m["name"].endswith(".chat")]
+    assert shared and all(CELL in m["workloads"] for m in shared)
+
+
+def test_the_manifest_holds_the_cell_and_its_metrics():
+    manifest_holds(load(paths.ROOT, "BENCHMARK.json"), paths.ROOT)
 
 
 def test_state_and_kv_bytes_are_the_published_widths():

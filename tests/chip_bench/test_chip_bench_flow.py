@@ -50,6 +50,16 @@ def test_serve_flow_open_loop(serve_open):
         load("tiny-open.json"), 2.0)) > 8 and res["failed"] == 0
     assert {"step_max_s", "step_max_cpu_s", "step_max_gc_s",
             "step_max_beat_gap_s", "steps_over_1s"} <= set(res["notes"])
+    # the on-time rule reads the median; the mean and the maximum stay
+    # in the notes, with what the old rule would have said
+    notes = res["notes"]
+    assert {"lateness_mean_s", "lateness_median_s", "lateness_max_s",
+            "late_runs_mean_over_step"} <= set(notes)
+    assert res["checks"]["generator_on_time"] is True
+    assert res["compared"]["lateness_median_s"] == [
+        notes["lateness_median_s"], notes["step_mean_s"]]
+    assert "lateness_mean_s" not in res["compared"]
+    assert 0 <= notes["lateness_median_s"] <= notes["lateness_max_s"]
     e2e = res["end_to_end"]
     assert e2e["ttft_p90_ms"] > 0 and e2e["tpot_p90_ms"] > 0
     assert e2e["req_tokens_per_s"] > 0
@@ -68,6 +78,10 @@ def test_serve_flow_closed_loop_cuts_at_the_window():
     # only requests that finished are left: the ones in flight when the
     # window ended were cancelled and are not failures
     assert res["failed"] == 0 and res["attempted"] >= 6
+    # a closed loop's generator is on time by construction, as before
+    assert res["checks"]["generator_on_time"] is True
+    assert res["notes"]["lateness_median_s"] < res["notes"]["step_mean_s"]
+    assert res["notes"]["late_runs_mean_over_step"] == 0
     assert res["counters"]["slot_occupancy"] == pytest.approx(1.0, abs=0.05)
     assert "ttft_p90_ms" in res["end_to_end"]
     # whole requests finished in the window, and everything served in it

@@ -30,6 +30,38 @@ def train_context(config, seconds=0.5, devices=None):
                            compiles=harness.CompileCount())
 
 
+CELL = "gpt2-xl.zero3-4chip"
+
+
+def manifest_holds(manifest, root):
+    """What a manifest has to say of the four-chip cell, whatever else
+    it holds (test_chip_bench_family.py runs this against a manifest
+    that has grown by a cell, a configuration and metrics)."""
+    cell = harness.find(manifest["workloads"], CELL, "workload")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("gpt2-xl-zero3", "pretrain-1k-d1", 4)
+    assert "fits no one" in cell["why"]       # why it takes four
+    assert harness.find(manifest["configs"], cell["config"],
+                        "config")["reduced"] == []
+    rate = harness.find(manifest["end_to_end"], "train_tokens_per_s",
+                        "metric")
+    assert CELL in rate["workloads"]
+    exposed = harness.find(manifest["per_layer"], "comm.exposed_share.train",
+                           "metric")
+    assert exposed["workloads"] == [CELL] and \
+        exposed["moves"] == "train_tokens_per_s"
+    # every cell's files reach the readers as the harness loads them
+    for w in manifest["workloads"]:
+        loaded = harness.load_cell(root, os.path.join(
+            root, manifest["paths"][0]), w["name"])
+        assert loaded["config"]["name"] == w["config"]
+        assert loaded["traffic"]["kind"] in loaded["config"]
+
+
+def test_the_manifest_holds_the_four_chip_cell():
+    manifest_holds(MANIFEST, paths.ROOT)
+
+
 def test_zero3_over_four_devices_through_the_train_driver():
     """data/gpt2-tiny-zero3.json is the committed gpt2-xl-zero3 file's
     train block (mesh data=4, ZeRO-3, remat) at tiny widths."""
@@ -203,8 +235,12 @@ def test_one_step_a_dispatch_warms_past_the_timers_first_sync(
                                                  "note")}
     mix = dict(load("tiny-train.json"), steps_per_dispatch=1,
                warm_dispatches=warm)
+    # 3 s, not the 0.5 s of the tests above: with one step to a dispatch
+    # half a second holds two steps where the machine is busy, and
+    # loss_falls (the last tenth of the window's losses under the first)
+    # needs more than two to mean something
     ctx = harness.Context(paths.ROOT, paths.BENCH, load("gpt2-tiny.json"),
-                          mix, 5, 0.5, devices=jax.devices(),
+                          mix, 5, 3.0, devices=jax.devices(),
                           compiles=harness.CompileCount())
     from deepspeed_tpu.utils import timer
     in_set_up = []

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 import chip_bench_paths as paths
+import drive_serve
 import loadgen
 
 MIXES = sorted(f[:-5] for f in os.listdir(os.path.join(paths.BENCH, "traffic"))
@@ -144,6 +145,76 @@ def test_request_metrics_on_a_hand_made_list():
     assert m["lateness_mean_s"] == pytest.approx(0.01)
     assert m["lateness_median_s"] == pytest.approx(0.01)
     assert m["lateness_max_s"] == pytest.approx(0.01)
+
+
+STEP_S, CHAT = 0.12, 588        # falcon-h1.chat: 14.4/s x 40 s, steps of 0.12 s
+
+
+def late_records(lateness):
+    """Finished requests due evenly over 40 s, each submitted
+    ``lateness[i]`` seconds after it was due."""
+    due = np.linspace(0.0, 40.0, len(lateness), endpoint=False)
+    rows = [record(d, d + 1.0, d + 2.0, 11) for d in due]
+    for row, late in zip(rows, lateness):
+        row["submit_s"] = row["due_s"] + float(late)
+    return rows
+
+
+def half_a_step(n):
+    # submitted at the first step boundary after the due time
+    return np.random.default_rng(53).uniform(0.0, STEP_S, n)
+
+
+def one_stall():
+    """A sound run in which the host stood still once for 3 s: the 43
+    requests due in it (14.4/s x 3 s) are late by what was left of it."""
+    late = half_a_step(CHAT)
+    late[300:343] = np.linspace(3.0, 0.0, 43, endpoint=False)
+    return late
+
+
+@pytest.mark.parametrize("name,lateness,on_time,old_rule_fails", [
+    # every request late by a step and a half: a starved generator
+    ("starved", np.full(CHAT, 1.5 * STEP_S), False, 1),
+    # a submit() that blocks: the open loop has in effect closed, and
+    # each request is later than the one before it
+    ("closed_in_effect", np.linspace(0.0, 8.0, CHAT), False, 1),
+    ("sound", half_a_step(CHAT), True, 0),
+    # one stalled step: the mean passes a step, the median does not
+    ("one_stall", one_stall(), True, 1),
+    ("two_stalls", np.concatenate([one_stall()[:343], one_stall()[98:]]),
+     True, 1),
+    # half of the run late: that is no stall
+    ("half_late", np.concatenate([half_a_step(290), np.full(298, 2.0)]),
+     False, 1),
+    # a closed loop submits a request when the one before it finishes
+    ("closed_loop", np.zeros(64), True, 0),
+])
+def test_generator_on_time_reads_the_median(name, lateness, on_time,
+                                            old_rule_fails):
+    m = loadgen.request_metrics(late_records(lateness), 40.0)
+    ok, notes = drive_serve.generator_lateness(m, STEP_S)
+    assert ok is on_time, notes
+    assert notes["late_runs_mean_over_step"] == old_rule_fails
+    assert set(notes) == {"lateness_mean_s", "lateness_median_s",
+                          "lateness_max_s", "late_runs_mean_over_step"}
+    assert notes["lateness_max_s"] == pytest.approx(float(np.max(lateness)))
+    assert notes["lateness_median_s"] == pytest.approx(
+        float(np.median(lateness)))
+    # the stall stays in the tails, which are timed from the due time
+    assert m["failed"] == 0 and m["ttft_p90_ms"] == pytest.approx(1000.0)
+
+
+def test_a_stall_is_in_the_numbers_the_rule_no_longer_fails():
+    m = loadgen.request_metrics(late_records(one_stall()), 40.0)
+    ok, notes = drive_serve.generator_lateness(m, STEP_S)
+    assert ok and notes["lateness_max_s"] == 3.0
+    assert notes["lateness_mean_s"] > 1.3 * STEP_S > STEP_S > \
+        notes["lateness_median_s"] > 0.4 * STEP_S
+    # just under one mean step the run is on time; just over it, it is not
+    for late, want in ((STEP_S * 0.999, True), (STEP_S * 1.001, False)):
+        m = loadgen.request_metrics(late_records(np.full(9, late)), 40.0)
+        assert drive_serve.generator_lateness(m, STEP_S)[0] is want
 
 
 def test_request_metrics_with_nothing_finished_is_the_window():

@@ -8,6 +8,7 @@ import re
 import pytest
 
 import chip_bench_paths as paths
+import run as harness
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -22,42 +23,54 @@ def load(*parts):
 
 
 MANIFEST = load(paths.ROOT, "BENCHMARK.json")
-E2E = {m["name"]: m for m in MANIFEST["end_to_end"]}
-CELLS = [w["name"] for w in MANIFEST["workloads"]]
-
-
-def cells_of(metric):
-    return metric.get("workloads", CELLS)
-
-
-def test_manifest_has_exactly_the_contract_keys():
-    assert set(MANIFEST) == {"command", "paths", "run_seconds", "configs",
-                             "workloads", "end_to_end", "per_layer"}
-    assert len(json.dumps(MANIFEST)) < 64 * 1024
-    assert 1 <= MANIFEST["run_seconds"] <= 51
-    assert MANIFEST["command"][1].startswith(MANIFEST["paths"][0] + "/")
-    for p in MANIFEST["paths"]:
-        assert os.path.isdir(os.path.join(paths.ROOT, p))
-    n = len(MANIFEST["workloads"])
-    # the whole check fits: (2 + 14 n) runs of run_seconds + 60, 180 s a
-    # cell to compile, 1200 s spare, in 43200 s — at the full 24 cells
-    s = MANIFEST["run_seconds"]
-    assert (2 + 14 * 24) * (s + 60) + 24 * 180 + 1200 <= 43200
-    assert 1 <= n <= 24
-
-
-@pytest.mark.parametrize("section,keys,optional", [
+SECTIONS = [
     ("configs", {"name", "source", "file", "reduced", "why"}, set()),
     ("workloads", {"name", "config", "traffic", "chips", "why"}, set()),
     ("end_to_end", {"name", "unit", "better", "bound", "source"},
      {"workloads"}),
     ("per_layer", {"name", "unit", "better", "source", "layer", "moves"},
      {"workloads"}),
-])
-def test_entries_have_just_the_contract_keys(section, keys, optional):
-    names = [e["name"] for e in MANIFEST[section]]
+]
+
+# Every check below is a function of a manifest and of the root of the
+# tree that holds it, so that test_chip_bench_family.py can hold a GROWN
+# copy (one more cell, configuration, metric) to the same contract.
+
+
+def bench_of(manifest, root):
+    return os.path.join(root, manifest["paths"][0])
+
+
+def cells_of(manifest, metric):
+    return metric.get("workloads", [w["name"] for w in manifest["workloads"]])
+
+
+def metrics_of(manifest):
+    return manifest["end_to_end"] + manifest["per_layer"]
+
+
+def check_contract_keys(manifest, root):
+    assert set(manifest) == {"command", "paths", "run_seconds", "configs",
+                             "workloads", "end_to_end", "per_layer"}
+    assert len(json.dumps(manifest)) < 64 * 1024
+    assert 1 <= manifest["run_seconds"] <= 51
+    assert manifest["command"][1].startswith(manifest["paths"][0] + "/")
+    for p in manifest["paths"]:
+        assert os.path.isdir(os.path.join(root, p))
+    # the whole check fits: (2 + 14 n) runs of run_seconds + 60, 180 s a
+    # cell to compile, 1200 s spare, in 43200 s — at the full 24 cells
+    s = manifest["run_seconds"]
+    assert (2 + 14 * 24) * (s + 60) + 24 * 180 + 1200 <= 43200
+    # the count's one guard: no family's test pins how many there are
+    for section, most in (("workloads", 24), ("configs", 24),
+                          ("end_to_end", 16), ("per_layer", 128)):
+        assert 1 <= len(manifest[section]) <= most, section
+
+
+def check_entries(manifest, section, keys, optional):
+    names = [e["name"] for e in manifest[section]]
     assert len(names) == len(set(names))
-    for e in MANIFEST[section]:
+    for e in manifest[section]:
         assert keys <= set(e) <= keys | optional, e["name"]
         assert NAME.match(e["name"]), e["name"]
         for k in ("why", "layer", "source"):
@@ -66,19 +79,17 @@ def test_entries_have_just_the_contract_keys(section, keys, optional):
                     and "\t" not in e[k], (e["name"], k)
 
 
-def test_metric_names_do_not_collide_across_sections():
-    names = [m["name"] for m in MANIFEST["end_to_end"] +
-             MANIFEST["per_layer"]]
+def check_names_do_not_collide(manifest, root):
+    names = [m["name"] for m in metrics_of(manifest)]
     assert len(names) == len(set(names))
 
 
-@pytest.mark.parametrize("metric", MANIFEST["end_to_end"] +
-                         MANIFEST["per_layer"], ids=lambda m: m["name"])
-def test_metric_fields(metric):
+def check_metric_fields(manifest, metric):
     assert UNIT.match(metric["unit"])
     assert metric["better"] in ("lower", "higher")
     assert metric["source"] in SOURCES
-    assert set(cells_of(metric)) <= set(CELLS)
+    assert set(cells_of(manifest, metric)) <= \
+        {w["name"] for w in manifest["workloads"]}
     if "bound" in metric:
         assert metric["source"] in ("host_clock", "device_trace")
         assert 0.01 <= metric["bound"] <= 0.1
@@ -86,44 +97,41 @@ def test_metric_fields(metric):
         assert metric["unit"] == "%"
 
 
-def test_setup_s_is_reported_everywhere():
-    assert "workloads" not in E2E["setup_s"]
-    assert E2E["setup_s"]["bound"] <= 0.1
+def check_setup_s(manifest, root):
+    setup = next(m for m in manifest["end_to_end"] if m["name"] == "setup_s")
+    assert "workloads" not in setup
+    assert setup["bound"] <= 0.1
 
 
-@pytest.mark.parametrize("cell", MANIFEST["workloads"],
-                         ids=lambda w: w["name"])
-def test_cell(cell):
-    configs = {c["name"]: c for c in MANIFEST["configs"]}
+def check_cell(manifest, root, cell):
+    configs = {c["name"]: c for c in manifest["configs"]}
     assert cell["config"] in configs
     assert NAME.match(cell["traffic"])
     assert cell["chips"] in (1, 4)
-    mix = load(paths.BENCH, "traffic", cell["traffic"] + ".json")
-    assert os.path.isfile(os.path.join(paths.BENCH,
-                                       "drive_" + mix["kind"] + ".py"))
-    others = [m for m in MANIFEST["end_to_end"]
-              if m["name"] != "setup_s" and cell["name"] in cells_of(m)]
-    layers = [m for m in MANIFEST["per_layer"]
-              if cell["name"] in cells_of(m)]
+    bench = bench_of(manifest, root)
+    mix = load(bench, "traffic", cell["traffic"] + ".json")
+    assert os.path.isfile(os.path.join(bench, "drive_" + mix["kind"] + ".py"))
+    others = [m for m in manifest["end_to_end"] if m["name"] != "setup_s"
+              and cell["name"] in cells_of(manifest, m)]
+    layers = [m for m in manifest["per_layer"]
+              if cell["name"] in cells_of(manifest, m)]
     assert others and layers
-    cfg = load(paths.ROOT, configs[cell["config"]]["file"])
+    cfg = load(root, configs[cell["config"]]["file"])
     assert mix["kind"] in cfg, "the configuration has no sizes for this kind"
 
 
-def test_cells_are_unique_pairs_and_at_most_one_takes_four_chips():
-    pairs = [(w["config"], w["traffic"]) for w in MANIFEST["workloads"]]
+def check_pairs_and_four_chip_cells(manifest, root):
+    pairs = [(w["config"], w["traffic"]) for w in manifest["workloads"]]
     assert len(pairs) == len(set(pairs))
-    four = sum(w["chips"] == 4 for w in MANIFEST["workloads"])
+    four = sum(w["chips"] == 4 for w in manifest["workloads"])
     assert four <= max(1, len(pairs) // 4)
-    used = {w["config"] for w in MANIFEST["workloads"]}
-    assert used == {c["name"] for c in MANIFEST["configs"]}
+    used = {w["config"] for w in manifest["workloads"]}
+    assert used == {c["name"] for c in manifest["configs"]}
 
 
-@pytest.mark.parametrize("config", MANIFEST["configs"],
-                         ids=lambda c: c["name"])
-def test_configuration_file(config):
-    assert config["file"].startswith(MANIFEST["paths"][0] + "/configs/")
-    cfg = load(paths.ROOT, config["file"])
+def check_configuration_file(manifest, root, config):
+    assert config["file"].startswith(manifest["paths"][0] + "/configs/")
+    cfg = load(root, config["file"])
     for key in ("source", "reduced", "assumed", "program", "reference"):
         assert key in cfg, key
     assert cfg["source"] == config["source"]
@@ -135,17 +143,16 @@ def test_configuration_file(config):
     # every field the program's config class is given is a published key
     for src in cfg["program"]["fields"].values():
         assert src in cfg, src
-    files = [c["file"] for c in MANIFEST["configs"]]
+    files = [c["file"] for c in manifest["configs"]]
     assert len(files) == len(set(files))
 
 
-@pytest.mark.parametrize("config", MANIFEST["configs"],
-                         ids=lambda c: c["name"])
-def test_widths_are_the_published_ones(config):
-    """published/<config>.json (added with the configuration) lists the
-    public config's keys that must be carried unchanged."""
-    cfg = load(paths.ROOT, config["file"])
-    pub = load(os.path.dirname(os.path.abspath(__file__)), "published",
+def check_published_widths(manifest, root, config):
+    """published/<config>.json (added with the configuration, beside
+    these tests) lists the public config's keys that must be carried
+    unchanged."""
+    cfg = load(root, config["file"])
+    pub = load(root, manifest["paths"][1], "published",
                config["name"] + ".json")["keys"]
     assert not set(pub) & set(config["reduced"])
     assert any(WIDTHS.search(k) for k in pub)
@@ -153,42 +160,146 @@ def test_widths_are_the_published_ones(config):
         assert cfg[key] == value, key
 
 
-@pytest.mark.parametrize("metric", MANIFEST["per_layer"],
-                         ids=lambda m: m["name"])
-def test_layer_metric_file_agrees_with_the_manifest(metric):
-    spec = load(paths.BENCH, "layer_metrics", metric["name"] + ".json")
+def check_layer_metric_file(manifest, root, metric):
+    bench = bench_of(manifest, root)
+    e2e = {m["name"]: m for m in manifest["end_to_end"]}
+    spec = load(bench, "layer_metrics", metric["name"] + ".json")
     for key in ("name", "unit", "better", "source", "layer", "moves"):
         assert spec[key] == metric[key], key
-    assert spec.get("workloads", CELLS) == cells_of(metric)
+    assert cells_of(manifest, spec) == cells_of(manifest, metric)
     # moves names an end-to-end metric that each of its cells reports
-    assert metric["moves"] in E2E
-    assert set(cells_of(metric)) <= set(cells_of(E2E[metric["moves"]]))
-    mod, fn = spec["reader"].split(":")
-    assert os.path.isfile(os.path.join(paths.BENCH, mod + ".py"))
-    import importlib
-    assert callable(getattr(importlib.import_module(mod), fn))
+    assert metric["moves"] in e2e
+    assert set(cells_of(manifest, metric)) <= \
+        set(cells_of(manifest, e2e[metric["moves"]]))
+    # the reader resolves as the harness resolves it
+    assert ":" in spec["reader"]
+    assert callable(harness.load_function(bench, spec["reader"]))
 
 
-def test_no_stray_data_files():
-    have = {f[:-5] for f in os.listdir(os.path.join(paths.BENCH,
-                                                    "layer_metrics"))}
-    assert have == {m["name"] for m in MANIFEST["per_layer"]}
-    mixes = {f[:-5] for f in os.listdir(os.path.join(paths.BENCH, "traffic"))}
-    assert {w["traffic"] for w in MANIFEST["workloads"]} <= mixes
+def check_no_stray_data_files(manifest, root):
+    bench = bench_of(manifest, root)
+    have = {f[:-5] for f in os.listdir(os.path.join(bench, "layer_metrics"))}
+    assert have == {m["name"] for m in manifest["per_layer"]}
+    mixes = {f[:-5] for f in os.listdir(os.path.join(bench, "traffic"))}
+    assert {w["traffic"] for w in manifest["workloads"]} <= mixes
 
 
-def test_layers_with_one_name_are_spelled_alike():
-    layers = {m["layer"] for m in MANIFEST["per_layer"]}
+def check_layer_spelling(manifest, root):
+    layers = {m["layer"] for m in manifest["per_layer"]}
     assert len({l.lower() for l in layers}) == len(layers)
 
 
-def test_run_py_names_no_cell_config_mix_kernel_or_metric():
-    src = open(os.path.join(paths.BENCH, "run.py")).read()
+def check_run_py_names_nothing(manifest, root):
+    src = open(os.path.join(bench_of(manifest, root), "run.py")).read()
     names = [e["name"] for k in ("configs", "workloads", "end_to_end",
-                                 "per_layer") for e in MANIFEST[k]]
-    names += [w["traffic"] for w in MANIFEST["workloads"]]
+                                 "per_layer") for e in manifest[k]]
+    names += [w["traffic"] for w in manifest["workloads"]]
     names += ["paged_decode", "flash", "mistral", "gpt2", "llama"]
     for n in names:
         if n == "setup_s":      # the one metric every run reports
             continue
         assert n not in src, n
+
+
+def cell_metrics(manifest, root, suffix, cell):
+    """The ``per_layer`` entries of one cell's own suffix (``.win``,
+    ``.hyb``, ``.par``): one entry a ``layer_metrics/*<suffix>.json`` on
+    disk, each listing ``cell`` alone and agreeing with its file; their
+    files are returned, in the manifest's order.  A family's
+    ``manifest_holds`` starts from these, so that it counts entries
+    against files and never against a number."""
+    metrics = os.path.join(bench_of(manifest, root), "layer_metrics")
+    own = [m for m in manifest["per_layer"] if m["name"].endswith(suffix)]
+    assert sorted(m["name"] for m in own) == sorted(
+        f[:-len(".json")] for f in os.listdir(metrics)
+        if f.endswith(suffix + ".json"))
+    specs = [load(metrics, m["name"] + ".json") for m in own]
+    for m, spec in zip(own, specs):
+        assert m["workloads"] == [cell]
+        assert {k: spec[k] for k in m} == m
+    return specs
+
+
+WHOLE = [check_contract_keys, check_names_do_not_collide, check_setup_s,
+         check_pairs_and_four_chip_cells, check_no_stray_data_files,
+         check_layer_spelling, check_run_py_names_nothing]
+
+
+def manifest_holds(manifest, root):
+    """Every check of this file, over every entry of ``manifest``."""
+    for check in WHOLE:
+        check(manifest, root)
+    for section, keys, optional in SECTIONS:
+        check_entries(manifest, section, keys, optional)
+    for metric in metrics_of(manifest):
+        check_metric_fields(manifest, metric)
+    for cell in manifest["workloads"]:
+        check_cell(manifest, root, cell)
+    for config in manifest["configs"]:
+        check_configuration_file(manifest, root, config)
+        check_published_widths(manifest, root, config)
+    for metric in manifest["per_layer"]:
+        check_layer_metric_file(manifest, root, metric)
+
+
+def test_manifest_has_exactly_the_contract_keys():
+    check_contract_keys(MANIFEST, paths.ROOT)
+
+
+@pytest.mark.parametrize("section,keys,optional", SECTIONS)
+def test_entries_have_just_the_contract_keys(section, keys, optional):
+    check_entries(MANIFEST, section, keys, optional)
+
+
+def test_metric_names_do_not_collide_across_sections():
+    check_names_do_not_collide(MANIFEST, paths.ROOT)
+
+
+@pytest.mark.parametrize("metric", metrics_of(MANIFEST),
+                         ids=lambda m: m["name"])
+def test_metric_fields(metric):
+    check_metric_fields(MANIFEST, metric)
+
+
+def test_setup_s_is_reported_everywhere():
+    check_setup_s(MANIFEST, paths.ROOT)
+
+
+@pytest.mark.parametrize("cell", MANIFEST["workloads"],
+                         ids=lambda w: w["name"])
+def test_cell(cell):
+    check_cell(MANIFEST, paths.ROOT, cell)
+
+
+def test_cells_are_unique_pairs_and_at_most_one_takes_four_chips():
+    check_pairs_and_four_chip_cells(MANIFEST, paths.ROOT)
+
+
+@pytest.mark.parametrize("config", MANIFEST["configs"],
+                         ids=lambda c: c["name"])
+def test_configuration_file(config):
+    check_configuration_file(MANIFEST, paths.ROOT, config)
+
+
+@pytest.mark.parametrize("config", MANIFEST["configs"],
+                         ids=lambda c: c["name"])
+def test_widths_are_the_published_ones(config):
+    check_published_widths(MANIFEST, paths.ROOT, config)
+
+
+@pytest.mark.parametrize("metric", MANIFEST["per_layer"],
+                         ids=lambda m: m["name"])
+def test_layer_metric_file_agrees_with_the_manifest(metric):
+    check_layer_metric_file(MANIFEST, paths.ROOT, metric)
+
+
+def test_no_stray_data_files():
+    check_no_stray_data_files(MANIFEST, paths.ROOT)
+
+
+def test_layers_with_one_name_are_spelled_alike():
+    check_layer_spelling(MANIFEST, paths.ROOT)
+
+
+def test_run_py_names_no_cell_config_mix_kernel_or_metric():
+    check_run_py_names_nothing(MANIFEST, paths.ROOT)

@@ -15,6 +15,7 @@ import drive_serve
 import readers
 import readers_hybrid
 import run as harness
+import test_chip_bench_manifest as contract
 
 SEED = 2 ** 31 + 35
 
@@ -77,6 +78,40 @@ def test_a_reference_half_the_depth_fails_the_served_tokens(monkeypatch):
 CONFIG = load(paths.BENCH, "configs", "nemotron-3-nano-30b-a3b-l26-ep8.json")
 MIX = load(paths.BENCH, "traffic", "chat-steady-s128.json")
 PEAKS = load(paths.BENCH, "peaks.json")["devices"]["TPU v5e"]
+
+
+CELL = "nemotron3-nano.chat"
+
+
+def manifest_holds(manifest, root):
+    """What a manifest has to say of THIS family's cell, whatever else
+    it holds (test_chip_bench_family.py runs this against a manifest
+    that has grown by a cell, a configuration and metrics)."""
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("nemotron-3-nano-30b-a3b-l26-ep8", "chat-steady-s128", 1)
+    config = next(c for c in manifest["configs"]
+                  if c["name"] == cell["config"])
+    assert config["reduced"] == CONFIG["reduced"]
+    e2e = {m["name"]: m for m in manifest["end_to_end"]}
+    for name in ("ttft_p90_ms", "tpot_p90_ms", "req_tokens_per_s"):
+        assert CELL in e2e[name]["workloads"]
+    # one entry a layer_metrics/*.hyb.json on disk, each the cell's alone
+    own = contract.cell_metrics(manifest, root, ".hyb", CELL)
+    assert {m["name"] for m in own} >= {
+        "ssm.state_update.roofline.hyb", "moe.experts.roofline.hyb",
+        "moe.experts_dense.time_share.hyb", "moe.held_load_max_over_mean.hyb"}
+    for m in own:
+        assert m["moves"] in ("tpot_p90_ms", "ttft_p90_ms")
+        if "roofline" in m["name"]:
+            assert m["reader"].startswith("readers_hybrid:")
+    # the chat cells' shared metrics read this cell too
+    shared = [m for m in manifest["per_layer"] if m["name"].endswith(".chat")]
+    assert shared and all(CELL in m["workloads"] for m in shared)
+
+
+def test_the_manifest_holds_the_cell_and_its_metrics():
+    manifest_holds(load(paths.ROOT, "BENCHMARK.json"), paths.ROOT)
 
 
 def test_state_bytes_are_the_published_widths():

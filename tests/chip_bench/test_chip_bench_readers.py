@@ -148,7 +148,7 @@ def test_flash_roofline_leaves_out_a_second_custom_call():
 
 
 @pytest.mark.parametrize("metric", [
-    "kernel.paged_decode.time_share.lat", "kernel.paged_decode.time_share.thr",
+    "kernel.paged_decode.time_share.lat", "kernel.paged_prefill.time_share.thr",
     "flash_roofline.train"])
 def test_committed_kernel_metrics_match_the_kernel_by_its_own_name(metric):
     with open(os.path.join(paths.BENCH, "layer_metrics",

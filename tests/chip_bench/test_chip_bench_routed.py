@@ -434,7 +434,7 @@ def test_the_last_line_of_a_passing_run_gains_the_two_notes_alone(
     assert line["metrics"] is layer
     assert set(line["notes"]["checks"].values()) == {1}
     assert line["notes"]["setup_phases"] == ctx.notes["setup_phases"]
-    assert {"reference_worst_margin", "failed_requests", "lateness_mean_s",
+    assert {"reference_worst_margin", "failed_requests", "lateness_median_s",
             "window_compiles"} == set(line["notes"]["compared"])
 
 

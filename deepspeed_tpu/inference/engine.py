@@ -34,7 +34,7 @@ from deepspeed_tpu.parallel import sharding as shd
 from deepspeed_tpu.parallel.topology import make_mesh
 from deepspeed_tpu.serving.sampling import pipeline as policy_pipeline
 from deepspeed_tpu.serving.sharding import (ServingShardingConfig,
-                                            config_scope,
+                                            config_scope, is_page_leaf,
                                             pool_bytes_per_device,
                                             resolve_sequence_plan,
                                             split_pools)
@@ -213,6 +213,15 @@ class InferenceEngine:
         kv = getattr(cfg, "num_kv_heads", heads)
         return heads, kv
 
+    @property
+    def latent_cache(self):
+        """True for a model whose page pool holds ONE vector a token a
+        layer that every query head reads as key and value (multi-head
+        latent attention; ops/quant/kv.py ``latent_pool_layer``): the
+        module says so as ``latent_cache = True``.  Such a pool has one
+        head: ``_model_head_counts`` reads (heads, 1) off its config."""
+        return bool(getattr(self.module, "latent_cache", False))
+
     def _validate_mesh_for_model(self):
         """Construction-time mesh-shape validation: a ``model``-axis
         size that does not divide ``num_heads`` would shard attention
@@ -304,14 +313,16 @@ class InferenceEngine:
         IDENTICAL decisions at trace time, so what health() reports is
         what runs."""
         from deepspeed_tpu.ops.attention import decode as _decode_ops
+        from deepspeed_tpu.ops.quant.kv import page_leaf
         heads, kv_heads = self._model_head_counts()
         if page_size is None and pools is not None:
             layers = pools.get("layers") if isinstance(pools, dict) \
                 else None
             # a hybrid's first blocks may hold state, not pages
-            kv = [L for L in layers or () if "k_pages" in L]
+            kv = [page_leaf(L) for L in layers or ()
+                  if page_leaf(L) is not None]
             if kv:
-                page_size = int(kv[0]["k_pages"].shape[1])
+                page_size = int(kv[0].shape[1])
         cfg = getattr(self.module, "cfg", None)
         decide = functools.partial(
             _decode_ops.paged_kernel_decision,
@@ -745,10 +756,23 @@ class InferenceEngine:
                 f"slot, which {SLOT_STATE_REFUSALS[feature]}")
 
     def refuse_slot_state(self, feature):
-        """Raise where :meth:`slot_state_refusal` has a reason."""
+        """Raise where :meth:`slot_state_refusal` has a reason -- and
+        for a hand-off of LATENT pages.  A latent page IS a page (prefix
+        cache, verify and preemption run over it as over K/V pages, and
+        ``slot_state_refusal`` refuses nothing), but the hand-off
+        transport frames ``[n, page_size, kv_heads, d]`` K/V leaves
+        under the one pool sharding, and a latent layer's leaf has no
+        head dim and rides beside routing counters: shipping it is not
+        built."""
         why = self.slot_state_refusal(feature)
         if why is not None:
             raise ValueError(f"{feature} cannot serve this model: {why}")
+        if feature == "handoff" and self.latent_cache:
+            raise ValueError(
+                "handoff cannot serve this model: "
+                f"{type(self.module).__name__} keeps latent pages (one "
+                "vector a token a layer, no head dim), which the page-"
+                "chain transport does not frame yet")
 
     def init_paged_cache(self, num_pages, page_size, kv_dtype=None,
                          num_slots=None):
@@ -808,16 +832,18 @@ class InferenceEngine:
                 "OFF (pages must tile the 128-lane TPU layout): decode "
                 "runs the gather reference path — use page_size 128 or "
                 "256 for kernel-speed paged attention", stacklevel=2)
-        if not self.slot_state:
+        if not self.slot_state and not self.latent_cache:
             build = functools.partial(mod.init_paged_kv_cache, cfg,
                                       num_pages, page_size, dtype=dt)
         else:
-            # the family sizes its per-slot state by the slot count and
-            # its pools hold more than one kind of leaf: one sharding a
-            # leaf, by the leaf's name (serving/sharding.py)
+            # the family sizes its per-slot state by the slot count, or
+            # its pools hold more than one kind of leaf (a latent leaf
+            # has no head dim; routing counters ride beside it): one
+            # sharding a leaf, by the leaf's name (serving/sharding.py)
+            slots = {"num_slots": num_slots} if self.slot_state else {}
             build = functools.partial(mod.init_paged_kv_cache, cfg,
                                       num_pages, page_size, dtype=dt,
-                                      num_slots=num_slots)
+                                      **slots)
             struct = jax.eval_shape(build)
             if jax.tree.structure(struct) != jax.tree.structure(
                     getattr(self, "_pool_struct", None)):
@@ -861,6 +887,17 @@ class InferenceEngine:
             return 0, 0
         dt = self._kv_dtype_of(kv_dtype)
         return mod.window_ring(self.module.cfg, dt)
+
+    def latent_bytes_per_token(self, kv_dtype=None):
+        """(published, stored) bytes ONE token costs over all layers of
+        a latent page pool: the latent vector's own width, and the width
+        the pool stores it at (``latent_stored_dim``'s padding
+        included).  Its module exports ``latent_bytes_per_token(cfg,
+        dtype)``; (0, 0) for a model without a latent cache."""
+        if not self.latent_cache:
+            return 0, 0
+        return self._paged_module().latent_bytes_per_token(
+            self.module.cfg, self._kv_dtype_of(kv_dtype))
 
     def routing_counters(self, pools):
         """The routed layers' counters riding the pools (uint32, mod
@@ -1234,12 +1271,15 @@ class InferenceEngine:
             # quantized pool's per-row scales welded to their page: a
             # COW copy that moved payload without scales would dequantize
             # the private copy with the ORIGINAL page's scales forever
+            # (a leaf that is no page array -- the routing counters
+            # beside a latent leaf -- passes through)
             def copy(pools, src, dst):
                 return {"layers": [
                     {name: arr.at[dst].set(arr[src])
+                     if is_page_leaf(name) else arr
                      for name, arr in L.items()}
                     for L in pools["layers"]]}
-            pool_sh = self._serving_shardings().pool
+            pool_sh = self._pool_shardings()
 
             self._copy_page_fn = jax.jit(copy, donate_argnums=(0,),
                                          out_shardings=pool_sh)

@@ -204,7 +204,8 @@ def _live_pairs(page_table, positions, active, page_size):
 
 
 def _paged_decode_kernel(pair_ref, page_ref, pos_ref, n_ref, q_ref, k_ref,
-                         v_ref, *rest, scale, page_size, maxp, quantized):
+                         *rest, scale, page_size, maxp, quantized,
+                         value_dim=None):
     """Paged variant of ``_decode_kernel``: one grid step is ALL kv heads
     of one slot against ONE cache page, and the grid is the step's LIVE
     (slot, page) pairs in slot order (:func:`_live_pairs`, prefetched;
@@ -235,7 +236,14 @@ def _paged_decode_kernel(pair_ref, page_ref, pos_ref, n_ref, q_ref, k_ref,
     kv_heads, 1] blocks of the parallel scale pool, fetched through the
     SAME page-id index map, so a page and its scales are one unit)
     and dequantizes in VMEM right before the dot — only quantized bytes
-    ever stream from HBM."""
+    ever stream from HBM.
+
+    ``value_dim`` marks a LATENT pool (ops/quant/kv.py ``c_pages``
+    [num_pages, page_size, stored]: one head, no head dim): there is no
+    ``v_ref`` — the value is the leading ``value_dim`` features of the
+    K block this step already holds in VMEM, so a page costs one DMA."""
+    if value_dim is None:
+        v_ref, rest = rest[0], rest[1:]
     if quantized:
         ks_ref, vs_ref, _, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -251,17 +259,21 @@ def _paged_decode_kernel(pair_ref, page_ref, pos_ref, n_ref, q_ref, k_ref,
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     q = q_ref[0]                                          # [kv_h, g, d]
-    k = k_ref[0]                                          # [ps, kv_h, d]
-    v = v_ref[0]
-    if quantized:
-        k = (k.astype(jnp.float32) *
-             ks_ref[0].astype(jnp.float32)).astype(q.dtype)
-        v = (v.astype(jnp.float32) *
-             vs_ref[0].astype(jnp.float32)).astype(q.dtype)
-    # leading-batch dot over kv heads (Mosaic supports batch dims
-    # only at position 0 on both sides)
-    k = k.transpose(1, 0, 2)                              # [kv_h, ps, d]
-    v = v.transpose(1, 0, 2)
+    if value_dim is not None:
+        k = k_ref[...]                                    # [1, ps, d]
+        v = k[:, :, :value_dim]
+    else:
+        k = k_ref[0]                                      # [ps, kv_h, d]
+        v = v_ref[0]
+        if quantized:
+            k = (k.astype(jnp.float32) *
+                 ks_ref[0].astype(jnp.float32)).astype(q.dtype)
+            v = (v.astype(jnp.float32) *
+                 vs_ref[0].astype(jnp.float32)).astype(q.dtype)
+        # leading-batch dot over kv heads (Mosaic supports batch dims
+        # only at position 0 on both sides)
+        k = k.transpose(1, 0, 2)                          # [kv_h, ps, d]
+        v = v.transpose(1, 0, 2)
     s = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * scale       # [kv_h, g, ps]
@@ -299,10 +311,17 @@ def _paged_decode_kernel(pair_ref, page_ref, pos_ref, n_ref, q_ref, k_ref,
 
 def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
                          scale, interpret, k_scale=None, v_scale=None,
-                         active=None):
+                         active=None, value_dim=None):
     slots, one, h, d = q.shape
-    page_size, kv_h = k_pages.shape[1], k_pages.shape[2]
-    d_v = v_pages.shape[3]      # a value may be narrower than a key
+    page_size = k_pages.shape[1]
+    if value_dim is not None:
+        # a latent pool [num_pages, page_size, d]: one head, the value
+        # read from the key block
+        assert v_pages is None and k_scale is None and k_pages.ndim == 3
+        kv_h, d_v = 1, value_dim
+    else:
+        kv_h = k_pages.shape[2]
+        d_v = v_pages.shape[3]      # a value may be narrower than a key
     maxp = page_table.shape[1]
     group = h // kv_h
     quantized = k_scale is not None
@@ -319,10 +338,15 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
 
     q_spec = pl.BlockSpec((1, kv_h, group, d), slot_index)
     out_spec = pl.BlockSpec((1, kv_h, group, d_v), slot_index)
-    in_specs = [q_spec,
-                pl.BlockSpec((1, page_size, kv_h, d), page_index),
-                pl.BlockSpec((1, page_size, kv_h, d_v), page_index)]
-    operands = [q_g, k_pages, v_pages]
+    if value_dim is not None:
+        in_specs = [q_spec, pl.BlockSpec(
+            (1, page_size, d), lambda *a: page_index(*a)[:3])]
+        operands = [q_g, k_pages]
+    else:
+        in_specs = [q_spec,
+                    pl.BlockSpec((1, page_size, kv_h, d), page_index),
+                    pl.BlockSpec((1, page_size, kv_h, d_v), page_index)]
+        operands = [q_g, k_pages, v_pages]
     if quantized:
         scale_spec = pl.BlockSpec((1, page_size, kv_h, 1), page_index)
         in_specs += [scale_spec, scale_spec]
@@ -333,7 +357,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
     operands.append(jnp.zeros((slots, kv_h, group, d_v), q.dtype))
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                page_size=page_size, maxp=maxp,
-                               quantized=quantized)
+                               quantized=quantized, value_dim=value_dim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(jnp.maximum(n[0], 1),),
@@ -406,6 +430,10 @@ def paged_kernel_decision(*, num_heads, num_kv_heads, page_size,
     kernel's ``shard_map`` axes — and the reason names the kernel or
     the fallback of THAT path.  A sequence-parallel prefill dispatch
     never asks: its attention is the distributed transport's.
+
+    A latent pool (one leaf read as key and value) asks as one KV head:
+    the same rule decides, and its ``shard_map`` form splits slots over
+    ``data`` alone (the engine refuses a ``model`` axis over one head).
 
     ``dispatch`` says HOW the kernel runs: "direct" is a plain
     ``pallas_call`` (single device), "shard_map" wraps it per-shard
@@ -509,7 +537,7 @@ def _shard_map_axes(mesh, slots, h, kv_h):
 
 def _paged_decode_shard_map(q, k_pages, v_pages, page_table, positions,
                             *, scale, interpret, mesh, k_scale=None,
-                            v_scale=None, active=None):
+                            v_scale=None, active=None, value_dim=None):
     """Run the paged kernel per-shard over the serving mesh: kv pools
     enter sharded [pages, ps, KV_H/model, dim] (each device holds its
     kv-head slice of EVERY page — page ids are global, the host-side
@@ -520,17 +548,21 @@ def _paged_decode_shard_map(q, k_pages, v_pages, page_table, positions,
     to the local kv shard rides in; a sharded MHA model sees grouped
     heads the same way).  Inside the body ``_multichip_mesh`` reports
     False (the axis names are bound), so nothing re-triggers the mesh
-    bypass."""
+    bypass.  A latent pool (``value_dim`` set, ``v_pages`` None) enters
+    whole on every device — one head, nothing to split — and only the
+    slots shard."""
     from jax.sharding import PartitionSpec as P
     slots, _, h, d = q.shape
-    kv_h = k_pages.shape[2]
+    latent = value_dim is not None
+    kv_h = 1 if latent else k_pages.shape[2]
     head_ax, slot_ax = _shard_map_axes(mesh, slots, h, kv_h)
     q_spec = P(slot_ax, None, head_ax, None)
-    pool_spec = P(None, None, head_ax, None)
+    pool_spec = P(None, None, None) if latent else \
+        P(None, None, head_ax, None)
     if active is None:
         active = jnp.ones((slots,), bool)
-    in_specs = [q_spec, pool_spec, pool_spec, P(slot_ax, None),
-                P(slot_ax), P(slot_ax)]
+    in_specs = [q_spec, pool_spec, None if latent else pool_spec,
+                P(slot_ax, None), P(slot_ax), P(slot_ax)]
     args = [q, k_pages, v_pages, page_table, positions, active]
     if k_scale is not None:
         in_specs += [pool_spec, pool_spec]
@@ -540,7 +572,8 @@ def _paged_decode_shard_map(q, k_pages, v_pages, page_table, positions,
         ks, vs = scales if scales else (None, None)
         return _paged_decode_pallas(q_, kp_, vp_, pt_, pos_, scale=scale,
                                     interpret=interpret, k_scale=ks,
-                                    v_scale=vs, active=act_)
+                                    v_scale=vs, active=act_,
+                                    value_dim=value_dim)
 
     return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                          out_specs=q_spec, check_vma=False)(*args)
@@ -559,7 +592,7 @@ def gather_pages(pages, page_table):
 def paged_decode_attention(q, k_pages, v_pages, page_table, positions, *,
                            scale=None, bias=None, interpret=None,
                            force_kernel=False, k_scale=None,
-                           v_scale=None, active=None):
+                           v_scale=None, active=None, value_dim=None):
     """Single-token attention of ``q`` [slots, 1, heads, d] over a PAGED
     cache: a shared pool ``k_pages``/``v_pages`` [num_pages, page_size,
     kv_heads, d] indexed through ``page_table`` [slots, max_pages] with
@@ -600,6 +633,13 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, positions, *,
     carries extra additive terms (ALiBi); when present the fallback path
     runs (the paged kernel computes only the positional mask in-kernel).
 
+    ``v_pages=None, value_dim=n`` is the SHARED READ of a latent pool
+    (``k_pages`` is the one leaf ``c_pages`` [num_pages, page_size, d]
+    of ops/quant/kv.py, one head and no head dim): every query head's
+    key is the stored vector and its value that vector's leading ``n``
+    features — the kernel fetches a page once and slices the block in
+    VMEM; the fallback gathers the leaf once.  The output is ``n`` wide.
+
     Both paths are ``lax.scan``-compatible: every branch decision here
     is made on static python values, and ``positions``/``page_table``
     may be traced carries — the fused multi-step serving decode
@@ -609,7 +649,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, positions, *,
     """
     slots, l, h, d = q.shape
     page_size = k_pages.shape[1]
-    kv_h = k_pages.shape[2]
+    kv_h = 1 if value_dim is not None else k_pages.shape[2]
     max_len = page_table.shape[1] * page_size
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
     if interpret is None:
@@ -629,19 +669,19 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, positions, *,
         h, kv_h, page_size, has_bias=bias is not None,
         force_kernel=force_kernel)
     if l == 1 and decision["path"] == "kernel":
-        if mesh is not None:
-            return _paged_decode_shard_map(
-                q, k_pages, v_pages, page_table.astype(jnp.int32),
-                positions, scale=scale, interpret=interpret, mesh=mesh,
-                k_scale=k_scale, v_scale=v_scale, active=active)
-        return _paged_decode_pallas(q, k_pages, v_pages,
-                                    page_table.astype(jnp.int32), positions,
-                                    scale=scale, interpret=interpret,
-                                    k_scale=k_scale, v_scale=v_scale,
-                                    active=active)
+        call = _paged_decode_pallas if mesh is None else \
+            functools.partial(_paged_decode_shard_map, mesh=mesh)
+        return call(q, k_pages, v_pages, page_table.astype(jnp.int32),
+                    positions, scale=scale, interpret=interpret,
+                    k_scale=k_scale, v_scale=v_scale, active=active,
+                    value_dim=value_dim)
 
-    k_full = gather_pages(k_pages, page_table)
-    v_full = gather_pages(v_pages, page_table)
+    if value_dim is not None:
+        k_full = gather_pages(k_pages[:, :, None], page_table)
+        v_full = k_full[..., :value_dim]
+    else:
+        k_full = gather_pages(k_pages, page_table)
+        v_full = gather_pages(v_pages, page_table)
     if k_scale is not None:
         from deepspeed_tpu.ops.quant.kv import dequantize_kv_rows
         k_full = dequantize_kv_rows(k_full, gather_pages(k_scale,

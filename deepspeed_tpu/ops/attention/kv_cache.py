@@ -3,9 +3,12 @@
 A model file owns its projections (and their LoRA deltas), rotary /
 ALiBi and the head geometry; everything between ``q, k, v`` and the
 attention output is :func:`attend`.  Three caches exist (and a serving
-dispatch's pools may hold three kinds of per-layer entry: K/V PAGES,
-here; a recurrent layer's per-slot STATE, ops/ssm/state.py; a
-sliding-window layer's per-slot RING, ops/attention/window.py):
+dispatch's pools may hold four kinds of per-layer entry: pages of K and
+V, here; LATENT pages — one vector a token that every query head reads
+as key and, in its leading features, as value (multi-head latent
+attention), ops/quant/kv.py ``latent_pool_layer`` — here too; a
+recurrent layer's per-slot STATE, ops/ssm/state.py; a sliding-window
+layer's per-slot RING, ops/attention/window.py):
 
 * ``None`` — training / full forward: ``attn_impl`` picks the kernel.
 * the dense cache of ``generate()`` (:func:`init_dense`): per layer
@@ -50,7 +53,8 @@ from deepspeed_tpu.ops.attention.decode import (_repeat_kv,
 from deepspeed_tpu.ops.attention.flash import flash_attention
 from deepspeed_tpu.ops.attention.paged_prefill import paged_flash_prefill
 from deepspeed_tpu.ops.attention.reference import mha_reference
-from deepspeed_tpu.ops.quant.kv import (paged_gather, paged_pool_layer,
+from deepspeed_tpu.ops.quant.kv import (LATENT_LEAF, page_leaf,
+                                        paged_gather, paged_pool_layer,
                                         paged_write)
 
 
@@ -193,7 +197,7 @@ def advance(cache, new_layers):
 # ------------------------------------------------------ inside ``attn``
 
 def attend(q, k, v, positions, cache, *, impl="auto", window=0,
-           key_bias=None, sink=None, scale=None):
+           key_bias=None, sink=None, scale=None, value_dim=None):
     """Attention of q [b, l, h, d] over k [b, l, kv_h, d] / v [b, l,
     kv_h, d_v] and what ``cache`` (a :func:`layer_view`) already holds.
     Returns (out [b, l, h, d_v], the layer's updated cache or None).
@@ -209,8 +213,29 @@ def attend(q, k, v, positions, cache, *, impl="auto", window=0,
     wherever no page pool is involved; over pages the two paged
     kernels take ``d != d_v`` as they are.  ``scale`` (over pages only)
     replaces ``1 / sqrt(d)`` where q and k arrive zero-padded to the
-    width the pool stores."""
-    if sink is not None or q.shape[-1] != v.shape[-1]:
+    width the pool stores.
+
+    The LATENT case — ``v`` None and ``value_dim`` set, on a
+    :class:`PagedStep` whose entry is a latent leaf only: ``k`` [b, l,
+    w] is ONE vector a token (the normed latent and the rotated shared
+    key), ``q`` [b, l, h, w] the absorbed queries; every head scores
+    against the cached vector and sums its leading ``value_dim``
+    features, so the output is [b, l, h, value_dim].  ``scale`` is
+    required (the width is not the published head's).  The chunk's rows
+    are written through the page table first (``paged_write``'s rules),
+    then decode or the prefill kernel read each page ONCE."""
+    if value_dim is not None:
+        assert v is None and scale is not None and key_bias is None \
+            and sink is None and window == 0
+        assert isinstance(cache, PagedStep) and LATENT_LEAF in cache.layers, \
+            "the latent form of attend runs over a latent page pool only"
+        # the pool's own width (ops/quant/kv.latent_stored_dim): zeros
+        # add nothing to a score, and the value is the leading features
+        pad = cache.layers[LATENT_LEAF].shape[-1] - k.shape[-1]
+        if pad:
+            q = jnp.pad(q, ((0, 0),) * 3 + ((0, pad),))
+            k = jnp.pad(k, ((0, 0),) * 2 + ((0, pad),))
+    elif sink is not None or q.shape[-1] != v.shape[-1]:
         assert key_bias is None, "no key bias beside a sink or d != d_v"
         if cache is None:
             return window_ops.attend_fresh(q, k, v, window=window,
@@ -229,7 +254,7 @@ def attend(q, k, v, positions, cache, *, impl="auto", window=0,
     assert sink is None, "a sink logit over pages: no kernel takes one"
     if cache.mode != "decode":
         out, pools = _paged_multi(q, k, v, positions, cache, key_bias,
-                                  scale)
+                                  scale, value_dim)
     else:
         # single-token decode, written out HERE and not behind a call of
         # its own: the Pallas kernel's body is traced below this frame
@@ -243,16 +268,17 @@ def attend(q, k, v, positions, cache, *, impl="auto", window=0,
         # kv heads over `model`, slots over `data`, the page table
         # global — so this call site never changes with the topology
         pools, pt, pos = cache.layers, cache.page_table, positions[:, 0]
-        num_pages, ps = pools["k_pages"].shape[:2]
+        num_pages, ps = page_leaf(pools).shape[:2]
         bias = None if key_bias is None else \
             key_bias(jnp.arange(pt.shape[1] * ps))
         page_ids = jnp.where(
             cache.count, pt[jnp.arange(q.shape[0]), pos // ps], num_pages)
-        pools = paged_write(pools, page_ids, pos % ps, k[:, 0], v[:, 0])
+        pools = paged_write(pools, page_ids, pos % ps, k[:, 0],
+                            None if v is None else v[:, 0])
         out = paged_decode_attention(
-            q, pools["k_pages"], pools["v_pages"], pt, pos, bias=bias,
+            q, page_leaf(pools), pools.get("v_pages"), pt, pos, bias=bias,
             k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"),
-            active=cache.count, scale=scale)
+            active=cache.count, scale=scale, value_dim=value_dim)
     # multi-chip serving: pin the pools' kv-head sharding on the updated
     # arrays so GSPMD keeps the scatter/gather split over the `model`
     # axis (no-op on a single-device mesh; GQA pools shard num_kv_heads,
@@ -272,7 +298,7 @@ def _causal_bias(k_pos, pos, key_bias, window=0):
     return bias if key_bias is None else bias + key_bias(k_pos)
 
 
-def _paged_multi(q, k, v, pos, step, key_bias, scale=None):
+def _paged_multi(q, k, v, pos, step, key_bias, scale=None, value_dim=None):
     """Prefill and verify: write the ``count[r]`` valid columns of each
     row through its row of the page table, then attend causally over
     the row's pages — the ``paged_prefill`` kernel over the LIVE pages
@@ -284,7 +310,7 @@ def _paged_multi(q, k, v, pos, step, key_bias, scale=None):
     rejects) harmless: every stale position is either overwritten first
     or masked out by k_pos <= position."""
     pools, pt = step.layers, step.page_table
-    num_pages, ps = pools["k_pages"].shape[:2]
+    num_pages, ps = page_leaf(pools).shape[:2]
     b, l = pos.shape
     write = jnp.arange(l)[None, :] < step.count[:, None]
     rows = jnp.arange(b) if step.rows is None else step.rows
@@ -294,19 +320,22 @@ def _paged_multi(q, k, v, pos, step, key_bias, scale=None):
     pools = paged_write(pools, page_ids, pos % ps, k, v)
     pt_rows = pt if step.rows is None else pt[step.rows]
     if step.seq_parallel is None:
+        latent = value_dim is not None
         decision, mesh = trace_time_decision(
-            q.shape[2], k.shape[2], ps, has_bias=key_bias is not None,
-            multi_token=True)
+            q.shape[2], 1 if latent else k.shape[2], ps,
+            has_bias=key_bias is not None, multi_token=True)
         if decision["path"] == "kernel":
             return paged_flash_prefill(q, pools, pt_rows, pos[:, 0],
                                        step.count, mesh=mesh,
-                                       scale=scale), pools
-    k_slot, v_slot = paged_gather(pools, pt_rows, q.dtype)
+                                       scale=scale,
+                                       value_dim=value_dim), pools
+    k_slot, v_slot = paged_gather(pools, pt_rows, q.dtype, value_dim)
     if step.seq_parallel is None:
         bias = _causal_bias(jnp.arange(pt.shape[1] * ps), pos, key_bias)
         return decode_attention(q, k_slot, v_slot, bias=bias,
                                 scale=scale), pools
-    assert scale is None, "sequence-parallel prefill takes no scale"
+    assert scale is None and value_dim is None, \
+        "sequence-parallel prefill takes no scale and no latent pool"
     # sequence-parallel prefill: the write above already landed the
     # chunk's KV — with ids sequence-sharded, GSPMD all-gathers k/v over
     # the axis for the pool scatter, the collective the comm ledger

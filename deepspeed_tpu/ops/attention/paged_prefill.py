@@ -37,6 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.attention.decode import _shard_map_axes
 from deepspeed_tpu.ops.attention.flash import NEG_INF
+from deepspeed_tpu.ops.quant.kv import LATENT_LEAF
 
 
 def _tile_last(ri, ti, start_ref, last_ref, cols):
@@ -46,12 +47,17 @@ def _tile_last(ri, ti, start_ref, last_ref, cols):
     return jnp.minimum(last_ref[ri], start_ref[ri] + (ti + 1) * cols - 1)
 
 
-def _paged_prefill_kernel(pt_ref, start_ref, last_ref, q_ref, k_ref, v_ref,
-                          *rest, scale, page_size, group, np_, quantized):
+def _paged_prefill_kernel(pt_ref, start_ref, last_ref, q_ref, k_ref,
+                          *rest, scale, page_size, group, np_, quantized,
+                          value_dim=None):
     """One grid step: one q tile of one row, ALL kv heads, against ONE
     cache page.  Blocks span the pool's trailing (kv_heads, d) dims
     whole (see ``_paged_decode_kernel``); the per-kv-head products are
-    leading-batch dots."""
+    leading-batch dots.  ``value_dim`` marks a latent pool: no
+    ``v_ref``, the value is the leading ``value_dim`` features of the K
+    block (one DMA a page), as in the decode kernel."""
+    if value_dim is None:
+        v_ref, rest = rest[0], rest[1:]
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -72,15 +78,19 @@ def _paged_prefill_kernel(pt_ref, start_ref, last_ref, q_ref, k_ref, v_ref,
     @pl.when(ki * page_size <= _tile_last(ri, ti, start_ref, last_ref, cols))
     def _compute():
         q = q_ref[0]                                      # [kv_h, tq, d]
-        k = k_ref[0]                                      # [ps, kv_h, d]
-        v = v_ref[0]
-        if quantized:
-            k = (k.astype(jnp.float32) *
-                 ks_ref[0].astype(jnp.float32)).astype(q.dtype)
-            v = (v.astype(jnp.float32) *
-                 vs_ref[0].astype(jnp.float32)).astype(q.dtype)
-        k = k.transpose(1, 0, 2)                          # [kv_h, ps, d]
-        v = v.transpose(1, 0, 2)
+        if value_dim is not None:
+            k = k_ref[...]                                # [1, ps, d]
+            v = k[:, :, :value_dim]
+        else:
+            k = k_ref[0]                                  # [ps, kv_h, d]
+            v = v_ref[0]
+            if quantized:
+                k = (k.astype(jnp.float32) *
+                     ks_ref[0].astype(jnp.float32)).astype(q.dtype)
+                v = (v.astype(jnp.float32) *
+                     vs_ref[0].astype(jnp.float32)).astype(q.dtype)
+            k = k.transpose(1, 0, 2)                      # [kv_h, ps, d]
+            v = v.transpose(1, 0, 2)
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale   # [kv_h, tq, ps]
@@ -120,18 +130,26 @@ def _tile_cols(l, kv_h, group):
     return cols, -(-l // cols) * cols
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "value_dim"))
 def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
-                  count, *, scale, interpret):
+                  count, *, scale, interpret, value_dim=None):
     """The kernel call, jitted under its own name: every layer of a
     serving program (and every program of one geometry) shares ONE trace
     of the kernel body — a bare ``pallas_call`` re-traces it per call
     site — and the Mosaic instruction is named ``paged_prefill`` in the
     HLO, so readers that find the decode and flash kernels by the
-    model's ``attn`` scope never count this one."""
+    model's ``attn`` scope never count this one.  ``v_pages=None,
+    value_dim=n`` is the shared read of a latent pool (``k_pages`` the
+    one leaf [num_pages, page_size, d]; see ``paged_decode_attention``)."""
     b, l, h, d = q.shape
-    page_size, kv_h = k_pages.shape[1], k_pages.shape[2]
-    d_v = v_pages.shape[3]      # a value may be narrower than a key
+    page_size = k_pages.shape[1]
+    if value_dim is not None:
+        assert v_pages is None and k_scale is None and k_pages.ndim == 3
+        kv_h, d_v = 1, value_dim
+    else:
+        kv_h = k_pages.shape[2]
+        d_v = v_pages.shape[3]      # a value may be narrower than a key
     maxp = page_table.shape[1]
     group = h // kv_h
     quantized = k_scale is not None
@@ -154,17 +172,22 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
         return (ri, 0, ti, 0)
 
     q_spec = pl.BlockSpec((1, kv_h, tq, d), tile_index)
-    in_specs = [q_spec,
-                pl.BlockSpec((1, page_size, kv_h, d), page_index),
-                pl.BlockSpec((1, page_size, kv_h, d_v), page_index)]
-    operands = [q_g, k_pages, v_pages]
+    if value_dim is not None:
+        in_specs = [q_spec, pl.BlockSpec(
+            (1, page_size, d), lambda *a: page_index(*a)[:3])]
+        operands = [q_g, k_pages]
+    else:
+        in_specs = [q_spec,
+                    pl.BlockSpec((1, page_size, kv_h, d), page_index),
+                    pl.BlockSpec((1, page_size, kv_h, d_v), page_index)]
+        operands = [q_g, k_pages, v_pages]
     if quantized:
         scale_spec = pl.BlockSpec((1, page_size, kv_h, 1), page_index)
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     kernel = functools.partial(_paged_prefill_kernel, scale=scale,
                                page_size=page_size, group=group, np_=maxp,
-                               quantized=quantized)
+                               quantized=quantized, value_dim=value_dim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, l_pad // cols, maxp),
@@ -186,7 +209,8 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
 
 
 def paged_flash_prefill(q, pools, page_table, start, count, *,
-                            mesh=None, scale=None, interpret=None):
+                        mesh=None, scale=None, interpret=None,
+                        value_dim=None):
     """Causal attention of ``q`` [rows, l, heads, d] over a PAGED cache:
     column j of row r sits at position ``start[r] + j`` and sees key
     positions <= its own through ``page_table`` [rows, max_pages] (the
@@ -196,25 +220,31 @@ def paged_flash_prefill(q, pools, page_table, start, count, *,
     the outputs of padding columns and padding rows are finite and
     meaningless.  ``mesh`` set runs the kernel per shard under
     ``shard_map`` with the decode kernel's axes (kv heads over
-    ``model``, rows over ``data`` where they divide)."""
+    ``model``, rows over ``data`` where they divide).  A latent layer's
+    ``pools`` (its one leaf, ``value_dim`` set) runs the shared read;
+    under ``shard_map`` the leaf enters whole and only the rows shard."""
     d = q.shape[-1]
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     call = functools.partial(paged_prefill, scale=scale,
-                             interpret=interpret)
-    args = (q, pools["k_pages"], pools["v_pages"], pools.get("k_scale"),
+                             interpret=interpret, value_dim=value_dim)
+    latent = value_dim is not None
+    args = (q, pools[LATENT_LEAF] if latent else pools["k_pages"],
+            pools.get("v_pages"), pools.get("k_scale"),
             pools.get("v_scale"), page_table, start, count)
     if mesh is None:
         return call(*args)
     from jax.sharding import PartitionSpec as P
-    head_ax, row_ax = _shard_map_axes(mesh, q.shape[0], q.shape[2],
-                                      pools["k_pages"].shape[2])
+    head_ax, row_ax = _shard_map_axes(
+        mesh, q.shape[0], q.shape[2],
+        1 if latent else pools["k_pages"].shape[2])
     q_spec = P(row_ax, None, head_ax, None)
     pool_spec = P(None, None, head_ax, None)
     scale_spec = pool_spec if "k_scale" in pools else None
     return jax.shard_map(
         call, mesh=mesh,
-        in_specs=(q_spec, pool_spec, pool_spec, scale_spec, scale_spec,
+        in_specs=(q_spec, P(None, None, None) if latent else pool_spec,
+                  None if latent else pool_spec, scale_spec, scale_spec,
                   P(row_ax, None), P(row_ax), P(row_ax)),
         out_specs=q_spec, check_vma=False)(*args)

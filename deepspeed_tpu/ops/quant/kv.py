@@ -31,6 +31,13 @@ dim 1) matters: the pool axis family's single NamedSharding
 so the scales shard their kv-head dim over ``model`` exactly like the
 payload they describe.
 
+A LATENT layer (multi-head latent attention) holds ONE float leaf,
+``c_pages [num_pages, page_size, stored]`` (:func:`latent_pool_layer`):
+the vector every query head reads as its key and, in its leading
+features, as its value.  It is indexed by the same page ids and moves
+with its page like any other leaf; it has no head dim and takes no
+quantized dtype (refused by name).
+
 Quantization granularity is per token-row per kv-head (one scale per
 written KV vector).  Coarser per-page scales would need requantization
 on every append — pages fill token by token — which compounds error;
@@ -48,7 +55,9 @@ import jax.numpy as jnp
 __all__ = ["KV_QUANT_DTYPES", "is_quantized_kv", "kv_dtype_name",
            "kv_storage_dtype", "kv_qmax", "quantize_kv_rows",
            "dequantize_kv_rows", "paged_pool_layer", "paged_write",
-           "paged_gather", "kv_page_bytes", "fp8_supported"]
+           "paged_gather", "kv_page_bytes", "fp8_supported",
+           "LATENT_LEAF", "LANES", "latent_stored_dim", "latent_pool_layer",
+           "latent_page_bytes", "page_leaf"]
 
 # accepted quantized kv_dtype spellings (the float spellings live in
 # inference.engine.DTYPES); "fp8" is e4m3 — the inference-standard
@@ -56,6 +65,18 @@ __all__ = ["KV_QUANT_DTYPES", "is_quantized_kv", "kv_dtype_name",
 KV_QUANT_DTYPES = ("int8", "fp8")
 
 _QMAX = {"int8": 127.0, "fp8": 448.0}
+
+# A LATENT layer's entry (multi-head latent attention) holds ONE leaf
+# instead of two: a token's cache in the layer is one vector -- the
+# normed latent and the rotated shared key side by side -- that every
+# query head reads as its key, and whose leading ``value_dim`` features
+# are its value.  ``c_pages [num_pages, page_size, stored]``: no head
+# dim (there is one head, and a size-1 second-minor dim is what the
+# TPU's tiled layout pads to a whole sublane tile), tokens on the
+# sublanes, features on the lanes.
+LATENT_LEAF = "c_pages"
+# the minor dim of a TPU tile
+LANES = 128
 
 
 def fp8_supported():
@@ -88,10 +109,17 @@ def kv_storage_dtype(name):
                      f"expected one of {KV_QUANT_DTYPES}")
 
 
+def page_leaf(layer):
+    """The array of one pool layer dict whose leading dims are [pages,
+    page_size]: the K pages, or a latent layer's one leaf; None where
+    the entry holds no pages (per-slot state, a ring, counters)."""
+    return layer.get("k_pages", layer.get(LATENT_LEAF))
+
+
 def kv_dtype_name(layer):
     """Canonical kv-dtype name of one pool layer dict (the live truth —
     health() reports what is allocated, not what was configured)."""
-    dt = layer["k_pages"].dtype
+    dt = page_leaf(layer).dtype
     if "k_scale" in layer:
         return "int8" if dt == jnp.int8 else "fp8"
     return jnp.dtype(dt).name
@@ -126,7 +154,10 @@ def paged_pool_layer(num_pages, page_size, kv_heads, head_dim, dtype,
     leaves (int8/fp8 payload + f32 scale pools) when ``dtype`` is a
     quantized kv-dtype name.  ``head_dim`` is the width of a key and,
     unless ``v_dim`` says otherwise, of a value (MiMo-V2's keys are 192
-    wide beside values of 128)."""
+    wide beside values of 128).  A layer that caches ONE vector a token
+    for all heads, read as key and value (multi-head latent attention),
+    builds its entry with :func:`latent_pool_layer` instead: one leaf,
+    no head dim."""
     v_dim = head_dim if v_dim is None else v_dim
     if is_quantized_kv(dtype):
         st = kv_storage_dtype(dtype)
@@ -148,6 +179,47 @@ def paged_pool_layer(num_pages, page_size, kv_heads, head_dim, dtype,
     }
 
 
+def latent_stored_dim(width):
+    """The width a latent vector takes in the page pool: ``width``
+    rounded up to whole lane tiles where it is over one (576 -> 640, 11%
+    more than published).  A pool whose minor dim is no multiple of 128
+    lanes is not what the chip keeps row-major, and each paged kernel
+    call would copy the layer's whole pool in and out (PERF.md section
+    6, PR 42 c; PR 54 for this leaf).  Zeros in the padding add nothing
+    to a score, and a value is read from the leading features only."""
+    if width <= LANES:
+        return width
+    return -(-width // LANES) * LANES
+
+
+def _refuse_quantized_latent(dtype):
+    if is_quantized_kv(dtype):
+        raise ValueError(
+            f"kv_dtype={dtype!r} over a latent page pool is not built: "
+            "a latent entry holds the normed latent and the rotated "
+            "shared key in one row, and one scale a row over both is "
+            "another design -- serve this model with a float kv_dtype")
+
+
+def latent_pool_layer(num_pages, page_size, width, dtype):
+    """One LATENT layer's pool: the one leaf ``c_pages [num_pages,
+    page_size, latent_stored_dim(width)]``.  A quantized ``dtype`` is
+    refused by name: one scale a row over latent and rope key together
+    is another design (the two halves differ in range)."""
+    _refuse_quantized_latent(dtype)
+    return {LATENT_LEAF: jnp.zeros(
+        (num_pages, page_size, latent_stored_dim(width)), dtype)}
+
+
+def latent_page_bytes(num_layers, width, page_size, dtype):
+    """Exact bytes one page costs across ``num_layers`` latent layers
+    AS STORED (the padding of :func:`latent_stored_dim` included); the
+    published cost is ``width`` a token a layer."""
+    _refuse_quantized_latent(dtype)
+    return int(num_layers) * int(page_size) * latent_stored_dim(width) \
+        * jnp.dtype(dtype).itemsize
+
+
 def _qname(storage_dtype):
     return "int8" if storage_dtype == jnp.int8 else "fp8"
 
@@ -162,7 +234,14 @@ def paged_write(layer, page_ids, offsets, k_new, v_new):
     scale write uses the SAME masked ids, so payload and scale stay
     atomic per row.  The float path is byte-identical to the
     pre-quantization code (zero-cost-when-off: the branch is a
-    trace-time dict-key check)."""
+    trace-time dict-key check).  A LATENT layer takes its one vector a
+    token as ``k_new`` (``X + (stored,)``, already padded to the leaf's
+    width) and ``v_new`` None."""
+    if LATENT_LEAF in layer:
+        assert v_new is None, "a latent entry holds one vector a token"
+        c_pages = layer[LATENT_LEAF]
+        return {LATENT_LEAF: c_pages.at[page_ids, offsets].set(
+            k_new.astype(c_pages.dtype), mode="drop")}
     k_pages, v_pages = layer["k_pages"], layer["v_pages"]
     if "k_scale" not in layer:
         return {
@@ -184,10 +263,13 @@ def paged_write(layer, page_ids, offsets, k_new, v_new):
     }
 
 
-def paged_gather(pools, page_table, dtype):
+def paged_gather(pools, page_table, dtype, value_dim=None):
     """Gather per-slot contiguous K/V buffers through the page table,
     dequantizing when the pools are quantized: returns ``(k, v)`` of
-    shape ``[slots, max_pages * page_size, kv_heads, head_dim]``.  The
+    shape ``[slots, max_pages * page_size, kv_heads, head_dim]``.  Over
+    a LATENT layer the one leaf is gathered once: ``k`` is the vector
+    as stored (one head) and ``v`` its leading ``value_dim`` features.
+    The
     float path returns the raw gathered pages (exactly the
     pre-quantization behavior); the quantized path gathers payload AND
     scale pools (the scales ride the same page ids) and dequantizes to
@@ -199,6 +281,9 @@ def paged_gather(pools, page_table, dtype):
     map and dequantize in VMEM (shard_mapped per-shard on a
     multi-device mesh), so only quantized bytes stream from HBM."""
     from deepspeed_tpu.ops.attention.decode import gather_pages
+    if LATENT_LEAF in pools:
+        k = gather_pages(pools[LATENT_LEAF][:, :, None], page_table)
+        return k, k[..., :value_dim]
     k = gather_pages(pools["k_pages"], page_table)
     v = gather_pages(pools["v_pages"], page_table)
     if "k_scale" in pools:

@@ -130,6 +130,10 @@ class ServingMetrics:
         # what a token costs in pages and a slot in rings (gauges)
         self.kv_paged_bytes_per_token = 0
         self.kv_window_bytes_per_slot = 0
+        # a latent page pool: what a token costs as published (layers x
+        # the latent vector) and as the pool stores it, padding included
+        self.kv_latent_bytes_per_token = 0
+        self.kv_stored_bytes_per_token = 0
         self.mesh_info = {}            # serving topology (record_mesh)
         self._events = []
 
@@ -232,6 +236,12 @@ class ServingMetrics:
         self.kv_paged_bytes_per_token = int(paged_bytes_per_token)
         self.kv_window_bytes_per_slot = int(window_bytes_per_slot)
         self._write([("serving/state/pool_bytes", int(nbytes), step)])
+
+    def record_latent_pool(self, published, stored):
+        """One-shot gauges at scheduler construction over a latent page
+        pool: bytes a token as published and as stored."""
+        self.kv_latent_bytes_per_token = int(published)
+        self.kv_stored_bytes_per_token = int(stored)
 
     def record_state_resets(self, step, rows):
         """``rows`` prefill rows of one dispatch began at position 0:
@@ -755,6 +765,8 @@ class ServingMetrics:
             "prefill_kv_pairs": self.prefill_kv_pairs,
             "kv_paged_bytes_per_token": self.kv_paged_bytes_per_token,
             "kv_window_bytes_per_slot": self.kv_window_bytes_per_slot,
+            "kv_latent_bytes_per_token": self.kv_latent_bytes_per_token,
+            "kv_stored_bytes_per_token": self.kv_stored_bytes_per_token,
             "decode_live_page_share":
             round(self.decode_live_pages / self.decode_table_pages, 4)
             if self.decode_table_pages else None,

@@ -493,10 +493,10 @@ class ServingScheduler:
         self._pools_ref = pools_ref
         # live truth for health()/operators: derived from the allocated
         # leaves, not from config (a shared pool reports what it IS)
-        from deepspeed_tpu.ops.quant.kv import kv_dtype_name
+        from deepspeed_tpu.ops.quant.kv import kv_dtype_name, page_leaf
         self.kv_dtype_name = kv_dtype_name(next(
             entry for entry in self._pools_ref.pools["layers"]
-            if "k_pages" in entry))
+            if page_leaf(entry) is not None))
         # prefill-worker hook: a request submitted with handoff=True
         # finishes its prompt, emits the boundary token, and hands its
         # page chain to this callback instead of decoding on
@@ -619,6 +619,9 @@ class ServingScheduler:
                 self.mesh_info.get("state_pool_bytes_total", 0),
                 paged_bytes_per_token=engine.kv_page_bytes(page_size)
                 // page_size, window_bytes_per_slot=ring_bytes)
+        # a latent page pool's bytes a token, published and as stored
+        self.metrics.record_latent_pool(*getattr(
+            engine, "latent_bytes_per_token", lambda: (0, 0))())
         if self.prefix_cache_refused is not None:
             self.metrics.record_prefix_refused()
         self.step_idx = 0

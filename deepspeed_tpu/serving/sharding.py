@@ -10,6 +10,9 @@ over a multi-chip topology is a config change, not a rewrite:
   logical      default mesh ax   carried by
   ===========  ================  =============================================
   kv_heads     model             KV page pools [pages, page_size, KV_H, dim]
+                                 (a LATENT pool [pages, page_size, dim] has
+                                 one head and no head dim: nothing to pin,
+                                 and a `model` axis over it is refused)
   slots        data              per-slot carries (tok/active/lengths/
                                  emitted/budgets/eos), token blocks
                                  [SLOTS, H|K+1], the page table [SLOTS, maxp]
@@ -65,8 +68,11 @@ SERVING_AXIS_RULES = (
 # the logical axes of a pools leaf that is not a K/V page array, by the
 # leaf's name in its layer's entry (ops/ssm/state.py; a window layer's
 # ring, ops/attention/window.py; the routing counters of
-# moe/held_experts.py, in two leaves); every other leaf is a page array
+# moe/held_experts.py, in two leaves; a latent layer's one page leaf,
+# ops/quant/kv.py, which has no head dim); every other leaf is a K/V page
+# array
 POOL_LEAF_AXES = {
+    "c_pages": ("pages", None, None),
     "conv": ("slots", None, None),
     "ssm": ("slots", "ssm_heads", None, None),
     "k_ring": ("slots", None, "kv_heads", None),
@@ -99,6 +105,14 @@ class ServingShardingConfig:
         Raises a ValueError naming the axis and head count instead."""
         ax = self.axis("kv_heads")
         size = _mesh_axis_size(mesh, ax)
+        if size > 1 and num_kv_heads == 1:
+            raise ValueError(
+                f"mesh axis '{ax}' has size {size}, and this model's page "
+                "pool has ONE head a token (a latent cache that every "
+                "query head reads, or multi-query attention): one head "
+                f"splits over no '{ax}' axis, and serving it replicated "
+                f"beside weights split over '{ax}' is not built. Serve "
+                f"it on a mesh whose '{ax}' size is 1.")
         if size > 1 and num_kv_heads % size != 0:
             raise ValueError(
                 f"mesh axis '{ax}' has size {size}, which does not divide "
@@ -115,7 +129,10 @@ class ServingShardingConfig:
         not): the configured head axis must divide ``num_heads`` —
         intra-head tensor parallelism silently drifts ~1e-2 on legacy
         SPMD partitioners and has no serving sharding.  Fail loudly,
-        naming the axis and count."""
+        naming the axis and count.  (A page pool with ONE head a token —
+        a latent cache, multi-query attention — passes here on its query
+        heads; :meth:`validate` refuses it by name when the pools are
+        built, instead of failing on ``1 % size``.)"""
         ax = self.axis("kv_heads")
         size = _mesh_axis_size(mesh, ax)
         if size > 1 and num_heads % size != 0:
@@ -287,11 +304,18 @@ def split_pools(pools):
     reason)."""
     kv, other = [], []
     for entry in pools["layers"]:
-        kv.append({n: a for n, a in entry.items()
-                   if n not in POOL_LEAF_AXES})
+        kv.append({n: a for n, a in entry.items() if is_page_leaf(n)})
         other.append({n: a for n, a in entry.items()
-                      if n in POOL_LEAF_AXES and n != "walked"})
+                      if not is_page_leaf(n) and n != "walked"})
     return kv, other
+
+
+def is_page_leaf(name):
+    """True for a pools leaf whose leading dim is the PAGE dim (K, V and
+    their scales; a latent layer's ``c_pages``): what a page id indexes,
+    so what a page copy, a hand-off and the page ledgers touch."""
+    return name not in POOL_LEAF_AXES or \
+        POOL_LEAF_AXES[name][0] == "pages"
 
 
 def pool_bytes_per_device(pools):
@@ -359,7 +383,8 @@ def constrain_kv_pages(pages):
     cfg = _ACTIVE_CONFIG
     rules = dict(cfg.rules if cfg is not None else SERVING_AXIS_RULES)
     ax = rules.get("kv_heads")
-    if mesh is None or ax is None or ax not in mesh.shape:
+    if mesh is None or ax is None or ax not in mesh.shape \
+            or pages.ndim != 4:      # a latent leaf has no head dim to pin
         return pages
     size = int(mesh.shape[ax])
     if size <= 1 or pages.shape[2] % size != 0:

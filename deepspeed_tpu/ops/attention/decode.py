@@ -178,6 +178,23 @@ def _multichip_mesh():
     return not _inside_shard_map(mesh)
 
 
+def _segment_entries(live, width):
+    """A flat work list from per-segment counts: ``live`` int32 [n] says
+    how many entries (at most ``width``) segment s holds; entry i of the
+    list, in segment order, is (``seg[i]``, ``k[i]``), the k-th entry of
+    segment seg.  Returns (seg, k, total), seg and k int32 [n * width]
+    (past ``total`` they still name a valid segment and offset, so a
+    table lookup through them stays in range) and total int32 [1]."""
+    n = live.shape[0]
+    ends = jnp.cumsum(live)
+    i = jnp.arange(n * width, dtype=jnp.int32)
+    seg = jnp.minimum(
+        jnp.searchsorted(ends, i, side="right", method="compare_all"),
+        n - 1).astype(jnp.int32)
+    k = jnp.minimum(i - (ends - live)[seg], width - 1).astype(jnp.int32)
+    return seg, k, ends[-1:].astype(jnp.int32)
+
+
 def _live_pairs(page_table, positions, active, page_size):
     """The decode kernel's grid, from the step's own inputs: the live
     (slot, page) pairs of the ACTIVE slots in slot order.  A slot holds
@@ -192,15 +209,11 @@ def _live_pairs(page_table, positions, active, page_size):
     live = jnp.minimum(positions // page_size + 1, maxp)
     if active is not None:
         live = jnp.where(active, live, 0)
-    ends = jnp.cumsum(live)
-    i = jnp.arange(slots * maxp, dtype=jnp.int32)
-    slot = jnp.minimum(
-        jnp.searchsorted(ends, i, side="right", method="compare_all"),
-        slots - 1).astype(jnp.int32)
-    pair = slot * maxp + jnp.minimum(i - (ends - live)[slot], maxp - 1)
-    pair = pair.astype(jnp.int32)
-    pages = jnp.where(i < ends[-1], page_table.reshape(-1)[pair], 0)
-    return pair, pages, ends[-1:].astype(jnp.int32)
+    slot, k, n = _segment_entries(live, maxp)
+    pair = slot * maxp + k
+    pages = jnp.where(jnp.arange(pair.shape[0]) < n[0],
+                      page_table.reshape(-1)[pair], 0)
+    return pair, pages, n
 
 
 def _paged_decode_kernel(pair_ref, page_ref, pos_ref, n_ref, q_ref, k_ref,

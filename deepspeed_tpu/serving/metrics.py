@@ -127,6 +127,11 @@ class ServingMetrics:
         # the (query, key) pairs it scores
         self.prefill_kv_tokens = 0
         self.prefill_kv_pairs = 0
+        # and of the page table: over the dispatches, the (row, page)
+        # entries the rows' chunks reach (what the paged prefill
+        # kernel's work list holds) and padded rows x pages a slot
+        self.prefill_live_pages = 0
+        self.prefill_table_pages = 0
         # what a token costs in pages and a slot in rings (gauges)
         self.kv_paged_bytes_per_token = 0
         self.kv_window_bytes_per_slot = 0
@@ -205,19 +210,24 @@ class ServingMetrics:
             ])
 
     def record_prefill_dispatch(self, step, *, rows, padded_rows, tokens,
-                                kv_tokens=0, kv_pairs=0, riders=0):
+                                kv_tokens=0, kv_pairs=0, riders=0,
+                                live_pages=0, table_pages=0):
         """One shared prefill dispatch carried the next chunk of
         ``rows`` prefilling slots (``tokens`` prompt tokens) in a
         ``padded_rows``-row bucket, and beside them the next token of
         ``riders`` decoding slots (one-token rows: in neither ``rows``
         nor ``tokens``); over all of them its rows read ``kv_tokens``
         keys of the paged layers and scored ``kv_pairs`` (query, key)
-        pairs."""
+        pairs, on ``live_pages`` (row, page) entries of the
+        ``table_pages`` = ``padded_rows`` x pages a slot that the
+        dispatch's page table holds."""
         self.prefill_dispatches += 1
         self.ride_rows += int(riders)
         self.ride_dispatches += riders > 0
         self.prefill_kv_tokens += int(kv_tokens)
         self.prefill_kv_pairs += int(kv_pairs)
+        self.prefill_live_pages += int(live_pages)
+        self.prefill_table_pages += int(table_pages)
         self.prefill_rows += rows
         self.prefill_padded_rows += padded_rows
         self.prefill_by_bucket[padded_rows] += 1
@@ -770,6 +780,9 @@ class ServingMetrics:
             "decode_live_page_share":
             round(self.decode_live_pages / self.decode_table_pages, 4)
             if self.decode_table_pages else None,
+            "prefill_live_page_share":
+            round(self.prefill_live_pages / self.prefill_table_pages, 4)
+            if self.prefill_table_pages else None,
             "state_resets": self.state_resets,
             "prefix_cache_refused": self.prefix_cache_refused,
             "moe_assignments": self.moe_assignments,

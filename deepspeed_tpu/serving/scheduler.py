@@ -2039,14 +2039,19 @@ class ServingScheduler:
                 ids, slots, n_valid, self.kv.table, self.lengths,
                 self.pools, adapter_ids=a_ids, adapters=a_pack)
         # a chunk of n columns from position s reads s + n keys of a
-        # paged layer and scores n * s + n * (n + 1) / 2 pairs
+        # paged layer and scores n * s + n * (n + 1) / 2 pairs, on the
+        # pages up to position s + n - 1 (a padding row: one page)
         starts = [int(self.lengths[slot]) for slot, _, _ in rows]
         self.metrics.record_prefill_dispatch(
             self.step_idx, rows=len(rows) - riders, padded_rows=padded,
             tokens=tokens, riders=riders,
             kv_tokens=sum(starts) + tokens + riders,
             kv_pairs=sum(s * len(c) + len(c) * (len(c) + 1) // 2
-                         for s, (_, _, c) in zip(starts, rows)))
+                         for s, (_, _, c) in zip(starts, rows)),
+            live_pages=sum((s + len(c) - 1) // self.kv.page_size + 1
+                           for s, (_, _, c) in zip(starts, rows))
+            + padded - len(rows),
+            table_pages=padded * self.kv.table.shape[1])
         if self.slot_state:
             # a row whose first position is 0 started from zeros
             # whatever its slot held (ops/ssm/state.py)

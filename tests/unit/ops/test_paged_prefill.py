@@ -22,7 +22,9 @@ from deepspeed_tpu.models.gpt2 import GPT2, gpt2_tiny
 from deepspeed_tpu.ops.attention import kv_cache
 from deepspeed_tpu.ops.attention.decode import (kernel_mode_scope,
                                                 paged_kernel_decision)
-from deepspeed_tpu.ops.attention.paged_prefill import _tile_cols
+from deepspeed_tpu.ops.attention.paged_prefill import (_live_steps,
+                                                       _tile_cols,
+                                                       paged_prefill)
 from deepspeed_tpu.parallel.topology import make_mesh
 from deepspeed_tpu.runtime.config import MeshConfig
 from deepspeed_tpu.serving import ServingScheduler
@@ -31,10 +33,10 @@ SLOTS, MAXP, PAGES = 4, 6, 40
 GQA, MHA = (32, 8, 128), (4, 4, 64)      # heads, kv heads, head dim
 
 
-def _pools(rng, kv_h, d, ps, dtype):
+def _pools(rng, kv_h, d, ps, dtype, pages=PAGES):
     """One layer's pools, full of history (a float pool: normal draws;
     an int8 pool: payload and per-row scales)."""
-    pools = kv_cache.init_paged(1, PAGES, ps, kv_h, d, dtype)["layers"][0]
+    pools = kv_cache.init_paged(1, pages, ps, kv_h, d, dtype)["layers"][0]
     out = {}
     for name, a in pools.items():
         if a.dtype == jnp.int8:
@@ -160,6 +162,264 @@ def test_dead_pages_are_never_read(mode):
     assert not np.isfinite(np.asarray(ref[0])[valid]).all()
 
 
+# ------------------------------------------------------ the work list
+
+# (start, count) a row: mid-prompt rows, one-token riders with long
+# histories, padding rows (count 0), a row at capacity (its chunk ends
+# on the table's last position) and a row that begins at 0
+def _dispatch_rows(ps, maxp, l):
+    cap = maxp * ps
+    return [(3 * ps, l), (ps + 5, l - 3), (cap - ps - 7, 1), (0, 0),
+            (cap - l, l), (0, l), (2 * ps - 1, 1), (4 * ps + 9, 0)]
+
+
+def _brute_force_steps(rows, ps, maxp, cols, tiles):
+    """Every (row, tile, page) with the page's first position at or
+    under the last position the tile may see, in grid order."""
+    out = []
+    for r, (start, count) in enumerate(rows):
+        last = start + count - 1 if count else 0
+        for t in range(tiles):
+            tile_last = min(last, start + (t + 1) * cols - 1)
+            out += [(r, t, k) for k in range(maxp) if k * ps <= tile_last]
+    return out
+
+
+@pytest.mark.parametrize("ps", [16, 128])
+@pytest.mark.parametrize("l,kv_h,group", [(8, 8, 4), (32, 1, 32),
+                                          (40, 64, 1)],
+                         ids=["one_tile", "latent_group32", "two_tiles"])
+def test_the_work_list_is_the_live_steps_in_grid_order(l, kv_h, group, ps):
+    maxp = 7
+    cols, l_pad = _tile_cols(l, kv_h, group)
+    tiles = l_pad // cols
+    assert tiles == (2 if l == 40 else 1)
+    rows = _dispatch_rows(ps, maxp, l)
+    start, count = (jnp.asarray(c, jnp.int32) for c in zip(*rows))
+    last = jnp.where(count > 0, start + count - 1, 0)
+    table = np.arange(len(rows) * maxp, dtype=np.int32) \
+        .reshape(len(rows), maxp)[:, ::-1] + 3
+    tile, k_idx, pages, n = jax.jit(_live_steps, static_argnums=(3, 4, 5))(
+        jnp.asarray(table), start, last, cols, tiles, ps)
+    want = _brute_force_steps(rows, ps, maxp, cols, tiles)
+    assert int(n[0]) == len(want) < len(rows) * tiles * maxp
+    tile, k_idx, pages = (np.asarray(a) for a in (tile, k_idx, pages))
+    assert tile.shape == k_idx.shape == pages.shape == \
+        (len(rows) * tiles * maxp,)
+    assert list(zip(tile[:len(want)] // tiles, tile[:len(want)] % tiles,
+                    k_idx[:len(want)])) == want
+    assert pages[:len(want)].tolist() == [table[r, k] for r, _, k in want]
+    # a padding row holds page 0 alone, a tile; the row at capacity the
+    # whole of its table row
+    for r in (3, 7):
+        assert [s for s in want if s[0] == r] == \
+            [(r, t, 0) for t in range(tiles)]
+    assert [k for r, t, k in want if (r, t) == (4, tiles - 1)] == \
+        list(range(maxp))
+    # the tail is never read, and still names entries of the table
+    assert ((0 <= tile) & (tile < len(rows) * tiles)).all()
+    assert ((0 <= k_idx) & (k_idx < maxp)).all()
+    assert not pages[len(want):].any()
+
+
+def test_rows_at_capacity_list_the_whole_grid():
+    ps, maxp, l = 16, 5, 8
+    start = jnp.full(3, maxp * ps - l, jnp.int32)
+    table = jnp.arange(3 * maxp, dtype=jnp.int32).reshape(3, maxp)
+    tile, k_idx, pages, n = _live_steps(table, start, start + l - 1, 8, 1,
+                                        ps)
+    assert int(n[0]) == 3 * maxp
+    assert np.asarray(tile * maxp + k_idx).tolist() == \
+        np.asarray(pages).tolist() == list(range(3 * maxp))
+
+
+def _grid_form(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
+               count, *, scale, value_dim=None):
+    """The kernel as PR 34 wrote it, kept here as the yardstick: grid
+    (rows, q tiles, max_pages), the same body under ``pl.when`` on the
+    steps past a tile's last visible page, the index map clamped to the
+    last live page.  Interpret mode only."""
+    import functools
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from deepspeed_tpu.ops.attention.flash import NEG_INF
+
+    b, l, h, d = q.shape
+    ps = k_pages.shape[1]
+    latent = value_dim is not None
+    kv_h, d_v = (1, value_dim) if latent else \
+        (k_pages.shape[2], v_pages.shape[3])
+    maxp, group, quantized = page_table.shape[1], h // kv_h, \
+        k_scale is not None
+    cols, l_pad = _tile_cols(l, kv_h, group)
+    tq = cols * group
+    q_g = jnp.pad(q, ((0, 0), (0, l_pad - l), (0, 0), (0, 0))) \
+        .reshape(b, l_pad, kv_h, group, d).transpose(0, 2, 1, 3, 4) \
+        .reshape(b, kv_h, l_pad * group, d)
+    start = start.astype(jnp.int32)
+    last = jnp.where(count > 0, start + count.astype(jnp.int32) - 1, 0)
+
+    def tile_last(ri, ti, st, ls):
+        return jnp.minimum(ls[ri], st[ri] + (ti + 1) * cols - 1)
+
+    def kernel(pt_ref, start_ref, last_ref, q_ref, k_ref, *rest):
+        if not latent:
+            v_ref, rest = rest[0], rest[1:]
+        if quantized:
+            ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        else:
+            o_ref, m_scr, l_scr, acc_scr = rest
+        ri, ti, ki = (pl.program_id(a) for a in range(3))
+
+        @pl.when(ki == 0)
+        def _init():
+            m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+            l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
+            acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+        @pl.when(ki * ps <= tile_last(ri, ti, start_ref, last_ref))
+        def _compute():
+            q = q_ref[0]
+            if latent:
+                k = k_ref[...]
+                v = k[:, :, :value_dim]
+            else:
+                k, v = k_ref[0], v_ref[0]
+                if quantized:
+                    k = (k.astype(jnp.float32) *
+                         ks_ref[0].astype(jnp.float32)).astype(q.dtype)
+                    v = (v.astype(jnp.float32) *
+                         vs_ref[0].astype(jnp.float32)).astype(q.dtype)
+                k, v = k.transpose(1, 0, 2), v.transpose(1, 0, 2)
+            s = jax.lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) * scale
+            k_rel = ki * ps - start_ref[ri] - ti * cols + \
+                jax.lax.broadcasted_iota(jnp.int32, (1, 1, ps), 2)
+            row = jax.lax.broadcasted_iota(jnp.int32, (1, tq, 1), 1)
+            s = jnp.where(k_rel * group <= row, s, NEG_INF)
+            m_prev, l_prev = m_scr[:, :, :1], l_scr[:, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            acc_scr[:] = acc_scr[:] * alpha + pv
+            m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+        @pl.when(ki == maxp - 1)
+        def _finalize():
+            o_ref[0] = (acc_scr[:] / l_scr[:, :, :1]).astype(o_ref.dtype)
+
+    def page_index(ri, ti, ki, pt, st, ls):
+        live = tile_last(ri, ti, st, ls) // ps
+        return (pt[ri, jnp.minimum(ki, live)], 0, 0, 0)
+
+    def tile_index(ri, ti, ki, pt, st, ls):
+        return (ri, 0, ti, 0)
+
+    q_spec = pl.BlockSpec((1, kv_h, tq, d), tile_index)
+    if latent:
+        in_specs = [q_spec, pl.BlockSpec(
+            (1, ps, d), lambda *a: page_index(*a)[:3])]
+        operands = [q_g, k_pages]
+    else:
+        in_specs = [q_spec, pl.BlockSpec((1, ps, kv_h, d), page_index),
+                    pl.BlockSpec((1, ps, kv_h, d_v), page_index)]
+        operands = [q_g, k_pages, v_pages]
+    if quantized:
+        in_specs += [pl.BlockSpec((1, ps, kv_h, 1), page_index)] * 2
+        operands += [k_scale, v_scale]
+    out = pl.pallas_call(
+        kernel, interpret=True,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, l_pad // cols, maxp),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, kv_h, tq, d_v), tile_index),
+            scratch_shapes=[pltpu.VMEM((kv_h, tq, 128), jnp.float32),
+                            pltpu.VMEM((kv_h, tq, 128), jnp.float32),
+                            pltpu.VMEM((kv_h, tq, d_v), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q_g.shape[:3] + (d_v,), q.dtype),
+    )(page_table.astype(jnp.int32), start, last, *operands)
+    return out.reshape(b, kv_h, l_pad, group, d_v) \
+        .transpose(0, 2, 1, 3, 4).reshape(b, l_pad, h, d_v)[:, :l]
+
+
+FORMS = {      # heads, kv heads, key dim, value dim, chunk, pool, q dtype
+    "f32": (8, 2, 32, 32, 8, jnp.float32, jnp.float32),
+    "bf16_gqa": (32, 8, 128, 128, 8, jnp.bfloat16, jnp.bfloat16),
+    "int8_pool": (8, 2, 32, 32, 8, "int8", jnp.bfloat16),
+    "key_wider_than_value": (8, 2, 64, 32, 8, jnp.bfloat16, jnp.bfloat16),
+    "latent": (8, 1, 48, 32, 8, jnp.bfloat16, jnp.bfloat16),
+    "two_q_tiles": (64, 64, 16, 16, 40, jnp.float32, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_valid_outputs_equal_the_grid_form_to_the_bit(form):
+    """Prompt rows, riders, padding rows and a row at capacity through
+    the live-step kernel and through the (rows, tiles, max_pages) form:
+    every valid position is the same bits (the body is the same
+    arithmetic on the same pages in the same order), and here the
+    padding rows and columns are too."""
+    h, kv_h, d_k, d_v, l, pool_dtype, q_dtype = FORMS[form]
+    ps, maxp = 16, 7
+    rng = np.random.default_rng(8)
+    rows = _dispatch_rows(ps, maxp, l)
+    start, count = (jnp.asarray(c, jnp.int32) for c in zip(*rows))
+    table = jnp.asarray(rng.permutation(len(rows) * maxp)
+                        .reshape(len(rows), maxp), jnp.int32)
+    num_pages = len(rows) * maxp
+    q = jnp.asarray(rng.standard_normal((len(rows), l, h, d_k)), q_dtype)
+    if form == "latent":
+        args = (jnp.asarray(rng.standard_normal((num_pages, ps, d_k)),
+                            pool_dtype), None, None, None)
+        kw = dict(value_dim=d_v)
+    else:
+        pools = _pools(rng, kv_h, d_k, ps, pool_dtype, num_pages)
+        args = (pools["k_pages"], pools["v_pages"][..., :d_v],
+                pools.get("k_scale"), pools.get("v_scale"))
+        kw = {}
+    got = paged_prefill(q, *args, table, start, count, scale=d_k ** -0.5,
+                        interpret=True, **kw)
+    want = _grid_form(q, *args, table, start, count, scale=d_k ** -0.5,
+                      **kw)
+    assert got.shape == want.shape == (len(rows), l, h, d_v)
+    assert got.dtype == want.dtype == q_dtype
+    got, want = (np.asarray(o, np.float32) for o in (got, want))
+    valid = np.arange(l)[None] < np.asarray(count)[:, None]
+    assert valid.sum() and np.isfinite(got).all()
+    assert np.array_equal(got[valid], want[valid])
+    assert np.array_equal(got, want)
+
+
+def test_the_grid_is_the_live_steps_of_the_dispatch():
+    """3 live rows in a 16-row bucket over slots of 12 pages: the
+    kernel's one grid axis is bound by the list's count -- the live
+    rows' pages and one page a padding row -- where the (rows, tiles,
+    max_pages) form walked 192 steps."""
+    ps, maxp, l, h, d = 16, 12, 8, 4, 16
+    start = jnp.asarray([5 * ps, ps + 3, 9 * ps + 8] + [0] * 13, jnp.int32)
+    count = jnp.asarray([l, l, 1] + [0] * 13, jnp.int32)
+    table = jnp.arange(16 * maxp, dtype=jnp.int32).reshape(16, maxp)
+    q = jnp.ones((16, l, h, d), jnp.float32)
+    pool = jnp.ones((16 * maxp, ps, h, d), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda *a: paged_prefill(
+        *a, scale=1.0, interpret=True))(q, pool, pool, None, None, table,
+                                        start, count)
+    calls = [e for e in jaxpr.jaxpr.eqns[-1].params["jaxpr"].eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    grid = calls[0].params["grid_mapping"].grid
+    assert len(grid) == 1 and not isinstance(grid[0], int)   # dynamic
+    last = jnp.where(count > 0, start + count - 1, 0)
+    *_, n = _live_steps(table, start, last, 8, 1, ps)
+    assert int(n[0]) == (6 + 2 + 10) + 13 < 16 * maxp
+
+
 # ------------------------------------------------------- the decision
 
 def _mesh_2x4():
@@ -232,13 +492,36 @@ def test_served_prefill_takes_the_path_health_reports(mode, path):
                          for t in texts)
 
 
-def _prefill_program_texts(engine):
-    """The lowered text of every prefill signature the engine
-    dispatched, from its comm-ledger capture."""
+def _prefill_program_texts(engine, compiled=False):
+    """The lowered (or compiled) text of every prefill signature the
+    engine dispatched, from its comm-ledger capture."""
     out = []
     for (name, _, _), (fn, specs, statics) in engine._comm_capture.items():
         if name == "prefill":
             with engine._serving_scope():
-                out.append(getattr(engine, fn).lower(*specs, *statics)
-                           .as_text())
+                low = getattr(engine, fn).lower(*specs, *statics)
+            out.append((low.compile() if compiled else low).as_text())
     return out
+
+
+def test_a_prefill_program_computes_the_work_list_once_for_its_layers():
+    """Nothing in the list depends on a layer, so the compiled prefill
+    program of a two-layer model holds the list's search as often as a
+    one-layer model's: once, whatever the number of kernel calls."""
+    mentions = {}
+    for layers in (1, 2):
+        engine = deepspeed_tpu.init_inference(
+            model=GPT2(dataclasses.replace(gpt2_tiny(), num_layers=layers)),
+            dtype="float32", kv_cache_dtype="float32",
+            mesh={"data": 1, "model": 1}, paged_kernel="force")
+        engine.init_params()
+        sched = ServingScheduler(engine, num_slots=2, num_pages=12,
+                                 page_size=16, max_pages_per_slot=6,
+                                 prefill_chunk=8, comm_telemetry=True)
+        sched.submit(np.arange(1, 20, dtype=np.int32), max_new_tokens=2)
+        sched.run()
+        low, = _prefill_program_texts(engine)
+        assert low.count("call @paged_prefill") == layers
+        text, = _prefill_program_texts(engine, compiled=True)
+        mentions[layers] = text.count("searchsorted")
+    assert mentions[1] == mentions[2] > 0

@@ -524,6 +524,9 @@ def test_prefix_cache_and_preemption_run_over_latent_pages(engine, served):
         9 * 8 * s["kv_stored_bytes_per_token"]
     assert s["state_pool_bytes"] == 0 and s["prefix_cache_refused"] == 0
     assert s["decode_kv_tokens"] > 0 and s["prefill_kv_pairs"] > 0
+    # prompts of at most 33 tokens in slots of 48: the dispatches never
+    # reach the whole of their page tables
+    assert 0 < s["prefill_live_page_share"] < 1
     assert 0 < s["moe_held_assignments"] < s["moe_assignments"]
     h = sched.health()
     assert h["paged_attention"]["heads"] == [4, 1]

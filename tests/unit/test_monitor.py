@@ -253,6 +253,31 @@ def test_decode_live_page_share_is_live_pages_over_the_tables_walked():
         round(125 / (20 * 24), 4)
 
 
+def test_prefill_live_page_share_is_live_pages_over_the_tables_dispatched():
+    """Hand-made dispatches over slots of 6 pages: the share is the
+    (row, page) entries the rows' chunks reach -- prompt rows, riders
+    and one page a padding row -- over padded rows x 6; None before any
+    dispatch, and a decode horizon leaves it alone."""
+    m = ServingMetrics(None)
+    assert m.summary()["prefill_live_page_share"] is None
+    m.record_horizon(1, 8, 16, 0.0, live_pages=29, table_pages=24)
+    assert m.summary()["prefill_live_page_share"] is None
+    # 3 prompt rows in a bucket of 4: chunks ending in pages 1, 3 and 6,
+    # and the padding row's one page
+    m.record_prefill_dispatch(1, rows=3, padded_rows=4, tokens=24,
+                              live_pages=1 + 3 + 6 + 1, table_pages=4 * 6)
+    assert m.summary()["prefill_live_page_share"] == round(11 / 24, 4)
+    # 2 prompt rows and 2 riders deep in their slots fill a bucket of 4
+    m.record_prefill_dispatch(2, rows=2, padded_rows=4, tokens=16,
+                              riders=2, live_pages=2 + 4 + 5 + 6,
+                              table_pages=4 * 6)
+    s = m.summary()
+    assert s["ride_rows"] == 2 and s["prefill_dispatches"] == 2
+    assert s["prefill_live_page_share"] == round((11 + 17) / 48, 4)
+    # the decode counter beside it is its own
+    assert s["decode_live_page_share"] == round(29 / (8 * 24), 4)
+
+
 @pytest.mark.parametrize("dispatches,by_bucket,pad_share", [
     ([], {}, 0.0),
     ([(20, 32)], {"32": 1}, 12 / 32),

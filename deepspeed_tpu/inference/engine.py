@@ -335,6 +335,39 @@ class InferenceEngine:
         return dict(decide(), multi_token=decide(multi_token=True),
                     heads=[heads, kv_heads])
 
+    def prefill_key_block_counter(self, pools, chunk):
+        """How the ``paged_prefill`` kernel walks a ``[rows, chunk]``
+        prefill dispatch over ``pools``, as a function ``(starts,
+        counts) -> dict`` of ``record_prefill_dispatch``'s page
+        counters (``ops/attention/paged_prefill.count_key_blocks`` at
+        this engine's geometry: per-shard head counts where the kernel
+        runs under ``shard_map``); None for a model without a page
+        pool.  It is the kernel's own plan whichever path the decision
+        takes, as ``prefill_live_page_share`` always was."""
+        from deepspeed_tpu.ops.attention import decode as _decode_ops
+        from deepspeed_tpu.ops.attention import paged_prefill as _pp
+        from deepspeed_tpu.ops.quant.kv import page_leaf
+        layers = pools.get("layers") if isinstance(pools, dict) else ()
+        # a hybrid's first blocks may hold state, not pages
+        leaf = next((page_leaf(L) for L in layers or ()
+                     if page_leaf(L) is not None), None)
+        heads, kv_heads = self._model_head_counts()
+        if leaf is None or not heads:
+            return None
+        # leaf: [pages, page_size, (kv_heads,) d]
+        kv_heads = 1 if leaf.ndim == 3 else kv_heads or heads
+        with self._serving_scope():
+            head_ax, _ = _decode_ops._shard_map_axes(
+                self.mesh, 1, heads, kv_heads)
+        shards = int(self.mesh.shape[head_ax]) if head_ax else 1
+        cols, tiles, block = _pp.key_block_plan(
+            chunk, heads // shards, kv_heads // shards, int(leaf.shape[1]),
+            int(leaf.shape[-1]), jnp.dtype(self.dtype).itemsize,
+            leaf.dtype.itemsize)
+        return functools.partial(
+            _pp.count_key_blocks, page_size=int(leaf.shape[1]), cols=cols,
+            tiles=tiles, block=block)
+
     def serving_mesh_info(self, pools=None, num_slots=None):
         """Mesh topology + serving-sharding snapshot for operators
         (``bin/ds_serve`` startup log and ``health()``): per-axis mesh

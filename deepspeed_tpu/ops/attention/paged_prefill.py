@@ -10,15 +10,35 @@ bias and float32 scores in HBM, all sized by the slot's CAPACITY — never
 exists: a row costs its LIVE pages.
 
 Design:
-  * grid = the dispatch's LIVE (row, q tile, page) steps, row-major,
-    tile-major, page-minor (:func:`_live_steps`, scalar-prefetched; its
-    length is the grid's dynamic bound, as ``_live_pairs`` is the decode
-    kernel's): tile t of row r lists the pages up to the last position
-    it may see and no more, so a dispatch walks the pages its rows hold
-    and not ``rows * tiles * max_pages``.  Pages stay innermost, so the
-    online-softmax state (m, l, acc; float32) lives in VMEM scratch
-    across a tile's pages, as in the decode kernel and flash.py: reset
-    at a tile's page 0, written out at its last listed page.
+  * a grid step is a KEY BLOCK: one q tile of one row against ``block``
+    consecutive pages of the row's table (:func:`_block_pages`; 8 pages
+    of 128 tokens for most geometries), each page its own ``BlockSpec``
+    of the same pool operand, laid side by side in VMEM.  The online-
+    softmax update — two cross-lane reductions, ``alpha``, the float32
+    accumulator's rescale and store — happens once a block, and ``p · v``
+    is one contraction over the block's keys, so the sum over its pages
+    is the MXU's.  (At one page a step the two reductions alone were
+    over half of a step: PERF.md section 6, PR 56.)
+  * grid = the dispatch's LIVE steps, row-major, tile-major, key-minor
+    (:func:`_live_steps`, scalar-prefetched; its length is the grid's
+    dynamic bound, as ``_live_pairs`` is the decode kernel's): tile t of
+    row r lists the pages up to the last position it may see and no
+    more.  Keys stay innermost, so the online-softmax state (m, l, acc;
+    float32) lives in VMEM scratch across a tile's steps, as in the
+    decode kernel and flash.py: reset at a tile's page 0, written out at
+    the step that holds its last page.
+  * a tile holds ``_last_page + 1`` pages, not a multiple of ``block``
+    (:func:`_tile_steps`): a tail of one page or under half a block is
+    WALKED, one page a step through the same body at one page's width
+    (a padding row's one page, a short prompt's two); a longer tail is
+    one more block whose dead pages the causal mask hides.  A page slot
+    that a step does not read keeps the id it last held, so it costs no
+    DMA and never names a page the dispatch does not hold.
+  * ``block`` comes from the shapes alone: about 1,024 keys, 512 where
+    a key is 512 features or wider (there the two matmuls bind at 512
+    and a longer block only lengthens tails), halved while the block's
+    buffers would outgrow :data:`VMEM_BUDGET`; one page a step where
+    nothing larger fits.
   * q arrives regrouped per kv head, [rows, kv_heads, l * group, d]
     (row ``j * group + g`` of a kv head's tile is column j of query head
     ``kv * group + g``): one MXU-shaped tile per kv head — Mistral's
@@ -26,7 +46,7 @@ Design:
     whose tile would outgrow VMEM is split over the q-tile grid axis.
   * live pages only: a page is listed iff its first position is <=
     the last position the tile may see, so a page past it costs no DMA
-    and no grid step; the next step's page is in flight while this one
+    and no grid step; the next step's pages are in flight while this one
     is computed, across tile and row boundaries too.  A padding row
     (``count == 0``) lists ONE step, position 0's page, so every output
     block is written (finite, meaningless) and none needs a zero fill.
@@ -41,6 +61,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -49,38 +70,104 @@ from deepspeed_tpu.ops.attention.decode import (_segment_entries,
 from deepspeed_tpu.ops.attention.flash import NEG_INF
 from deepspeed_tpu.ops.quant.kv import LATENT_LEAF
 
+BLOCK_KEYS = 1024           # keys a grid step, at most 8 pages
+WIDE_KEY = 512              # a key this wide: BLOCK_KEYS // 2
+VMEM_LIMIT = 48 << 20       # the call's scoped-VMEM limit (v5e: 128 MiB)
+VMEM_BUDGET = 32 << 20      # what _vmem_bytes may count of it
 
-def _last_page(start, last, ti, cols, page_size, maxp):
+
+def _last_page(start, last, ti, cols, page_size, maxp, minimum=jnp.minimum):
     """Index, in its row's table, of the last page q tile ``ti`` of a
     row may attend to: the page of the row's last WRITTEN position
     ``last``, or of the tile's own last column if that comes first."""
-    return jnp.minimum(
-        jnp.minimum(last, start + (ti + 1) * cols - 1) // page_size,
-        maxp - 1)
+    return minimum(
+        minimum(last, start + (ti + 1) * cols - 1) // page_size, maxp - 1)
 
 
-def _live_steps(page_table, start, last, cols, tiles, page_size):
+def _tail_walked(tail, block):
+    """Whether the ``tail`` pages a tile holds past its last whole block
+    are walked a page a step: one page, or under half a block."""
+    return 2 * tail < max(block, 3)
+
+
+def _tile_steps(live, block):
+    """(key blocks, one-page steps) of a tile that holds ``live`` pages:
+    its whole blocks, and the ``live % block`` pages left over walked
+    page by page (:func:`_tail_walked`) or as one more block, masked
+    past the last live page.  numpy or jnp integers."""
+    tail = live % block
+    walked = _tail_walked(tail, block)
+    return live // block + 1 - walked, tail * walked
+
+
+def _is_block(k, live, block):
+    """Whether the step that starts at a tile's page ``k`` is a whole
+    block: :func:`_tile_steps`, asked of one step."""
+    return (k + block <= live) | \
+        jnp.logical_not(_tail_walked(live % block, block))
+
+
+def _live_steps(page_table, start, last, cols, tiles, page_size, block):
     """The kernel's grid, from the dispatch's own inputs: the live
-    (row, q tile, page) steps in row-major, tile-major, page-minor
-    order.  Tile t of row r holds pages ``0 ... _last_page(r, t)``;
-    ``last`` is 0 for a padding row, which therefore holds page 0 alone
-    (one step a tile: its output block is written like any other).
-    Returns three int32 lists of the grid's capacity, ``rows * tiles *
-    max_pages`` -- entry i of ``tile`` is ``r * tiles + t`` of the i-th
-    live step, of ``k`` its page's index in the row's table, of
-    ``pages`` that page's id (0 past the live entries) -- and ``n``
-    int32 [1], how many entries are live: at least one a tile.  Nothing
+    (row, q tile, key block) steps in row-major, tile-major, key-minor
+    order.  Tile t of row r holds pages ``0 ... _last_page(r, t)`` in
+    the steps :func:`_tile_steps` counts, blocks first; ``last`` is 0
+    for a padding row, which therefore holds page 0 alone (one step a
+    tile: its output block is written like any other).  Returns int32
+    lists of the grid's capacity ``cap`` = tiles x the most steps a
+    tile can hold -- entry i of ``tile`` is ``r * tiles + t`` of the
+    i-th live step, of ``k`` its first page's index in the row's table,
+    and entry ``j * cap + i`` of ``pages`` the id in the step's page
+    slot j -- and ``n`` int32 [1], how many entries are live: at least
+    one a tile.  Slot 0 holds a one-page step's page; a slot the step
+    does not read (1.. of a one-page step, the dead end of a masked
+    block, any slot past the live entries) repeats the id it last held:
+    no DMA, and a page the dispatch holds (page 0 before any).  Nothing
     here depends on a layer, so XLA computes it once a dispatch for all
-    of them.  The lists ride in scalar memory: 256 rows x 256 pages
-    still compile for a v5e, 256 x 512 do not."""
+    of them.  The lists ride in scalar memory."""
     maxp = page_table.shape[1]
     t = jnp.arange(tiles, dtype=jnp.int32)
     live = _last_page(start[:, None], last[:, None], t[None], cols,
                       page_size, maxp).reshape(-1) + 1
-    tile, k, n = _segment_entries(live, maxp)
-    pages = jnp.where(jnp.arange(tile.shape[0]) < n[0],
-                      page_table[tile // tiles, k], 0)
-    return tile, k, pages, n
+    blocks, singles = _tile_steps(live, block)
+    width = int(sum(_tile_steps(np.arange(1, maxp + 1), block)).max())
+    tile, e, n = _segment_entries((blocks + singles).astype(jnp.int32),
+                                  width)
+    # entry e of a tile: its blocks, then the walked tail page by page
+    k = e + jnp.minimum(e, blocks[tile]) * (block - 1)
+    i = jnp.arange(tile.shape[0], dtype=jnp.int32)
+    listed = i < n[0]
+    # slot j of step i reads page k + j: slot 0 always, the others in a
+    # block as far as the tile's pages go; else it keeps the last read
+    j = jnp.arange(block, dtype=jnp.int32)[:, None]
+    reads = listed & ((j == 0) | (_is_block(k, live[tile], block) &
+                                  (k + j < live[tile])))
+    src = jax.lax.cummax(jnp.where(reads, i, 0), axis=1)
+    first = (tile // tiles) * maxp + k        # into the flattened table
+    pages = jnp.where((src > 0) | reads[:, :1],
+                      page_table.reshape(-1)[first[src] + j], 0)
+    return tile, k, pages.reshape(-1), n
+
+
+def count_key_blocks(start, count, *, max_pages, page_size, cols, tiles,
+                     block):
+    """What :func:`_live_steps` lists for a dispatch whose row r holds
+    ``count[r]`` columns from position ``start[r]`` (0 columns: a
+    padding row), counted on the host (numpy) under the names
+    ``ServingMetrics.record_prefill_dispatch`` takes: ``live_pages``
+    the (row, tile, page) entries the tiles may see, of ``table_pages``
+    = rows x tiles x ``max_pages``; ``key_blocks`` the grid steps that
+    walk them and ``block_pages`` the pages those steps compute (==
+    ``live_pages`` unless a masked tail block ran; ``key_blocks`` ==
+    ``live_pages`` at one page a step)."""
+    start, count = np.asarray(start, np.int64), np.asarray(count, np.int64)
+    last = np.where(count > 0, start + count - 1, 0)
+    live = _last_page(start[:, None], last[:, None], np.arange(tiles)[None],
+                      cols, page_size, max_pages, np.minimum) + 1
+    blocks, singles = _tile_steps(live, block)
+    return dict(live_pages=int(live.sum()), table_pages=live.size * max_pages,
+                key_blocks=int((blocks + singles).sum()),
+                block_pages=int((blocks * block + singles).sum()))
 
 
 def _row_and_tile(tile, tiles):
@@ -92,27 +179,32 @@ def _row_and_tile(tile, tiles):
 
 
 def _paged_prefill_kernel(tile_ref, k_idx_ref, page_ref, start_ref, last_ref,
-                          q_ref, k_ref, *rest, scale, page_size, group,
-                          maxp, tiles, quantized, value_dim=None):
-    """One grid step: one q tile of one row, ALL kv heads, against ONE
-    cache page; the grid is the dispatch's live steps
-    (:func:`_live_steps`), so every step computes.  Blocks span the
-    pool's trailing (kv_heads, d) dims whole (see
+                          q_ref, *rest, scale, page_size, group, maxp, tiles,
+                          block, quantized, value_dim=None):
+    """One grid step: one q tile of one row, ALL kv heads, against one
+    KEY BLOCK -- ``block`` consecutive pages of the row, or one page of
+    a walked tail; the grid is the dispatch's live steps
+    (:func:`_live_steps`), so every step computes.  ``rest`` holds
+    ``block`` refs an operand (K, V, their scales), one a page slot.
+    Blocks span the pool's trailing (kv_heads, d) dims whole (see
     ``_paged_decode_kernel``); the per-kv-head products are
-    leading-batch dots.  ``value_dim`` marks a latent pool: no
-    ``v_ref``, the value is the leading ``value_dim`` features of the K
-    block (one DMA a page), as in the decode kernel."""
+    leading-batch dots.  ``value_dim`` marks a latent pool: no V refs,
+    the value is the leading ``value_dim`` features of the K block (one
+    DMA a page), as in the decode kernel."""
+    k_refs, rest = rest[:block], rest[block:]
     if value_dim is None:
-        v_ref, rest = rest[0], rest[1:]
+        v_refs, rest = rest[:block], rest[block:]
     if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, m_scr, l_scr, acc_scr = rest
+        ks_refs, vs_refs, rest = rest[:block], rest[block:2 * block], \
+            rest[2 * block:]
+    o_ref, m_scr, l_scr, acc_scr = rest
     i = pl.program_id(0)
     ri, ti = _row_and_tile(tile_ref[i], tiles)
     ki = k_idx_ref[i]
     tq = q_ref.shape[2]
     cols = tq // group
+    live = _last_page(start_ref[ri], last_ref[ri], ti, cols, page_size,
+                      maxp) + 1
 
     @pl.when(ki == 0)
     def _init():
@@ -120,49 +212,65 @@ def _paged_prefill_kernel(tile_ref, k_idx_ref, page_ref, start_ref, last_ref,
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    # position 0 is live for every row, so a tile's page 0 is always
-    # listed and the statistics are finite from the first page on
-    q = q_ref[0]                                          # [kv_h, tq, d]
-    if value_dim is not None:
-        k = k_ref[...]                                    # [1, ps, d]
-        v = k[:, :, :value_dim]
+    def side_by_side(refs, w, axis):
+        parts = [r[...] if value_dim is not None else r[0]
+                 for r in refs[:w]]
+        return parts[0] if w == 1 else jnp.concatenate(parts, axis=axis)
+
+    def update(w):
+        """The online-softmax update over the step's first ``w`` pages.
+        Position 0 is live for every row, so a tile's page 0 is always
+        listed and the statistics are finite from the first step on."""
+        keys = w * page_size
+        q = q_ref[0]                                      # [kv_h, tq, d]
+        if value_dim is not None:
+            k = side_by_side(k_refs, w, 1)                # [1, keys, d]
+            v = k[:, :, :value_dim]
+        else:
+            k = side_by_side(k_refs, w, 0)                # [keys, kv_h, d]
+            v = side_by_side(v_refs, w, 0)
+            if quantized:
+                k = (k.astype(jnp.float32) * side_by_side(ks_refs, w, 0)
+                     .astype(jnp.float32)).astype(q.dtype)
+                v = (v.astype(jnp.float32) * side_by_side(vs_refs, w, 0)
+                     .astype(jnp.float32)).astype(q.dtype)
+            k = k.transpose(1, 0, 2)                      # [kv_h, keys, d]
+            v = v.transpose(1, 0, 2)
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale   # [kv_h, tq, keys]
+        # tile row r is column r // group: key position p is visible iff
+        # p <= start + col, i.e. (p - first column's position) * group
+        # <= r — no integer division in-kernel
+        k_rel = ki * page_size - start_ref[ri] - ti * cols + \
+            jax.lax.broadcasted_iota(jnp.int32, (1, 1, keys), 2)
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, tq, 1), 1)
+        s = jnp.where(k_rel * group <= row, s, NEG_INF)
+
+        m_prev = m_scr[:, :, :1]                          # [kv_h, tq, 1]
+        l_prev = l_scr[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)           # [kv_h, tq, d_v]
+        acc_scr[:] = acc_scr[:] * alpha + pv
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    if block == 1:
+        update(1)
+        pages = 1
     else:
-        k = k_ref[0]                                      # [ps, kv_h, d]
-        v = v_ref[0]
-        if quantized:
-            k = (k.astype(jnp.float32) *
-                 ks_ref[0].astype(jnp.float32)).astype(q.dtype)
-            v = (v.astype(jnp.float32) *
-                 vs_ref[0].astype(jnp.float32)).astype(q.dtype)
-        k = k.transpose(1, 0, 2)                          # [kv_h, ps, d]
-        v = v.transpose(1, 0, 2)
-    s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale       # [kv_h, tq, ps]
-    # tile row r is column r // group: key position p is visible iff
-    # p <= start + col, i.e. (p - first column's position) * group
-    # <= r — no integer division in-kernel
-    k_rel = ki * page_size - start_ref[ri] - ti * cols + \
-        jax.lax.broadcasted_iota(jnp.int32, (1, 1, page_size), 2)
-    row = jax.lax.broadcasted_iota(jnp.int32, (1, tq, 1), 1)
-    s = jnp.where(k_rel * group <= row, s, NEG_INF)
+        wide = _is_block(ki, live, block)
+        pl.when(wide)(lambda: update(block))
+        pl.when(jnp.logical_not(wide))(lambda: update(1))
+        pages = jnp.where(wide, block, 1)
 
-    m_prev = m_scr[:, :, :1]                              # [kv_h, tq, 1]
-    l_prev = l_scr[:, :, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_new = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
-    pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)               # [kv_h, tq, d]
-    acc_scr[:] = acc_scr[:] * alpha + pv
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    # the tile's last listed page
-    @pl.when(ki == _last_page(start_ref[ri], last_ref[ri], ti, cols,
-                              page_size, maxp))
+    # the step that holds the tile's last page
+    @pl.when(ki + pages >= live)
     def _finalize():
         o_ref[0] = (acc_scr[:] / l_scr[:, :, :1]).astype(o_ref.dtype)
 
@@ -170,11 +278,55 @@ def _paged_prefill_kernel(tile_ref, k_idx_ref, page_ref, start_ref, last_ref,
 def _tile_cols(l, kv_h, group):
     """(columns a q tile, padded chunk length): columns are a multiple
     of 8 (the float32 sublane tile, so ``cols * group`` rows tile for
-    any group) and one float32 [kv_h, rows, 128] buffer — the scores,
-    the accumulator, each statistic — stays within 1 MiB."""
+    any group) and one float32 [kv_h, rows, 128] buffer — a page's
+    scores, the accumulator, each statistic — stays within 1 MiB."""
     cap = max(8, (2048 // (kv_h * group)) // 8 * 8)
     cols = min(cap, -(-l // 8) * 8)
     return cols, -(-l // cols) * cols
+
+
+def _vmem_bytes(block, page_size, rows, kv_h, d, itemsize, pool_itemsize):
+    """What a step of ``block`` pages keeps in VMEM, counted from the
+    shapes (``rows`` = kv heads x a q tile's rows; a value as wide as a
+    key, which it never exceeds here): float32 scores and P, the pages
+    double-buffered and once more side by side (in float32 too where
+    they are dequantised), the q and output tiles double-buffered, the
+    three scratches.  The compiler's own count at the cells' geometries
+    is under this (PERF.md section 6, PR 56)."""
+    keys = block * page_size
+    kv = 2 * kv_h * keys * d
+    return (rows * keys * (4 + itemsize)
+            + kv * (2 * pool_itemsize + itemsize
+                    + 4 * (pool_itemsize != itemsize))
+            + 4 * rows * d * itemsize
+            + rows * (2 * 128 + d) * 4)
+
+
+def _block_pages(page_size, rows, kv_h, d, itemsize, pool_itemsize):
+    """Pages a grid step, from the shapes: :data:`BLOCK_KEYS` keys (half
+    that for a key of :data:`WIDE_KEY` features or more) in at most 8
+    pages, halved while :func:`_vmem_bytes` is over
+    :data:`VMEM_BUDGET`; 1 where no larger block fits."""
+    keys = BLOCK_KEYS // 2 if d >= WIDE_KEY else BLOCK_KEYS
+    block = max(1, min(8, keys // page_size))
+    while block > 1 and _vmem_bytes(block, page_size, rows, kv_h, d,
+                                    itemsize, pool_itemsize) > VMEM_BUDGET:
+        block //= 2
+    return block
+
+
+def key_block_plan(chunk, heads, kv_heads, page_size, d, itemsize,
+                   pool_itemsize):
+    """(columns a q tile, q tiles, pages a key block) of the kernel call
+    for a ``[rows, chunk]`` dispatch of ``heads`` query heads over a
+    pool of ``kv_heads`` heads (1 for a latent pool) whose keys are
+    ``d`` wide: what :func:`paged_prefill` will run, for whoever counts
+    its steps (:func:`count_key_blocks`)."""
+    group = heads // kv_heads
+    cols, l_pad = _tile_cols(chunk, kv_heads, group)
+    return cols, l_pad // cols, _block_pages(
+        page_size, kv_heads * cols * group, kv_heads, d, itemsize,
+        pool_itemsize)
 
 
 @functools.partial(jax.jit,
@@ -200,8 +352,10 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
     maxp = page_table.shape[1]
     group = h // kv_h
     quantized = k_scale is not None
-    cols, l_pad = _tile_cols(l, kv_h, group)
-    tq = cols * group
+    cols, tiles, block = key_block_plan(l, h, kv_h, page_size, d,
+                                        q.dtype.itemsize,
+                                        k_pages.dtype.itemsize)
+    tq, l_pad = cols * group, cols * tiles
     # [b, l, h, d] -> [b, kv_h, l_pad * group, d]: head kv*group + g is
     # kv head kv's g-th query head (the _repeat_kv grouping)
     q_g = jnp.pad(q, ((0, 0), (0, l_pad - l), (0, 0), (0, 0))) \
@@ -211,34 +365,37 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
     # a padding row (count == 0) sees position 0 alone: finite, unused
     last = jnp.where(count > 0, start + count.astype(jnp.int32) - 1, 0)
 
-    tiles = l_pad // cols
     tile, k_idx, pages, n = _live_steps(page_table.astype(jnp.int32), start,
-                                        last, cols, tiles, page_size)
-
-    def page_index(i, tile, k_idx, pages, st, ls):
-        return (pages[i], 0, 0, 0)
+                                        last, cols, tiles, page_size, block)
+    cap = tile.shape[0]
 
     def tile_index(i, tile, k_idx, pages, st, ls):
         ri, ti = _row_and_tile(tile[i], tiles)
         return (ri, 0, ti, 0)
 
-    q_spec = pl.BlockSpec((1, kv_h, tq, d), tile_index)
+    def page_slots(shape):
+        """``block`` views of one pool operand, slot j at the id the
+        list holds for it."""
+        zeros = (0,) * (len(shape) - 1)
+        return [pl.BlockSpec(shape, lambda i, tile, k_idx, pages, st, ls,
+                             j=j: (pages[j * cap + i],) + zeros)
+                for j in range(block)]
+
+    in_specs, operands = [pl.BlockSpec((1, kv_h, tq, d), tile_index)], [q_g]
     if value_dim is not None:
-        in_specs = [q_spec, pl.BlockSpec(
-            (1, page_size, d), lambda *a: page_index(*a)[:3])]
-        operands = [q_g, k_pages]
+        pools = [((1, page_size, d), k_pages)]
     else:
-        in_specs = [q_spec,
-                    pl.BlockSpec((1, page_size, kv_h, d), page_index),
-                    pl.BlockSpec((1, page_size, kv_h, d_v), page_index)]
-        operands = [q_g, k_pages, v_pages]
+        pools = [((1, page_size, kv_h, d), k_pages),
+                 ((1, page_size, kv_h, d_v), v_pages)]
     if quantized:
-        scale_spec = pl.BlockSpec((1, page_size, kv_h, 1), page_index)
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
+        pools += [((1, page_size, kv_h, 1), k_scale),
+                  ((1, page_size, kv_h, 1), v_scale)]
+    for shape, pool in pools:
+        in_specs += page_slots(shape)
+        operands += [pool] * block
     kernel = functools.partial(_paged_prefill_kernel, scale=scale,
                                page_size=page_size, group=group, maxp=maxp,
-                               tiles=tiles, quantized=quantized,
+                               tiles=tiles, block=block, quantized=quantized,
                                value_dim=value_dim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
@@ -254,6 +411,7 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec, name="paged_prefill",
         out_shape=jax.ShapeDtypeStruct(q_g.shape[:3] + (d_v,), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(tile, k_idx, pages, start, last, *operands)
     return out.reshape(b, kv_h, l_pad, group, d_v).transpose(0, 2, 1, 3, 4) \

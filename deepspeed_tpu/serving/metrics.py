@@ -132,6 +132,11 @@ class ServingMetrics:
         # kernel's work list holds) and padded rows x pages a slot
         self.prefill_live_pages = 0
         self.prefill_table_pages = 0
+        # and of the kernel's grid: the steps it walks (key blocks of
+        # several pages, one-page steps of a walked tail) and the pages
+        # those steps compute (a masked tail block computes dead ones)
+        self.prefill_key_blocks = 0
+        self.prefill_block_pages = 0
         # what a token costs in pages and a slot in rings (gauges)
         self.kv_paged_bytes_per_token = 0
         self.kv_window_bytes_per_slot = 0
@@ -211,7 +216,8 @@ class ServingMetrics:
 
     def record_prefill_dispatch(self, step, *, rows, padded_rows, tokens,
                                 kv_tokens=0, kv_pairs=0, riders=0,
-                                live_pages=0, table_pages=0):
+                                live_pages=0, table_pages=0, key_blocks=0,
+                                block_pages=0):
         """One shared prefill dispatch carried the next chunk of
         ``rows`` prefilling slots (``tokens`` prompt tokens) in a
         ``padded_rows``-row bucket, and beside them the next token of
@@ -220,7 +226,9 @@ class ServingMetrics:
         keys of the paged layers and scored ``kv_pairs`` (query, key)
         pairs, on ``live_pages`` (row, page) entries of the
         ``table_pages`` = ``padded_rows`` x pages a slot that the
-        dispatch's page table holds."""
+        dispatch's page table holds; the ``paged_prefill`` kernel walks
+        them in ``key_blocks`` grid steps that compute ``block_pages``
+        pages (``ops/attention/paged_prefill.count_key_blocks``)."""
         self.prefill_dispatches += 1
         self.ride_rows += int(riders)
         self.ride_dispatches += riders > 0
@@ -228,6 +236,8 @@ class ServingMetrics:
         self.prefill_kv_pairs += int(kv_pairs)
         self.prefill_live_pages += int(live_pages)
         self.prefill_table_pages += int(table_pages)
+        self.prefill_key_blocks += int(key_blocks)
+        self.prefill_block_pages += int(block_pages)
         self.prefill_rows += rows
         self.prefill_padded_rows += padded_rows
         self.prefill_by_bucket[padded_rows] += 1
@@ -783,6 +793,12 @@ class ServingMetrics:
             "prefill_live_page_share":
             round(self.prefill_live_pages / self.prefill_table_pages, 4)
             if self.prefill_table_pages else None,
+            "prefill_key_block_fill_share":
+            round(self.prefill_live_pages / self.prefill_block_pages, 4)
+            if self.prefill_block_pages else None,
+            "prefill_pages_per_step":
+            round(self.prefill_live_pages / self.prefill_key_blocks, 4)
+            if self.prefill_key_blocks else None,
             "state_resets": self.state_resets,
             "prefix_cache_refused": self.prefix_cache_refused,
             "moe_assignments": self.moe_assignments,

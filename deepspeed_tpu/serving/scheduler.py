@@ -624,6 +624,11 @@ class ServingScheduler:
             engine, "latent_bytes_per_token", lambda: (0, 0))())
         if self.prefix_cache_refused is not None:
             self.metrics.record_prefix_refused()
+        # the paged_prefill kernel's own count of a dispatch's pages
+        # and grid steps (None: no page pool)
+        self._count_key_blocks = getattr(
+            engine, "prefill_key_block_counter", lambda *_: None)(
+                self.pools, self.prefill_chunk)
         self.step_idx = 0
         self._ema_step_s = None      # EWMA of step wall time (health)
         # admission feasibility uses the MEDIAN of a recent window, not
@@ -2040,18 +2045,20 @@ class ServingScheduler:
                 self.pools, adapter_ids=a_ids, adapters=a_pack)
         # a chunk of n columns from position s reads s + n keys of a
         # paged layer and scores n * s + n * (n + 1) / 2 pairs, on the
-        # pages up to position s + n - 1 (a padding row: one page)
+        # pages up to position s + n - 1 (a padding row: one page), in
+        # the grid steps the kernel's own module counts
         starts = [int(self.lengths[slot]) for slot, _, _ in rows]
+        pad = [0] * (padded - len(rows))
         self.metrics.record_prefill_dispatch(
             self.step_idx, rows=len(rows) - riders, padded_rows=padded,
             tokens=tokens, riders=riders,
             kv_tokens=sum(starts) + tokens + riders,
             kv_pairs=sum(s * len(c) + len(c) * (len(c) + 1) // 2
                          for s, (_, _, c) in zip(starts, rows)),
-            live_pages=sum((s + len(c) - 1) // self.kv.page_size + 1
-                           for s, (_, _, c) in zip(starts, rows))
-            + padded - len(rows),
-            table_pages=padded * self.kv.table.shape[1])
+            **(self._count_key_blocks(
+                starts + pad, [len(c) for _, _, c in rows] + pad,
+                max_pages=self.kv.table.shape[1])
+               if self._count_key_blocks else {}))
         if self.slot_state:
             # a row whose first position is 0 started from zeros
             # whatever its slot held (ops/ssm/state.py)

@@ -22,8 +22,14 @@ from deepspeed_tpu.models.gpt2 import GPT2, gpt2_tiny
 from deepspeed_tpu.ops.attention import kv_cache
 from deepspeed_tpu.ops.attention.decode import (kernel_mode_scope,
                                                 paged_kernel_decision)
-from deepspeed_tpu.ops.attention.paged_prefill import (_live_steps,
+from deepspeed_tpu.ops.attention import paged_prefill as paged_prefill_module
+from deepspeed_tpu.ops.attention.paged_prefill import (VMEM_BUDGET,
+                                                       VMEM_LIMIT,
+                                                       _live_steps,
                                                        _tile_cols,
+                                                       _vmem_bytes,
+                                                       count_key_blocks,
+                                                       key_block_plan,
                                                        paged_prefill)
 from deepspeed_tpu.parallel.topology import make_mesh
 from deepspeed_tpu.runtime.config import MeshConfig
@@ -173,9 +179,94 @@ def _dispatch_rows(ps, maxp, l):
             (cap - l, l), (0, l), (2 * ps - 1, 1), (4 * ps + 9, 0)]
 
 
-def _brute_force_steps(rows, ps, maxp, cols, tiles):
+def _brute_force_steps(rows, ps, maxp, cols, tiles, block):
+    """Every (row, tile) in grid order with its live pages -- those
+    whose first position is at or under the last position the tile may
+    see -- cut into the kernel's steps: whole blocks of ``block`` pages,
+    then the pages left over one a step where they are one page or
+    under half a block, else as one more block.  A step is (row, tile, first page,
+    pages it reads, pages it computes)."""
+    out = []
+    for r, (start, count) in enumerate(rows):
+        last = start + count - 1 if count else 0
+        for t in range(tiles):
+            tile_last = min(last, start + (t + 1) * cols - 1)
+            live = sum(k * ps <= tile_last for k in range(maxp))
+            k = 0
+            while live - k >= block:
+                out.append((r, t, k, block, block))
+                k += block
+            if 2 * (live - k) >= max(block, 3):
+                out.append((r, t, k, live - k, block))
+            else:
+                out += [(r, t, j, 1, 1) for j in range(k, live)]
+    return out
+
+
+@pytest.mark.parametrize("block", [1, 2, 4, 8])
+@pytest.mark.parametrize("ps", [16, 128])
+@pytest.mark.parametrize("l,kv_h,group", [(8, 8, 4), (32, 1, 32),
+                                          (40, 64, 1)],
+                         ids=["one_tile", "latent_group32", "two_tiles"])
+def test_the_work_list_is_the_live_key_blocks_in_grid_order(l, kv_h, group,
+                                                            ps, block):
+    """Every live page of every (row, tile) once, in order, in the slot
+    of the step the kernel reads it in; a slot a step does not read
+    repeats what it last held."""
+    maxp = 19
+    cols, l_pad = _tile_cols(l, kv_h, group)
+    tiles = l_pad // cols
+    assert tiles == (2 if l == 40 else 1)
+    rows = _dispatch_rows(ps, maxp, l) + [(9 * ps + 3, l), (11 * ps, 1)]
+    start, count = (jnp.asarray(c, jnp.int32) for c in zip(*rows))
+    last = jnp.where(count > 0, start + count - 1, 0)
+    table = np.arange(len(rows) * maxp, dtype=np.int32) \
+        .reshape(len(rows), maxp)[:, ::-1] + 3
+    tile, k_idx, pages, n = jax.jit(_live_steps, static_argnums=(3, 4, 5, 6))(
+        jnp.asarray(table), start, last, cols, tiles, ps, block)
+    want = _brute_force_steps(rows, ps, maxp, cols, tiles, block)
+    tile, k_idx, pages = (np.asarray(a) for a in (tile, k_idx, pages))
+    cap = tile.shape[0]
+    assert int(n[0]) == len(want) <= cap < len(rows) * tiles * maxp or \
+        block == 1 and cap == len(rows) * tiles * maxp
+    assert k_idx.shape == (cap,) and pages.shape == (block * cap,)
+    assert list(zip(tile[:len(want)] // tiles, tile[:len(want)] % tiles,
+                    k_idx[:len(want)])) == [s[:3] for s in want]
+    slots = pages.reshape(block, cap)
+    held, read = np.zeros(block, np.int64), []
+    for i, (r, t, k, reads, _) in enumerate(want):
+        for j in range(block):
+            if j < reads:
+                held[j] = table[r, k + j]
+                read.append((r, t, k + j))
+        assert slots[:, i].tolist() == held.tolist(), (i, want[i])
+    # ... which is each (row, tile)'s live pages, each once, in order
+    assert read == [(r, t, k) for r, t, k in _brute_force_pages(
+        rows, ps, maxp, cols, tiles)]
+    assert sum(s[3] for s in want) == len(read)
+    # a padding row holds page 0 alone, a tile, whatever the block; the
+    # row at capacity the whole of its table row
+    for r in (3, 7):
+        assert [s for s in want if s[0] == r] == \
+            [(r, t, 0, 1, 1) for t in range(tiles)]
+    assert sum(s[3] for s in want if s[:2] == (4, tiles - 1)) == maxp
+    # past the live entries nothing is read, and every list still names
+    # entries of the table (the slots what they last held)
+    assert ((0 <= tile) & (tile < len(rows) * tiles)).all()
+    assert (0 <= k_idx).all()
+    assert (slots[:, len(want):] == held[:, None]).all()
+    # the host's count of the same dispatch (the scheduler's counter)
+    assert count_key_blocks(
+        [s for s, _ in rows], [c for _, c in rows], max_pages=maxp,
+        page_size=ps, cols=cols, tiles=tiles, block=block) == dict(
+            live_pages=len(read), table_pages=len(rows) * tiles * maxp,
+            key_blocks=len(want), block_pages=sum(s[4] for s in want))
+
+
+def _brute_force_pages(rows, ps, maxp, cols, tiles):
     """Every (row, tile, page) with the page's first position at or
-    under the last position the tile may see, in grid order."""
+    under the last position the tile may see, in grid order: PR 55's
+    list, and what one page a step still walks."""
     out = []
     for r, (start, count) in enumerate(rows):
         last = start + count - 1 if count else 0
@@ -185,52 +276,54 @@ def _brute_force_steps(rows, ps, maxp, cols, tiles):
     return out
 
 
-@pytest.mark.parametrize("ps", [16, 128])
-@pytest.mark.parametrize("l,kv_h,group", [(8, 8, 4), (32, 1, 32),
-                                          (40, 64, 1)],
-                         ids=["one_tile", "latent_group32", "two_tiles"])
-def test_the_work_list_is_the_live_steps_in_grid_order(l, kv_h, group, ps):
-    maxp = 7
-    cols, l_pad = _tile_cols(l, kv_h, group)
-    tiles = l_pad // cols
-    assert tiles == (2 if l == 40 else 1)
+def test_one_page_a_step_lists_every_live_page():
+    """block == 1 is the list of PR 55: a step a live page."""
+    ps, maxp, l = 16, 7, 8
     rows = _dispatch_rows(ps, maxp, l)
-    start, count = (jnp.asarray(c, jnp.int32) for c in zip(*rows))
-    last = jnp.where(count > 0, start + count - 1, 0)
-    table = np.arange(len(rows) * maxp, dtype=np.int32) \
-        .reshape(len(rows), maxp)[:, ::-1] + 3
-    tile, k_idx, pages, n = jax.jit(_live_steps, static_argnums=(3, 4, 5))(
-        jnp.asarray(table), start, last, cols, tiles, ps)
-    want = _brute_force_steps(rows, ps, maxp, cols, tiles)
-    assert int(n[0]) == len(want) < len(rows) * tiles * maxp
-    tile, k_idx, pages = (np.asarray(a) for a in (tile, k_idx, pages))
-    assert tile.shape == k_idx.shape == pages.shape == \
-        (len(rows) * tiles * maxp,)
-    assert list(zip(tile[:len(want)] // tiles, tile[:len(want)] % tiles,
-                    k_idx[:len(want)])) == want
-    assert pages[:len(want)].tolist() == [table[r, k] for r, _, k in want]
-    # a padding row holds page 0 alone, a tile; the row at capacity the
-    # whole of its table row
-    for r in (3, 7):
-        assert [s for s in want if s[0] == r] == \
-            [(r, t, 0) for t in range(tiles)]
-    assert [k for r, t, k in want if (r, t) == (4, tiles - 1)] == \
-        list(range(maxp))
-    # the tail is never read, and still names entries of the table
-    assert ((0 <= tile) & (tile < len(rows) * tiles)).all()
-    assert ((0 <= k_idx) & (k_idx < maxp)).all()
-    assert not pages[len(want):].any()
+    assert [s[:3] for s in _brute_force_steps(rows, ps, maxp, 8, 1, 1)] == \
+        _brute_force_pages(rows, ps, maxp, 8, 1)
 
 
-def test_rows_at_capacity_list_the_whole_grid():
+@pytest.mark.parametrize("block", [1, 4])
+def test_rows_at_capacity_list_the_whole_grid(block):
     ps, maxp, l = 16, 5, 8
     start = jnp.full(3, maxp * ps - l, jnp.int32)
     table = jnp.arange(3 * maxp, dtype=jnp.int32).reshape(3, maxp)
     tile, k_idx, pages, n = _live_steps(table, start, start + l - 1, 8, 1,
-                                        ps)
-    assert int(n[0]) == 3 * maxp
-    assert np.asarray(tile * maxp + k_idx).tolist() == \
-        np.asarray(pages).tolist() == list(range(3 * maxp))
+                                        ps, block)
+    if block == 1:
+        assert int(n[0]) == 3 * maxp
+        assert np.asarray(tile * maxp + k_idx).tolist() == \
+            np.asarray(pages).tolist() == list(range(3 * maxp))
+    else:       # five pages: a block of four and one page walked
+        assert int(n[0]) == tile.shape[0] == 3 * 2
+        assert np.asarray(k_idx).tolist() == [0, 4] * 3
+        assert np.asarray(pages).reshape(4, 6)[0].tolist() == \
+            [0, 4, 5, 9, 10, 14]
+
+
+@pytest.mark.parametrize("name,args,block", [
+    # chunk 32: heads, kv heads, page size, key width, q and pool bytes
+    ("kanana2-30b.longdoc-batch", (32, 32, 1, 128, 640, 2, 2), 4),
+    ("mistral7b.longprompt-batch", (32, 32, 8, 128, 128, 2, 2), 8),
+    ("mimo-v2-flash.longctx-batch", (32, 64, 4, 128, 256, 2, 2), 8),
+    ("mistral_int8_pool", (32, 32, 8, 128, 128, 2, 1), 8),
+    ("mistral_float32", (32, 32, 8, 128, 128, 4, 4), 4),
+    ("pages_of_16", (8, 32, 8, 16, 128, 2, 2), 8),
+    ("pages_of_512", (32, 32, 8, 512, 128, 2, 2), 2),
+    ("wide_heads_float32", (32, 64, 64, 128, 512, 4, 4), 1),
+])
+def test_the_block_comes_from_the_shapes(name, args, block):
+    """The three closed-loop cells' geometries take what the sweep on
+    the chip found best (PERF.md section 6, PR 56 a); a geometry whose
+    two-page block would outgrow the budget runs a page a step."""
+    cols, tiles, got = key_block_plan(*args)
+    assert (cols, tiles, got) == (32 if args[0] == 32 else 8, 1, block)
+    chunk, heads, kv_h, ps, d, size, pool_size = args
+    fits = [b for b in (1, 2, 4, 8) if _vmem_bytes(
+        b, ps, kv_h * cols * (heads // kv_h), kv_h, d, size, pool_size)
+        <= VMEM_BUDGET]
+    assert block == 1 or block in fits and VMEM_BUDGET < VMEM_LIMIT
 
 
 def _grid_form(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
@@ -358,15 +451,12 @@ FORMS = {      # heads, kv heads, key dim, value dim, chunk, pool, q dtype
 }
 
 
-@pytest.mark.parametrize("form", sorted(FORMS))
-def test_valid_outputs_equal_the_grid_form_to_the_bit(form):
-    """Prompt rows, riders, padding rows and a row at capacity through
-    the live-step kernel and through the (rows, tiles, max_pages) form:
-    every valid position is the same bits (the body is the same
-    arithmetic on the same pages in the same order), and here the
-    padding rows and columns are too."""
+def _against_the_grid_form(form, maxp=7):
+    """(kernel, grid form, valid mask, mask of the tiles that hold one
+    page, block) on prompt rows, riders, padding rows and a row at
+    capacity."""
     h, kv_h, d_k, d_v, l, pool_dtype, q_dtype = FORMS[form]
-    ps, maxp = 16, 7
+    ps = 16
     rng = np.random.default_rng(8)
     rows = _dispatch_rows(ps, maxp, l)
     start, count = (jnp.asarray(c, jnp.int32) for c in zip(*rows))
@@ -383,24 +473,84 @@ def test_valid_outputs_equal_the_grid_form_to_the_bit(form):
         args = (pools["k_pages"], pools["v_pages"][..., :d_v],
                 pools.get("k_scale"), pools.get("v_scale"))
         kw = {}
-    got = paged_prefill(q, *args, table, start, count, scale=d_k ** -0.5,
-                        interpret=True, **kw)
+    # un-jitted, so that a patched rule is the rule this call traces
+    got = paged_prefill.__wrapped__(q, *args, table, start, count,
+                                    scale=d_k ** -0.5, interpret=True, **kw)
     want = _grid_form(q, *args, table, start, count, scale=d_k ** -0.5,
                       **kw)
     assert got.shape == want.shape == (len(rows), l, h, d_v)
     assert got.dtype == want.dtype == q_dtype
+    cols, tiles, block = key_block_plan(
+        l, h, kv_h, ps, d_k, jnp.dtype(q_dtype).itemsize,
+        args[0].dtype.itemsize)
+    pages = {(r, t): 0 for r in range(len(rows)) for t in range(tiles)}
+    for r, t, _, reads, _ in _brute_force_steps(rows, ps, maxp, cols, tiles,
+                                                block):
+        pages[r, t] += reads
+    col = np.arange(l)
+    single = np.asarray([[pages[r, c // cols] == 1 for c in col]
+                         for r in range(len(rows))])
+    valid = col[None] < np.asarray(count)[:, None]
     got, want = (np.asarray(o, np.float32) for o in (got, want))
-    valid = np.arange(l)[None] < np.asarray(count)[:, None]
     assert valid.sum() and np.isfinite(got).all()
-    assert np.array_equal(got[valid], want[valid])
-    assert np.array_equal(got, want)
+    return got, want, valid, single, block
+
+
+def _assert_reassociated(form, got, want, valid, single):
+    """Valid outputs within the stated tolerance of the grid form's, and
+    the same bits in the tiles that hold one page."""
+    scale = np.abs(want[valid]).max()
+    tol = 1e-5 * scale if FORMS[form][6] == jnp.float32 else \
+        2 * 2.0 ** -8 * scale
+    assert np.abs(got - want)[valid].max() <= tol
+    assert np.array_equal(got[single], want[single])
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_valid_outputs_equal_the_grid_form_to_the_bit(form):
+    """Prompt rows, riders, padding rows and a row at capacity through
+    the key-block kernel and through the (rows, tiles, max_pages) form
+    of one page a step.  A block takes its running maximum once for
+    all its pages, so a tile of several steps agrees to float32
+    reassociation and not to the bit: 1e-5 of the largest output in
+    float32 (sums of ~100 products of magnitude one, reordered), two
+    ulps of the largest output where q is bfloat16 (P is rounded to
+    bfloat16 against another maximum, then each side rounds its output
+    once).  A tile that holds ONE page -- a padding row's, a first
+    chunk's -- is the same arithmetic on the same page: the same bits."""
+    got, want, valid, single, block = _against_the_grid_form(form)
+    assert block == 8 and single.any() and not single.all()
+    _assert_reassociated(form, got, want, valid, single)
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_one_page_a_step_is_the_grid_form_to_the_bit(form, monkeypatch):
+    """Where no larger block fits the budget the kernel runs as PR 55
+    left it: every output, padding rows and columns too, the bits of
+    the grid form."""
+    monkeypatch.setattr(paged_prefill_module, "VMEM_BUDGET", 0)
+    got, want, valid, single, block = _against_the_grid_form(form)
+    assert block == 1 and np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("keys,block", [(32, 2), (64, 4)])
+@pytest.mark.parametrize("form", ["f32", "int8_pool", "latent",
+                                  "two_q_tiles"])
+def test_smaller_blocks_agree_with_the_grid_form(form, keys, block,
+                                                 monkeypatch):
+    """Blocks of two and four pages over tables of eleven: whole blocks
+    followed by a walked page, by a masked block, by nothing."""
+    monkeypatch.setattr(paged_prefill_module, "BLOCK_KEYS", keys)
+    got, want, valid, single, got_block = _against_the_grid_form(form, 11)
+    assert got_block == block
+    _assert_reassociated(form, got, want, valid, single)
 
 
 def test_the_grid_is_the_live_steps_of_the_dispatch():
     """3 live rows in a 16-row bucket over slots of 12 pages: the
     kernel's one grid axis is bound by the list's count -- the live
-    rows' pages and one page a padding row -- where the (rows, tiles,
-    max_pages) form walked 192 steps."""
+    rows' key blocks and one page a padding row -- where the (rows,
+    tiles, max_pages) form walked 192 steps and a page a step 31."""
     ps, maxp, l, h, d = 16, 12, 8, 4, 16
     start = jnp.asarray([5 * ps, ps + 3, 9 * ps + 8] + [0] * 13, jnp.int32)
     count = jnp.asarray([l, l, 1] + [0] * 13, jnp.int32)
@@ -415,9 +565,16 @@ def test_the_grid_is_the_live_steps_of_the_dispatch():
     assert len(calls) == 1
     grid = calls[0].params["grid_mapping"].grid
     assert len(grid) == 1 and not isinstance(grid[0], int)   # dynamic
+    cols, tiles, block = key_block_plan(l, h, h, ps, d, 4, 4)
+    assert (cols, tiles, block) == (8, 1, 8)
     last = jnp.where(count > 0, start + count - 1, 0)
-    *_, n = _live_steps(table, start, last, 8, 1, ps)
-    assert int(n[0]) == (6 + 2 + 10) + 13 < 16 * maxp
+    *_, n = _live_steps(table, start, last, cols, tiles, ps, block)
+    # six pages: one masked block; two: walked; ten: a block and two
+    assert int(n[0]) == (1 + 2 + 3) + 13
+    assert count_key_blocks(start, count, max_pages=maxp, page_size=ps,
+                            cols=cols, tiles=tiles, block=block) == dict(
+        live_pages=(6 + 2 + 10) + 13, table_pages=16 * maxp, key_blocks=19,
+        block_pages=(8 + 2 + 10) + 13)
 
 
 # ------------------------------------------------------- the decision

@@ -278,6 +278,71 @@ def test_prefill_live_page_share_is_live_pages_over_the_tables_dispatched():
     assert s["decode_live_page_share"] == round(29 / (8 * 24), 4)
 
 
+def test_key_block_counters_are_live_pages_over_what_the_grid_walks():
+    """A hand-counted dispatch at four pages a key block, the tail of
+    one page walked and a longer one masked: rows holding 9, 6 and 1
+    pages and a padding row walk (2 blocks + 1 page) + (1 block + 1
+    masked block) + 1 + 1 = 7 steps over 9 + 8 + 1 + 1 computed pages.
+    None before a dispatch; a dispatch recorded without the kernel's
+    count (no page pool) leaves both None."""
+    m = ServingMetrics(None)
+    s = m.summary()
+    assert s["prefill_key_block_fill_share"] is None
+    assert s["prefill_pages_per_step"] is None
+    m.record_prefill_dispatch(1, rows=3, padded_rows=4, tokens=24)
+    s = m.summary()
+    assert s["prefill_key_block_fill_share"] is None
+    assert s["prefill_pages_per_step"] is None
+    m.record_prefill_dispatch(2, rows=3, padded_rows=4, tokens=24,
+                              live_pages=9 + 6 + 1 + 1, table_pages=4 * 12,
+                              key_blocks=3 + 2 + 1 + 1,
+                              block_pages=9 + 8 + 1 + 1)
+    s = m.summary()
+    assert s["prefill_key_block_fill_share"] == round(17 / 19, 4)
+    assert s["prefill_pages_per_step"] == round(17 / 7, 4)
+    # one page a step: both read 1.0
+    one = ServingMetrics(None)
+    one.record_prefill_dispatch(1, rows=2, padded_rows=2, tokens=16,
+                                live_pages=5, table_pages=12, key_blocks=5,
+                                block_pages=5)
+    s = one.summary()
+    assert s["prefill_key_block_fill_share"] == 1.0
+    assert s["prefill_pages_per_step"] == 1.0
+
+
+def test_the_scheduler_records_the_kernels_own_count_of_a_dispatch():
+    """The counters come from ops/attention/paged_prefill's count at the
+    engine's geometry: a 19-token prompt over pages of 16 in chunks of 8
+    is three dispatches holding 1, 1 and 2 live pages -- walked a page a
+    step, no block of 8 pages fits a tail that short."""
+    import numpy as np
+    import deepspeed_tpu
+    from deepspeed_tpu.models.gpt2 import GPT2, gpt2_tiny
+    from deepspeed_tpu.ops.attention.paged_prefill import key_block_plan
+    from deepspeed_tpu.serving import ServingScheduler
+    engine = deepspeed_tpu.init_inference(
+        model=GPT2(gpt2_tiny()), dtype="float32", kv_cache_dtype="float32",
+        mesh={"data": 1, "model": 1})
+    engine.init_params()
+    sched = ServingScheduler(engine, num_slots=2, num_pages=12,
+                             page_size=16, max_pages_per_slot=6,
+                             prefill_chunk=8)
+    cfg = engine.module.cfg
+    cols, tiles, block = key_block_plan(8, cfg.num_heads, cfg.num_heads, 16,
+                                        cfg.head_dim, 4, 4)
+    assert (tiles, block) == (1, 8)
+    count = sched._count_key_blocks([16, 0], [3, 0], max_pages=6)
+    assert count == dict(live_pages=3, table_pages=12, key_blocks=3,
+                         block_pages=3)
+    sched.submit(np.arange(1, 20, dtype=np.int32), max_new_tokens=2)
+    sched.run()
+    s = sched.metrics.summary()
+    assert s["prefill_dispatches"] == 3
+    assert s["prefill_live_page_share"] == round(4 / 18, 4)
+    assert s["prefill_key_block_fill_share"] == 1.0
+    assert s["prefill_pages_per_step"] == 1.0
+
+
 @pytest.mark.parametrize("dispatches,by_bucket,pad_share", [
     ([], {}, 0.0),
     ([(20, 32)], {"32": 1}, 12 / 32),

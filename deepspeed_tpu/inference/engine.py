@@ -1980,18 +1980,102 @@ class InferenceEngine:
         deterministic fp32 argmax, ties breaking to the LOWEST token id
         — the exact comparison ``verify_multi`` replays on device, so
         speculative verification stays token-exact vs this function."""
+        single = not isinstance(logits, (list, tuple)) and \
+            np.ndim(logits) == 1
+        toks = self.sample_launch(logits, do_sample, temperature, top_k,
+                                  top_p)
+        out = [int(t) for t in np.asarray(jax.device_get(toks))]
+        return out[0] if single else out
+
+    def sample_launch(self, logits, do_sample=False, temperature=1.0,
+                      top_k=0, top_p=1.0):
+        """The device half of :meth:`sample_from_logits`: the sampled
+        tokens of every row as an int32 ``[n]`` device array, their
+        copy to the host started and not waited for.  The scheduler
+        keeps it with a prefill dispatch it leaves in flight and pulls
+        it one dispatch later."""
         if isinstance(logits, (list, tuple)):
             rows = jnp.stack([jnp.asarray(r) for r in logits])
         else:
             rows = jnp.asarray(logits)
-        single = rows.ndim == 1
-        if single:
+        if rows.ndim == 1:
             rows = rows[None]
         self._rng, rng = jax.random.split(self._rng)
         toks = _sample_tokens(rows, rng, do_sample, temperature, top_k,
                               top_p)
-        out = [int(t) for t in np.asarray(jax.device_get(toks))]
-        return out[0] if single else out
+        toks.copy_to_host_async()
+        return toks
+
+    # ------------------------------------------- tokens kept on device
+    def _token_feedback_fns(self):
+        """The two programs that keep a boundary sample's tokens on the
+        device between prefill dispatches, one signature a row bucket
+        each: ``keep(tokens [slots], sampled [rows], slot [rows])``
+        files row r's token under ``slot[r]`` (``slot[r] == slots``:
+        not kept), and ``ids(host_ids [rows, chunk], src [rows], tokens
+        [slots])`` is ``host_ids`` with ``tokens[src[r]]`` in column 0
+        of every row whose ``src[r] >= 0``.  Their outputs are pinned
+        replicated, as ``prefill_into_slots`` stages its ids, so the
+        prefill program keeps its one signature a bucket."""
+        if getattr(self, "_token_keep_fn", None) is None:
+            rep = self._serving_shardings().replicated
+
+            def keep(tokens, sampled, slot):
+                return tokens.at[slot].set(sampled.astype(tokens.dtype),
+                                           mode="drop")
+
+            def ids(host_ids, src, tokens):
+                first = jnp.where(src >= 0, tokens[jnp.maximum(src, 0)],
+                                  host_ids[:, 0])
+                return host_ids.at[:, 0].set(first)
+            self._token_keep_fn = jax.jit(keep, out_shardings=rep)
+            self._token_ids_fn = jax.jit(ids, out_shardings=rep)
+        return self._token_keep_fn, self._token_ids_fn
+
+    def slot_tokens(self, num_slots):
+        """A zeroed per-slot token vector for :meth:`keep_sampled`."""
+        return jax.device_put(np.zeros(num_slots, np.int32),
+                              self._serving_shardings().replicated)
+
+    def keep_sampled(self, tokens, sampled, slot):
+        """``tokens`` with the sampled token of row r under ``slot[r]``
+        (rows whose ``slot[r]`` is ``len(tokens)`` are dropped)."""
+        keep, _ = self._token_feedback_fns()
+        with dist.mesh_scope(self.mesh):
+            return self._dispatch("keep_sampled", keep, tokens, sampled,
+                                  np.asarray(slot, np.int32))
+
+    def prefill_ids(self, host_ids, src, tokens):
+        """The ``ids_chunk`` of a prefill dispatch some of whose rows'
+        first input id is a token still on the device: row r takes
+        ``tokens[src[r]]`` where ``src[r] >= 0``, else ``host_ids``."""
+        _, ids = self._token_feedback_fns()
+        with dist.mesh_scope(self.mesh):
+            return self._dispatch("prefill_ids", ids,
+                                  np.asarray(host_ids, np.int32),
+                                  np.asarray(src, np.int32), tokens)
+
+    def warm_token_feedback(self, sampled, chunk, num_slots):
+        """Compile :meth:`keep_sampled` and :meth:`prefill_ids` for the
+        row bucket ``sampled`` came from, once an engine: a scheduler
+        calls this with the first boundary sample of each bucket (the
+        real one: a jit signature is keyed by where its inputs live),
+        so the two are built where the bucket's prefill program is (its
+        first use, a warm-up's) and never in a window that is measured.
+        Not a serving dispatch: no ``ds.engine.launch`` event."""
+        rows = int(np.shape(sampled)[0])
+        key = (rows, int(chunk), int(num_slots))
+        if getattr(self, "_token_feedback_warm", None) is None:
+            self._token_feedback_warm = set()
+        if key in self._token_feedback_warm:
+            return
+        self._token_feedback_warm.add(key)
+        keep, ids = self._token_feedback_fns()
+        with dist.mesh_scope(self.mesh):
+            tokens = keep(self.slot_tokens(num_slots), sampled,
+                          np.full(rows, num_slots, np.int32))
+            ids(np.zeros((rows, chunk), np.int32),
+                np.full(rows, -1, np.int32), tokens)
 
     def serving_prefill_compile_count(self):
         """Compiled signatures behind prefill_into_slots — bounded by

@@ -232,7 +232,7 @@ class LocalReplica:
             return False
         s = self.sched
         return bool(s.waiting) or bool(s._inflight) or \
-            bool(s._pending_attach) or \
+            bool(s._pf_flight) or bool(s._pending_attach) or \
             any(r is not None for r in s.slot_req)
 
     def step(self, step_idx, epoch=None):
@@ -285,6 +285,7 @@ class LocalReplica:
             return
         try:
             sched._inflight.clear()
+            sched._pf_flight.clear()
             for slot in range(sched.num_slots):
                 if sched.kv.slot_page_count(slot):
                     sched.kv.release_slot(slot)

@@ -80,6 +80,14 @@ class ServingMetrics:
         self.ride_dispatches = 0
         self.slot_bound_steps = 0
         self.horizon_none_steps = 0
+        # prefill look-ahead (the scheduler's _launch_boundary): shared
+        # dispatches launched while the one before had its sampled
+        # tokens still on the device, boundaries that were pulled at
+        # once or early by reason, and rows a dispatch computed for a
+        # request the pull before it found finished
+        self.lookahead_dispatches = 0
+        self.lookahead_fallbacks = Counter()
+        self.overrun_rows = 0
         self.seq_prefill_routed = 0    # prompts routed onto the sp path
         self.seq_prefill_chunks = 0    # sp chunk dispatches
         self.seq_prefill_tokens = 0    # prompt tokens landed via sp chunks
@@ -217,7 +225,7 @@ class ServingMetrics:
     def record_prefill_dispatch(self, step, *, rows, padded_rows, tokens,
                                 kv_tokens=0, kv_pairs=0, riders=0,
                                 live_pages=0, table_pages=0, key_blocks=0,
-                                block_pages=0):
+                                block_pages=0, lookahead=False):
         """One shared prefill dispatch carried the next chunk of
         ``rows`` prefilling slots (``tokens`` prompt tokens) in a
         ``padded_rows``-row bucket, and beside them the next token of
@@ -228,8 +236,11 @@ class ServingMetrics:
         ``table_pages`` = ``padded_rows`` x pages a slot that the
         dispatch's page table holds; the ``paged_prefill`` kernel walks
         them in ``key_blocks`` grid steps that compute ``block_pages``
-        pages (``ops/attention/paged_prefill.count_key_blocks``)."""
+        pages (``ops/attention/paged_prefill.count_key_blocks``).
+        ``lookahead``: it was launched while the dispatch before it had
+        its sampled tokens still on the device."""
         self.prefill_dispatches += 1
+        self.lookahead_dispatches += bool(lookahead)
         self.ride_rows += int(riders)
         self.ride_dispatches += riders > 0
         self.prefill_kv_tokens += int(kv_tokens)
@@ -331,6 +342,25 @@ class ServingMetrics:
         no decode horizon followed."""
         self.slot_bound_steps += 1
         self.horizon_none_steps += bool(no_horizon)
+
+    def record_lookahead_fallback(self, reason):
+        """A prefill boundary whose tokens were pulled before the next
+        dispatch was launched: at once (``horizon``, ``policy``,
+        ``drain``, ``other``) or early, from flight (``eviction``,
+        ``drain``, ``other``)."""
+        self.lookahead_fallbacks[reason] += 1
+
+    def record_lookahead_pull(self, overrun_rows):
+        """The pull of a boundary that was in flight across a step
+        boundary dropped ``overrun_rows`` tokens: rows computed for a
+        request that had finished or been closed by then."""
+        self.overrun_rows += int(overrun_rows)
+
+    def prefill_lookahead_share(self):
+        """Share of the shared prefill dispatches launched before the
+        previous one's sampled tokens were pulled."""
+        return self.lookahead_dispatches / self.prefill_dispatches \
+            if self.prefill_dispatches else 0.0
 
     def horizon_none_share(self):
         """Share of the slot-bound steps that launched no horizon."""
@@ -763,6 +793,11 @@ class ServingMetrics:
             "ride_rows": self.ride_rows,
             "ride_steps_share": round(self.ride_steps_share(), 4),
             "horizon_none_share": round(self.horizon_none_share(), 4),
+            "prefill_lookahead_share":
+            round(self.prefill_lookahead_share(), 4),
+            "prefill_lookahead_fallbacks":
+            dict(sorted(self.lookahead_fallbacks.items())),
+            "prefill_overrun_rows": self.overrun_rows,
             "prefill_dispatches_by_bucket":
             self.prefill_dispatches_by_bucket(),
             "seq_prefill_routed": self.seq_prefill_routed,

@@ -15,7 +15,9 @@ granularity.  Each ``step()`` is one scheduler iteration:
      dispatch (rows padded to a row bucket: x4 to 16 rows, x2 above);
      while requests wait for a slot the decoding slots may ride it as
      one-token rows, where the measured step walls say that pays
-     (``_plan_ride``),
+     (``_plan_ride``); a dispatch they rode with no horizon after it
+     stays in flight, and the next step launches its own before it
+     pulls this one's tokens (**Look-ahead**, below),
   4. run ONE fused multi-step decode ("horizon") over all running
      slots: up to ``decode_horizon_steps`` tokens per slot in a single
      ``decode_multi`` dispatch, with token feedback, EOS detection and
@@ -109,6 +111,26 @@ chained horizon is in flight (a failing emit callback, a cancel, an
 expired deadline) close the request immediately but defer the page
 release until the in-flight horizon is harvested — the device may still
 be writing that slot's pages.
+
+**Look-ahead.**  Slot-bound, a step whose decoding slots rode the
+prefill dispatch and that launches no horizon has nothing left that
+needs the sampled tokens, so it leaves them on the device
+(``_launch_boundary``; every sampling row's request is ``owed`` one
+token).  The next step settles what the dispatch decides whatever its
+tokens are (``_advance_in_flight``: a request whose owed token is its
+last leaves its slot, a finished prompt decodes from here), sweeps,
+admits and plans on that, stages and launches its own dispatch, whose
+riders read their input id from the device's per-slot tokens, and only
+then pulls (``_pull``): sweep to launch, the one host gap a step, runs
+under the device's time.  The barrier step is the same step with the
+pull in front; ``_why_pull_now`` says from the step's own state when
+that holds (a horizon or a speculative round follows, a row carries a
+policy, a grammar or a hand-off, drain has begun), and a dispatch in
+flight is pulled early where growing a row would evict a live slot.
+What only a token's VALUE decides -- an end of sequence, and likewise a
+cancel, a deadline or a failing callback found at the pull -- costs one
+computed row: the next dispatch's row for that slot is dropped at its
+pull, and the slot is parked (``_zombies``) with its pages until then.
 
 Failure policy (the serving half of docs/resilience.md):
 
@@ -327,6 +349,10 @@ class Request:
         self.eos_token_id = eos_token_id
         self.on_token = on_token
         self.out_tokens = []
+        # tokens sampled for this request on the device and not pulled
+        # yet: 1 while the prefill dispatch that computed its next
+        # token is in flight across a step boundary (_launch_boundary)
+        self.owed = 0
         self.state = WAITING
         self.prefill_pos = 0
         self.cached_prefix_tokens = 0   # prefix-cache reuse at last admit
@@ -366,7 +392,7 @@ class Request:
 
     @property
     def remaining_new(self):
-        return self.max_new_tokens - len(self.out_tokens)
+        return self.max_new_tokens - len(self.out_tokens) - self.owed
 
     def cancel(self):
         """Request cancellation; honored at the next step boundary (the
@@ -379,7 +405,8 @@ class Request:
 
     def _finished_by(self, tok):
         return (self.eos_token_id is not None and
-                tok == self.eos_token_id) or self.remaining_new <= 0
+                tok == self.eos_token_id) or \
+            len(self.out_tokens) >= self.max_new_tokens
 
 
 class ServingScheduler:
@@ -746,6 +773,13 @@ class ServingScheduler:
             max(1, int(self.kv.pool.num_pages * self.prefill_reserve_frac))
         self.overlap = bool(overlap)
         self._inflight = deque()       # dispatched horizons, FIFO, depth<=2
+        # prefill dispatches whose sampled tokens are still on the
+        # device (_launch_boundary), oldest first: one across a step
+        # boundary, two between a step's launch and its pull; and the
+        # sampled tokens by slot, the device's last_tok, that the next
+        # dispatch's riders read their input id from
+        self._pf_flight = deque()
+        self._dev_tok = None
         self._zombies = set()          # slots terminated host-side while a
                                        # chained horizon still runs them
         self._chain_budgets = None     # budgets baseline for the live chain
@@ -1246,7 +1280,9 @@ class ServingScheduler:
         coherence invariant.  Pages the cache declines (duplicate
         chains, cap) and the partial tail are released normally."""
         seq = req.orig_prompt + req.out_tokens
-        n_full = max(0, len(seq) - 1) // self.kv.page_size
+        # (a token still owed by a dispatch in flight is the final one:
+        # no full page reaches it, so the keys are all on the host)
+        n_full = max(0, len(seq) + req.owed - 1) // self.kv.page_size
         pages = self.kv.take_slot_pages(slot)
         keep, tail = pages[:n_full], pages[n_full:]
         leftover = self.prefix_cache.insert(
@@ -1263,7 +1299,9 @@ class ServingScheduler:
             except Exception:   # a broken drafter must not break retire
                 pass
 
-    def _retire(self, slot):
+    def _vacate(self, slot):
+        """The slot's half of a retirement: its pages go to the prefix
+        cache or back to the pool and the slot is free to admit into."""
         req = self.slot_req[slot]
         self._spec_release(slot, req)
         if self.prefix_cache is not None:
@@ -1273,12 +1311,48 @@ class ServingScheduler:
         self.slot_req[slot] = None
         self.lengths[slot] = 0
         self._release_adapter(slot)
+
+    def _finish(self, req):
+        """The request's half of a retirement."""
         self._finalize(req, FINISHED)
         if self._collect is not None:
             # run()'s result set stays complete even after the bounded
             # history evicts this request
             self._collect[req.rid] = list(req.out_tokens)
         self.metrics.record_completion(self.step_idx)
+
+    def _retire(self, slot):
+        req = self.slot_req[slot]
+        rec = self._flight_of(slot)
+        if rec is None:
+            self._vacate(slot)
+        else:
+            # a prefill dispatch in flight computes one more row for
+            # this slot (its token is dropped at that dispatch's pull):
+            # the pages stay the slot's until then
+            self._park(slot, rec, donate=req)
+        self._finish(req)
+
+    def _flight_of(self, slot):
+        """The newest prefill dispatch in flight that samples a row for
+        the request now in ``slot``, or None."""
+        req = self.slot_req[slot]
+        for rec in reversed(self._pf_flight):
+            if any(s == slot and r is req for _, s, r, _ in rec["rows"]):
+                return rec
+        return None
+
+    def _park(self, slot, rec, donate=None):
+        """Empty ``slot`` of its request but keep its pages until the
+        in-flight dispatch ``rec`` is pulled (``donate``: the finished
+        request whose pages then go to the prefix cache)."""
+        self._spec_release(slot, self.slot_req[slot])
+        self.slot_req[slot] = None
+        self._release_adapter(slot)
+        self._zombies.add(slot)
+        rec["release_after"].add(slot)
+        if donate is not None:
+            rec["donate"][slot] = donate
 
     def _close_slot(self, slot, state, reason):
         """Terminal removal of a live slot for cancel/shed/fail: release
@@ -1289,6 +1363,10 @@ class ServingScheduler:
         self.slot_req[slot] = None
         self.lengths[slot] = 0
         self._release_adapter(slot)
+        self._record_closed(req, state, reason)
+
+    def _record_closed(self, req, state, reason):
+        """The request's half of a cancel / shed / fail."""
         self._finalize(req, state, reason)
         self.metrics.record_terminal(self.step_idx, state, req.rid, reason)
         if state == FAILED:
@@ -1400,6 +1478,11 @@ class ServingScheduler:
                 if chain is not None:
                     chain.add("cache_drain", pages=drained)
                 continue
+            if self._pf_flight:
+                # a victim re-queues with its emitted tokens folded
+                # into its prompt: every token has to be on the host
+                self._pull_prefill("eviction")
+                continue
             victim = self._preempt_youngest(protect=slot, chain=chain)
             if victim is None:
                 if chain is not None:
@@ -1486,9 +1569,10 @@ class ServingScheduler:
             if req is None:
                 continue
             if req.cancelled:
-                self._close_slot(slot, CANCELLED, "cancelled")
+                self._close_slot_or_defer(slot, CANCELLED, "cancelled")
             elif req.past_deadline(now):
-                self._close_slot(slot, SHED, "deadline expired mid-flight")
+                self._close_slot_or_defer(slot, SHED,
+                                          "deadline expired mid-flight")
         if any(r.cancelled or r.past_deadline(now) for r in self.waiting):
             keep = deque()
             for req in self.waiting:
@@ -1515,11 +1599,13 @@ class ServingScheduler:
         phases = self.phases
         before = dict(phases.seconds)
         with phases("step") as ph_step:
-            if not self._inflight and not self._cycle_open:
+            if not self._inflight and not self._cycle_open and \
+                    not self._pf_flight:
                 # nothing on the device: the cycle _step_cost times
                 # starts with this step (else at the last harvest's
-                # end, or the end of a step that rode and carried no
-                # horizon)
+                # end, the end of a step that rode and carried no
+                # horizon, or the last pull of a prefill dispatch that
+                # was in flight across a step boundary)
                 self._cycle_t0 = ph_step.t0
             self._cycle_open = False
             # fault point: slow-step / loop-level fault injection. Fires
@@ -1548,10 +1634,17 @@ class ServingScheduler:
                     w, n = self._harvest()
                     t_wait += w
                     pulled += n
+                if self._pf_flight and self.draining:
+                    # drain has begun: today's order, the pull first
+                    with phases("prefill"):
+                        self._pull_prefill("drain")
                 # 1. cancellations + deadlines leave at the boundary
                 with phases("sweep") as ph:
                     now = ph.t0
                     self._sweep(now)
+                    # what the dispatch in flight settles whatever its
+                    # tokens are: admission sees those slots free
+                    self._advance_in_flight()
                 # 2. admit waiting requests into free slots (retirement
                 # happens at harvest, so slots are already recycled);
                 # handoff chains go first — their pages are already held
@@ -1577,7 +1670,7 @@ class ServingScheduler:
                 # 4. dispatch ONE fused decode horizon over running slots
                 # (none where the rows rode and the plan was no horizon)
                 launched = self._dispatch()
-                if self._riders and not launched:
+                if self._riders and not launched and not self._pf_flight:
                     self._close_ride_cycle()
                 if bound:
                     self.metrics.record_slot_bound_step(
@@ -1647,7 +1740,8 @@ class ServingScheduler:
         if ph_step.last_s > min(self._slow_step_max_s, SLOW_STEP_S):
             self._note_long_step(ph_step.last_s, before)
         return bool(self.waiting) or n_running > 0 or \
-            bool(self._inflight) or bool(self._pending_attach)
+            bool(self._inflight) or bool(self._pending_attach) or \
+            bool(self._pf_flight)
 
     def _note_long_step(self, wall_s, before):
         """The longest step so far, and every step over SLOW_STEP_S,
@@ -1951,10 +2045,10 @@ class ServingScheduler:
                     rows.append((slot, req, chunk))
                 # else self-preempted: back in the queue
             except PagePoolExhausted as e:
-                self._close_slot(slot, SHED, f"page capacity: {e}")
+                self._close_slot_or_defer(slot, SHED, f"page capacity: {e}")
             except Exception as e:   # containment: fail one, not all
-                self._close_slot(slot, FAILED,
-                                 f"{type(e).__name__}: {e}")
+                self._close_slot_or_defer(slot, FAILED,
+                                          f"{type(e).__name__}: {e}")
         if rows and self._ride is not None:
             rows += self._ride_rows()
         # a later row's growth may have evicted an earlier row's slot:
@@ -1964,6 +2058,7 @@ class ServingScheduler:
                 if self.slot_req[s] is r and r.state in (PREFILL, RUNNING)]
         if all(r.state == RUNNING for _, r, _ in rows):
             rows = []    # no prompt row is left: no dispatch to ride
+        rec = None
         if rows:
             logits = self._prefill_dispatch(rows)
             done = []
@@ -1975,10 +2070,22 @@ class ServingScheduler:
                 req.prefill_pos += len(chunk)
                 if req.prefill_pos == len(req.prompt):
                     done.append((i, slot, req))
-            if done:
-                blocks.append((logits, done))
+            rec = self._boundary(logits, done)
+        stays = False
+        if rec is not None:
+            why = self._why_pull_now(rec)
+            stays = why is None
+            if stays:
+                self._launch_boundary(rec)
+            else:
+                self.metrics.record_lookahead_fallback(why)
+        # the tokens of the dispatch the last step left in flight: this
+        # step's is on the device behind it (none was launched: "other")
+        self._pull_prefill(None if rows else "other", newer=rec)
         for logits, done in blocks:
-            self._sample_boundary(logits, done)
+            self._pull(self._boundary(logits, done))
+        if not stays:
+            self._pull(rec)
 
     def _sp_routed(self, req):
         """Whether ``req``'s prompt takes the sequence-parallel path's
@@ -2010,10 +2117,10 @@ class ServingScheduler:
                 if self._grow_or_evict(slot, int(self.lengths[slot]) + 1):
                     rows.append((slot, req, [int(self.last_tok[slot])]))
             except PagePoolExhausted as e:
-                self._close_slot(slot, SHED, f"page capacity: {e}")
+                self._close_slot_or_defer(slot, SHED, f"page capacity: {e}")
             except Exception as e:   # containment: fail one, not all
-                self._close_slot(slot, FAILED,
-                                 f"{type(e).__name__}: {e}")
+                self._close_slot_or_defer(slot, FAILED,
+                                          f"{type(e).__name__}: {e}")
         return rows
 
     def _prefill_dispatch(self, rows):
@@ -2028,17 +2135,28 @@ class ServingScheduler:
         ids = np.zeros((padded, self.prefill_chunk), np.int32)
         slots = np.full(padded, rows[0][0], np.int32)
         n_valid = np.zeros(padded, np.int32)
-        for i, (slot, _, chunk) in enumerate(rows):
+        # a rider's input id is its slot's newest token: last_tok, or,
+        # while the dispatch that sampled it is in flight (req.owed),
+        # the device's copy of it (_dev_tok, by slot)
+        src = np.full(padded, -1, np.int32)
+        for i, (slot, req, chunk) in enumerate(rows):
             ids[i, :len(chunk)] = chunk
             slots[i] = slot
             n_valid[i] = len(chunk)
+            if req.state == RUNNING:
+                ids[i, 0] = self.last_tok[slot]
+                if req.owed:
+                    src[i] = slot
         riders = sum(req.state == RUNNING for _, req, _ in rows)
         tokens = int(n_valid.sum()) - riders
         self._prefill_rode = True
         self._riders = riders
+        lookahead = bool(self._pf_flight)
         with self.phases("prefill_chunk", rows=len(rows) - riders,
                          padded_rows=padded, tokens=tokens,
-                         riders=riders):
+                         riders=riders, lookahead=int(lookahead)):
+            if (src >= 0).any():
+                ids = self._ids_from_device(ids, src)
             a_ids, a_pack = self._adapter_args()
             logits, self.pools = self.engine.prefill_into_slots(
                 ids, slots, n_valid, self.kv.table, self.lengths,
@@ -2051,7 +2169,7 @@ class ServingScheduler:
         pad = [0] * (padded - len(rows))
         self.metrics.record_prefill_dispatch(
             self.step_idx, rows=len(rows) - riders, padded_rows=padded,
-            tokens=tokens, riders=riders,
+            tokens=tokens, riders=riders, lookahead=lookahead,
             kv_tokens=sum(starts) + tokens + riders,
             kv_pairs=sum(s * len(c) + len(c) * (len(c) + 1) // 2
                          for s, (_, _, c) in zip(starts, rows)),
@@ -2066,6 +2184,21 @@ class ServingScheduler:
             if fresh:
                 self.metrics.record_state_resets(self.step_idx, fresh)
         return logits
+
+    def _ids_from_device(self, ids, src):
+        """``ids`` with column 0 of row r read from the device's token
+        of slot ``src[r]`` (``src[r] >= 0``): the sampled tokens of the
+        dispatches in flight are filed by slot first (``_dev_tok``, the
+        device's ``last_tok``), then the rows gather theirs -- two
+        small programs a row bucket, inside this dispatch's phase."""
+        if self._dev_tok is None:
+            self._dev_tok = self.engine.slot_tokens(self.num_slots)
+        for rec in self._pf_flight:
+            if rec["slots"] is not None:
+                self._dev_tok = self.engine.keep_sampled(
+                    self._dev_tok, rec["toks"], rec["slots"])
+                rec["slots"] = None
+        return self.engine.prefill_ids(ids, src, self._dev_tok)
 
     def _prefill_seq_parallel(self, slot, req):
         """One wide sequence-sharded chunk of ONE routed request (its
@@ -2090,72 +2223,180 @@ class ServingScheduler:
         req.prefill_pos += n_valid
         return logits if req.prefill_pos == len(req.prompt) else None
 
-    def _sample_boundary(self, logits, finishing):
-        """First tokens of the requests whose prompt finished in one
-        prefill dispatch, and the next tokens of the slots that rode it
-        (``_ride_rows``; RUNNING, where a finishing row is PREFILL):
-        ``logits`` is the dispatch's whole [n, vocab] block and
-        ``finishing`` lists ``(row, slot, req)``.  The sample runs over
-        EVERY row of the block (one program per row bucket, never per
-        finishing count or row index); non-finishing and padding rows'
-        tokens are dropped on the host."""
+    def _boundary(self, logits, finishing):
+        """The record of one prefill dispatch's boundary: the first
+        tokens of the requests whose prompt finished in it and the next
+        tokens of the slots that rode it (``_ride_rows``; RUNNING,
+        where a finishing row is PREFILL).  ``logits`` is the
+        dispatch's whole [n, vocab] block and ``finishing`` lists
+        ``(row, slot, req)``; None where no row samples.  The sample
+        runs over EVERY row of the block (one program per row bucket,
+        never per finishing count or row index); non-finishing and
+        padding rows' tokens are dropped on the host.  Nothing runs
+        here: ``_pull`` samples and emits at once (a barrier step, the
+        order every step had), or ``_launch_boundary`` puts the sample
+        on the device now and ``_pull`` takes its tokens one dispatch
+        later."""
         # a later slot's growth (a sequence-parallel reservation) may
         # have evicted an earlier finishing slot — drop stale entries
         # BEFORE the batched sample (the policy-table gathers index by
         # slot, so a vacated slot must not reach them)
-        finishing = [(i, s, r) for i, s, r in finishing
-                     if self.slot_req[s] is r
-                     and r.state in (PREFILL, RUNNING)]
-        if not finishing:
+        rows = [(i, s, r, r.state == RUNNING) for i, s, r in finishing
+                if self.slot_req[s] is r and r.state in (PREFILL, RUNNING)]
+        if not rows:
+            return None
+        return {"logits": logits, "rows": rows, "toks": None, "slots": None,
+                "left": set(), "release_after": set(), "donate": {},
+                "policy": self._batch_needs_policy([s for _, s, _, _ in
+                                                    rows])}
+
+    def _why_pull_now(self, rec):
+        """Why the shared dispatch's boundary ``rec`` cannot stay in
+        flight across the step boundary (None: it can).  Read off the
+        step's own state: it stays where nothing after it in this step
+        needs its tokens and nothing a row carries reads them on the
+        host.  ``horizon``: a decode horizon or a speculative round
+        follows, which starts from ``last_tok`` (and a chat client's
+        first token must not wait a step); ``policy``: a row samples
+        under a decoding policy or a grammar (penalty counts and masks
+        are functions of the emitted tokens), owes a hand-off, or was
+        routed sequence-parallel; ``drain``: shutdown has begun;
+        ``other``: the scheduler was built with ``overlap=False``."""
+        if not (self._riders and self._ride == 0):
+            return "horizon"
+        if self.draining:
+            return "drain"
+        if not self.overlap:
+            return "other"
+        if rec["policy"] or any(
+                r.grammar is not None or r.handoff or
+                getattr(r, "seq_parallel", False)
+                for _, _, r, _ in rec["rows"]):
+            return "policy"
+        return None
+
+    def _launch_boundary(self, rec):
+        """Put ``rec``'s sample on the device behind its dispatch and
+        leave the tokens there: every row owes its request one token,
+        and the next dispatch's riders read their input id from the
+        device (``_ids_from_device``), without the host."""
+        toks = rec["toks"] = self.engine.sample_launch(
+            rec["logits"], **self.sampling)
+        # row -> slot for the device's per-slot tokens (num_slots: a
+        # row that samples for nobody), filed by the next dispatch
+        rec["slots"] = np.full(int(np.shape(toks)[0]), self.num_slots,
+                               np.int32)
+        for i, slot, req, _ in rec["rows"]:
+            rec["slots"][i] = slot
+            req.owed += 1
+
+    def _advance_in_flight(self):
+        """What the prefill dispatch in flight settles whatever its
+        tokens turn out to be, applied before the next step's admission
+        and plan: a request whose owed token is its last leaves its
+        slot (the pages go on now: the device runs dispatches in order,
+        and a page's next owner writes it after this dispatch), and a
+        request whose prompt the dispatch finished decodes from here.
+        An end of sequence, a cancel, a deadline or a failing callback
+        is found at the pull, one dispatch later: that row of the next
+        dispatch is computed and dropped (``_pull``)."""
+        for rec in self._pf_flight:
+            for i, slot, req, _ in rec["rows"]:
+                if self.slot_req[slot] is not req:
+                    continue           # closed by the sweep: parked
+                if req.remaining_new <= 0:
+                    self._vacate(slot)
+                    rec["left"].add(i)
+                elif req.state == PREFILL:
+                    req.state = RUNNING
+
+    def _pull_prefill(self, why=None, newer=None):
+        """Pull every prefill dispatch in flight, oldest first (``why``:
+        the reason the next dispatch could not be launched before it,
+        counted; None where it was).  ``newer`` is the boundary of the
+        dispatch just launched behind them: where it stays in flight it
+        is in flight from here on, so a slot whose request ends at one
+        of these pulls is parked on it."""
+        pulled = list(self._pf_flight)
+        self._pf_flight.clear()
+        if newer is not None and newer["toks"] is not None:
+            self._pf_flight.append(newer)
+        for rec in pulled:
+            if why is not None:
+                self.metrics.record_lookahead_fallback(why)
+            self._pull(rec)
+
+    def _pull(self, rec):
+        """The host half of a boundary: the blocking pull of ``rec``'s
+        sampled tokens (``first_token_wait``; the sample itself where
+        ``_launch_boundary`` has not run it) and the emit loop
+        (``first_token``), in row order.  A record that was in flight
+        across a step boundary may hold rows the host has since
+        overtaken: a request the sweep closed, or one that ended on the
+        token before (its slot was parked, its row computed on pages
+        the slot still held): their tokens are dropped
+        (``prefill_overrun_rows``), and the pages parked on this record
+        go on afterwards.  A cancel or a deadline such a record's pull
+        finds is honored as at a harvest: the token past it dropped."""
+        if rec is None:
             return
+        rows, flown = rec["rows"], rec["toks"] is not None
         # the batched sample is shared work (like the decode dispatch);
-        # emit/callback stays contained per request below
-        if self._batch_needs_policy([s for _, s, _ in finishing]):
-            # boundary token under the decoding policy: same pipeline,
-            # same position-keyed stream as the fused decode (token 0
-            # of the request draws from fold_in(key, sample_offset)).
-            # Per-row lanes cover the whole block; a non-finishing row
-            # borrows the first finishing slot's lanes (its draw is
-            # independent of the other rows' and dropped below)
-            self._ensure_policy_tables()
-            n = int(np.shape(logits)[0])
-            sl = np.full(n, finishing[0][1], np.int64)
-            idx = np.zeros(n, np.int32)
-            for i, s, r in finishing:
-                sl[i] = s
-                idx[i] = r.sample_offset + len(r.out_tokens)
-            sample = self.engine.sample_from_logits_policy
-            args, kw = (
-                logits, self._samp_keys[sl], idx, self._samp_temps[sl],
-                self._samp_topk[sl], self._samp_topp[sl],
-                self._samp_rep[sl], self._samp_pres[sl],
-                self._samp_freq[sl], self._tok_counts[sl],
-                self._grammar_masks[sl]), {}
-        else:
-            sample = self.engine.sample_from_logits
-            args, kw = (logits,), self.sampling
-        # the sample's launch and the blocking pull of its tokens: the
-        # host waits here until the prefill dispatch is done
-        with self.phases("first_token_wait", rows=len(finishing)):
-            toks = sample(*args, **kw)
-        with self.phases("first_token"):
-            for i, slot, req in finishing:
-                if self.slot_req[slot] is not req or \
-                        req.state not in (PREFILL, RUNNING):
-                    continue   # closed by an earlier row's emit epilogue
-                tok = toks[i]
-                try:
-                    if req.state == RUNNING:
-                        # a rider's token is a burst of one
-                        self.metrics.record_tbt(
-                            self.step_idx, time.monotonic() - req.t_last)
-                    self._emit(req, tok)
-                    self._note_emitted(slot, req, tok)
-                except Exception as e:
-                    self._close_slot(slot, FAILED,
-                                     f"{type(e).__name__}: {e}")
+        # emit/callback stays contained per request below.  The host
+        # waits here until the prefill dispatch is done (one that was in
+        # flight has the next one queued behind it)
+        with self.phases("first_token_wait", rows=len(rows)) as ph:
+            if rec["policy"]:
+                toks = self._sample_under_policy(rec)
+            else:
+                if not flown:
+                    rec["toks"] = self.engine.sample_launch(
+                        rec["logits"], **self.sampling)
+                    # the programs that keep a sample on the device are
+                    # built where a bucket's sample first runs
+                    self.engine.warm_token_feedback(
+                        rec["toks"], self.prefill_chunk, self.num_slots)
+                toks = np.asarray(rec["toks"])
+        t_done = ph.t0 + ph.last_s
+        overrun = 0
+        with self.phases("first_token") as ph:
+            now = ph.t0
+            for i, slot, req, rider in rows:
+                req.owed -= flown
+                # left its slot at the last plan (_advance_in_flight):
+                # this is its last token, and there is no slot to close
+                left = i in rec["left"]
+                if not left and (self.slot_req[slot] is not req or
+                                 req.state not in (PREFILL, RUNNING)):
+                    overrun += flown   # closed since the launch
                     continue
-                if req._finished_by(tok) or self._grammar_finished(req):
+                tok, closed = int(toks[i]), None
+                if flown and req.cancelled:
+                    closed = (CANCELLED, "cancelled")
+                elif flown and req.past_deadline(now):
+                    closed = (SHED, "deadline expired mid-flight")
+                else:
+                    try:
+                        if rider:
+                            # a rider's token is a burst of one
+                            self.metrics.record_tbt(
+                                self.step_idx,
+                                time.monotonic() - req.t_last)
+                        self._emit(req, tok)
+                        if not left:
+                            self._note_emitted(slot, req, tok)
+                    except Exception as e:
+                        closed = (FAILED, f"{type(e).__name__}: {e}")
+                if closed is not None:
+                    overrun += closed[0] != FAILED   # its token dropped
+                    if left:
+                        self._record_closed(req, *closed)
+                    else:
+                        self._close_slot_or_defer(slot, *closed)
+                    continue
+                if left:
+                    self._finish(req)
+                elif req._finished_by(tok) or self._grammar_finished(req):
                     self._retire(slot)
                 elif req.handoff and self.on_handoff is not None:
                     self._do_handoff(slot, req, tok)
@@ -2165,6 +2406,37 @@ class ServingScheduler:
                     if req.grammar is not None:
                         self._grammar_masks[slot] = \
                             req.grammar.token_mask()
+            for slot in rec["release_after"]:
+                req = rec["donate"].get(slot)
+                if req is not None and self.prefix_cache is not None:
+                    self._donate_pages(slot, req)
+                else:
+                    self.kv.release_slot(slot)
+                self.lengths[slot] = 0
+                self._zombies.discard(slot)
+        if flown:
+            self.metrics.record_lookahead_pull(overrun)
+            self._close_ride_cycle(at=t_done)
+
+    def _sample_under_policy(self, rec):
+        """``rec``'s boundary tokens under the decoding policy: same
+        pipeline, same position-keyed stream as the fused decode (token
+        0 of the request draws from fold_in(key, sample_offset)).
+        Per-row lanes cover the whole block; a non-finishing row
+        borrows the first finishing slot's lanes (its draw is
+        independent of the other rows' and dropped by the caller)."""
+        self._ensure_policy_tables()
+        n = int(np.shape(rec["logits"])[0])
+        sl = np.full(n, rec["rows"][0][1], np.int64)
+        idx = np.zeros(n, np.int32)
+        for i, s, r, _ in rec["rows"]:
+            sl[i] = s
+            idx[i] = r.sample_offset + len(r.out_tokens)
+        return self.engine.sample_from_logits_policy(
+            rec["logits"], self._samp_keys[sl], idx, self._samp_temps[sl],
+            self._samp_topk[sl], self._samp_topp[sl], self._samp_rep[sl],
+            self._samp_pres[sl], self._samp_freq[sl],
+            self._tok_counts[sl], self._grammar_masks[sl])
 
     # ------------------------------------------------ disaggregated KV
     def _do_handoff(self, slot, req, tok):
@@ -2335,6 +2607,7 @@ class ServingScheduler:
         # then shed the survivors instead of losing them silently
         while self._inflight:
             self._harvest()
+        self._pull_prefill("drain")
         for slot in range(self.num_slots):
             if self.slot_req[slot] is not None:
                 self._close_slot(slot, SHED, "shutdown drain: grace "
@@ -2523,12 +2796,15 @@ class ServingScheduler:
                 if self.slot_req[s] is not None
                 and self.slot_req[s].state == RUNNING]
 
-    def _close_ride_cycle(self):
+    def _close_ride_cycle(self, at=None):
         """The end of a step whose decode pass was its prefill dispatch
         and that launched no horizon: the device is done (the boundary
         sample's pull waited for it), so the cycle ``_step_cost`` times
-        ends here, as a harvest ends one that carried a horizon."""
-        now = time.monotonic()
+        ends here, as a harvest ends one that carried a horizon.  A
+        dispatch that was in flight across a step boundary ends its
+        cycle at its pull (``at``), and the next begins there: pull to
+        pull, one dispatch's time on a device that is kept busy."""
+        now = time.monotonic() if at is None else at
         self._step_cost.add((RIDE, 0), now - self._cycle_t0)
         self._cycle_t0, self._cycle_open = now, True
 
@@ -3224,23 +3500,21 @@ class ServingScheduler:
                             if rec["widths"][s] > 0))
 
     def _close_slot_or_defer(self, slot, state, reason):
-        """Terminal removal discovered at a horizon boundary.  If a
-        chained horizon is still in flight with this slot unfrozen, the
-        device may be writing the slot's pages: close the request's
-        bookkeeping NOW (state, metrics, history) but hold the pages
-        until that horizon is harvested."""
-        if not self._inflight:
+        """Terminal removal discovered at a horizon boundary, or while
+        a prefill dispatch is in flight.  If a chained horizon is still
+        in flight with this slot unfrozen, or a prefill dispatch in
+        flight samples a row for it, the device may be writing the
+        slot's pages: close the request's bookkeeping NOW (state,
+        metrics, history) but hold the pages until that horizon is
+        harvested, that dispatch pulled."""
+        rec = self._inflight[-1] if self._inflight \
+            else self._flight_of(slot)
+        if rec is None:
             self._close_slot(slot, state, reason)
             return
         req = self.slot_req[slot]
-        self._spec_release(slot, req)
-        self.slot_req[slot] = None
-        self._finalize(req, state, reason)
-        self.metrics.record_terminal(self.step_idx, state, req.rid, reason)
-        if state == FAILED:
-            self._last_error = f"rid={req.rid}: {reason}"
-        self._zombies.add(slot)
-        self._inflight[-1]["release_after"].add(slot)
+        self._park(slot, rec)
+        self._record_closed(req, state, reason)
 
     def run(self, max_steps=100000):
         """Drive step() until idle; returns {rid: generated tokens} for

@@ -278,6 +278,50 @@ def test_prefill_live_page_share_is_live_pages_over_the_tables_dispatched():
     assert s["decode_live_page_share"] == round(29 / (8 * 24), 4)
 
 
+def _lookahead_share(m):
+    """Three of four hand-made dispatches were launched before the one
+    before was pulled; nothing dispatched reads 0.0."""
+    assert m.summary()["prefill_lookahead_share"] == 0.0
+    m.record_prefill_dispatch(1, rows=3, padded_rows=4, tokens=24)
+    for step in (2, 3, 4):
+        m.record_prefill_dispatch(step, rows=2, padded_rows=4, tokens=16,
+                                  riders=2, lookahead=True)
+    assert m.summary()["prefill_lookahead_share"] == 0.75
+    assert m.summary()["prefill_dispatches"] == 4
+
+
+def _lookahead_fallbacks(m):
+    """Counted by reason, in the reasons' order; a reason never hit is
+    absent, and the map is no number (a scalar sink never sees it)."""
+    assert m.summary()["prefill_lookahead_fallbacks"] == {}
+    for why in ("horizon", "policy", "horizon", "eviction", "drain",
+                "other", "horizon"):
+        m.record_lookahead_fallback(why)
+    got = m.summary()["prefill_lookahead_fallbacks"]
+    assert got == {"drain": 1, "eviction": 1, "horizon": 3, "other": 1,
+                   "policy": 1}
+    assert list(got) == sorted(got)
+
+
+def _overrun_rows(m):
+    """Summed over the pulls of dispatches that were in flight; a pull
+    that dropped nothing adds nothing."""
+    assert m.summary()["prefill_overrun_rows"] == 0
+    for dropped in (0, 2, 0, 1):
+        m.record_lookahead_pull(dropped)
+    assert m.summary()["prefill_overrun_rows"] == 3
+
+
+@pytest.mark.parametrize("check", [_lookahead_share, _lookahead_fallbacks,
+                                   _overrun_rows],
+                         ids=["share", "fallbacks", "overrun_rows"])
+def test_the_prefill_look_ahead_counters(check):
+    """``summary()``'s three counters of the slot-bound look-ahead
+    (``ServingScheduler._launch_boundary``), each from hand-made
+    records."""
+    check(ServingMetrics(None))
+
+
 def test_key_block_counters_are_live_pages_over_what_the_grid_walks():
     """A hand-counted dispatch at four pages a key block, the tail of
     one page walked and a longer one masked: rows holding 9, 6 and 1

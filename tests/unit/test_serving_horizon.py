@@ -553,10 +553,13 @@ def test_no_horizon_is_never_chosen_without_a_ride(engine):
 
 
 def test_a_step_with_no_horizon_still_closes_its_cycle(engine):
-    """Rows ride and no horizon follows: the step files its cycle under
-    ``(RIDE, 0)`` at its end, and the next cycle starts THERE, so what
-    the host does between two such steps is in the second one's wall
-    (as it is between two harvests)."""
+    """Rows ride and no horizon follows: the cycle is filed under
+    ``(RIDE, 0)`` and the next cycle starts THERE, so what the host
+    does between two such steps is in the second one's wall (as it is
+    between two harvests).  The step that leaves its dispatch in flight
+    across the boundary files that cycle at the dispatch's pull, one
+    step later (pull to pull); one that pulls at once files it at its
+    own end."""
     rng = np.random.default_rng(5)
     sched = ServingScheduler(engine, decode_horizon_steps=8, **CFG)
     _seed_walls(sched, 30.0, 8.75, (2, 8))
@@ -567,22 +570,29 @@ def test_a_step_with_no_horizon_still_closes_its_cycle(engine):
         sched.submit(rng.integers(0, 256, n).astype(np.int32),
                      max_new_tokens=new)
     pause, after_ride_only = 0.03, []
-    busy = True
+    busy, slept, ahead = True, False, 0
     while busy:
         was_open = sched._cycle_open
-        seen = len(filed)
+        seen, flown = len(filed), bool(sched._pf_flight)
         busy = sched.step()
         ride_only = bool(sched._riders) and not sched._inflight
-        assert sched._cycle_open == ride_only
+        ahead += bool(sched._pf_flight)
+        closed = flown or (ride_only and not sched._pf_flight)
+        assert sched._cycle_open == closed
+        if closed:
+            walls = [w for f, w in filed[seen:] if f == (RIDE, 0)]
+            assert len(walls) == 1
+            if was_open and slept:
+                after_ride_only.append(walls[0])
+        slept = ride_only
         if ride_only:
-            assert filed[seen:] and filed[-1][0] == (RIDE, 0)
-            if was_open:
-                after_ride_only.append(filed[-1][1])
             time.sleep(pause)
+    assert ahead and not sched._pf_flight
     assert after_ride_only and min(after_ride_only) >= pause
     assert all(0 < w < 60 for _, w in filed)
     s = sched.summary()
     assert s["horizon_none_share"] > 0 and s["ride_rows"] > 0
+    assert s["prefill_lookahead_share"] > 0
 
 
 # ------------------------------------------------- host-input staging

@@ -600,10 +600,14 @@ def test_phases_feed_the_span_tracer_under_the_old_names(engine):
     assert all(set(e[7]) == {"horizon", "slots", "slot_bound", "riders",
                              "p_ms", "d_ms"}
                for e in by_name["horizon_dispatch"])
-    assert all(set(e[7]) == {"rows", "padded_rows", "tokens", "riders"}
+    # since PR 59 beside whether the dispatch was launched before the
+    # last one's tokens were pulled (0 while nothing waits)
+    assert all(set(e[7]) == {"rows", "padded_rows", "tokens", "riders",
+                             "lookahead"}
                for e in by_name["prefill_chunk"])
     assert {e[7]["riders"] for name in ("horizon_dispatch", "prefill_chunk")
             for e in by_name[name]} == {0}
+    assert {e[7]["lookahead"] for e in by_name["prefill_chunk"]} == {0}
     assert sum(e[7]["tokens"] for e in by_name["harvest"]) + \
         len(by_name["request"]) == sum(len(w) for w in want)
     # the tracer's spans and the accumulators are one measurement
@@ -615,6 +619,55 @@ def test_phases_feed_the_span_tracer_under_the_old_names(engine):
     other = SpanTracer(process="u")
     sched.tracer = other
     assert sched.phases.tracer is other
+
+
+def test_the_boundary_phases_open_once_a_dispatch_under_look_ahead(engine):
+    """Slot-bound with the ride forced, prefill dispatches stay in
+    flight across step boundaries: ``first_token_wait`` and
+    ``first_token`` still open once for every dispatch that samples,
+    one right after the other, inside ``prefill`` inside ``step`` -- so
+    the readers that cut the device's idle time by these names
+    (``sched.idle_in_boundary.*``, ``host_busy_frac``) keep reading --
+    and a dispatch launched ahead says so (``lookahead=1``)."""
+    from tests.unit.test_serving_ride import hold_walls
+    rng = np.random.default_rng(7)
+    tracer = SpanTracer(process="t")
+    sched = ServingScheduler(engine, tracer=tracer, **CFG)
+    hold_walls(sched, 0)
+    for n, new in [(40, 6), (33, 9), (20, 5), (25, 12), (20, 7), (36, 10),
+                   (14, 4), (30, 8)]:
+        sched.submit(rng.integers(0, 256, n).astype(np.int32),
+                     max_new_tokens=new)
+    sched.run()
+    s = sched.summary()
+    assert s["prefill_lookahead_share"] > 0.3
+    by_name = {}
+    for e in tracer.events:
+        by_name.setdefault(e[1], []).append(e)
+    waits, emits = by_name["first_token_wait"], by_name["first_token"]
+    chunks = by_name["prefill_chunk"]
+    # every dispatch with riders samples; so may one without
+    sampling = sum(e[7]["riders"] > 0 for e in chunks)
+    assert sampling <= len(waits) == len(emits) <= len(chunks)
+    assert sum(e[7]["lookahead"] for e in chunks) == round(
+        s["prefill_lookahead_share"] * len(chunks))
+
+    def span(e):
+        return e[3], e[3] + e[4]
+
+    def inside(child, parents):
+        lo, hi = span(child)
+        return any(p_lo <= lo and hi <= p_hi
+                   for p_lo, p_hi in map(span, parents))
+    for w, f in zip(sorted(waits, key=span), sorted(emits, key=span)):
+        assert span(w)[1] <= span(f)[0]         # the pull, then the emit
+        assert inside(w, by_name["prefill"]) and \
+            inside(f, by_name["prefill"])
+    assert all(inside(p, by_name["step"]) for p in by_name["prefill"])
+    # the accumulators are the same measurement
+    for name in ("first_token_wait", "first_token"):
+        assert len(by_name[name]) == sched.phases.counts[name]
+    assert s["host_busy_frac"] <= 1.0 and s["first_token_wait_frac"] >= 0.0
 
 
 def test_a_slow_step_is_recorded_with_its_phase_split(engine):

@@ -65,24 +65,25 @@ def _sample_tokens(logits, rng, do_sample, temperature, top_k, top_p):
     argmax, so any change here silently breaks token-exactness between
     spec-decode serving and ``generate()``.
     """
-    logits = logits.astype(jnp.float32)
-    if not do_sample or not temperature:
-        return jnp.argmax(logits, axis=-1)
-    if temperature and temperature != 1.0:
-        logits = logits / temperature
-    if top_k and top_k > 0:
-        kth = jnp.sort(logits, axis=-1)[:, -top_k][:, None]
-        logits = jnp.where(logits < kth, -jnp.inf, logits)
-    if top_p and top_p < 1.0:
-        sorted_logits = jnp.sort(logits, axis=-1)[:, ::-1]
-        probs = jax.nn.softmax(sorted_logits, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        # smallest set with cumulative prob >= top_p
-        cutoff_idx = jnp.sum(cum < top_p, axis=-1)
-        cutoff = jnp.take_along_axis(sorted_logits, cutoff_idx[:, None],
-                                     axis=-1)
-        logits = jnp.where(logits < cutoff, -jnp.inf, logits)
-    return jax.random.categorical(rng, logits, axis=-1)
+    with jax.named_scope("sample"):
+        logits = logits.astype(jnp.float32)
+        if not do_sample or not temperature:
+            return jnp.argmax(logits, axis=-1)
+        if temperature and temperature != 1.0:
+            logits = logits / temperature
+        if top_k and top_k > 0:
+            kth = jnp.sort(logits, axis=-1)[:, -top_k][:, None]
+            logits = jnp.where(logits < kth, -jnp.inf, logits)
+        if top_p and top_p < 1.0:
+            sorted_logits = jnp.sort(logits, axis=-1)[:, ::-1]
+            probs = jax.nn.softmax(sorted_logits, axis=-1)
+            cum = jnp.cumsum(probs, axis=-1)
+            # smallest set with cumulative prob >= top_p
+            cutoff_idx = jnp.sum(cum < top_p, axis=-1)
+            cutoff = jnp.take_along_axis(sorted_logits, cutoff_idx[:, None],
+                                         axis=-1)
+            logits = jnp.where(logits < cutoff, -jnp.inf, logits)
+        return jax.random.categorical(rng, logits, axis=-1)
 
 
 class InferenceEngine:
@@ -981,7 +982,9 @@ class InferenceEngine:
                     adapters=adapters, seq_parallel=seq_parallel))
             # the model already reduced each row to its chunk's boundary
             # position (the only one a scheduler ever samples from)
-            return logits[:, 0], step.pools
+            with jax.named_scope("head"):
+                rows = logits[:, 0]
+            return rows, step.pools
 
         seq_plan = self.seq_parallel_plan()
 
@@ -1041,12 +1044,15 @@ class InferenceEngine:
                 new_active = active & (nxt != eos_ids) & (emitted < budgets)
                 return (nxt, new_active, cache.lengths, emitted,
                         cache.layers), (nxt, active)
-            (tok, active, lengths, emitted, layers), (toks, valid) = \
-                jax.lax.scan(body,
-                             (tok, active, lengths, emitted,
-                              pools["layers"]),
-                             jnp.arange(horizon))
-            return (toks.T, valid.T, tok, active, lengths, emitted,
+            with jax.named_scope("horizon"):
+                (tok, active, lengths, emitted, layers), (toks, valid) = \
+                    jax.lax.scan(body,
+                                 (tok, active, lengths, emitted,
+                                  pools["layers"]),
+                                 jnp.arange(horizon))
+            with jax.named_scope("horizon"):
+                toks, valid = toks.T, valid.T
+            return (toks, valid, tok, active, lengths, emitted,
                     {"layers": layers})
 
         def verify_multi(params, tok, drafts, widths, active, page_table,
@@ -1077,38 +1083,39 @@ class InferenceEngine:
                 {"params": materialize(params)}, x,
                 cache=kv_cache.verify_step(pools["layers"], page_table,
                                            lengths, cols, adapters=adapters))
-            # the greedy contract: fp32 argmax, ties to the lowest id
-            g = jnp.argmax(logits.astype(jnp.float32),
-                           axis=-1).astype(jnp.int32)       # [slots, K+1]
-            jK = jnp.arange(K)
-            ok = (drafts == g[:, :K]) & (jK[None, :] < widths[:, None])
-            a = jnp.cumprod(ok.astype(jnp.int32), axis=1).sum(axis=1)
-            bonus = jnp.take_along_axis(g, a[:, None], axis=1)
-            jW = jnp.arange(K + 1)
-            drafts_pad = jnp.concatenate(
-                [drafts, jnp.zeros((slots, 1), jnp.int32)], axis=1)
-            # emitted stream: accepted drafts then the bonus token
-            # (positions past it are frozen padding, masked by `valid`)
-            out_toks = jnp.where(jW[None, :] < a[:, None], drafts_pad,
-                                 bonus)
-            nominal = a + 1
-            is_eos = (out_toks == eos_ids[:, None]) & \
-                (eos_ids[:, None] >= 0)
-            has_eos = jnp.any(is_eos, axis=1)
-            n_eos = jnp.where(has_eos, jnp.argmax(is_eos, axis=1) + 1,
-                              K + 2)
-            n = jnp.minimum(jnp.minimum(nominal, n_eos),
-                            jnp.maximum(budgets - emitted, 0))
-            n = jnp.where(active, n, 0)
-            valid = jW[None, :] < n[:, None]
-            emitted_end = emitted + n
-            last = jnp.take_along_axis(
-                out_toks, jnp.maximum(n - 1, 0)[:, None], axis=1)[:, 0]
-            tok_end = jnp.where(n > 0, last, tok)
-            emitted_eos = has_eos & (n_eos <= n)
-            active_end = active & ~emitted_eos & (emitted_end < budgets)
-            lengths_end = lengths + n
-            accepted = jnp.minimum(a, n)
+            with jax.named_scope("sample"):
+                # the greedy contract: fp32 argmax, ties to the lowest id
+                g = jnp.argmax(logits.astype(jnp.float32),
+                               axis=-1).astype(jnp.int32)       # [slots, K+1]
+                jK = jnp.arange(K)
+                ok = (drafts == g[:, :K]) & (jK[None, :] < widths[:, None])
+                a = jnp.cumprod(ok.astype(jnp.int32), axis=1).sum(axis=1)
+                bonus = jnp.take_along_axis(g, a[:, None], axis=1)
+                jW = jnp.arange(K + 1)
+                drafts_pad = jnp.concatenate(
+                    [drafts, jnp.zeros((slots, 1), jnp.int32)], axis=1)
+                # emitted stream: accepted drafts then the bonus token
+                # (positions past it are frozen padding, masked by `valid`)
+                out_toks = jnp.where(jW[None, :] < a[:, None], drafts_pad,
+                                     bonus)
+                nominal = a + 1
+                is_eos = (out_toks == eos_ids[:, None]) & \
+                    (eos_ids[:, None] >= 0)
+                has_eos = jnp.any(is_eos, axis=1)
+                n_eos = jnp.where(has_eos, jnp.argmax(is_eos, axis=1) + 1,
+                                  K + 2)
+                n = jnp.minimum(jnp.minimum(nominal, n_eos),
+                                jnp.maximum(budgets - emitted, 0))
+                n = jnp.where(active, n, 0)
+                valid = jW[None, :] < n[:, None]
+                emitted_end = emitted + n
+                last = jnp.take_along_axis(
+                    out_toks, jnp.maximum(n - 1, 0)[:, None], axis=1)[:, 0]
+                tok_end = jnp.where(n > 0, last, tok)
+                emitted_eos = has_eos & (n_eos <= n)
+                active_end = active & ~emitted_eos & (emitted_end < budgets)
+                lengths_end = lengths + n
+                accepted = jnp.minimum(a, n)
             return (out_toks, valid, tok_end, active_end, lengths_end,
                     emitted_end, accepted, step.pools)
 
@@ -1150,11 +1157,14 @@ class InferenceEngine:
                 new_active = active & (nxt != eos_ids) & (emitted < budgets)
                 return (nxt, new_active, cache.lengths, emitted,
                         counts, cache.layers), (nxt, active)
-            (tok, active, lengths, emitted, counts, layers), \
-                (toks, valid) = jax.lax.scan(
-                    body, (tok, active, lengths, emitted, counts,
-                           pools["layers"]), jnp.arange(horizon))
-            return (toks.T, valid.T, tok, active, lengths, emitted,
+            with jax.named_scope("horizon"):
+                (tok, active, lengths, emitted, counts, layers), \
+                    (toks, valid) = jax.lax.scan(
+                        body, (tok, active, lengths, emitted, counts,
+                               pools["layers"]), jnp.arange(horizon))
+            with jax.named_scope("horizon"):
+                toks, valid = toks.T, valid.T
+            return (toks, valid, tok, active, lengths, emitted,
                     counts, {"layers": layers})
 
         def verify_multi_policy(params, tok, drafts, widths, active,
@@ -1185,53 +1195,54 @@ class InferenceEngine:
                 {"params": materialize(params)}, x_in,
                 cache=kv_cache.verify_step(pools["layers"], page_table,
                                            lengths, cols))
-            drafts_pad = jnp.concatenate(
-                [drafts, jnp.zeros((slots, 1), jnp.int32)], axis=1)
+            with jax.named_scope("sample"):
+                drafts_pad = jnp.concatenate(
+                    [drafts, jnp.zeros((slots, 1), jnp.int32)], axis=1)
 
-            def col(carry, j):
-                counts_c, accepting, acc, bonus = carry
-                lg = policy_pipeline.process_logits(
-                    logits[:, j], counts_c, mask, temps, top_ks, top_ps,
-                    rep_pens, pres_pens, freq_pens)
-                d = drafts_pad[:, j]
-                is_draft = (j < widths) & accepting
-                is_bonus = (j == widths) & accepting
-                accept_col, fallback = policy_pipeline.accept_or_resample(
-                    lg, d, keys, tok_base + j, temps)
-                bonus_col = policy_pipeline.bonus_sample(
-                    lg, keys, tok_base + j, temps)
-                draft_accept = is_draft & accept_col
-                reject_now = is_draft & ~accept_col
-                bonus = jnp.where(reject_now, fallback,
-                                  jnp.where(is_bonus, bonus_col, bonus))
-                counts_c = counts_c.at[jnp.arange(slots), d].add(
-                    draft_accept.astype(jnp.int32))
-                acc = acc + draft_accept.astype(jnp.int32)
-                return (counts_c, draft_accept, acc, bonus), None
-            (counts, _, a, bonus), _ = jax.lax.scan(
-                col, (counts, active, jnp.zeros(slots, jnp.int32),
-                      jnp.zeros(slots, jnp.int32)), jnp.arange(K + 1))
-            jW = jnp.arange(K + 1)
-            out_toks = jnp.where(jW[None, :] < a[:, None], drafts_pad,
-                                 bonus[:, None])
-            nominal = a + 1
-            is_eos = (out_toks == eos_ids[:, None]) & \
-                (eos_ids[:, None] >= 0)
-            has_eos = jnp.any(is_eos, axis=1)
-            n_eos = jnp.where(has_eos, jnp.argmax(is_eos, axis=1) + 1,
-                              K + 2)
-            n = jnp.minimum(jnp.minimum(nominal, n_eos),
-                            jnp.maximum(budgets - emitted, 0))
-            n = jnp.where(active, n, 0)
-            valid = jW[None, :] < n[:, None]
-            emitted_end = emitted + n
-            last = jnp.take_along_axis(
-                out_toks, jnp.maximum(n - 1, 0)[:, None], axis=1)[:, 0]
-            tok_end = jnp.where(n > 0, last, tok)
-            emitted_eos = has_eos & (n_eos <= n)
-            active_end = active & ~emitted_eos & (emitted_end < budgets)
-            lengths_end = lengths + n
-            accepted = jnp.minimum(a, n)
+                def col(carry, j):
+                    counts_c, accepting, acc, bonus = carry
+                    lg = policy_pipeline.process_logits(
+                        logits[:, j], counts_c, mask, temps, top_ks, top_ps,
+                        rep_pens, pres_pens, freq_pens)
+                    d = drafts_pad[:, j]
+                    is_draft = (j < widths) & accepting
+                    is_bonus = (j == widths) & accepting
+                    accept_col, fallback = policy_pipeline.accept_or_resample(
+                        lg, d, keys, tok_base + j, temps)
+                    bonus_col = policy_pipeline.bonus_sample(
+                        lg, keys, tok_base + j, temps)
+                    draft_accept = is_draft & accept_col
+                    reject_now = is_draft & ~accept_col
+                    bonus = jnp.where(reject_now, fallback,
+                                      jnp.where(is_bonus, bonus_col, bonus))
+                    counts_c = counts_c.at[jnp.arange(slots), d].add(
+                        draft_accept.astype(jnp.int32))
+                    acc = acc + draft_accept.astype(jnp.int32)
+                    return (counts_c, draft_accept, acc, bonus), None
+                (counts, _, a, bonus), _ = jax.lax.scan(
+                    col, (counts, active, jnp.zeros(slots, jnp.int32),
+                          jnp.zeros(slots, jnp.int32)), jnp.arange(K + 1))
+                jW = jnp.arange(K + 1)
+                out_toks = jnp.where(jW[None, :] < a[:, None], drafts_pad,
+                                     bonus[:, None])
+                nominal = a + 1
+                is_eos = (out_toks == eos_ids[:, None]) & \
+                    (eos_ids[:, None] >= 0)
+                has_eos = jnp.any(is_eos, axis=1)
+                n_eos = jnp.where(has_eos, jnp.argmax(is_eos, axis=1) + 1,
+                                  K + 2)
+                n = jnp.minimum(jnp.minimum(nominal, n_eos),
+                                jnp.maximum(budgets - emitted, 0))
+                n = jnp.where(active, n, 0)
+                valid = jW[None, :] < n[:, None]
+                emitted_end = emitted + n
+                last = jnp.take_along_axis(
+                    out_toks, jnp.maximum(n - 1, 0)[:, None], axis=1)[:, 0]
+                tok_end = jnp.where(n > 0, last, tok)
+                emitted_eos = has_eos & (n_eos <= n)
+                active_end = active & ~emitted_eos & (emitted_end < budgets)
+                lengths_end = lengths + n
+                accepted = jnp.minimum(a, n)
             return (out_toks, valid, tok_end, active_end, lengths_end,
                     emitted_end, accepted, counts, step.pools)
 
@@ -1933,11 +1944,12 @@ class InferenceEngine:
         if getattr(self, "_policy_rows_fn", None) is None:
             def rows_fn(rows, keys, tok_idx, temps, top_ks, top_ps,
                         rep_pens, pres_pens, freq_pens, counts, mask):
-                x = policy_pipeline.process_logits(
-                    rows, counts, mask, temps, top_ks, top_ps, rep_pens,
-                    pres_pens, freq_pens)
-                return policy_pipeline.sample_processed(
-                    x, keys, tok_idx, temps).astype(jnp.int32)
+                with jax.named_scope("sample"):
+                    x = policy_pipeline.process_logits(
+                        rows, counts, mask, temps, top_ks, top_ps,
+                        rep_pens, pres_pens, freq_pens)
+                    return policy_pipeline.sample_processed(
+                        x, keys, tok_idx, temps).astype(jnp.int32)
             self._policy_rows_fn = jax.jit(rows_fn)
         n = rows.shape[0]
         with dist.mesh_scope(self.mesh):
@@ -2021,13 +2033,16 @@ class InferenceEngine:
             rep = self._serving_shardings().replicated
 
             def keep(tokens, sampled, slot):
-                return tokens.at[slot].set(sampled.astype(tokens.dtype),
-                                           mode="drop")
+                with jax.named_scope("sample"):
+                    return tokens.at[slot].set(
+                        sampled.astype(tokens.dtype), mode="drop")
 
             def ids(host_ids, src, tokens):
-                first = jnp.where(src >= 0, tokens[jnp.maximum(src, 0)],
-                                  host_ids[:, 0])
-                return host_ids.at[:, 0].set(first)
+                with jax.named_scope("sample"):
+                    first = jnp.where(src >= 0,
+                                      tokens[jnp.maximum(src, 0)],
+                                      host_ids[:, 0])
+                    return host_ids.at[:, 0].set(first)
             self._token_keep_fn = jax.jit(keep, out_shardings=rep)
             self._token_ids_fn = jax.jit(ids, out_shardings=rep)
         return self._token_keep_fn, self._token_ids_fn

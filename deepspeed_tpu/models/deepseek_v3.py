@@ -191,11 +191,14 @@ class MLAttention(nn.Module):
         ckr = _proj(cfg, rank + dr, ("embed", None), "wkv_a")(x)
         # the latent is normed BEFORE it is cached, the shared key
         # cached rotated
-        c = RMSNorm(cfg.rms_eps, cfg.dtype, name="kv_a_norm")(
-            ckr[..., :rank])
-        k_r = rope(ckr[..., None, rank:], positions, base=cfg.rope_theta)
-        q_nope = q[..., :dn]
-        q_rope = rope(q[..., dn:], positions, base=cfg.rope_theta)
+        with jax.named_scope("attn_proj"):
+            c = ckr[..., :rank]
+        c = RMSNorm(cfg.rms_eps, cfg.dtype, name="kv_a_norm")(c)
+        with jax.named_scope("rope"):
+            k_r = rope(ckr[..., None, rank:], positions,
+                       base=cfg.rope_theta)
+            q_nope = q[..., :dn]
+            q_rope = rope(q[..., dn:], positions, base=cfg.rope_theta)
         # [rank, heads, nope | v]: head h's W_UK beside its W_UV
         w_kvb = _value(self.param(
             "wkv_b", nn.with_partitioning(nn.initializers.normal(0.02),
@@ -207,19 +210,24 @@ class MLAttention(nn.Module):
             with jax.named_scope("mla_absorb"):
                 q_abs = jnp.einsum("blhd,rhd->blhr", q_nope,
                                    w_kvb[..., :dn])
+            with jax.named_scope("attn_proj"):
+                q_lat = jnp.concatenate([q_abs, q_rope], -1)
+                k_lat = jnp.concatenate([c, k_r[:, :, 0]], -1)
             u, new_cache = kv_cache.attend(
-                jnp.concatenate([q_abs, q_rope], -1),
-                jnp.concatenate([c, k_r[:, :, 0]], -1), None, positions,
-                cache, value_dim=rank, scale=cfg.qk_head_dim ** -0.5)
+                q_lat, k_lat, None, positions, cache, value_dim=rank,
+                scale=cfg.qk_head_dim ** -0.5)
             with jax.named_scope("mla_absorb"):
                 out = jnp.einsum("blhr,rhd->blhd", u, w_kvb[..., dn:])
         else:
-            kv = jnp.einsum("blr,rhd->blhd", c, w_kvb)
-            k = jnp.concatenate(
-                [kv[..., :dn], jnp.broadcast_to(k_r, (b, l, h, dr))], -1)
+            with jax.named_scope("attn_proj"):
+                kv = jnp.einsum("blr,rhd->blhd", c, w_kvb)
+                k = jnp.concatenate(
+                    [kv[..., :dn], jnp.broadcast_to(k_r, (b, l, h, dr))],
+                    -1)
+                q_full, v = jnp.concatenate([q_nope, q_rope], -1), \
+                    kv[..., dn:]
             out, new_cache = kv_cache.attend(
-                jnp.concatenate([q_nope, q_rope], -1), k, kv[..., dn:],
-                positions, cache, impl=cfg.attn_impl)
+                q_full, k, v, positions, cache, impl=cfg.attn_impl)
         out = _proj(cfg, cfg.hidden_size, ("heads", "embed"), "wo")(
             out.reshape(b, l, h * dv))
         return out, new_cache
@@ -299,7 +307,8 @@ class DeepseekBlock(nn.Module):
         attn, new_cache = MLAttention(cfg, name="attn")(
             RMSNorm(cfg.rms_eps, cfg.dtype, name="input_norm")(x),
             positions, kv_view)
-        x = x + attn
+        with jax.named_scope("residual"):
+            x = x + attn
         u = RMSNorm(cfg.rms_eps, cfg.dtype, name="pre_ff_norm")(x)
         if self.routed:
             out, stats = DeepseekMoE(cfg, name="moe")(u, cache)
@@ -307,7 +316,8 @@ class DeepseekBlock(nn.Module):
                 new_cache = dict(new_cache, **count_routing(routing, stats))
         else:
             out = SwiGLUMLP(cfg, cfg.intermediate_size, name="mlp")(u)
-        return x + out, new_cache
+        with jax.named_scope("residual"):
+            return x + out, new_cache
 
 
 class DeepseekV3(nn.Module):
@@ -331,7 +341,8 @@ class DeepseekV3(nn.Module):
             "embed_tokens", nn.with_partitioning(
                 nn.initializers.normal(0.02), ("vocab", "embed")),
             (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype))
-        x = embed.astype(cfg.dtype)[input_ids]
+        with jax.named_scope("embed"):
+            x = embed.astype(cfg.dtype)[input_ids]
         new_layers = []
         for i in range(cfg.num_layers):
             x, new_c = DeepseekBlock(

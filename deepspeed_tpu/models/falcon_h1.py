@@ -39,6 +39,7 @@ import math
 from typing import Any, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -176,13 +177,15 @@ class FalconAttention(nn.Module):
         b, l, _ = x.shape
         h, kv_h, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         q = _proj(cfg, h * d, ("embed", "heads"), "wq")(x)
-        k = _scaled(_proj(cfg, kv_h * d, ("embed", "kv"), "wk")(x),
-                    cfg.key_multiplier)
+        k = _proj(cfg, kv_h * d, ("embed", "kv"), "wk")(x)
+        with jax.named_scope("attn_proj"):
+            k = _scaled(k, cfg.key_multiplier)
         v = _proj(cfg, kv_h * d, ("embed", "kv"), "wv")(x)
-        q = apply_rotary_emb(q.reshape(b, l, h, d), positions,
-                             base=cfg.rope_base)
-        k = apply_rotary_emb(k.reshape(b, l, kv_h, d), positions,
-                             base=cfg.rope_base)
+        with jax.named_scope("rope"):
+            q = apply_rotary_emb(q.reshape(b, l, h, d), positions,
+                                 base=cfg.rope_base)
+            k = apply_rotary_emb(k.reshape(b, l, kv_h, d), positions,
+                                 base=cfg.rope_base)
         out, new_cache = kv_cache.attend(
             q, k, v.reshape(b, l, kv_h, d), positions, cache,
             impl=cfg.attn_impl)
@@ -274,14 +277,21 @@ class FalconH1Block(nn.Module):
         cfg = self.cfg
         u = RMSNorm(cfg.rms_eps, cfg.dtype, name="input_norm")(x)
         kv_view, state_view = _split_entry(cache)
+        with jax.named_scope("norm"):
+            u_attn = _scaled(u, cfg.attention_in_multiplier)
         attn, new_kv = FalconAttention(cfg, name="attn")(
-            _scaled(u, cfg.attention_in_multiplier), positions, kv_view)
+            u_attn, positions, kv_view)
+        with jax.named_scope("norm"):
+            u_ssm = _scaled(u, cfg.ssm_in_multiplier)
         ssm, new_state = FalconMamba(cfg, name="mamba")(
-            _scaled(u, cfg.ssm_in_multiplier), positions, state_view)
-        x = x + _scaled(attn, cfg.attention_out_multiplier) + \
-            _scaled(ssm, cfg.ssm_out_multiplier)
-        x = x + FalconMLP(cfg, name="mlp")(
+            u_ssm, positions, state_view)
+        with jax.named_scope("residual"):
+            x = x + _scaled(attn, cfg.attention_out_multiplier) + \
+                _scaled(ssm, cfg.ssm_out_multiplier)
+        mlp_out = FalconMLP(cfg, name="mlp")(
             RMSNorm(cfg.rms_eps, cfg.dtype, name="pre_ff_norm")(x))
+        with jax.named_scope("residual"):
+            x = x + mlp_out
         if cache is None:
             return x, None
         # each side returned its own leaves; the entry is both
@@ -308,8 +318,9 @@ class FalconH1(nn.Module):
             "embed_tokens", nn.with_partitioning(
                 nn.initializers.normal(0.02), ("vocab", "embed")),
             (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype))
-        x = _scaled(embed.astype(cfg.dtype)[input_ids],
-                    cfg.embedding_multiplier)
+        with jax.named_scope("embed"):
+            x = _scaled(embed.astype(cfg.dtype)[input_ids],
+                        cfg.embedding_multiplier)
         new_layers = []
         for i in range(cfg.num_layers):
             x, new_c = FalconH1Block(cfg, name=f"layers_{i}")(
@@ -317,8 +328,9 @@ class FalconH1(nn.Module):
             new_layers.append(new_c)
         x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(
             kv_cache.head_rows(cache, x))
-        logits = _scaled(_proj(cfg, cfg.vocab_size, ("embed", "vocab"),
-                               "lm_head")(x), cfg.lm_head_multiplier)
+        with jax.named_scope("head"):
+            logits = _scaled(_proj(cfg, cfg.vocab_size, ("embed", "vocab"),
+                                   "lm_head")(x), cfg.lm_head_multiplier)
         if cache is None:
             return logits
         return logits, kv_cache.advance(cache, new_layers)

@@ -212,9 +212,11 @@ class Block(nn.Module):
                 epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, name="ln_2")(x)
             assert not self.use_moe, "parallel residual + MoE unsupported"
             mlp_out = MLP(cfg, name="mlp")(h, deterministic, ad, ad_rows)
-            out = x + attn_out + mlp_out
+            with jax.named_scope("residual"):
+                out = x + attn_out + mlp_out
         else:
-            x = x + attn_out
+            with jax.named_scope("residual"):
+                x = x + attn_out
             h = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                              name="ln_2")(x)
             if self.use_moe:
@@ -231,7 +233,8 @@ class Block(nn.Module):
                               name="moe")(h, deterministic)
             else:
                 h = MLP(cfg, name="mlp")(h, deterministic, ad, ad_rows)
-            out = x + h
+            with jax.named_scope("residual"):
+                out = x + h
         if pld_keep is not None:
             # progressive layer drop (reference
             # runtime/progressive_layer_drop.py + the PLD paper's
@@ -279,13 +282,14 @@ def _embed_tokens(wte_v, wpe_v, input_ids, cfg, positions=None,
             return w[i]
         return plan.pin_batch(plan.take(w, i, path + (name,)))
 
-    x = take(wte_v.astype(cfg.dtype), input_ids, "wte")
-    if wpe_v is not None:
-        if positions is None:
-            positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
-        x = x + take(wpe_v.astype(cfg.dtype), positions + cfg.pos_offset,
-                     "wpe")
-    return x
+    with jax.named_scope("embed"):
+        x = take(wte_v.astype(cfg.dtype), input_ids, "wte")
+        if wpe_v is not None:
+            if positions is None:
+                positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
+            x = x + take(wpe_v.astype(cfg.dtype),
+                         positions + cfg.pos_offset, "wpe")
+        return x
 
 
 def _head_logits(x, cfg, *, wte_v=None, dense_ctor=None, gather_at=None):
@@ -296,11 +300,12 @@ def _head_logits(x, cfg, *, wte_v=None, dense_ctor=None, gather_at=None):
                      name="ln_f")(x)
     if cfg.tie_embeddings:
         assert wte_v is not None, "tied head needs the embedding table"
-        if gather_at is not None:
-            plan, path = gather_at
-            return plan.einsum("ble,ve->blv", x, wte_v.astype(cfg.dtype),
-                               path + ("wte",))
-        return jnp.einsum("ble,ve->blv", x, wte_v.astype(cfg.dtype))
+        with jax.named_scope("head"):
+            if gather_at is not None:
+                plan, path = gather_at
+                return plan.einsum("ble,ve->blv", x,
+                                   wte_v.astype(cfg.dtype), path + ("wte",))
+            return jnp.einsum("ble,ve->blv", x, wte_v.astype(cfg.dtype))
     return dense_ctor(cfg.vocab_size, cfg, ("embed", "vocab"),
                       name="lm_head", use_bias=cfg.lm_head_bias)(x)
 

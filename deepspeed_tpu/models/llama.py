@@ -16,6 +16,7 @@ import dataclasses
 from typing import Any, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -98,8 +99,9 @@ class LlamaAttention(nn.Module):
         q = q.reshape(b, l, h, d)
         k = k.reshape(b, l, kv_h, d)
         v = v.reshape(b, l, kv_h, d)
-        q = apply_rotary_emb(q, positions, base=cfg.rope_base)
-        k = apply_rotary_emb(k, positions, base=cfg.rope_base)
+        with jax.named_scope("rope"):
+            q = apply_rotary_emb(q, positions, base=cfg.rope_base)
+            k = apply_rotary_emb(k, positions, base=cfg.rope_base)
 
         # GQA stays grouped through every cache (ops/attention/kv_cache.py
         # expands it only for flash and the sequence-parallel transports)
@@ -151,10 +153,13 @@ class LlamaBlock(nn.Module):
         attn_out, new_cache = LlamaAttention(cfg, name="attn")(
             RMSNorm(cfg.rms_eps, cfg.dtype, name="input_norm")(x),
             positions, cache)
-        x = x + attn_out
-        x = x + LlamaMLP(cfg, name="mlp")(
+        with jax.named_scope("residual"):
+            x = x + attn_out
+        mlp_out = LlamaMLP(cfg, name="mlp")(
             RMSNorm(cfg.rms_eps, cfg.dtype, name="post_attn_norm")(x),
             ad, ad_rows)
+        with jax.named_scope("residual"):
+            x = x + mlp_out
         if plan is not None:
             x = plan.pin_batch(x)
         return x, new_cache
@@ -182,12 +187,13 @@ class Llama(nn.Module):
         # table is gathered at the lookup and at a tied head, every
         # QDense kernel at its matmul, and the batch stays on `data`
         plan = zero_gather.active() if cache is None else None
-        if plan is not None:
-            x = plan.pin_batch(plan.take(
-                embed_v.astype(cfg.dtype), input_ids,
-                self.path + ("embed_tokens",)))
-        else:
-            x = embed_v.astype(cfg.dtype)[input_ids]
+        with jax.named_scope("embed"):
+            if plan is not None:
+                x = plan.pin_batch(plan.take(
+                    embed_v.astype(cfg.dtype), input_ids,
+                    self.path + ("embed_tokens",)))
+            else:
+                x = embed_v.astype(cfg.dtype)[input_ids]
 
         block = LlamaBlock
         if cfg.remat and cache is None:
@@ -201,11 +207,15 @@ class Llama(nn.Module):
 
         x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(
             kv_cache.head_rows(cache, x))
-        if cfg.tie_embeddings and plan is not None:
-            logits = plan.einsum("ble,ve->blv", x, embed_v.astype(cfg.dtype),
-                                 self.path + ("embed_tokens",))
-        elif cfg.tie_embeddings:
-            logits = jnp.einsum("ble,ve->blv", x, embed_v.astype(cfg.dtype))
+        if cfg.tie_embeddings:
+            with jax.named_scope("head"):
+                if plan is not None:
+                    logits = plan.einsum(
+                        "ble,ve->blv", x, embed_v.astype(cfg.dtype),
+                        self.path + ("embed_tokens",))
+                else:
+                    logits = jnp.einsum("ble,ve->blv", x,
+                                        embed_v.astype(cfg.dtype))
         else:
             logits = _proj(cfg, cfg.vocab_size, ("embed", "vocab"),
                            "lm_head")(x)

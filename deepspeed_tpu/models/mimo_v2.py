@@ -47,6 +47,7 @@ import dataclasses
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.llama import RMSNorm
@@ -183,14 +184,16 @@ class MiMoAttention(nn.Module):
         k = _proj(cfg, kv_h * d, ("embed", "kv"), "wk")(x)
         v = _proj(cfg, kv_h * d_v, ("embed", "kv"), "wv")(x)
         base = cfg.swa_rope_theta if window else cfg.rope_theta
-        q = apply_partial_rotary(q.reshape(b, l, h, d), positions,
-                                 cfg.rotary_dim, base=base)
-        k = apply_partial_rotary(k.reshape(b, l, kv_h, d), positions,
-                                 cfg.rotary_dim, base=base)
+        with jax.named_scope("rope"):
+            q = apply_partial_rotary(q.reshape(b, l, h, d), positions,
+                                     cfg.rotary_dim, base=base)
+            k = apply_partial_rotary(k.reshape(b, l, kv_h, d), positions,
+                                     cfg.rotary_dim, base=base)
         # the value scale in float32, rounded once (a scale rounded to
         # bfloat16 first would put one common error on every value)
-        v = (v.astype(jnp.float32) * cfg.attention_value_scale) \
-            .astype(v.dtype).reshape(b, l, kv_h, d_v)
+        with jax.named_scope("attn_proj"):
+            v = (v.astype(jnp.float32) * cfg.attention_value_scale) \
+                .astype(v.dtype).reshape(b, l, kv_h, d_v)
         sink = None
         if cfg.add_swa_attention_sink_bias if window \
                 else cfg.add_full_attention_sink_bias:
@@ -299,7 +302,8 @@ class MiMoBlock(nn.Module):
             cfg, self.kind, name="swa" if self.kind == WINDOW else "attn")(
             RMSNorm(cfg.rms_eps, cfg.dtype, name="input_norm")(x),
             positions, kv_view)
-        x = x + attn
+        with jax.named_scope("residual"):
+            x = x + attn
         u = RMSNorm(cfg.rms_eps, cfg.dtype, name="pre_ff_norm")(x)
         if self.routed:
             out, stats = MiMoMoE(cfg, name="moe")(u, cache)
@@ -307,7 +311,8 @@ class MiMoBlock(nn.Module):
                 new_cache = dict(new_cache, **count_routing(routing, stats))
         else:
             out = MiMoMLP(cfg, name="mlp")(u)
-        return x + out, new_cache
+        with jax.named_scope("residual"):
+            return x + out, new_cache
 
 
 class MiMoV2(nn.Module):
@@ -331,7 +336,8 @@ class MiMoV2(nn.Module):
             "embed_tokens", nn.with_partitioning(
                 nn.initializers.normal(0.02), ("vocab", "embed")),
             (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype))
-        x = embed.astype(cfg.dtype)[input_ids]
+        with jax.named_scope("embed"):
+            x = embed.astype(cfg.dtype)[input_ids]
         new_layers = []
         for i, (kind, routed) in enumerate(zip(cfg.layer_pattern,
                                                cfg.moe_pattern)):

@@ -324,7 +324,8 @@ class NemotronBlock(nn.Module):
         out, new_cache = MIXERS[self.kind](self.cfg, name=self.kind)(
             RMSNorm(self.cfg.rms_eps, self.cfg.dtype, name="norm")(x),
             positions, cache)
-        return x + out, new_cache
+        with jax.named_scope("residual"):
+            return x + out, new_cache
 
 
 class NemotronH(nn.Module):
@@ -347,7 +348,8 @@ class NemotronH(nn.Module):
             "embed_tokens", nn.with_partitioning(
                 nn.initializers.normal(0.02), ("vocab", "embed")),
             (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype))
-        x = embed.astype(cfg.dtype)[input_ids]
+        with jax.named_scope("embed"):
+            x = embed.astype(cfg.dtype)[input_ids]
         new_layers = []
         for i, ch in enumerate(cfg.pattern):
             x, new_c = NemotronBlock(cfg, KINDS[ch], name=f"layers_{i}")(
@@ -426,9 +428,10 @@ def routing_leaves():
 def count_routing(entry, stats):
     """The entry's two counter leaves with one call's ``stats`` added."""
     out, at = {}, 0
-    for name, n in ROUTING_LEAVES.items():
-        out[name] = entry[name] + stats[at:at + n]
-        at += n
+    with jax.named_scope("router"):
+        for name, n in ROUTING_LEAVES.items():
+            out[name] = entry[name] + stats[at:at + n]
+            at += n
     return out
 
 

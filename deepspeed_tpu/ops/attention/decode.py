@@ -341,7 +341,9 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
     # [slots, 1, h, d] -> [slots, kv_h, group, d]: head kv*group + g is
     # kv head kv's g-th query head (the _repeat_kv grouping)
     q_g = q.reshape(slots, kv_h, group, d)
-    pair, pages, n = _live_pairs(page_table, positions, active, page_size)
+    with jax.named_scope("cache"):
+        pair, pages, n = _live_pairs(page_table, positions, active,
+                                     page_size)
 
     def slot_index(i, pair, pages, pos, n):
         return (pair[i] // maxp, 0, 0, 0)

@@ -35,7 +35,11 @@ submodule, ``jax.named_scope`` or kernel ``name=`` may come between
 (ops/attention/paged_prefill.py), is NAMED for the same reason turned
 round: inside the same scope it would be counted as paged decode, so
 its call is jitted under its own name and its instruction reads
-``paged_prefill``.
+``paged_prefill``.  The scopes this file does open (``cache`` round the
+page writes and the lengths' bookkeeping, ``head`` round the boundary
+rows) close before a kernel is called: they name what
+``tracing.component`` reads and leave every kernel's name the
+``attn`` scope's.
 """
 
 import dataclasses
@@ -138,15 +142,16 @@ def positions(cache, b, l):
     """Absolute positions [b, l] of this call's tokens.  A prefill row
     starts at ``lengths[slot]``, which a prefix-cache hit seeds to the
     cached boundary (not 0, not page-aligned)."""
-    if isinstance(cache, PagedStep):
-        lens = cache.lengths if cache.mode != "prefill" \
-            else cache.lengths[cache.rows]
-        pos = lens[:, None]
-        if cache.mode != "decode":
-            pos = pos + jnp.arange(l)[None, :]
-        return jnp.broadcast_to(pos, (b, l))
-    start = 0 if cache is None else cache["layers"][0]["index"]
-    return jnp.broadcast_to(start + jnp.arange(l)[None], (b, l))
+    with jax.named_scope("cache"):
+        if isinstance(cache, PagedStep):
+            lens = cache.lengths if cache.mode != "prefill" \
+                else cache.lengths[cache.rows]
+            pos = lens[:, None]
+            if cache.mode != "decode":
+                pos = pos + jnp.arange(l)[None, :]
+            return jnp.broadcast_to(pos, (b, l))
+        start = 0 if cache is None else cache["layers"][0]["index"]
+        return jnp.broadcast_to(start + jnp.arange(l)[None], (b, l))
 
 
 def layer_view(cache, i):
@@ -173,8 +178,9 @@ def head_rows(cache, x):
     skip the full-vocab head for the chunk's other positions (~30% of a
     prefill step at gpt2-small shapes)."""
     if isinstance(cache, PagedStep) and cache.mode == "prefill":
-        return jnp.take_along_axis(
-            x, jnp.maximum(cache.count - 1, 0)[:, None, None], axis=1)
+        with jax.named_scope("head"):
+            return jnp.take_along_axis(
+                x, jnp.maximum(cache.count - 1, 0)[:, None, None], axis=1)
     return x
 
 
@@ -182,15 +188,16 @@ def advance(cache, new_layers):
     """The cache a model returns beside its logits."""
     if not isinstance(cache, PagedStep):
         return {"layers": new_layers}
-    if cache.mode == "prefill":
-        lengths = cache.lengths.at[cache.rows].add(cache.count)
-    elif cache.mode == "verify":
-        # widths columns written per slot (already 0 for inactive
-        # slots); the engine's verify primitive rewinds this to the
-        # emitted-token count after acceptance
-        lengths = cache.lengths + cache.count
-    else:
-        lengths = cache.lengths + cache.count.astype(jnp.int32)
+    with jax.named_scope("cache"):
+        if cache.mode == "prefill":
+            lengths = cache.lengths.at[cache.rows].add(cache.count)
+        elif cache.mode == "verify":
+            # widths columns written per slot (already 0 for inactive
+            # slots); the engine's verify primitive rewinds this to the
+            # emitted-token count after acceptance
+            lengths = cache.lengths + cache.count
+        else:
+            lengths = cache.lengths + cache.count.astype(jnp.int32)
     return dataclasses.replace(cache, lengths=lengths, layers=new_layers)
 
 
@@ -271,10 +278,12 @@ def attend(q, k, v, positions, cache, *, impl="auto", window=0,
         num_pages, ps = page_leaf(pools).shape[:2]
         bias = None if key_bias is None else \
             key_bias(jnp.arange(pt.shape[1] * ps))
-        page_ids = jnp.where(
-            cache.count, pt[jnp.arange(q.shape[0]), pos // ps], num_pages)
-        pools = paged_write(pools, page_ids, pos % ps, k[:, 0],
-                            None if v is None else v[:, 0])
+        with jax.named_scope("cache"):
+            page_ids = jnp.where(
+                cache.count, pt[jnp.arange(q.shape[0]), pos // ps],
+                num_pages)
+            pools = paged_write(pools, page_ids, pos % ps, k[:, 0],
+                                None if v is None else v[:, 0])
         out = paged_decode_attention(
             q, page_leaf(pools), pools.get("v_pages"), pt, pos, bias=bias,
             k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"),
@@ -312,13 +321,14 @@ def _paged_multi(q, k, v, pos, step, key_bias, scale=None, value_dim=None):
     pools, pt = step.layers, step.page_table
     num_pages, ps = page_leaf(pools).shape[:2]
     b, l = pos.shape
-    write = jnp.arange(l)[None, :] < step.count[:, None]
-    rows = jnp.arange(b) if step.rows is None else step.rows
-    page_ids = jnp.where(write, pt[rows[:, None], pos // ps], num_pages)
-    # out-of-bounds page ids drop; quantized pools carry parallel
-    # per-row scale pools that the same masked ids update atomically
-    pools = paged_write(pools, page_ids, pos % ps, k, v)
-    pt_rows = pt if step.rows is None else pt[step.rows]
+    with jax.named_scope("cache"):
+        write = jnp.arange(l)[None, :] < step.count[:, None]
+        rows = jnp.arange(b) if step.rows is None else step.rows
+        page_ids = jnp.where(write, pt[rows[:, None], pos // ps], num_pages)
+        # out-of-bounds page ids drop; quantized pools carry parallel
+        # per-row scale pools that the same masked ids update atomically
+        pools = paged_write(pools, page_ids, pos % ps, k, v)
+        pt_rows = pt if step.rows is None else pt[step.rows]
     if step.seq_parallel is None:
         latent = value_dim is not None
         decision, mesh = trace_time_decision(
@@ -364,11 +374,12 @@ def _attend_dense(q, k, v, positions, cache, window, key_bias):
     p iff j <= p (``index`` is traced, so no dynamic slicing).
     Single-token steps hit the Pallas softmax_context kernel; GQA caches
     are consumed grouped, never expanded."""
-    at = (0, cache["index"], 0, 0)
-    k_cache = lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), at)
-    v_cache = lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), at)
+    with jax.named_scope("cache"):
+        at = (0, cache["index"], 0, 0)
+        k_cache = lax.dynamic_update_slice(
+            cache["k"], k.astype(cache["k"].dtype), at)
+        v_cache = lax.dynamic_update_slice(
+            cache["v"], v.astype(cache["v"].dtype), at)
     new_cache = {"k": k_cache, "v": v_cache,
                  "index": cache["index"] + q.shape[1]}
     bias = _causal_bias(jnp.arange(k_cache.shape[1]), positions, key_bias,

@@ -365,8 +365,10 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
     # a padding row (count == 0) sees position 0 alone: finite, unused
     last = jnp.where(count > 0, start + count.astype(jnp.int32) - 1, 0)
 
-    tile, k_idx, pages, n = _live_steps(page_table.astype(jnp.int32), start,
-                                        last, cols, tiles, page_size, block)
+    with jax.named_scope("cache"):
+        tile, k_idx, pages, n = _live_steps(
+            page_table.astype(jnp.int32), start, last, cols, tiles,
+            page_size, block)
     cap = tile.shape[0]
 
     def tile_index(i, tile, k_idx, pages, st, ls):

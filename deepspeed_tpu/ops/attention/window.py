@@ -42,6 +42,7 @@ the full forward, ``generate()``'s dense cache): the flash and
 contiguous-decode kernels take neither.
 """
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -104,11 +105,12 @@ def attend_dense(q, k, v, positions, cache, *, window=0, sink=None):
     """``generate()``'s dense cache ``{"k", "v", "index"}``: append at
     ``index`` and attend over the whole buffer under the positional
     mask (``kv_cache._attend_dense`` with a sink and two widths)."""
-    at = (0, cache["index"], 0, 0)
-    k_cache = lax.dynamic_update_slice(cache["k"],
-                                       k.astype(cache["k"].dtype), at)
-    v_cache = lax.dynamic_update_slice(cache["v"],
-                                       v.astype(cache["v"].dtype), at)
+    with jax.named_scope("cache"):
+        at = (0, cache["index"], 0, 0)
+        k_cache = lax.dynamic_update_slice(cache["k"],
+                                           k.astype(cache["k"].dtype), at)
+        v_cache = lax.dynamic_update_slice(cache["v"],
+                                           v.astype(cache["v"].dtype), at)
     k_pos = jnp.arange(k_cache.shape[1])[None]
     out = masked_attention(q, k_cache, v_cache,
                            visible(positions, k_pos, window), sink)
@@ -146,9 +148,10 @@ def attend_ring(q, k, v, positions, step, *, window, sink=None):
     if step.mode == "decode":
         pos = positions[:, 0]
         # an out-of-range slot id drops an inactive slot's write
-        at = jnp.where(step.count.astype(bool), jnp.arange(b), slots)
-        k_ring = k_ring.at[at, pos % window].set(k[:, 0], mode="drop")
-        v_ring = v_ring.at[at, pos % window].set(v[:, 0], mode="drop")
+        with jax.named_scope("cache"):
+            at = jnp.where(step.count.astype(bool), jnp.arange(b), slots)
+            k_ring = k_ring.at[at, pos % window].set(k[:, 0], mode="drop")
+            v_ring = v_ring.at[at, pos % window].set(v[:, 0], mode="drop")
         out = masked_attention(q, k_ring, v_ring,
                                (_held(pos, window) >= 0)[:, None, :], sink)
         return out, {"k_ring": k_ring, "v_ring": v_ring}
@@ -168,8 +171,9 @@ def attend_ring(q, k, v, positions, step, *, window, sink=None):
         jnp.concatenate([v_ring[step.rows], v], axis=1),
         visible(positions, k_pos, window) & live[:, None, :], sink)
     # the chunk's last <= window valid columns, each to its own row
-    keep = valid & (cols >= step.count[:, None] - window)
-    at = jnp.where(keep, step.rows[:, None], slots)
-    return out, {
-        "k_ring": k_ring.at[at, positions % window].set(k, mode="drop"),
-        "v_ring": v_ring.at[at, positions % window].set(v, mode="drop")}
+    with jax.named_scope("cache"):
+        keep = valid & (cols >= step.count[:, None] - window)
+        at = jnp.where(keep, step.rows[:, None], slots)
+        return out, {
+            "k_ring": k_ring.at[at, positions % window].set(k, mode="drop"),
+            "v_ring": v_ring.at[at, positions % window].set(v, mode="drop")}

@@ -26,6 +26,7 @@ scatter / select below, never copied.  ``generate()``'s dense cache
 holds the same two leaves with a batch row where the slot is.
 """
 
+import jax
 import jax.numpy as jnp
 
 # What a model that keeps something per SLOT beside the page pool — a
@@ -68,10 +69,11 @@ def read(entry, step):
         raise NotImplementedError(
             f"a recurrent-state layer cannot run a {step.mode!r} step: "
             "rejected tokens would have to be rolled out of the state")
-    fresh = step.lengths[step.rows] == 0
-    conv, ssm = entry["conv"][step.rows], entry["ssm"][step.rows]
-    return (jnp.where(fresh[:, None, None], 0, conv),
-            jnp.where(fresh[:, None, None, None], 0, ssm))
+    with jax.named_scope("cache"):
+        fresh = step.lengths[step.rows] == 0
+        conv, ssm = entry["conv"][step.rows], entry["ssm"][step.rows]
+        return (jnp.where(fresh[:, None, None], 0, conv),
+                jnp.where(fresh[:, None, None, None], 0, ssm))
 
 
 def write(entry, step, conv, ssm):
@@ -79,11 +81,15 @@ def write(entry, step, conv, ssm):
     inactive slots leave theirs as it was."""
     conv = conv.astype(entry["conv"].dtype)
     if step.mode == "decode":
+        # a select over the whole pool that XLA fuses into the state
+        # update and roots the fusion at: left under the mixer's scope,
+        # or the update would read as ``cache`` (PERF.md section 6 PR 60)
         live = step.count.astype(bool)
         return {"conv": jnp.where(live[:, None, None], conv, entry["conv"]),
                 "ssm": jnp.where(live[:, None, None, None], ssm,
                                  entry["ssm"])}
-    # an out-of-range slot id drops the row's write
-    slots = jnp.where(step.count > 0, step.rows, entry["ssm"].shape[0])
-    return {"conv": entry["conv"].at[slots].set(conv, mode="drop"),
-            "ssm": entry["ssm"].at[slots].set(ssm, mode="drop")}
+    with jax.named_scope("cache"):
+        # an out-of-range slot id drops the row's write
+        slots = jnp.where(step.count > 0, step.rows, entry["ssm"].shape[0])
+        return {"conv": entry["conv"].at[slots].set(conv, mode="drop"),
+                "ssm": entry["ssm"].at[slots].set(ssm, mode="drop")}

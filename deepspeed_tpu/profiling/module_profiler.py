@@ -1,15 +1,30 @@
-"""Per-module flops / bytes / latency breakdown from a real device trace.
+"""Device time by model component and by module, from a device trace.
 
 Reference: ``deepspeed/profiling/flops_profiler/profiler.py:23`` prints
 per-module flops/MACs/latency by monkey-patching torch.nn.functional.
-On TPU the ground truth is better: ``jax.profiler.trace`` records every
-XLA op's measured device time, its flop count and HBM bytes accessed,
-AND the originating module path (flax named_scopes flow into the HLO
-metadata as the ``tf_op`` stat, e.g.
-``jit(step)/GPT2/h_3/attn/qkv/dot_general``). This module captures one
-traced step and aggregates those records into the reference-style
-module tree — with measured (post-fusion) numbers rather than analytic
-estimates, so it finds layout copies and bandwidth sinks the analytic
+Here the numbers are measured: ``jax.profiler.trace`` records every
+executed XLA operation's device time on the "XLA Ops" line of a
+``/device:TPU:n`` plane.  What a v5e's trace holds of an operation (the
+probe of PR 60, one traced run of the chat cell, PERF.md section 7):
+the event itself carries its offset and duration only; its METADATA
+(``plane.event_stats[ev.metadata_id]``, which
+``jax.profiler.ProfileData`` does not show) carries ``tf_op`` -- the
+instruction's ``op_name``, i.e. the name stack it was traced under,
+flax module names and ``jax.named_scope``s, e.g.
+``jit(decode_multi)/horizon/while/body/closed_call/Llama/layers_13/mlp/
+w_down/dot_general:`` -- beside ``hlo_category``, ``program_id``,
+``flops``, ``bytes_accessed`` / ``raw_bytes_accessed`` and ``source``.
+Operations the compiler made itself (copies, asynchronous slices of a
+weight) carry no ``tf_op``, and a fusion carries ONE: where XLA put its
+metadata.  An executable loaded from the persistent compile cache
+carries the paths of the tree that compiled it
+(``utils/compile_cache.py``).
+
+This module aggregates those records twice: by COMPONENT
+(``tracing.component``: the closed vocabulary the benchmark's
+``scope.*`` metrics are pinned to, with ``fwd`` / ``bwd`` in training),
+then into the reference-style module tree -- measured, post-fusion
+numbers, so it finds layout copies and bandwidth sinks an analytic
 profiler cannot see.
 """
 
@@ -23,17 +38,27 @@ from collections import defaultdict
 import jax
 
 from deepspeed_tpu.profiling.xplane import device_plane, read_xspace
-from deepspeed_tpu.utils.logging import logger
+from deepspeed_tpu.tracing import component, pass_of, path_parts
 
 _JIT_PREFIX = re.compile(r"^jit\([^)]*\)/")
+# their events span the operations inside them
+WRAPPERS = ("while", "conditional", "call")
+# path parts that name no module: a loop's or a call's own, and the
+# scopes round a whole loop (``tracing.COMPONENTS``: the horizon's and
+# the step loop's own bookkeeping reads under them in the component
+# table)
+_NOT_MODULES = ("while", "body", "cond", "closed_call", "horizon",
+                "train_loop")
 
 
 def capture_trace(step_fn, n_steps=3, trace_dir=None):
     """Run ``step_fn`` (already warmed/compiled) ``n_steps`` times under
     the jax profiler; returns the op records from the device plane.
 
-    Record: {"op", "module", "leaf_op", "category", "duration_ps",
-    "flops", "bytes", "occurrences"} aggregated over the traced steps.
+    Record: {"op", "module", "component", "pass", "leaf_op", "category",
+    "duration_ps", "flops", "bytes", "occurrences"} aggregated over the
+    traced steps; loops, conditionals and calls, whose events span the
+    operations inside them, are left out.
     """
     own = trace_dir is None
     trace_dir = trace_dir or tempfile.mkdtemp(prefix="ds_modprof_")
@@ -72,10 +97,16 @@ def _aggregate(plane, n_steps):
             meta_stats = plane.event_stats.get(ev.metadata_id, {})
             stats = {**meta_stats, **ev.stats}
             name = plane.event_names.get(ev.metadata_id, "?")
+            op = name.split(" = ")[0].lstrip("%")
+            if op.split(".")[0] in WRAPPERS:
+                continue
+            tf_op = stats.get("tf_op", "")
             rec = by_op.setdefault(ev.metadata_id, {
-                "op": name.split(" = ")[0].lstrip("%"),
-                "module": _module_path(stats.get("tf_op", "")),
-                "leaf_op": _leaf_op(stats.get("tf_op", "")),
+                "op": op,
+                "module": _module_path(tf_op),
+                "component": component(tf_op, op),
+                "pass": pass_of(tf_op),
+                "leaf_op": _leaf_op(tf_op),
                 "category": stats.get("hlo_category", ""),
                 "duration_ps": 0, "flops": 0, "bytes": 0,
                 "occurrences": 0,
@@ -97,8 +128,8 @@ def _module_path(tf_op):
     become a fwd/bwd phase tag instead of polluting the tree."""
     if not tf_op:
         return "(unattributed)"
-    p = _JIT_PREFIX.sub("", tf_op).rstrip(":")
-    parts = p.split("/")
+    parts = [p for p in path_parts(_JIT_PREFIX.sub("", tf_op))
+             if p not in _NOT_MODULES] or [""]
     head, phase = parts[0], ""
     if head.startswith("transpose("):
         phase = " [bwd]"
@@ -145,6 +176,22 @@ def aggregate_by_module(records, depth=3):
     return rows
 
 
+def aggregate_by_component(records):
+    """Rows (component, pass, ms_per_step, share) by time: device time
+    by ``tracing.component`` and, in training, forward / backward."""
+    groups = defaultdict(int)
+    for r in records:
+        groups[(r.get("component", "unattributed"),
+                r.get("pass", ""))] += r["duration_ps"]
+    n = records[0]["steps"] if records else 1
+    total_ps = sum(groups.values())
+    rows = [{"component": c, "pass": p, "ms": ps / 1e9 / n,
+             "share": ps / total_ps if total_ps else 0.0}
+            for (c, p), ps in groups.items()]
+    rows.sort(key=lambda r: -r["ms"])
+    return rows
+
+
 def top_traffic_consumers(records, k=3):
     """The k op groups moving the most HBM bytes per step — the tool
     that finds layout transposes and unfused read passes (VERDICT r4
@@ -169,9 +216,14 @@ def format_profile(records, depth=3, top=25):
     tot_ms = sum(r["ms"] for r in rows)
     tot_gf = sum(r["gflops"] for r in rows)
     tot_gb = sum(r["gb"] for r in rows)
-    out = [f"per-module profile (measured device trace, {n} steps)",
-           f"{'module':44s} {'ms/step':>9s} {'GFLOP':>9s} "
-           f"{'GB':>7s} {'share':>6s}"]
+    out = [f"device time by component (measured device trace, {n} steps)",
+           f"{'component':20s} {'pass':>5s} {'ms/step':>9s} {'share':>6s}"]
+    for c in aggregate_by_component(records):
+        out.append(f"{c['component']:20s} {c['pass']:>5s} {c['ms']:9.3f} "
+                   f"{c['share']:6.1%}")
+    out += [f"per-module profile (measured device trace, {n} steps)",
+            f"{'module':44s} {'ms/step':>9s} {'GFLOP':>9s} "
+            f"{'GB':>7s} {'share':>6s}"]
     for r in rows[:top]:
         out.append(f"{r['module'][:44]:44s} {r['ms']:9.3f} "
                    f"{r['gflops']:9.2f} {r['gb']:7.3f} "

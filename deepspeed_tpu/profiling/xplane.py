@@ -1,11 +1,24 @@
 """Minimal XSpace (xplane.pb) reader — no tensorflow/tensorboard needed.
 
+THE decoder of a device trace's per-operation stats: the operator's
+tables (``module_profiler``: ``engine.module_profile()``, ``ds_serve
+--profile-steps``) and the benchmark's component reader
+(``benchmarks/chip/readers_scopes.py``) both read an ``.xplane.pb``
+through :func:`read_xspace`.  ``jax.profiler.ProfileData`` reads the
+same file faster (0.4 s against 7 s for the chat cell's 27 MB, 345,000
+events) and is what the benchmark's interval arithmetic uses, but it
+shows an event's OWN stats only — on a v5e ``device_offset_ps``,
+``device_duration_ps``, ``Time Scale Multiplier`` — and not the stats
+of the event's METADATA, where the TPU runtime puts everything that
+names an operation: ``tf_op`` (the instruction's ``op_name`` path),
+``hlo_category``, ``program_id``, ``flops``, ``bytes_accessed``,
+``source`` (the probe of PR 60, PERF.md section 7).
+
 jax.profiler.trace writes TPU op-level timing as an XSpace protobuf
 (tsl/profiler/protobuf/xplane.proto). The tensorboard profile plugin
 that normally reads it drags in tensorflow + a protobuf-version
-minefield, so this module hand-decodes the handful of fields the
-per-module profiler consumes (field numbers verified against
-tsl xplane_pb2):
+minefield, so this module hand-decodes the handful of fields its
+readers consume (field numbers verified against tsl xplane_pb2):
 
     XSpace.planes = 1
     XPlane.name = 2, .lines = 3, .event_metadata = 4 (map),

@@ -453,15 +453,16 @@ class DeepSpeedEngine:
             logits, mut = module.apply(
                 {"params": params}, batch["input_ids"], rngs=rngs,
                 mutable=["intermediates"], **kw)
-            loss = gpt2_loss_fn(logits, batch)
-            aux = [v for path, v in
-                   flax.traverse_util.flatten_dict(
-                       mut.get("intermediates", {})).items()
-                   if path[-1] == "moe_aux_loss"]
-            if aux:
-                # sow stores a tuple per call site
-                terms = [jnp.asarray(x) for tup in aux for x in tup]
-                loss = loss + moe_coef * sum(terms)
+            with jax.named_scope("loss"):
+                loss = gpt2_loss_fn(logits, batch)
+                aux = [v for path, v in
+                       flax.traverse_util.flatten_dict(
+                           mut.get("intermediates", {})).items()
+                       if path[-1] == "moe_aux_loss"]
+                if aux:
+                    # sow stores a tuple per call site
+                    terms = [jnp.asarray(x) for tup in aux for x in tup]
+                    loss = loss + moe_coef * sum(terms)
             return loss
 
         return loss_fn
@@ -888,7 +889,8 @@ class DeepSpeedEngine:
             def scaled_loss(p):
                 with zero_gather_scope(plan):
                     loss = loss_fn(prep(p), batch, rng, **loss_kw)
-                return loss.astype(jnp.float32) * scale / gas, loss
+                with jax.named_scope("loss"):
+                    return loss.astype(jnp.float32) * scale / gas, loss
 
             (s_loss, loss), grads = jax.value_and_grad(
                 scaled_loss, has_aux=True)(params)
@@ -903,45 +905,48 @@ class DeepSpeedEngine:
         check_overflow = self.fp16_enabled
 
         def apply_grads(state, acc, lr):
-            scale = state.scaler.loss_scale
-            grads = jax.tree.map(lambda g: g / (scale * predivide), acc)
-            overflow = has_overflow(grads) if check_overflow \
-                else jnp.bool_(False)
+            with jax.named_scope("optimizer"):
+                scale = state.scaler.loss_scale
+                grads = jax.tree.map(lambda g: g / (scale * predivide), acc)
+                overflow = has_overflow(grads) if check_overflow \
+                    else jnp.bool_(False)
 
-            gnorm = optax.global_norm(grads)
-            if clip_norm > 0.0:
-                factor = jnp.minimum(1.0, clip_norm / (gnorm + 1e-6))
-                grads = jax.tree.map(lambda g: g * factor, grads)
+                gnorm = optax.global_norm(grads)
+                if clip_norm > 0.0:
+                    factor = jnp.minimum(1.0, clip_norm / (gnorm + 1e-6))
+                    grads = jax.tree.map(lambda g: g * factor, grads)
 
-            opt_state = state.opt_state
-            # drive the LR schedule value into inject_hyperparams state
-            # (skipped for a client optimizer with no schedule: its own
-            # hyperparams stand)
-            if drive_lr and hasattr(opt_state, "hyperparams"):
-                hp = dict(opt_state.hyperparams)
-                hp["learning_rate"] = jnp.asarray(lr, jnp.float32)
-                opt_state = opt_state._replace(hyperparams=hp)
+                opt_state = state.opt_state
+                # drive the LR schedule value into inject_hyperparams state
+                # (skipped for a client optimizer with no schedule: its own
+                # hyperparams stand)
+                if drive_lr and hasattr(opt_state, "hyperparams"):
+                    hp = dict(opt_state.hyperparams)
+                    hp["learning_rate"] = jnp.asarray(lr, jnp.float32)
+                    opt_state = opt_state._replace(hyperparams=hp)
 
-            updates, new_opt = tx.update(grads, opt_state, state.params)
-            new_params = optax.apply_updates(state.params, updates)
+                updates, new_opt = tx.update(grads, opt_state, state.params)
+                new_params = optax.apply_updates(state.params, updates)
 
-            # skip-step on overflow (reference stage_1_and_2.py:1636 semantics)
-            if check_overflow:
-                new_params = jax.tree.map(
-                    lambda n, o: jnp.where(overflow, o, n), new_params,
-                    state.params)
-                new_opt = jax.tree.map(
-                    lambda n, o: jnp.where(overflow, o, n), new_opt,
-                    opt_state)
+                # skip-step on overflow (reference
+                # stage_1_and_2.py:1636 semantics)
+                if check_overflow:
+                    new_params = jax.tree.map(
+                        lambda n, o: jnp.where(overflow, o, n), new_params,
+                        state.params)
+                    new_opt = jax.tree.map(
+                        lambda n, o: jnp.where(overflow, o, n), new_opt,
+                        opt_state)
 
-            scaler = update_scale(state.scaler, overflow)
-            new_state = state.replace(
-                step=state.step + 1,
-                skipped_steps=state.skipped_steps + overflow.astype(jnp.int32),
-                params=new_params, opt_state=new_opt, scaler=scaler)
-            metrics = {"grad_norm": gnorm, "overflow": overflow,
-                       "loss_scale": scaler.loss_scale}
-            return new_state, metrics
+                scaler = update_scale(state.scaler, overflow)
+                new_state = state.replace(
+                    step=state.step + 1,
+                    skipped_steps=state.skipped_steps +
+                    overflow.astype(jnp.int32),
+                    params=new_params, opt_state=new_opt, scaler=scaler)
+                metrics = {"grad_norm": gnorm, "overflow": overflow,
+                           "loss_scale": scaler.loss_scale}
+                return new_state, metrics
 
         # One fused dispatch per micro batch; the boundary step folds the
         # optimizer apply into the same XLA program so the whole train step
@@ -1023,7 +1028,8 @@ class DeepSpeedEngine:
 
         def micro_next(params, scale, acc, batch, rng):
             loss, grads = fwd_bwd(params, scale, batch, rng)
-            return loss, jax.tree.map(jnp.add, acc, grads)
+            with jax.named_scope("optimizer"):
+                return loss, jax.tree.map(jnp.add, acc, grads)
 
         self._micro_next = jax.jit(
             micro_next, donate_argnums=(2,),
@@ -1032,7 +1038,8 @@ class DeepSpeedEngine:
         def step_last(params, opt_state, rest, acc, batch, rng, lr):
             state = rest.replace(params=params, opt_state=opt_state)
             loss, grads = fwd_bwd(params, state.scaler.loss_scale, batch, rng)
-            acc = jax.tree.map(jnp.add, acc, grads)
+            with jax.named_scope("optimizer"):
+                acc = jax.tree.map(jnp.add, acc, grads)
             new_state, metrics = apply_grads(state, acc, lr)
             return loss, new_state, metrics
 
@@ -1059,13 +1066,15 @@ class DeepSpeedEngine:
             for i in range(n_micro):
                 b = jax.tree.map(lambda x: x[i], batches)
                 loss, grads = fwd_bwd(params, scale, b, rngs[i])
-                acc = grads if acc is None else \
-                    jax.tree.map(jnp.add, acc, grads)
+                with jax.named_scope("optimizer"):
+                    acc = grads if acc is None else \
+                        jax.tree.map(jnp.add, acc, grads)
                 losses.append(loss)
             new_state, metrics = apply_grads(state, acc, lr)
             # mean computed in-program: fetching per-micro losses would
             # cost a host round trip per step
-            return jnp.mean(jnp.stack(losses)), new_state, metrics
+            with jax.named_scope("loss"):
+                return jnp.mean(jnp.stack(losses)), new_state, metrics
 
         # params donated too: _train_batch_fused commits the new state
         # before control returns, so no caller can observe the donated
@@ -1095,9 +1104,10 @@ class DeepSpeedEngine:
                 return (new_state.params, new_state.opt_state,
                         new_state.replace(params=None, opt_state=None)), \
                     (loss, metrics)
-            (p, o, r), (losses, metrics) = jax.lax.scan(
-                body, (params, opt_state, rest), (batches, rngs, lrs))
-            last = jax.tree.map(lambda m: m[-1], metrics)
+            with jax.named_scope("train_loop"):
+                (p, o, r), (losses, metrics) = jax.lax.scan(
+                    body, (params, opt_state, rest), (batches, rngs, lrs))
+                last = jax.tree.map(lambda m: m[-1], metrics)
             return losses, r.replace(params=p, opt_state=o), last
 
         self._step_loop = jax.jit(

@@ -59,15 +59,17 @@ def _path_key(path):
 def _to_use(w, at_rest, at_use):
     # pinned to the shard first: left free, an elementwise producer takes
     # the gathered sharding and the all-gather moves what came before it
-    w = jax.lax.with_sharding_constraint(w, at_rest)
-    return jax.lax.with_sharding_constraint(w, at_use)
+    with jax.named_scope("zero_gather"):
+        w = jax.lax.with_sharding_constraint(w, at_rest)
+        return jax.lax.with_sharding_constraint(w, at_use)
 
 
 def _to_rest(g, at_rest, dtype):
     """A float32 weight gradient into the at-rest spec (the
     reduce-scatter), then the leaf's own dtype."""
     assert g.dtype == jnp.float32, g.dtype
-    return jax.lax.with_sharding_constraint(g, at_rest).astype(dtype)
+    with jax.named_scope("zero_gather"):
+        return jax.lax.with_sharding_constraint(g, at_rest).astype(dtype)
 
 
 @functools.lru_cache(maxsize=None)

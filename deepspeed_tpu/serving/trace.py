@@ -64,33 +64,44 @@ from deepspeed_tpu.utils.logging import logger
 # -------------------------------------------- device-profile integration
 
 def profile_serving(sched, n_steps=8, trace_dir=None, depth=3):
-    """Capture a JAX device profile of ``n_steps`` serving horizons and
-    aggregate it per module (the dormant ``profiling/`` xplane pipeline,
-    pointed at the serving loop instead of a train step).
+    """Capture a JAX device profile of ``n_steps`` scheduler steps and
+    aggregate it by model component and per module (the ``profiling/``
+    xplane pipeline, pointed at the serving loop instead of a train
+    step).
 
-    Returns ``{"rows": [...], "table": str}`` from
-    ``profiling.module_profiler`` — measured post-fusion device time /
-    flops / HBM bytes per module.  Raises RuntimeError where the
-    backend records no device plane (plain CPU jax builds); callers
-    (``ds_serve --profile-steps``) degrade to a warning.
+    Returns ``{"components": [...], "rows": [...], "table": str}`` from
+    ``profiling.module_profiler`` — measured post-fusion device time by
+    ``tracing.component`` (the vocabulary the benchmark's ``scope.*``
+    metrics read), then time / flops / HBM bytes per module.  Raises
+    RuntimeError where the backend records no device plane (plain CPU
+    jax builds); callers (``ds_serve --profile-steps``) degrade to a
+    warning.
     """
     from deepspeed_tpu.profiling.module_profiler import (
-        aggregate_by_module, capture_trace, format_profile)
+        aggregate_by_component, aggregate_by_module, capture_trace,
+        format_profile)
 
     records = capture_trace(lambda: sched.step(), n_steps=n_steps,
                             trace_dir=trace_dir)
-    rows = aggregate_by_module(records, depth=depth)
-    return {"rows": rows, "table": format_profile(records, depth=depth)}
+    return {"components": aggregate_by_component(records),
+            "rows": aggregate_by_module(records, depth=depth),
+            "table": format_profile(records, depth=depth)}
 
 
 def write_profile_report(report, out_dir):
     """Drop the per-module aggregation next to the trace artifacts:
-    ``module_profile.json`` (rows) + ``module_profile.txt`` (table)."""
+    ``module_profile.json`` (rows), ``component_profile.json`` (seconds
+    by component) + ``module_profile.txt`` (both tables)."""
     os.makedirs(out_dir, exist_ok=True)
     jpath = os.path.join(out_dir, "module_profile.json")
     with open(jpath, "w") as f:
         json.dump(report["rows"], f, indent=2)
         f.write("\n")
+    if "components" in report:
+        with open(os.path.join(out_dir, "component_profile.json"),
+                  "w") as f:
+            json.dump(report["components"], f, indent=2)
+            f.write("\n")
     tpath = os.path.join(out_dir, "module_profile.txt")
     with open(tpath, "w") as f:
         f.write(report["table"] + "\n")

@@ -294,6 +294,122 @@ for _op in ("all_reduce", "all_gather", "reduce_scatter", "all_to_all",
 del _op
 
 
+# ---------------------------------------------------------------------
+# Components: which part of the model a device operation belongs to.
+# Every instruction of a compiled program carries the name stack it was
+# traced under as ``op_name`` metadata (flax module names and
+# ``jax.named_scope``s, e.g.
+# ``jit(decode_multi)/horizon/while/body/closed_call/Llama/layers_3/mlp/
+# w_down/dot_general``), and a device profile's "XLA Ops" events carry
+# it as the ``tf_op`` stat of their metadata.  ONE closed vocabulary
+# maps such a path to a component, the same for every model family and
+# both engines; the operator's table (``profiling/module_profiler.py``) and
+# the benchmark's ``scope.*`` metrics (whose files are pinned to this
+# map by ``tests/chip_bench/test_chip_bench_scopes.py``) both read it.
+#
+# The rule: split the path on ``/``; ``jit(...)`` / ``jvp(...)`` /
+# ``transpose(...)`` wrappers, ``while`` / ``body`` / ``cond`` /
+# ``branch_N``, layer indices (``layers_3`` -> ``layers``) and primitive
+# names are not tokens; THE INNERMOST TOKEN THAT IS IN THE MAP DECIDES:
+#
+#   .../layers_3/attn/pallas_call                      -> attn_core
+#   .../layers_3/attn/q_proj/dot_general               -> attn_proj
+#   .../layers_3/attn/cache/scatter                    -> cache
+#   .../layers_3/mlp/w_down/dot_general                -> mlp
+#   .../transpose(jvp(GPT2))/h_3/mlp/fc_in/dot_general -> mlp, "bwd"
+#   .../sample/argmax                                  -> sample
+#
+# A collective is ``comm`` by its opcode whatever its path; a path with
+# no token of the map is ``other``, an operation with no path (one the
+# compiler made itself: a copy, an asynchronous slice of a weight)
+# ``unattributed``.  Two more forms the compiler gives what it makes: a
+# path that ENDS at a loop or a call (``.../horizon/while/body/
+# closed_call``: the loop's own metadata on a copy or a prefetch beside
+# it; JAX ends every path it emits with a primitive) is ``unattributed``
+# too, and an argument's name (``params['layers_3']['moe']['w_up']``:
+# a copy of that weight) reads by its keys.  A fusion counts where XLA
+# put its metadata: a matmul fused with the next norm's reduction reads
+# under ONE of them (on the TPU the matmul's, PERF.md section 7).
+
+COMPONENTS = {
+    "embed": ("embed", "embed_tokens", "wte", "wpe", "ln_embed"),
+    "norm": ("norm", "input_norm", "post_attn_norm", "pre_ff_norm",
+             "ln_1", "ln_2", "kv_a_norm", "residual"),
+    "attn_proj": ("wq", "wk", "wv", "wo", "wkv_a", "qkv", "proj",
+                  "q_proj", "k_proj", "v_proj", "o_proj", "rope",
+                  "mla_absorb", "attn_proj"),
+    "attn_core": ("attn", "swa"),
+    "cache": ("cache", "pools", "page_table"),
+    "mlp": ("mlp", "shared", "shared_up", "shared_down"),
+    "router": ("router",),
+    # XLA's TPU expansion of ``lax.ragged_dot`` names its kernel's path
+    # ``ragged-dot-none`` and drops the scope it was traced under
+    "experts": ("experts", "moe", "ragged-dot-none"),
+    "ssm": ("ssm", "mamba"),
+    "head": ("head", "lm_head", "ln_f", "norm_f"),
+    "sample": ("sample", "horizon"),
+    "loss": ("loss",),
+    "optimizer": ("optimizer", "train_loop"),
+    "comm": ("zero_gather",),
+    "other": (),
+    "unattributed": (),
+}
+# opcodes (an instruction's own name, ``all-gather-start.7``) that are
+# ``comm`` whatever the path; ``async-collective-start`` / ``-done`` are
+# the TPU compiler's wrappers of asynchronous gathers and
+# reduce-scatters
+COLLECTIVE_OPCODES = ("all-gather", "all-reduce", "reduce-scatter",
+                      "collective-permute", "all-to-all",
+                      "async-collective")
+_TOKEN_COMPONENT = {tok: comp for comp, toks in COMPONENTS.items()
+                    for tok in toks}
+_LAYER_INDEX = re.compile(r"_\d+$")
+# ``transpose(jvp(loss))`` -> ``loss``: autodiff wraps the outermost
+# scope of the function it transforms; ``jit(name)`` holds a function's
+# name, never a scope's
+_WRAPPED = re.compile(r"^(?:(?!jit\()\w+\()+([^()]*)\)+$")
+_ARGUMENT = re.compile(r"^(\w+)((?:\[[^\]]*\])+)$")
+_CONTROL_FLOW = ("while", "body", "cond", "closed_call")
+
+
+def path_parts(op_name):
+    """An ``op_name`` as its parts, outermost first: split on ``/``, or
+    an argument's name by its keys.  Of an operation merged from several
+    (``a/mul;b/add``) the first path is read."""
+    op_name = op_name.split(";")[0].rstrip(":")
+    arg = _ARGUMENT.match(op_name)
+    if arg:
+        return [arg.group(1)] + re.findall(r"\['([^']*)'\]", arg.group(2))
+    return op_name.split("/")
+
+
+def component(op_name, opcode=""):
+    """The component of :data:`COMPONENTS` an operation belongs to, from
+    its ``op_name`` path (``tf_op`` of a device event) and, for
+    collectives, its opcode."""
+    if opcode.lstrip("%").startswith(COLLECTIVE_OPCODES):
+        return "comm"
+    parts = path_parts(op_name)
+    if not op_name or parts[-1] in _CONTROL_FLOW or \
+            parts[-1].startswith("branch_"):
+        return "unattributed"
+    for part in reversed(parts):
+        part = _WRAPPED.sub(r"\1", part)
+        comp = _TOKEN_COMPONENT.get(part) or \
+            _TOKEN_COMPONENT.get(_LAYER_INDEX.sub("", part))
+        if comp is not None:
+            return comp
+    return "other"
+
+
+def pass_of(op_name):
+    """``"bwd"`` / ``"fwd"`` from a training path's ``transpose(...)`` /
+    ``jvp(...)`` wrappers, ``""`` for a path with neither."""
+    if "transpose(" in op_name:
+        return "bwd"
+    return "fwd" if "jvp(" in op_name else ""
+
+
 # ---------------------------------------------------------------- spans
 
 class _NullSpan:

@@ -1,74 +1,27 @@
 """Per-module trace profiler (VERDICT r4 task 7: the reference
 print_model_profile equivalent). The xplane reader is tested against
-hand-encoded protobuf bytes (CPU backends emit no op-level trace), the
-aggregation against synthetic records."""
-
-import struct
+hand-encoded protobuf bytes (``xplane_file.py``: CPU backends emit no
+op-level trace), the aggregation against synthetic records."""
 
 import jax
 import pytest
 
 from deepspeed_tpu.profiling.module_profiler import (
-    _module_path, aggregate_by_module, format_profile,
-    top_traffic_consumers)
+    _aggregate, _module_path, aggregate_by_component, aggregate_by_module,
+    format_profile, top_traffic_consumers)
 from deepspeed_tpu.profiling.xplane import device_plane, read_xspace
-
-
-# ------------------------------------------------- tiny proto encoder
-def _tag(fno, wt):
-    return _uv(fno << 3 | wt)
-
-
-def _uv(n):
-    out = b""
-    while True:
-        b7 = n & 0x7F
-        n >>= 7
-        if n:
-            out += bytes([b7 | 0x80])
-        else:
-            return out + bytes([b7])
-
-
-def _ld(fno, payload):
-    return _tag(fno, 2) + _uv(len(payload)) + payload
-
-
-def _vi(fno, val):
-    return _tag(fno, 0) + _uv(val)
-
-
-def _stat(mid, sval=None, ival=None):
-    body = _vi(1, mid)
-    if sval is not None:
-        body += _ld(5, sval.encode())
-    if ival is not None:
-        body += _vi(4, ival)
-    return body
+from xplane_file import write_xspace
 
 
 def _make_xspace(tmp_path):
     """One plane '/device:TPU:0' with an 'XLA Ops' line: two events of
     one op attributed to GPT2/h_0/attn with 2 GFLOP + 1 GB each."""
-    # map entries: key=1 varint, value=2 msg (id=1, name=2, stats=5)
-    def meta_entry(field, key, name, stats=b""):
-        val = _vi(1, key) + _ld(2, name) + stats
-        return _ld(field, _vi(1, key) + _ld(2, val))
-
-    sm = (meta_entry(5, 1, b"tf_op") + meta_entry(5, 2, b"flops") +
-          meta_entry(5, 3, b"raw_bytes_accessed"))
-    ev_meta_stats = (
-        _ld(5, _stat(1, sval="jit(step)/jvp(GPT2)/h_0/attn/dot_general:"))
-        + _ld(5, _stat(2, ival=2_000_000_000))
-        + _ld(5, _stat(3, ival=1_000_000_000)))
-    em = meta_entry(4, 7, b"%fusion.1 = f32[8] fusion(...)",
-                    ev_meta_stats)
-    event = _ld(4, _vi(1, 7) + _vi(3, 500_000_000))   # 0.5 ms
-    line = _ld(3, _ld(2, b"XLA Ops") + event + event)
-    plane = _ld(1, _ld(2, b"/device:TPU:0") + line + em + sm)
-    path = tmp_path / "t.xplane.pb"
-    path.write_bytes(plane)
-    return str(path)
+    metadata = {7: ("%fusion.1 = f32[8] fusion(...)", {
+        "tf_op": "jit(step)/jvp(GPT2)/h_0/attn/dot_general:",
+        "flops": 2_000_000_000, "raw_bytes_accessed": 1_000_000_000})}
+    return write_xspace(tmp_path / "t.xplane.pb", [
+        ("/device:TPU:0", metadata,
+         {"XLA Ops": [(7, 500_000_000)] * 2})])     # 0.5 ms each
 
 
 def test_xplane_reader_roundtrip(tmp_path):
@@ -93,6 +46,15 @@ def test_module_path_normalization():
         == "GPT2/h_3/mlp/fc_in [bwd]"
     assert _module_path("") == "(unattributed)"
     assert _module_path("jit(f)/add:") == "(top)"
+    # a loop's own parts and the scope round it name no module
+    assert _module_path(
+        "jit(decode_multi)/horizon/while/body/closed_call/Llama/layers_3/"
+        "mlp/w_down/dot_general:") == "Llama/layers_3/mlp/w_down"
+    assert _module_path("jit(decode_multi)/horizon/while:") == "(top)"
+    assert _module_path(
+        "jit(step_loop)/train_loop/while/body/closed_call/"
+        "transpose(jvp(GPT2))/h_0/attn/qkv/dot_general:") == \
+        "GPT2/h_0/attn/qkv [bwd]"
 
 
 def _recs():
@@ -120,6 +82,35 @@ def test_aggregation_and_traffic():
     assert "GPT2/h_0/mlp" in table
 
 
+def test_records_carry_component_and_pass(tmp_path):
+    """A recorded trace through the whole pipeline: the wrapper's event
+    is left out, every record has its component (``tracing.component``)
+    and its pass, and the component table heads the printed profile."""
+    metadata = {
+        1: ("%fusion.1 = f32[8] fusion(...)", {
+            "tf_op": "jit(step)/transpose(jvp(GPT2))/h_0/mlp/fc_in/"
+                     "dot_general:", "flops": 10}),
+        2: ("%while.3 = (s32[]) while(...)", {
+            "tf_op": "jit(step)/train_loop/while"}),
+        3: ("%copy-done.2 = f32[8] copy-done(...)", {"flops": 0}),
+        4: ("%all-reduce.1 = f32[8] all-reduce(...)", {
+            "tf_op": "jit(step)/jvp(GPT2)/h_0/mlp/fc_in/dot_general:"})}
+    path = write_xspace(tmp_path / "t.xplane.pb", [(
+        "/device:TPU:0", metadata,
+        {"XLA Ops": [(2, 9_000), (1, 4_000), (3, 1_000), (4, 3_000)]})])
+    records = _aggregate(device_plane(read_xspace(path)), 1)
+    assert {(r["op"], r["component"], r["pass"]) for r in records} == {
+        ("fusion.1", "mlp", "bwd"), ("copy-done.2", "unattributed", ""),
+        ("all-reduce.1", "comm", "fwd")}
+    rows = aggregate_by_component(records)
+    assert [(r["component"], r["pass"]) for r in rows] == [
+        ("mlp", "bwd"), ("comm", "fwd"), ("unattributed", "")]
+    assert sum(r["share"] for r in rows) == pytest.approx(1.0)
+    table = format_profile(records)
+    assert table.index("device time by component") < \
+        table.index("per-module profile")
+
+
 @pytest.mark.skipif(jax.default_backend() != "tpu",
                     reason="op-level device tracing needs TPU")
 def test_engine_module_profile_live():
@@ -139,4 +130,6 @@ def test_engine_module_profile_live():
         np.int32)}
     records, table = engine.module_profile(batch, depth=2, n_steps=2)
     assert any("h_0" in r["module"] for r in records)
-    assert "TOTAL" in table
+    assert {"mlp", "optimizer", "loss"} <= {r["component"] for r in records}
+    assert {"fwd", "bwd"} <= {r["pass"] for r in records}
+    assert "TOTAL" in table and "device time by component" in table

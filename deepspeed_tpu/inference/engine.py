@@ -2020,15 +2020,18 @@ class InferenceEngine:
 
     # ------------------------------------------- tokens kept on device
     def _token_feedback_fns(self):
-        """The two programs that keep a boundary sample's tokens on the
-        device between prefill dispatches, one signature a row bucket
+        """The programs that keep a boundary sample's tokens on the
+        device for the dispatch after it, one signature a row bucket
         each: ``keep(tokens [slots], sampled [rows], slot [rows])``
         files row r's token under ``slot[r]`` (``slot[r] == slots``:
         not kept), and ``ids(host_ids [rows, chunk], src [rows], tokens
         [slots])`` is ``host_ids`` with ``tokens[src[r]]`` in column 0
-        of every row whose ``src[r] >= 0``.  Their outputs are pinned
-        replicated, as ``prefill_into_slots`` stages its ids, so the
-        prefill program keeps its one signature a bucket."""
+        of every row whose ``src[r] >= 0``; and one a slot count:
+        ``merge(host [slots], owed [slots], tokens [slots])``, a
+        horizon's ``last_tok`` with the device's token where ``owed``.
+        Their outputs are pinned replicated, as ``prefill_into_slots``
+        stages its ids (``decode_multi`` stages its tokens itself), so
+        the model's programs keep their one signature a bucket."""
         if getattr(self, "_token_keep_fn", None) is None:
             rep = self._serving_shardings().replicated
 
@@ -2043,9 +2046,15 @@ class InferenceEngine:
                                       tokens[jnp.maximum(src, 0)],
                                       host_ids[:, 0])
                     return host_ids.at[:, 0].set(first)
+
+            def merge(host, owed, tokens):
+                with jax.named_scope("sample"):
+                    return jnp.where(owed, tokens, host)
             self._token_keep_fn = jax.jit(keep, out_shardings=rep)
             self._token_ids_fn = jax.jit(ids, out_shardings=rep)
-        return self._token_keep_fn, self._token_ids_fn
+            self._token_merge_fn = jax.jit(merge, out_shardings=rep)
+        return self._token_keep_fn, self._token_ids_fn, \
+            self._token_merge_fn
 
     def slot_tokens(self, num_slots):
         """A zeroed per-slot token vector for :meth:`keep_sampled`."""
@@ -2055,7 +2064,7 @@ class InferenceEngine:
     def keep_sampled(self, tokens, sampled, slot):
         """``tokens`` with the sampled token of row r under ``slot[r]``
         (rows whose ``slot[r]`` is ``len(tokens)`` are dropped)."""
-        keep, _ = self._token_feedback_fns()
+        keep, _, _ = self._token_feedback_fns()
         with dist.mesh_scope(self.mesh):
             return self._dispatch("keep_sampled", keep, tokens, sampled,
                                   np.asarray(slot, np.int32))
@@ -2064,15 +2073,26 @@ class InferenceEngine:
         """The ``ids_chunk`` of a prefill dispatch some of whose rows'
         first input id is a token still on the device: row r takes
         ``tokens[src[r]]`` where ``src[r] >= 0``, else ``host_ids``."""
-        _, ids = self._token_feedback_fns()
+        _, ids, _ = self._token_feedback_fns()
         with dist.mesh_scope(self.mesh):
             return self._dispatch("prefill_ids", ids,
                                   np.asarray(host_ids, np.int32),
                                   np.asarray(src, np.int32), tokens)
 
+    def decode_tokens(self, host_toks, owed, tokens):
+        """The ``toks`` of a ``decode_multi`` launched before a prefill
+        boundary's sample is pulled: slot s starts from ``tokens[s]``
+        (the device's copy) where ``owed[s]``, else from ``host_toks``."""
+        _, _, merge = self._token_feedback_fns()
+        with dist.mesh_scope(self.mesh):
+            return self._dispatch("decode_tokens", merge,
+                                  np.array(host_toks, np.int32),
+                                  np.asarray(owed, bool), tokens)
+
     def warm_token_feedback(self, sampled, chunk, num_slots):
-        """Compile :meth:`keep_sampled` and :meth:`prefill_ids` for the
-        row bucket ``sampled`` came from, once an engine: a scheduler
+        """Compile :meth:`keep_sampled`, :meth:`prefill_ids` and
+        :meth:`decode_tokens` for the row bucket ``sampled`` came from
+        (and the slot count), once an engine: a scheduler
         calls this with the first boundary sample of each bucket (the
         real one: a jit signature is keyed by where its inputs live),
         so the two are built where the bucket's prefill program is (its
@@ -2085,12 +2105,14 @@ class InferenceEngine:
         if key in self._token_feedback_warm:
             return
         self._token_feedback_warm.add(key)
-        keep, ids = self._token_feedback_fns()
+        keep, ids, merge = self._token_feedback_fns()
         with dist.mesh_scope(self.mesh):
             tokens = keep(self.slot_tokens(num_slots), sampled,
                           np.full(rows, num_slots, np.int32))
             ids(np.zeros((rows, chunk), np.int32),
                 np.full(rows, -1, np.int32), tokens)
+            merge(np.zeros(num_slots, np.int32), np.zeros(num_slots, bool),
+                  tokens)
 
     def serving_prefill_compile_count(self):
         """Compiled signatures behind prefill_into_slots — bounded by

@@ -88,6 +88,8 @@ class ServingMetrics:
         self.lookahead_dispatches = 0
         self.lookahead_fallbacks = Counter()
         self.overrun_rows = 0
+        self.horizons_after_boundary = 0
+        self.horizons_before_pull = 0
         self.seq_prefill_routed = 0    # prompts routed onto the sp path
         self.seq_prefill_chunks = 0    # sp chunk dispatches
         self.seq_prefill_tokens = 0    # prompt tokens landed via sp chunks
@@ -238,7 +240,8 @@ class ServingMetrics:
         them in ``key_blocks`` grid steps that compute ``block_pages``
         pages (``ops/attention/paged_prefill.count_key_blocks``).
         ``lookahead``: it was launched while the dispatch before it had
-        its sampled tokens still on the device."""
+        its sampled tokens still on the device, or while the horizon
+        before it was unharvested."""
         self.prefill_dispatches += 1
         self.lookahead_dispatches += bool(lookahead)
         self.ride_rows += int(riders)
@@ -344,23 +347,43 @@ class ServingMetrics:
         self.horizon_none_steps += bool(no_horizon)
 
     def record_lookahead_fallback(self, reason):
-        """A prefill boundary whose tokens were pulled before the next
-        dispatch was launched: at once (``horizon``, ``policy``,
-        ``drain``, ``other``) or early, from flight (``eviction``,
-        ``drain``, ``other``)."""
+        """A step kept the barrier order at one of its two points.  A
+        prefill boundary whose tokens were pulled before anything else
+        was launched: at once (``policy``, ``spec``, ``drain``,
+        ``other``) or early, from flight (``eviction``, ``drain``,
+        ``other``).  Or a prefill dispatch launched only after the
+        horizon in flight at the step's start had been harvested
+        (``not_slot_bound``, ``no_prefill``, ``ride``, ``pages``,
+        ``policy``, ``spec``, ``drain``)."""
         self.lookahead_fallbacks[reason] += 1
 
     def record_lookahead_pull(self, overrun_rows):
-        """The pull of a boundary that was in flight across a step
-        boundary dropped ``overrun_rows`` tokens: rows computed for a
-        request that had finished or been closed by then."""
+        """``overrun_rows`` rows were computed for a request that had
+        finished or been closed by then and dropped: by the pull of a
+        boundary that was in flight across a step boundary, or by the
+        harvest of a horizon launched ahead of its boundary's pull."""
         self.overrun_rows += int(overrun_rows)
+
+    def record_horizon_after_boundary(self, before_pull):
+        """A decode horizon was launched in a step whose prefill
+        dispatch sampled a row; ``before_pull``: ahead of the pull of
+        that sample, off the device's copy of its tokens."""
+        self.horizons_after_boundary += 1
+        self.horizons_before_pull += bool(before_pull)
 
     def prefill_lookahead_share(self):
         """Share of the shared prefill dispatches launched before the
-        previous one's sampled tokens were pulled."""
+        dispatch ahead of them was back on the host: the previous
+        one's sampled tokens not pulled, or the horizon in flight not
+        harvested."""
         return self.lookahead_dispatches / self.prefill_dispatches \
             if self.prefill_dispatches else 0.0
+
+    def horizon_lookahead_share(self):
+        """Share of the horizons that followed a prefill boundary in
+        its step and were launched before its pull."""
+        return self.horizons_before_pull / self.horizons_after_boundary \
+            if self.horizons_after_boundary else 0.0
 
     def horizon_none_share(self):
         """Share of the slot-bound steps that launched no horizon."""
@@ -798,6 +821,8 @@ class ServingMetrics:
             "prefill_lookahead_fallbacks":
             dict(sorted(self.lookahead_fallbacks.items())),
             "prefill_overrun_rows": self.overrun_rows,
+            "horizon_lookahead_share":
+            round(self.horizon_lookahead_share(), 4),
             "prefill_dispatches_by_bucket":
             self.prefill_dispatches_by_bucket(),
             "seq_prefill_routed": self.seq_prefill_routed,

@@ -124,13 +124,27 @@ riders read their input id from the device's per-slot tokens, and only
 then pulls (``_pull``): sweep to launch, the one host gap a step, runs
 under the device's time.  The barrier step is the same step with the
 pull in front; ``_why_pull_now`` says from the step's own state when
-that holds (a horizon or a speculative round follows, a row carries a
-policy, a grammar or a hand-off, drain has begun), and a dispatch in
-flight is pulled early where growing a row would evict a live slot.
+that holds (a speculative round follows, a request carries a policy, a
+grammar or a hand-off, drain has begun), and a dispatch in flight is
+pulled early where growing a row would evict a live slot.
 What only a token's VALUE decides -- an end of sequence, and likewise a
 cancel, a deadline or a failing callback found at the pull -- costs one
 computed row: the next dispatch's row for that slot is dropped at its
 pull, and the slot is parked (``_zombies``) with its pages until then.
+
+A step that launches a horizon never blocks on the device with nothing
+queued behind what it blocks on either.  Its horizon goes out before
+the pull of the step's first tokens, the slots that finished a prompt
+reading their first token on the device (``_last_tok_on_device``); and
+slot-bound, a step that finds a horizon in flight sweeps, admits, plans
+and launches its prefill dispatch before it harvests
+(``_why_harvest_first`` / ``_why_harvest_now``), so the device runs
+``horizon k-1, prefill k, sample, horizon k, prefill k+1`` back to
+back.  A request whose budget the horizon in flight exhausts ends in it
+whatever it samples and leaves its slot at that plan; a slot freed by
+an end of sequence is admitted into a step later.  Such a prefill
+dispatch grows from free pages only: where a row would evict, the
+horizon is harvested first.
 
 Failure policy (the serving half of docs/resilience.md):
 
@@ -780,6 +794,8 @@ class ServingScheduler:
         # dispatch's riders read their input id from
         self._pf_flight = deque()
         self._dev_tok = None
+        self._boundary_now = False     # this step's dispatch samples a row
+        self._harvest_first = None     # why this step harvested first
         self._zombies = set()          # slots terminated host-side while a
                                        # chained horizon still runs them
         self._chain_budgets = None     # budgets baseline for the live chain
@@ -1334,9 +1350,14 @@ class ServingScheduler:
         self._finish(req)
 
     def _flight_of(self, slot):
-        """The newest prefill dispatch in flight that samples a row for
-        the request now in ``slot``, or None."""
+        """The newest dispatch in flight that computes for the request
+        now in ``slot`` past a token the host has not seen: a prefill
+        dispatch that samples a row for it, or a horizon launched off
+        the device's copy of its newest token (``ahead``); or None."""
         req = self.slot_req[slot]
+        for rec in reversed(self._inflight):
+            if slot in rec["ahead"] and rec["reqs"][slot] is req:
+                return rec
         for rec in reversed(self._pf_flight):
             if any(s == slot and r is req for _, s, r, _ in rec["rows"]):
                 return rec
@@ -1353,6 +1374,19 @@ class ServingScheduler:
         rec["release_after"].add(slot)
         if donate is not None:
             rec["donate"][slot] = donate
+
+    def _release_parked(self, rec):
+        """The dispatch ``rec`` is off the device: the slots parked on
+        it give up their pages (a finished request's to the prefix
+        cache) and may be admitted into."""
+        for slot in rec["release_after"]:
+            req = rec["donate"].get(slot)
+            if req is not None and self.prefix_cache is not None:
+                self._donate_pages(slot, req)
+            else:
+                self.kv.release_slot(slot)
+            self.lengths[slot] = 0
+            self._zombies.discard(slot)
 
     def _close_slot(self, slot, state, reason):
         """Terminal removal of a live slot for cancel/shed/fail: release
@@ -1477,6 +1511,14 @@ class ServingScheduler:
             if drained:
                 if chain is not None:
                     chain.add("cache_drain", pages=drained)
+                continue
+            if self._inflight:
+                # a prefill dispatch that goes out ahead of a horizon's
+                # harvest grows from free pages only: the horizon's
+                # slots give up theirs (retirements), or are evicted,
+                # once its tokens are on the host
+                self._harvest_first = "pages"
+                self._harvest_all()
                 continue
             if self._pf_flight:
                 # a victim re-queues with its emitted tokens folded
@@ -1616,22 +1658,26 @@ class ServingScheduler:
 
             t_wait, pulled = 0.0, 0
             chained = False
+            # why the horizon in flight is harvested before this step's
+            # prefill dispatch is launched (None: it is not, or there is
+            # none); counted with the dispatch (_prefill_dispatch)
+            self._harvest_first = None
             if self._inflight:
                 if self.overlap:
                     # overlap: put the NEXT horizon on the device before
                     # doing this one's host bookkeeping
                     with phases("chain"):
                         chained = self._try_chain()
-                w, n = self._harvest()
-                t_wait += w
-                pulled += n
-            if not chained:
-                # conservative barrier: membership may change below, so
-                # no horizon may remain in flight (its page-table
-                # snapshot would go stale and eviction could corrupt
-                # live pages)
-                while self._inflight:
+                if chained:
                     w, n = self._harvest()
+                    t_wait += w
+                    pulled += n
+                else:
+                    self._harvest_first = self._why_harvest_first()
+            if not chained:
+                if self._harvest_first is not None:
+                    # the barrier order: the horizon's tokens first
+                    w, n = self._harvest_all()
                     t_wait += w
                     pulled += n
                 if self._pf_flight and self.draining:
@@ -1652,12 +1698,29 @@ class ServingScheduler:
                     self._admit_attached(now)
                     self._admit(now)
                     self._plan_ride(now)
+                if self._inflight:
+                    # the horizon is still in flight: the prefill
+                    # dispatch goes out ahead of its harvest, unless the
+                    # plan just made needs the horizon's tokens
+                    self._harvest_first = self._why_harvest_now()
+                    if self._harvest_first is not None:
+                        w, n = self._harvest_all()
+                        t_wait += w
+                        pulled += n
+                        with phases("admit"):
+                            self._admit(now)
+                            self._plan_ride(now)
                 bound, free = self._slot_bound, self.slot_req.count(None)
                 # 3. one prompt chunk per prefilling slot (chunked
                 # prefill), and where _plan_ride said so the next token
                 # of every decoding slot as a one-token row beside them
                 with phases("prefill"):
                     self._prefill()
+                # the horizon that dispatch went out ahead of: its emit
+                # loop and retirements run under the dispatch's time
+                w, n = self._harvest_all()
+                t_wait += w
+                pulled += n
                 if self._riders and self.waiting and \
                         self.slot_req.count(None) > free:
                     # a rider's last token retired it at the boundary:
@@ -1670,8 +1733,17 @@ class ServingScheduler:
                 # 4. dispatch ONE fused decode horizon over running slots
                 # (none where the rows rode and the plan was no horizon)
                 launched = self._dispatch()
-                if self._riders and not launched and not self._pf_flight:
-                    self._close_ride_cycle()
+                if self._riders and not launched:
+                    # this step's decode pass was its prefill dispatch:
+                    # its tokens stay on the device across the step
+                    # boundary (or were pulled: the cycle ends here)
+                    if not self._pf_flight:
+                        self._close_ride_cycle()
+                elif self._pf_flight:
+                    # the boundary's tokens, behind the horizon that was
+                    # launched off the device's copy of them
+                    with phases("prefill"):
+                        self._pull_prefill()
                 if bound:
                     self.metrics.record_slot_bound_step(
                         bool(self._riders) and not launched)
@@ -2027,6 +2099,7 @@ class ServingScheduler:
         token is an emitted token, not a prompt token."""
         self._prefill_rode = False   # until _prefill_dispatch says so
         self._riders = 0
+        self._boundary_now = False   # until a row of the dispatch samples
         rows = []        # (slot, req, chunk) riding the shared dispatch
         blocks = []      # (logits [n, vocab], [(row, slot, req)] to sample)
         for slot in range(self.num_slots):
@@ -2071,6 +2144,7 @@ class ServingScheduler:
                 if req.prefill_pos == len(req.prompt):
                     done.append((i, slot, req))
             rec = self._boundary(logits, done)
+            self._boundary_now = rec is not None
         stays = False
         if rec is not None:
             why = self._why_pull_now(rec)
@@ -2151,7 +2225,11 @@ class ServingScheduler:
         tokens = int(n_valid.sum()) - riders
         self._prefill_rode = True
         self._riders = riders
-        lookahead = bool(self._pf_flight)
+        # launched with the dispatch before it not yet back on the host:
+        # a boundary's tokens, or a whole horizon's
+        lookahead = bool(self._pf_flight or self._inflight)
+        if self._harvest_first is not None:
+            self.metrics.record_lookahead_fallback(self._harvest_first)
         with self.phases("prefill_chunk", rows=len(rows) - riders,
                          padded_rows=padded, tokens=tokens,
                          riders=riders, lookahead=int(lookahead)):
@@ -2191,6 +2269,21 @@ class ServingScheduler:
         dispatches in flight are filed by slot first (``_dev_tok``, the
         device's ``last_tok``), then the rows gather theirs -- two
         small programs a row bucket, inside this dispatch's phase."""
+        self._file_sampled()
+        return self.engine.prefill_ids(ids, src, self._dev_tok)
+
+    def _last_tok_on_device(self, ahead):
+        """``last_tok`` for a horizon's launch with the slots ``ahead``
+        reading the device's copy (``_dev_tok``): their newest token is
+        a boundary sample that has not been pulled."""
+        self._file_sampled()
+        owed = np.zeros(self.num_slots, bool)
+        owed[ahead] = True
+        return self.engine.decode_tokens(self.last_tok, owed, self._dev_tok)
+
+    def _file_sampled(self):
+        """File the sampled tokens of the dispatches in flight by slot
+        (``_dev_tok``), once a dispatch."""
         if self._dev_tok is None:
             self._dev_tok = self.engine.slot_tokens(self.num_slots)
         for rec in self._pf_flight:
@@ -2198,7 +2291,6 @@ class ServingScheduler:
                 self._dev_tok = self.engine.keep_sampled(
                     self._dev_tok, rec["toks"], rec["slots"])
                 rec["slots"] = None
-        return self.engine.prefill_ids(ids, src, self._dev_tok)
 
     def _prefill_seq_parallel(self, slot, req):
         """One wide sequence-sharded chunk of ONE routed request (its
@@ -2247,41 +2339,111 @@ class ServingScheduler:
             return None
         return {"logits": logits, "rows": rows, "toks": None, "slots": None,
                 "left": set(), "release_after": set(), "donate": {},
+                # the step's decode pass was this dispatch and nothing
+                # else: the cycle _step_cost times ends at its pull
+                "ride_only": self._rode_only(),
                 "policy": self._batch_needs_policy([s for _, s, _, _ in
                                                     rows])}
 
     def _why_pull_now(self, rec):
-        """Why the shared dispatch's boundary ``rec`` cannot stay in
-        flight across the step boundary (None: it can).  Read off the
-        step's own state: it stays where nothing after it in this step
-        needs its tokens and nothing a row carries reads them on the
-        host.  ``horizon``: a decode horizon or a speculative round
-        follows, which starts from ``last_tok`` (and a chat client's
-        first token must not wait a step); ``policy``: a row samples
-        under a decoding policy or a grammar (penalty counts and masks
-        are functions of the emitted tokens), owes a hand-off, or was
-        routed sequence-parallel; ``drain``: shutdown has begun;
-        ``other``: the scheduler was built with ``overlap=False``."""
-        if not (self._riders and self._ride == 0):
-            return "horizon"
+        """Why the shared dispatch's boundary ``rec`` has to be pulled
+        before anything else is launched (None: it need not).  Read off
+        the step's own state: its tokens may stay on the device where
+        what follows reads them there -- the decode horizon of this
+        step, launched off the device's copy (``_last_tok_on_device``)
+        and ahead of the pull that ends the step, or, where the rows
+        decoding rode the dispatch and no horizon follows, the next
+        step's dispatch -- and nothing reads them on the host.
+        ``drain``: shutdown has begun; ``other``: the scheduler was
+        built with ``overlap=False``; ``spec``: a speculative round
+        follows, whose drafter reads ``out_tokens``; ``policy``: a
+        request samples under a decoding policy or a grammar (penalty
+        counts and masks are functions of the emitted tokens), owes a
+        hand-off, or was routed sequence-parallel."""
         if self.draining:
             return "drain"
         if not self.overlap:
             return "other"
-        if rec["policy"] or any(
-                r.grammar is not None or r.handoff or
-                getattr(r, "seq_parallel", False)
-                for _, _, r, _ in rec["rows"]):
+        if self._spec is not None:
+            return "spec"
+        if rec["policy"] or not self._all_plain():
             return "policy"
         return None
+
+    def _all_plain(self):
+        """Whether every request in a slot takes the plain programs and
+        the plain order of a boundary: the scheduler's greedy default,
+        no decoding policy, grammar or hand-off, not routed
+        sequence-parallel."""
+        return self._default_greedy and not any(
+            self._req_needs_policy(r) or r.handoff or
+            getattr(r, "seq_parallel", False)
+            for r in self.slot_req if r is not None)
+
+    def _why_harvest_first(self):
+        """Why the horizon in flight at a step's start is harvested
+        before the step sweeps, admits and launches its prefill dispatch
+        (None: the dispatch goes out first, ``_why_harvest_now`` and
+        page pressure permitting).  The chunks of the slots in PREFILL
+        depend on no token of the horizon, so the host's boundary work
+        can run under the horizon's time and the device finds the
+        dispatch queued when the horizon ends.  What that order costs is
+        an admission: a slot the harvest frees is admitted into one step
+        later.  ``not_slot_bound``: the last admission left nobody
+        waiting, so an arrival during the horizon could have its first
+        chunk in this step's dispatch and must not wait a step for it
+        (slot-bound, whoever waits had no slot before the harvest
+        either); ``drain``, ``spec``: as ``_why_pull_now``; ``policy``:
+        a request in a slot is not plain (``_all_plain``), tenancy
+        quotas, hand-off chains waiting to attach, or a
+        sequence-parallel route (its reservation evicts)."""
+        if self.draining:
+            return "drain"
+        if self._spec is not None:
+            return "spec"
+        if not self._slot_bound:
+            return "not_slot_bound"
+        if self.tenancy is not None or self._pending_attach or \
+                (self.seq_plan is not None and
+                 self.seq_parallel_threshold > 0) or \
+                not self._all_plain():
+            return "policy"
+        return None
+
+    def _why_harvest_now(self):
+        """The second half of ``_why_harvest_first``, once the step has
+        admitted and planned with the horizon still in flight:
+        ``no_prefill``: no slot is in PREFILL, there is nothing to
+        launch ahead, and the slots the harvest frees are admitted into
+        in this step; ``ride``: the plan is for the decoding slots to
+        ride the dispatch, and their input ids are the horizon's
+        tokens; ``policy``: a request just admitted is not plain."""
+        if not any(r is not None and r.state == PREFILL
+                   for r in self.slot_req):
+            return "no_prefill"
+        if self._ride is not None:
+            return "ride"
+        if not self._all_plain():
+            return "policy"
+        return None
+
+    def _harvest_all(self):
+        """Harvest every horizon in flight, oldest first; returns the
+        sums of ``_harvest``'s ``(device_wait_s, tokens_delivered)``."""
+        wait, pulled = 0.0, 0
+        while self._inflight:
+            w, n = self._harvest()
+            wait += w
+            pulled += n
+        return wait, pulled
 
     def _launch_boundary(self, rec):
         """Put ``rec``'s sample on the device behind its dispatch and
         leave the tokens there: every row owes its request one token,
-        and the next dispatch's riders read their input id from the
-        device (``_ids_from_device``), without the host."""
-        toks = rec["toks"] = self.engine.sample_launch(
-            rec["logits"], **self.sampling)
+        and what is launched next reads its input ids from the device
+        (``_ids_from_device``, ``_last_tok_on_device``), without the
+        host."""
+        toks = self._sample(rec)
         # row -> slot for the device's per-slot tokens (num_slots: a
         # row that samples for nobody), filed by the next dispatch
         rec["slots"] = np.full(int(np.shape(toks)[0]), self.num_slots,
@@ -2289,6 +2451,17 @@ class ServingScheduler:
         for i, slot, req, _ in rec["rows"]:
             rec["slots"][i] = slot
             req.owed += 1
+
+    def _sample(self, rec):
+        """Launch ``rec``'s batched sample (its tokens stay on the
+        device until they are pulled) and return the device array."""
+        toks = rec["toks"] = self.engine.sample_launch(
+            rec["logits"], **self.sampling)
+        # the programs that keep a sample on the device are built where
+        # a bucket's sample first runs
+        self.engine.warm_token_feedback(toks, self.prefill_chunk,
+                                        self.num_slots)
+        return toks
 
     def _advance_in_flight(self):
         """What the prefill dispatch in flight settles whatever its
@@ -2309,6 +2482,17 @@ class ServingScheduler:
                     rec["left"].add(i)
                 elif req.state == PREFILL:
                     req.state = RUNNING
+        # and the horizon a prefill dispatch is about to be launched
+        # ahead of: a request whose budget it exhausts ends in it
+        # whatever it samples, so its slot is admitted into now and not
+        # a step late (its length then is kept for the harvest's counts)
+        for rec in self._inflight:
+            for slot in rec["slots"]:
+                req = rec["reqs"][slot]
+                if self.slot_req[slot] is req and \
+                        req.remaining_new <= rec["max_advance"][slot]:
+                    rec["left"][slot] = int(self.lengths[slot])
+                    self._vacate(slot)
 
     def _pull_prefill(self, why=None, newer=None):
         """Pull every prefill dispatch in flight, oldest first (``why``:
@@ -2350,12 +2534,7 @@ class ServingScheduler:
                 toks = self._sample_under_policy(rec)
             else:
                 if not flown:
-                    rec["toks"] = self.engine.sample_launch(
-                        rec["logits"], **self.sampling)
-                    # the programs that keep a sample on the device are
-                    # built where a bucket's sample first runs
-                    self.engine.warm_token_feedback(
-                        rec["toks"], self.prefill_chunk, self.num_slots)
+                    self._sample(rec)
                 toks = np.asarray(rec["toks"])
         t_done = ph.t0 + ph.last_s
         overrun = 0
@@ -2406,17 +2585,11 @@ class ServingScheduler:
                     if req.grammar is not None:
                         self._grammar_masks[slot] = \
                             req.grammar.token_mask()
-            for slot in rec["release_after"]:
-                req = rec["donate"].get(slot)
-                if req is not None and self.prefix_cache is not None:
-                    self._donate_pages(slot, req)
-                else:
-                    self.kv.release_slot(slot)
-                self.lengths[slot] = 0
-                self._zombies.discard(slot)
+            self._release_parked(rec)
         if flown:
             self.metrics.record_lookahead_pull(overrun)
-            self._close_ride_cycle(at=t_done)
+            if rec["ride_only"]:
+                self._close_ride_cycle(at=t_done)
 
     def _sample_under_policy(self, rec):
         """``rec``'s boundary tokens under the decoding policy: same
@@ -2788,6 +2961,11 @@ class ServingScheduler:
         if rode:
             self._ride = h
 
+    def _rode_only(self):
+        """Whether this step's decode pass was its prefill dispatch and
+        the plan is for no horizon to follow (``_pick_horizon``)."""
+        return bool(self._riders) and self._ride == 0
+
     def _running_slots(self, among=None):
         """The slots of ``among`` (all of them) that hold a RUNNING
         request."""
@@ -3091,6 +3269,7 @@ class ServingScheduler:
             "toks": toks, "valid": valid, "tok_end": tok_end,
             "active_end": active_end, "lengths_end": lengths_end,
             "emitted_end": emitted_end, "release_after": set(),
+            "donate": {}, "ahead": (), "left": {},
             "t_dispatch": time.monotonic(),
         })
         if self.tracer.enabled:
@@ -3104,7 +3283,19 @@ class ServingScheduler:
         slot; returns whether a dispatch was launched (none where the
         rows rode the prefill dispatch and no horizon follows).  The
         batched dispatch is shared — an error here is NOT attributable
-        to one request and must surface loudly."""
+        to one request and must surface loudly.
+
+        Where this step's prefill boundary is still on the device
+        (``_launch_boundary``) the horizon is launched before its pull:
+        a request whose prompt the dispatch finished decodes from here
+        on (``_advance_in_flight``), and the slots whose newest token
+        is that sample's (``req.owed``: ``ahead``) read it on the
+        device.  What the pull then finds -- an end of sequence, a
+        cancel, a deadline, a failing callback -- leaves the horizon's
+        row for that slot to be dropped at the harvest, the slot parked
+        on it until then."""
+        if not self._rode_only():
+            self._advance_in_flight()
         running = self._running_slots()
         if not running:
             return False
@@ -3124,11 +3315,19 @@ class ServingScheduler:
             if horizon:
                 horizon, running = self._reserve(running, horizon)
             picked, p_s, d_s = self._turnover
+            # (a reservation that evicted has pulled the boundary)
+            ahead = [s for s in running if self.slot_req[s].owed]
             ph.note(horizon=horizon, slots=len(running),
                     slot_bound=int(self._slot_bound), riders=self._riders,
+                    ahead=len(ahead),
                     p_ms=round(p_s * 1e3, 3), d_ms=round(d_s * 1e3, 3))
             if not running or not horizon:
                 return False
+            if self._boundary_now:
+                self.metrics.record_horizon_after_boundary(
+                    before_pull=bool(self._pf_flight))
+            toks = self._last_tok_on_device(ahead) if ahead \
+                else self.last_tok
             active = np.zeros(self.num_slots, bool)
             active[running] = True
             budgets = np.zeros(self.num_slots, np.int32)
@@ -3140,7 +3339,7 @@ class ServingScheduler:
             if self._batch_needs_policy(running):
                 pol = self._policy_args(running)
                 out = self.engine.decode_multi_policy(
-                    self.last_tok, active, self.kv.table, self.lengths,
+                    toks, active, self.kv.table, self.lengths,
                     self.pools, horizon=horizon, budgets=budgets,
                     eos_ids=self._eos_ids, **pol)
                 self.metrics.record_policy_dispatch(self.step_idx,
@@ -3149,7 +3348,7 @@ class ServingScheduler:
                 pol = None
                 a_ids, a_pack = self._adapter_args()
                 out = self.engine.decode_multi(
-                    self.last_tok, active, self.kv.table, self.lengths,
+                    toks, active, self.kv.table, self.lengths,
                     self.pools, horizon=horizon, budgets=budgets,
                     eos_ids=self._eos_ids, adapter_ids=a_ids,
                     adapters=a_pack, **self.sampling)
@@ -3158,11 +3357,13 @@ class ServingScheduler:
                 {s: self.slot_req[s] for s in running}, policy=pol,
                 turnover=picked,
                 cycle_t0=self._cycle_t0 if self._prefill_rode else None,
-                form=(RIDE, horizon) if self._riders else horizon)
+                form=(RIDE, horizon) if self._riders else horizon,
+                ahead=frozenset(ahead))
         return True
 
     def _commit_dispatch(self, out, running, horizon, reqs, policy=None,
-                         turnover=False, cycle_t0=None, form=None):
+                         turnover=False, cycle_t0=None, form=None,
+                         ahead=frozenset()):
         if policy is not None:
             # the policy twin returns a counts carry before the pools:
             # a chained continuation stages IT (device truth mid-chain)
@@ -3189,7 +3390,14 @@ class ServingScheduler:
             "toks": toks, "valid": valid, "tok_end": tok_end,
             "active_end": active_end, "lengths_end": lengths_end,
             "emitted_end": emitted_end, "release_after": set(),
-            "policy": policy, "t_dispatch": time.monotonic(),
+            "donate": {}, "policy": policy, "t_dispatch": time.monotonic(),
+            # slot -> its length at the launch, for the requests that
+            # left their slot before the harvest (_advance_in_flight)
+            "left": {},
+            # the slots launched off the device's copy of a token the
+            # host had not pulled (_last_tok_on_device): one the pull
+            # then closes is parked here, its row dropped at the harvest
+            "ahead": ahead,
             # whether the slot-bound rule chose this horizon below the
             # configured pick, where the cycle _step_cost times began
             # (None: no prefill dispatch rode it, or it is chained) and
@@ -3365,24 +3573,38 @@ class ServingScheduler:
                          spec=spec) as ph:
             now = ph.t0
             pulled = live_rows = kv_tokens = live_pages = win_tokens = 0
+            overrun = 0
             for slot in rec["slots"]:
                 req = rec["reqs"][slot]
-                if req.state in TERMINAL or self.slot_req[slot] is not req:
-                    continue       # closed at an earlier boundary (zombie)
+                # left its slot at this step's plan (_advance_in_flight):
+                # these are its last tokens, and there is no slot to close
+                left = slot in rec["left"]
+                if req.state in TERMINAL or not (
+                        left or self.slot_req[slot] is req):
+                    # closed at an earlier boundary (zombie), or at the
+                    # pull this horizon was launched ahead of
+                    overrun += slot in rec["ahead"]
+                    continue
+
+                def close(state, reason, slot=slot, req=req, left=left):
+                    if left:
+                        self._record_closed(req, state, reason)
+                    else:
+                        self._close_slot_or_defer(slot, state, reason)
                 if req.cancelled:
                     # tokens generated past the cancel are dropped: honored
                     # at the horizon boundary, like the legacy step boundary
-                    self._close_slot_or_defer(slot, CANCELLED, "cancelled")
+                    close(CANCELLED, "cancelled")
                     continue
                 if req.past_deadline(now):
-                    self._close_slot_or_defer(slot, SHED,
-                                              "deadline expired mid-flight")
+                    close(SHED, "deadline expired mid-flight")
                     continue
                 n = int(valid[slot].sum())
                 # step j of the n this slot emits at attends over the
                 # length it began the horizon with and its j + 1 new tokens,
                 # i.e. the pages up to its cursor at position length + j
-                length = int(self.lengths[slot])
+                length = rec["left"][slot] if left \
+                    else int(self.lengths[slot])
                 live_rows += n
                 kv_tokens += n * length + n * (n + 1) // 2
                 live_pages += sum((length + j) // self.kv.page_size + 1
@@ -3406,10 +3628,10 @@ class ServingScheduler:
                         # grammar rejection of a delivered token fails THIS
                         # request (the device mask should make it
                         # impossible — reaching it means corrupted state)
-                        self._note_emitted(slot, req, tok)
+                        if not left:
+                            self._note_emitted(slot, req, tok)
                     except Exception as e:  # per-request emit/callback fault
-                        self._close_slot_or_defer(
-                            slot, FAILED, f"{type(e).__name__}: {e}")
+                        close(FAILED, f"{type(e).__name__}: {e}")
                         break
                     if req._finished_by(tok) or self._grammar_finished(req):
                         # the device froze the slot at this same token, so
@@ -3417,7 +3639,10 @@ class ServingScheduler:
                         # immediate release is safe.  A grammar cursor with
                         # no continuation (done) finishes the request even
                         # without eos — the constrained output is complete.
-                        self._retire(slot)
+                        if left:
+                            self._finish(req)
+                        else:
+                            self._retire(slot)
                         break
                 if self.slot_req[slot] is req and req.state == RUNNING and \
                         req.grammar is not None:
@@ -3441,10 +3666,9 @@ class ServingScheduler:
                         self.last_tok[slot] = int(toks[slot][valid[slot]][-1])
             if rec.get("spec"):
                 self._harvest_spec(rec, valid)
-            for slot in rec["release_after"]:
-                self.kv.release_slot(slot)
-                self.lengths[slot] = 0
-                self._zombies.discard(slot)
+            self._release_parked(rec)
+            if overrun:
+                self.metrics.record_lookahead_pull(overrun)
             if rec.get("spec"):
                 self.metrics.record_spec_wait(self.step_idx, wait)
             else:

@@ -294,12 +294,12 @@ def _lookahead_fallbacks(m):
     """Counted by reason, in the reasons' order; a reason never hit is
     absent, and the map is no number (a scalar sink never sees it)."""
     assert m.summary()["prefill_lookahead_fallbacks"] == {}
-    for why in ("horizon", "policy", "horizon", "eviction", "drain",
-                "other", "horizon"):
+    for why in ("pages", "policy", "pages", "eviction", "drain",
+                "other", "pages", "not_slot_bound"):
         m.record_lookahead_fallback(why)
     got = m.summary()["prefill_lookahead_fallbacks"]
-    assert got == {"drain": 1, "eviction": 1, "horizon": 3, "other": 1,
-                   "policy": 1}
+    assert got == {"drain": 1, "eviction": 1, "not_slot_bound": 1,
+                   "other": 1, "pages": 3, "policy": 1}
     assert list(got) == sorted(got)
 
 
@@ -312,11 +312,21 @@ def _overrun_rows(m):
     assert m.summary()["prefill_overrun_rows"] == 3
 
 
+def _horizon_share(m):
+    """Two of the three horizons that followed a boundary in its step
+    were launched before its pull; none launched reads 0.0."""
+    assert m.summary()["horizon_lookahead_share"] == 0.0
+    for before_pull in (True, False, True):
+        m.record_horizon_after_boundary(before_pull)
+    assert m.summary()["horizon_lookahead_share"] == round(2 / 3, 4)
+
+
 @pytest.mark.parametrize("check", [_lookahead_share, _lookahead_fallbacks,
-                                   _overrun_rows],
-                         ids=["share", "fallbacks", "overrun_rows"])
+                                   _overrun_rows, _horizon_share],
+                         ids=["share", "fallbacks", "overrun_rows",
+                              "horizon_share"])
 def test_the_prefill_look_ahead_counters(check):
-    """``summary()``'s three counters of the slot-bound look-ahead
+    """``summary()``'s four counters of the slot-bound look-ahead
     (``ServingScheduler._launch_boundary``), each from hand-made
     records."""
     check(ServingMetrics(None))

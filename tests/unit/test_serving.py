@@ -249,6 +249,7 @@ def test_page_pool_exhausted_dead_end():
     sched.lengths = np.zeros(2, np.int32)
     sched.waiting = deque()
     sched._pf_flight = deque()    # no prefill dispatch in flight
+    sched._inflight = deque()     # and no horizon
     sched.step_idx = 0
     sched.prefix_cache = None     # nothing cached -> nothing reclaimable
     from deepspeed_tpu.serving.mem_telemetry import NULL_MEM

@@ -195,11 +195,21 @@ def _segment_entries(live, width):
     return seg, k, ends[-1:].astype(jnp.int32)
 
 
-def _live_pairs(page_table, positions, active, page_size):
+def _first_live_page(positions, page_size, window):
+    """The first page a decode query at ``positions`` visits under a
+    sliding ``window`` (the page of position ``pos - window + 1``); 0
+    without one."""
+    if not window:
+        return 0
+    return jnp.maximum(positions - window + 1, 0) // page_size
+
+
+def _live_pairs(page_table, positions, active, page_size, window=0):
     """The decode kernel's grid, from the step's own inputs: the live
     (slot, page) pairs of the ACTIVE slots in slot order.  A slot holds
     ``positions // page_size + 1`` live pages (its cursor's page
-    included) if it is active and none if not.  Returns (``pair`` int32
+    included; under a ``window`` those from :func:`_first_live_page`
+    on) if it is active and none if not.  Returns (``pair`` int32
     [slots * max_pages]: entry i is ``slot * max_pages + k`` of the i-th
     live pair, the flat index of its page-table entry; ``pages`` int32
     [slots * max_pages]: that entry's page id, 0 past the live entries;
@@ -207,9 +217,14 @@ def _live_pairs(page_table, positions, active, page_size):
     on a layer, so XLA computes it once a decode step for all of them."""
     slots, maxp = page_table.shape
     live = jnp.minimum(positions // page_size + 1, maxp)
+    if window:
+        lo = _first_live_page(positions, page_size, window)
+        live = live - lo
     if active is not None:
         live = jnp.where(active, live, 0)
     slot, k, n = _segment_entries(live, maxp)
+    if window:
+        k = jnp.minimum(k + lo[slot], maxp - 1)
     pair = slot * maxp + k
     pages = jnp.where(jnp.arange(pair.shape[0]) < n[0],
                       page_table.reshape(-1)[pair], 0)
@@ -218,7 +233,7 @@ def _live_pairs(page_table, positions, active, page_size):
 
 def _paged_decode_kernel(pair_ref, page_ref, pos_ref, n_ref, q_ref, k_ref,
                          *rest, scale, page_size, maxp, quantized,
-                         value_dim=None):
+                         value_dim=None, window=0):
     """Paged variant of ``_decode_kernel``: one grid step is ALL kv heads
     of one slot against ONE cache page, and the grid is the step's LIVE
     (slot, page) pairs in slot order (:func:`_live_pairs`, prefetched;
@@ -254,7 +269,12 @@ def _paged_decode_kernel(pair_ref, page_ref, pos_ref, n_ref, q_ref, k_ref,
     ``value_dim`` marks a LATENT pool (ops/quant/kv.py ``c_pages``
     [num_pages, page_size, stored]: one head, no head dim): there is no
     ``v_ref`` — the value is the leading ``value_dim`` features of the
-    K block this step already holds in VMEM, so a page costs one DMA."""
+    K block this step already holds in VMEM, so a page costs one DMA.
+
+    ``window`` > 0: the query sees the last ``window`` positions alone
+    (its own included), and the slot's pairs start at
+    :func:`_first_live_page`, whose oldest visible key keeps the
+    statistics finite as position 0 does without a window."""
     if value_dim is None:
         v_ref, rest = rest[0], rest[1:]
     if quantized:
@@ -265,7 +285,7 @@ def _paged_decode_kernel(pair_ref, page_ref, pos_ref, n_ref, q_ref, k_ref,
     ki = jax.lax.rem(pair_ref[i], maxp)
     pos = pos_ref[jax.lax.div(pair_ref[i], maxp)]
 
-    @pl.when(ki == 0)
+    @pl.when(ki == _first_live_page(pos, page_size, window))
     def _init():
         m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
@@ -292,7 +312,10 @@ def _paged_decode_kernel(pair_ref, page_ref, pos_ref, n_ref, q_ref, k_ref,
         preferred_element_type=jnp.float32) * scale       # [kv_h, g, ps]
     k_pos = ki * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, 1, page_size), 2)
-    s = jnp.where(k_pos <= pos, s, NEG_INF)
+    seen = k_pos <= pos
+    if window:
+        seen &= k_pos > pos - window
+    s = jnp.where(seen, s, NEG_INF)
 
     m_prev = m_scr[:, :, :1]                              # [kv_h, g, 1]
     l_prev = l_scr[:, :, :1]
@@ -324,7 +347,7 @@ def _paged_decode_kernel(pair_ref, page_ref, pos_ref, n_ref, q_ref, k_ref,
 
 def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
                          scale, interpret, k_scale=None, v_scale=None,
-                         active=None, value_dim=None):
+                         active=None, value_dim=None, window=0):
     slots, one, h, d = q.shape
     page_size = k_pages.shape[1]
     if value_dim is not None:
@@ -343,7 +366,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
     q_g = q.reshape(slots, kv_h, group, d)
     with jax.named_scope("cache"):
         pair, pages, n = _live_pairs(page_table, positions, active,
-                                     page_size)
+                                     page_size, window)
 
     def slot_index(i, pair, pages, pos, n):
         return (pair[i] // maxp, 0, 0, 0)
@@ -372,7 +395,8 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions, *,
     operands.append(jnp.zeros((slots, kv_h, group, d_v), q.dtype))
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                page_size=page_size, maxp=maxp,
-                               quantized=quantized, value_dim=value_dim)
+                               quantized=quantized, value_dim=value_dim,
+                               window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(jnp.maximum(n[0], 1),),
@@ -552,7 +576,8 @@ def _shard_map_axes(mesh, slots, h, kv_h):
 
 def _paged_decode_shard_map(q, k_pages, v_pages, page_table, positions,
                             *, scale, interpret, mesh, k_scale=None,
-                            v_scale=None, active=None, value_dim=None):
+                            v_scale=None, active=None, value_dim=None,
+                            window=0):
     """Run the paged kernel per-shard over the serving mesh: kv pools
     enter sharded [pages, ps, KV_H/model, dim] (each device holds its
     kv-head slice of EVERY page — page ids are global, the host-side
@@ -588,7 +613,7 @@ def _paged_decode_shard_map(q, k_pages, v_pages, page_table, positions,
         return _paged_decode_pallas(q_, kp_, vp_, pt_, pos_, scale=scale,
                                     interpret=interpret, k_scale=ks,
                                     v_scale=vs, active=act_,
-                                    value_dim=value_dim)
+                                    value_dim=value_dim, window=window)
 
     return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                          out_specs=q_spec, check_vma=False)(*args)
@@ -607,7 +632,8 @@ def gather_pages(pages, page_table):
 def paged_decode_attention(q, k_pages, v_pages, page_table, positions, *,
                            scale=None, bias=None, interpret=None,
                            force_kernel=False, k_scale=None,
-                           v_scale=None, active=None, value_dim=None):
+                           v_scale=None, active=None, value_dim=None,
+                           window=0):
     """Single-token attention of ``q`` [slots, 1, heads, d] over a PAGED
     cache: a shared pool ``k_pages``/``v_pages`` [num_pages, page_size,
     kv_heads, d] indexed through ``page_table`` [slots, max_pages] with
@@ -655,6 +681,10 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, positions, *,
     features — the kernel fetches a page once and slices the block in
     VMEM; the fallback gathers the leaf once.  The output is ``n`` wide.
 
+    ``window`` > 0 is sliding-window attention: key positions ``position
+    - window < p <= position`` are live, and the kernel walks the pages
+    that hold them alone.
+
     Both paths are ``lax.scan``-compatible: every branch decision here
     is made on static python values, and ``positions``/``page_table``
     may be traced carries — the fused multi-step serving decode
@@ -689,7 +719,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, positions, *,
         return call(q, k_pages, v_pages, page_table.astype(jnp.int32),
                     positions, scale=scale, interpret=interpret,
                     k_scale=k_scale, v_scale=v_scale, active=active,
-                    value_dim=value_dim)
+                    value_dim=value_dim, window=window)
 
     if value_dim is not None:
         k_full = gather_pages(k_pages[:, :, None], page_table)
@@ -707,6 +737,9 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, positions, *,
                                     q.dtype)
     k_pos = jnp.arange(max_len)
     mask = k_pos[None, None, None, :] <= positions[:, None, None, None]
+    if window:
+        mask &= k_pos[None, None, None, :] > \
+            positions[:, None, None, None] - window
     full_bias = jnp.where(mask, 0.0, jnp.finfo(jnp.float32).min)
     if bias is not None:
         full_bias = full_bias + bias.astype(jnp.float32)

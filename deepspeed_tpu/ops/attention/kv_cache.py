@@ -211,7 +211,9 @@ def attend(q, k, v, positions, cache, *, impl="auto", window=0,
     ``window`` > 0 is local sliding-window attention (a query sees the
     last ``window`` positions, its own included); on a
     :class:`PagedStep` such a layer's entry is a ring a slot, not pages
-    (ops/attention/window.py).  ``key_bias`` maps key positions [n] to
+    (ops/attention/window.py): XLA ops over a ring of ``window`` rows,
+    the paged kernels below over one of two pages more, through
+    ``window.page_view``.  ``key_bias`` maps key positions [n] to
     an additive bias broadcastable to [b, h, l, n] (ALiBi: softmax is
     shift-invariant per query row, so slopes * key_pos == slopes *
     (key_pos - query_pos)).  ``sink`` [h] is one logit a query head
@@ -254,14 +256,24 @@ def attend(q, k, v, positions, cache, *, impl="auto", window=0,
         return _attend_fresh(q, k, v, impl, window, key_bias), None
     if not isinstance(cache, PagedStep):
         return _attend_dense(q, k, v, positions, cache, window, key_bias)
+    ring = None
     if window > 0:
         assert key_bias is None, "a window ring takes no key bias"
-        return window_ops.attend_ring(q, k, v, positions, cache,
-                                      window=window, sink=sink)
+        ring = cache.layers
+        page = window_ops.ring_page_size(ring, window)
+        if not page:
+            return window_ops.attend_ring(q, k, v, positions, cache,
+                                          window=window, sink=sink)
+        # a ring longer than its window: a page pool of the layer's
+        # own, read by the paged kernels below through a derived table
+        assert cache.seq_parallel is None, \
+            "sequence-parallel prefill takes no window ring"
+        cache, positions = window_ops.page_view(cache, positions, window,
+                                                page)
     assert sink is None, "a sink logit over pages: no kernel takes one"
     if cache.mode != "decode":
         out, pools = _paged_multi(q, k, v, positions, cache, key_bias,
-                                  scale, value_dim)
+                                  scale, value_dim, window)
     else:
         # single-token decode, written out HERE and not behind a call of
         # its own: the Pallas kernel's body is traced below this frame
@@ -287,7 +299,10 @@ def attend(q, k, v, positions, cache, *, impl="auto", window=0,
         out = paged_decode_attention(
             q, page_leaf(pools), pools.get("v_pages"), pt, pos, bias=bias,
             k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"),
-            active=cache.count, scale=scale, value_dim=value_dim)
+            active=cache.count, scale=scale, value_dim=value_dim,
+            window=window)
+    if ring is not None:
+        return out, window_ops.ring_entry(pools, ring)
     # multi-chip serving: pin the pools' kv-head sharding on the updated
     # arrays so GSPMD keeps the scatter/gather split over the `model`
     # axis (no-op on a single-device mesh; GQA pools shard num_kv_heads,
@@ -307,7 +322,8 @@ def _causal_bias(k_pos, pos, key_bias, window=0):
     return bias if key_bias is None else bias + key_bias(k_pos)
 
 
-def _paged_multi(q, k, v, pos, step, key_bias, scale=None, value_dim=None):
+def _paged_multi(q, k, v, pos, step, key_bias, scale=None, value_dim=None,
+                 window=0):
     """Prefill and verify: write the ``count[r]`` valid columns of each
     row through its row of the page table, then attend causally over
     the row's pages — the ``paged_prefill`` kernel over the LIVE pages
@@ -337,11 +353,12 @@ def _paged_multi(q, k, v, pos, step, key_bias, scale=None, value_dim=None):
         if decision["path"] == "kernel":
             return paged_flash_prefill(q, pools, pt_rows, pos[:, 0],
                                        step.count, mesh=mesh,
-                                       scale=scale,
-                                       value_dim=value_dim), pools
+                                       scale=scale, value_dim=value_dim,
+                                       window=window), pools
     k_slot, v_slot = paged_gather(pools, pt_rows, q.dtype, value_dim)
     if step.seq_parallel is None:
-        bias = _causal_bias(jnp.arange(pt.shape[1] * ps), pos, key_bias)
+        bias = _causal_bias(jnp.arange(pt.shape[1] * ps), pos, key_bias,
+                            window)
         return decode_attention(q, k_slot, v_slot, bias=bias,
                                 scale=scale), pools
     assert scale is None and value_dim is None, \

@@ -51,6 +51,14 @@ Design:
     (``count == 0``) lists ONE step, position 0's page, so every output
     block is written (finite, meaningless) and none needs a zero fill.
     The causal mask ``k_pos <= start + j`` is computed in-kernel.
+  * ``window`` > 0 (static; a sliding-window layer's ring read as a
+    page pool of its own, ops/attention/window.py): column j sees the
+    last ``window`` positions, its own included.  A tile's steps start
+    at the page of its first column's oldest visible position
+    (:func:`_first_page`) instead of page 0, so a row costs its
+    window's pages whatever its context, and the mask gains ``k_pos >
+    q_pos - window``.  ``window=0`` traces the program it always was
+    (tests/unit/test_window_paged.py holds its jaxpr).
   * operands in the pool's / q's dtype, scores, statistics and
     accumulator in float32, P cast to V's dtype before P·V: the
     arithmetic of ``_gqa_reference``.  Quantized pools dequantize in
@@ -84,6 +92,18 @@ def _last_page(start, last, ti, cols, page_size, maxp, minimum=jnp.minimum):
         minimum(last, start + (ti + 1) * cols - 1) // page_size, maxp - 1)
 
 
+def _first_page(start, ti, cols, page_size, window, live):
+    """Index, in its row's table, of the first page q tile ``ti`` of a
+    row visits under a sliding ``window``: the page of the oldest
+    position the tile's FIRST column sees, ``start + ti * cols - window
+    + 1``, and never past the tile's last page ``live - 1`` (a tile of
+    padding columns, a padding row).  Without a window every tile
+    starts at page 0 and nobody asks."""
+    return jnp.minimum(
+        jnp.maximum(start + ti * cols - window + 1, 0) // page_size,
+        live - 1)
+
+
 def _tail_walked(tail, block):
     """Whether the ``tail`` pages a tile holds past its last whole block
     are walked a page a step: one page, or under half a block."""
@@ -107,10 +127,12 @@ def _is_block(k, live, block):
         jnp.logical_not(_tail_walked(live % block, block))
 
 
-def _live_steps(page_table, start, last, cols, tiles, page_size, block):
+def _live_steps(page_table, start, last, cols, tiles, page_size, block,
+                window=0):
     """The kernel's grid, from the dispatch's own inputs: the live
     (row, q tile, key block) steps in row-major, tile-major, key-minor
-    order.  Tile t of row r holds pages ``0 ... _last_page(r, t)`` in
+    order.  Tile t of row r holds pages ``_first_page(r, t) ...
+    _last_page(r, t)`` (the first is 0 without a ``window``) in
     the steps :func:`_tile_steps` counts, blocks first; ``last`` is 0
     for a padding row, which therefore holds page 0 alone (one step a
     tile: its output block is written like any other).  Returns int32
@@ -129,8 +151,16 @@ def _live_steps(page_table, start, last, cols, tiles, page_size, block):
     t = jnp.arange(tiles, dtype=jnp.int32)
     live = _last_page(start[:, None], last[:, None], t[None], cols,
                       page_size, maxp).reshape(-1) + 1
-    blocks, singles = _tile_steps(live, block)
-    width = int(sum(_tile_steps(np.arange(1, maxp + 1), block)).max())
+    held, most = live, maxp
+    if window:
+        # a tile under a window holds its last pages alone: at most the
+        # window and its own columns, whatever the context
+        lo = _first_page(start[:, None], t[None], cols, page_size,
+                         window, live.reshape(-1, tiles)).reshape(-1)
+        held = live - lo
+        most = min(maxp, (window + cols - 2) // page_size + 2)
+    blocks, singles = _tile_steps(held, block)
+    width = int(sum(_tile_steps(np.arange(1, most + 1), block)).max())
     tile, e, n = _segment_entries((blocks + singles).astype(jnp.int32),
                                   width)
     # entry e of a tile: its blocks, then the walked tail page by page
@@ -140,8 +170,10 @@ def _live_steps(page_table, start, last, cols, tiles, page_size, block):
     # slot j of step i reads page k + j: slot 0 always, the others in a
     # block as far as the tile's pages go; else it keeps the last read
     j = jnp.arange(block, dtype=jnp.int32)[:, None]
-    reads = listed & ((j == 0) | (_is_block(k, live[tile], block) &
-                                  (k + j < live[tile])))
+    reads = listed & ((j == 0) | (_is_block(k, held[tile], block) &
+                                  (k + j < held[tile])))
+    if window:
+        k = k + lo[tile]          # from here on an index into the table
     src = jax.lax.cummax(jnp.where(reads, i, 0), axis=1)
     first = (tile // tiles) * maxp + k        # into the flattened table
     pages = jnp.where((src > 0) | reads[:, :1],
@@ -180,7 +212,7 @@ def _row_and_tile(tile, tiles):
 
 def _paged_prefill_kernel(tile_ref, k_idx_ref, page_ref, start_ref, last_ref,
                           q_ref, *rest, scale, page_size, group, maxp, tiles,
-                          block, quantized, value_dim=None):
+                          block, quantized, value_dim=None, window=0):
     """One grid step: one q tile of one row, ALL kv heads, against one
     KEY BLOCK -- ``block`` consecutive pages of the row, or one page of
     a walked tail; the grid is the dispatch's live steps
@@ -190,7 +222,9 @@ def _paged_prefill_kernel(tile_ref, k_idx_ref, page_ref, start_ref, last_ref,
     ``_paged_decode_kernel``); the per-kv-head products are
     leading-batch dots.  ``value_dim`` marks a latent pool: no V refs,
     the value is the leading ``value_dim`` features of the K block (one
-    DMA a page), as in the decode kernel."""
+    DMA a page), as in the decode kernel.  ``window`` > 0: a query sees
+    the last ``window`` positions alone, and the tile's steps start at
+    :func:`_first_page` (``held`` counts its pages from there)."""
     k_refs, rest = rest[:block], rest[block:]
     if value_dim is None:
         v_refs, rest = rest[:block], rest[block:]
@@ -205,8 +239,10 @@ def _paged_prefill_kernel(tile_ref, k_idx_ref, page_ref, start_ref, last_ref,
     cols = tq // group
     live = _last_page(start_ref[ri], last_ref[ri], ti, cols, page_size,
                       maxp) + 1
+    lo = _first_page(start_ref[ri], ti, cols, page_size, window, live) \
+        if window else 0
 
-    @pl.when(ki == 0)
+    @pl.when(ki == lo)
     def _init():
         m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
@@ -220,7 +256,11 @@ def _paged_prefill_kernel(tile_ref, k_idx_ref, page_ref, start_ref, last_ref,
     def update(w):
         """The online-softmax update over the step's first ``w`` pages.
         Position 0 is live for every row, so a tile's page 0 is always
-        listed and the statistics are finite from the first step on."""
+        listed and the statistics are finite from the first step on.
+        (Under a window a tile's first page may hold no key that its
+        LATER columns see: such a row's statistics stay at NEG_INF,
+        which is finite, and the first step with a visible key -- its
+        own position at the latest -- rescales them away by alpha = 0.)"""
         keys = w * page_size
         q = q_ref[0]                                      # [kv_h, tq, d]
         if value_dim is not None:
@@ -245,7 +285,11 @@ def _paged_prefill_kernel(tile_ref, k_idx_ref, page_ref, start_ref, last_ref,
         k_rel = ki * page_size - start_ref[ri] - ti * cols + \
             jax.lax.broadcasted_iota(jnp.int32, (1, 1, keys), 2)
         row = jax.lax.broadcasted_iota(jnp.int32, (1, tq, 1), 1)
-        s = jnp.where(k_rel * group <= row, s, NEG_INF)
+        seen = k_rel * group <= row
+        if window:
+            # ... and p > start + col - window
+            seen &= (k_rel + window) * group > row
+        s = jnp.where(seen, s, NEG_INF)
 
         m_prev = m_scr[:, :, :1]                          # [kv_h, tq, 1]
         l_prev = l_scr[:, :, :1]
@@ -264,7 +308,8 @@ def _paged_prefill_kernel(tile_ref, k_idx_ref, page_ref, start_ref, last_ref,
         update(1)
         pages = 1
     else:
-        wide = _is_block(ki, live, block)
+        wide = _is_block(ki - lo, live - lo, block) if window \
+            else _is_block(ki, live, block)
         pl.when(wide)(lambda: update(block))
         pl.when(jnp.logical_not(wide))(lambda: update(1))
         pages = jnp.where(wide, block, 1)
@@ -329,10 +374,10 @@ def key_block_plan(chunk, heads, kv_heads, page_size, d, itemsize,
         pool_itemsize)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "interpret", "value_dim"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret",
+                                             "value_dim", "window"))
 def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
-                  count, *, scale, interpret, value_dim=None):
+                  count, *, scale, interpret, value_dim=None, window=0):
     """The kernel call, jitted under its own name: every layer of a
     serving program (and every program of one geometry) shares ONE trace
     of the kernel body — a bare ``pallas_call`` re-traces it per call
@@ -340,7 +385,11 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
     HLO, so readers that find the decode and flash kernels by the
     model's ``attn`` scope never count this one.  ``v_pages=None,
     value_dim=n`` is the shared read of a latent pool (``k_pages`` the
-    one leaf [num_pages, page_size, d]; see ``paged_decode_attention``)."""
+    one leaf [num_pages, page_size, d]; see ``paged_decode_attention``).
+    ``window`` > 0 is sliding-window attention: column j sees positions
+    ``start + j - window + 1 ... start + j``, and a tile visits the
+    pages from its first column's oldest visible position on, so what a
+    row costs is its window's pages, not its context's."""
     b, l, h, d = q.shape
     page_size = k_pages.shape[1]
     if value_dim is not None:
@@ -368,7 +417,7 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
     with jax.named_scope("cache"):
         tile, k_idx, pages, n = _live_steps(
             page_table.astype(jnp.int32), start, last, cols, tiles,
-            page_size, block)
+            page_size, block, window)
     cap = tile.shape[0]
 
     def tile_index(i, tile, k_idx, pages, st, ls):
@@ -398,7 +447,7 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
     kernel = functools.partial(_paged_prefill_kernel, scale=scale,
                                page_size=page_size, group=group, maxp=maxp,
                                tiles=tiles, block=block, quantized=quantized,
-                               value_dim=value_dim)
+                               value_dim=value_dim, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(n[0],),
@@ -422,7 +471,7 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
 
 def paged_flash_prefill(q, pools, page_table, start, count, *,
                         mesh=None, scale=None, interpret=None,
-                        value_dim=None):
+                        value_dim=None, window=0):
     """Causal attention of ``q`` [rows, l, heads, d] over a PAGED cache:
     column j of row r sits at position ``start[r] + j`` and sees key
     positions <= its own through ``page_table`` [rows, max_pages] (the
@@ -430,7 +479,8 @@ def paged_flash_prefill(q, pools, page_table, start, count, *,
     chunk ALREADY written (``paged_write``); ``count[r]`` columns of row
     r are valid — pages past the last valid column are never read, and
     the outputs of padding columns and padding rows are finite and
-    meaningless.  ``mesh`` set runs the kernel per shard under
+    meaningless.  ``window`` > 0 cuts what a column sees to the last
+    ``window`` positions, its own included.  ``mesh`` set runs the kernel per shard under
     ``shard_map`` with the decode kernel's axes (kv heads over
     ``model``, rows over ``data`` where they divide).  A latent layer's
     ``pools`` (its one leaf, ``value_dim`` set) runs the shared read;
@@ -440,7 +490,8 @@ def paged_flash_prefill(q, pools, page_table, start, count, *,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     call = functools.partial(paged_prefill, scale=scale,
-                             interpret=interpret, value_dim=value_dim)
+                             interpret=interpret, value_dim=value_dim,
+                             window=window)
     latent = value_dim is not None
     args = (q, pools[LATENT_LEAF] if latent else pools["k_pages"],
             pools.get("v_pages"), pools.get("k_scale"),

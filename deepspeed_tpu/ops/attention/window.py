@@ -34,7 +34,37 @@ and nothing else:
 whose column is dropped: ``p_ij = exp(s_ij) / (exp(sink_h) + sum_j
 exp(s_ij))``.  Keys and values may differ in width.
 
-Everything here is XLA ops (128 keys a slot); the pool is donated to
+Two forms read a ring, and the ring's SHAPE says which (never a model's
+name, an option or a flag):
+
+* ``rows == window`` (:func:`init_ring`): XLA ops over the whole ring,
+  :func:`attend_ring` -- a score tensor [rows, heads, chunk, window +
+  chunk] in HBM, which is right at 128 keys a slot and takes a sink and
+  two widths.
+* ``rows == window + 2 pages`` (:func:`init_paged_ring`): the two paged
+  kernels (paged_prefill.py, decode.py, each given ``window=``) read
+  the leaf as a page pool of its own.  Position ``p`` still sits at row
+  ``p % rows``; reshaped -- free, the leaf is contiguous -- it is
+  ``[slots x rows / page, page, kv_heads, dim]``, and :func:`page_view`
+  derives a table a dispatch row: with ``lo`` the page of the oldest
+  position the row's first query sees, table entry ``l`` is page
+  ``slot x pages + (lo + l) % pages`` and the row's positions are
+  counted from ``lo``'s first (the masks are differences of positions,
+  so nothing else changes, and the table is ``rows / page`` wide
+  whatever the context).  The chunk is written through that table
+  FIRST and read after, as a page pool's is, so the ring must hold the
+  window of the chunk's first query beside the chunk itself, each
+  page they touch a page of its own: ``window + l - 1`` positions on
+  end lie in ``window / page + 2`` pages where the chunk straddles a
+  page boundary, for any ``l <= page + 2`` -- hence the two pages more,
+  and one fewer leaves some start without a page for its last
+  (tests/unit/test_window_paged.py counts both).  No score tensor
+  wider than a key block reaches HBM, and a row costs its window's
+  pages.  A sink has no kernel here and raises.  Off the TPU (and
+  under ``paged_kernel="reference"``) the same view runs the gather
+  reference, as a page pool's does.
+
+The XLA form is 128 keys a slot; the pool is donated to
 every dispatch and updated in place by the scatters below.
 :func:`masked_attention` is also what a model with a sink or with
 ``k_dim != v_dim`` runs where no serving cache is involved (training,
@@ -42,11 +72,14 @@ the full forward, ``generate()``'s dense cache): the flash and
 contiguous-decode kernels take neither.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 F32 = jnp.float32
+RING_LEAVES = ("k_ring", "v_ring")
 
 
 def init_ring(slots, window, kv_heads, k_dim, v_dim, dtype):
@@ -54,9 +87,92 @@ def init_ring(slots, window, kv_heads, k_dim, v_dim, dtype):
             "v_ring": jnp.zeros((slots, window, kv_heads, v_dim), dtype)}
 
 
+def paged_ring_rows(window, page_size):
+    """Rows of a ring the paged kernels read: the window and two pages
+    (see the module's docstring for why two)."""
+    if window % page_size:
+        raise ValueError(
+            f"a paged ring holds whole pages: window {window} is no "
+            f"multiple of the page size {page_size}")
+    return window + 2 * page_size
+
+
+def init_paged_ring(slots, window, page_size, kv_heads, k_dim, v_dim, dtype):
+    """The ring of a window too long for XLA ops over all of it."""
+    return init_ring(slots, paged_ring_rows(window, page_size), kv_heads,
+                     k_dim, v_dim, dtype)
+
+
 def bytes_per_slot(window, kv_heads, k_dim, v_dim, dtype):
-    """Exact bytes one slot's ring costs in ONE window layer."""
+    """Exact bytes one slot's ring of ``window`` rows costs in ONE
+    window layer."""
     return window * kv_heads * (k_dim + v_dim) * jnp.dtype(dtype).itemsize
+
+
+def ring_page_size(entry, window):
+    """The page size the paged kernels read ``entry``'s ring at, from
+    its shape: 0 for a ring of ``window`` rows (the XLA form)."""
+    if "k_ring" not in entry:
+        raise ValueError(
+            "a window layer on the paged serving path keeps a ring a "
+            "slot, not pages: the model's init_paged_kv_cache has to "
+            "build its entry with ops/attention/window.init_ring or "
+            f"init_paged_ring (this entry holds {sorted(entry)})")
+    rows = entry["k_ring"].shape[1]
+    if rows == window:
+        return 0
+    page = (rows - window) // 2
+    if rows < window or rows != window + 2 * page or window % page:
+        raise ValueError(f"a ring of {rows} rows cannot serve a window of "
+                         f"{window}: {window} rows, or {window} and two "
+                         "pages that divide it")
+    return page
+
+
+def page_view(step, positions, window, page_size):
+    """(``step`` with the layer's ring as a page pool and the table
+    derived for it, ``positions`` counted from each row's first table
+    entry): what ``kv_cache.attend`` hands the paged write and the
+    paged kernels for a ring of ``window + 2 x page_size`` rows.  Row
+    r's table starts at ``lo``, the page of the oldest position its
+    first query sees, so it is ``rows / page_size`` wide at any
+    context.  The view names no slot (``rows`` None): its table is a
+    dispatch row's already."""
+    if step.mode not in ("prefill", "decode"):
+        raise NotImplementedError(
+            f"a window-ring layer cannot run a {step.mode!r} step: "
+            "rejected tokens would have to be taken out of a ring that "
+            "has overwritten what they replaced")
+    k_ring, v_ring = (step.layers[name] for name in RING_LEAVES)
+    slots, rows = k_ring.shape[:2]
+    pages = rows // page_size
+    b, l = positions.shape
+    # the window of the first query and the chunk are window + l - 1
+    # positions on end, which lie in this many pages at most
+    if (window + l - 3) // page_size + 2 > pages:
+        raise ValueError(
+            f"a chunk of {l} columns does not fit a ring of {rows} rows "
+            f"beside its first query's window of {window}: at most "
+            f"{rows - window - page_size + 2} columns a dispatch")
+    with jax.named_scope("cache"):
+        slot = jnp.arange(b) if step.rows is None else step.rows
+        lo = jnp.maximum(positions[:, 0] - window + 1, 0) // page_size
+        table = slot[:, None] * pages + \
+            (lo[:, None] + jnp.arange(pages)[None, :]) % pages
+        positions = positions - (lo * page_size)[:, None]
+
+    def as_pool(ring):
+        return ring.reshape((slots * pages, page_size) + ring.shape[2:])
+    view = dataclasses.replace(
+        step, layers={"k_pages": as_pool(k_ring), "v_pages": as_pool(v_ring)},
+        page_table=table.astype(jnp.int32), rows=None)
+    return view, positions
+
+
+def ring_entry(pools, like):
+    """A :func:`page_view`'s updated pools as the ring entry ``like``."""
+    return {name: pools[leaf].reshape(like[name].shape)
+            for name, leaf in zip(RING_LEAVES, ("k_pages", "v_pages"))}
 
 
 def masked_attention(q, k, v, mask, sink=None):
@@ -132,17 +248,11 @@ def attend_ring(q, k, v, positions, step, *, window, sink=None):
     ``layers`` is the ring entry.  Returns (out [b, l, h, v_dim], the
     updated entry)."""
     entry = step.layers
-    if "k_ring" not in entry:
-        raise ValueError(
-            "a window layer on the paged serving path keeps a ring a "
-            "slot, not pages: the model's init_paged_kv_cache has to "
-            "build its entry with ops/attention/window.init_ring (this "
-            f"entry holds {sorted(entry)})")
     k_ring, v_ring = entry["k_ring"], entry["v_ring"]
     slots, size = k_ring.shape[:2]
     if size != window:
         raise ValueError(f"a ring of {size} rows cannot serve a window "
-                         f"of {window}")
+                         f"of {window} as XLA ops")
     b, l = positions.shape
     k, v = k.astype(k_ring.dtype), v.astype(v_ring.dtype)
     if step.mode == "decode":

@@ -137,6 +137,10 @@ class ServingMetrics:
         # the (query, key) pairs it scores
         self.prefill_kv_tokens = 0
         self.prefill_kv_pairs = 0
+        # and of the WINDOW layers (0: none): the same two with each
+        # query's keys cut to the window, its own position included
+        self.prefill_window_tokens = 0
+        self.prefill_window_pairs = 0
         # and of the page table: over the dispatches, the (row, page)
         # entries the rows' chunks reach (what the paged prefill
         # kernel's work list holds) and padded rows x pages a slot
@@ -225,7 +229,8 @@ class ServingMetrics:
             ])
 
     def record_prefill_dispatch(self, step, *, rows, padded_rows, tokens,
-                                kv_tokens=0, kv_pairs=0, riders=0,
+                                kv_tokens=0, kv_pairs=0, window_tokens=0,
+                                window_pairs=0, riders=0,
                                 live_pages=0, table_pages=0, key_blocks=0,
                                 block_pages=0, lookahead=False):
         """One shared prefill dispatch carried the next chunk of
@@ -234,7 +239,9 @@ class ServingMetrics:
         ``riders`` decoding slots (one-token rows: in neither ``rows``
         nor ``tokens``); over all of them its rows read ``kv_tokens``
         keys of the paged layers and scored ``kv_pairs`` (query, key)
-        pairs, on ``live_pages`` (row, page) entries of the
+        pairs (in a window layer ``window_tokens`` keys, the window of
+        a row's first query and its own columns, and ``window_pairs``
+        pairs), on ``live_pages`` (row, page) entries of the
         ``table_pages`` = ``padded_rows`` x pages a slot that the
         dispatch's page table holds; the ``paged_prefill`` kernel walks
         them in ``key_blocks`` grid steps that compute ``block_pages``
@@ -248,6 +255,8 @@ class ServingMetrics:
         self.ride_dispatches += riders > 0
         self.prefill_kv_tokens += int(kv_tokens)
         self.prefill_kv_pairs += int(kv_pairs)
+        self.prefill_window_tokens += int(window_tokens)
+        self.prefill_window_pairs += int(window_pairs)
         self.prefill_live_pages += int(live_pages)
         self.prefill_table_pages += int(table_pages)
         self.prefill_key_blocks += int(key_blocks)
@@ -843,6 +852,8 @@ class ServingMetrics:
             "decode_window_tokens": self.decode_window_tokens,
             "prefill_kv_tokens": self.prefill_kv_tokens,
             "prefill_kv_pairs": self.prefill_kv_pairs,
+            "prefill_window_tokens": self.prefill_window_tokens,
+            "prefill_window_pairs": self.prefill_window_pairs,
             "kv_paged_bytes_per_token": self.kv_paged_bytes_per_token,
             "kv_window_bytes_per_slot": self.kv_window_bytes_per_slot,
             "kv_latent_bytes_per_token": self.kv_latent_bytes_per_token,

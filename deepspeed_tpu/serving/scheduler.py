@@ -2251,6 +2251,7 @@ class ServingScheduler:
             kv_tokens=sum(starts) + tokens + riders,
             kv_pairs=sum(s * len(c) + len(c) * (len(c) + 1) // 2
                          for s, (_, _, c) in zip(starts, rows)),
+            **self._window_prefill_counts(starts, rows),
             **(self._count_key_blocks(
                 starts + pad, [len(c) for _, _, c in rows] + pad,
                 max_pages=self.kv.table.shape[1])
@@ -2262,6 +2263,22 @@ class ServingScheduler:
             if fresh:
                 self.metrics.record_state_resets(self.step_idx, fresh)
         return logits
+
+    def _window_prefill_counts(self, starts, rows):
+        """What a window layer needs of a prefill dispatch: a row from
+        position ``s`` with ``n`` columns reads the keys its first
+        query sees and its own (positions ``s - window + 1 .. s + n -
+        1``, from 0 at the earliest) and scores, for column j, the last
+        ``window`` of ``s + j + 1`` keys."""
+        if not self._window:
+            return {}
+        w = self._window
+        lens = [len(c) for _, _, c in rows]
+        return dict(
+            window_tokens=sum(min(s + n, w + n - 1)
+                              for s, n in zip(starts, lens)),
+            window_pairs=sum(min(s + j + 1, w) for s, n in zip(starts, lens)
+                             for j in range(n)))
 
     def _ids_from_device(self, ids, src):
         """``ids`` with column 0 of row r read from the device's token

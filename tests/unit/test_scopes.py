@@ -122,6 +122,9 @@ def _family(name):
     if name == "mimo_v2":
         from deepspeed_tpu.models.mimo_v2 import MiMoV2, mimo_v2_tiny
         return MiMoV2(mimo_v2_tiny())
+    if name == "afmoe":
+        from deepspeed_tpu.models.afmoe import AFMoE, afmoe_tiny
+        return AFMoE(afmoe_tiny())
     from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3,
                                                   deepseek_v3_tiny)
     return DeepseekV3(deepseek_v3_tiny())
@@ -151,7 +154,8 @@ def serving_programs(family):
 
 @pytest.mark.parametrize("program", ["prefill", "decode_multi"])
 @pytest.mark.parametrize("family", ["llama", "gpt2", "nemotron_h",
-                                    "falcon_h1", "mimo_v2", "deepseek_v3"])
+                                    "falcon_h1", "mimo_v2", "deepseek_v3",
+                                    "afmoe"])
 def test_every_serving_operation_has_a_component(family, program):
     model, programs = serving_programs(family)
     assert unnamed(programs[program], model) == []
@@ -219,6 +223,15 @@ def test_every_training_operation_has_a_component(stage):
     ("jit(step)/transpose(jvp(loss))/jit(take_along_axis)/scatter-add", "",
      "loss", "bwd"),
     ("jit(prefill)/MiMoV2/layers_2/swa/wq/dot_general", "", "attn_proj", ""),
+    ("jit(prefill)/AFMoE/layers_2/swa/attn_proj/wg/dot_general", "",
+     "attn_proj", ""),
+    ("jit(prefill)/AFMoE/layers_2/swa/attn_proj/q_norm/rsqrt", "",
+     "attn_proj", ""),
+    ("jit(prefill)/AFMoE/layers_2/swa/jit(paged_prefill)/paged_prefill", "",
+     "attn_core", ""),
+    ("jit(prefill)/AFMoE/layers_2/norm/post_ff_norm/mul", "", "norm", ""),
+    ("jit(prefill)/AFMoE/layers_2/moe/shared/w_up/dot_general", "", "mlp",
+     ""),
     ("jit(prefill)/NemotronH/layers_0/mamba/ssm/mul", "", "ssm", ""),
     ("jit(prefill)/NemotronH/layers_1/moe/router/top_k", "", "router", ""),
     ("jit(prefill)/Llama/norm/rsqrt", "", "norm", ""),

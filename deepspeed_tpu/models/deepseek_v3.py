@@ -31,7 +31,24 @@ biases::
   u_h``.  The cache never holds a per-head key or value: ``rank + rope``
   values a token a layer (ops/quant/kv.py ``latent_pool_layer``), read
   once a page as key AND value by both paged kernels at a query group of
-  ``num_heads``.  Per (query, cached token) pair the absorbed form costs
+  ``num_heads``.  A chunk's query is made ONCE, by one contraction a
+  head of ``[q_nope | rope(q_rope)]`` (``nope + rope`` wide) with
+  :func:`_absorbed_query_map` — ``W_UK,h`` on the nope features, the
+  identity on the rope features, zero columns up to the width the pool
+  STORES (``kvq.latent_stored_dim``: 576 at 640) — so ``kv_cache.attend``
+  is handed ``[q'_h | q_rope,h | 0]`` as the kernels read it and pads
+  the chunk's key alone; no concatenation, layout copy or pad of a
+  ``[rows, chunk, heads, 576..640]`` tensor stands between the
+  contraction and the prefill kernel, which reads the query HEAD-MAJOR,
+  the layout a contraction batched over heads writes
+  (ops/attention/paged_prefill.py).  The map is ``heads x (nope + rope)
+  x stored`` values a layer a program, the query ``tokens x heads x
+  stored``: a dispatch of fewer tokens than ``nope + rope`` (a decode
+  step of 64 slots against 192) would write more map than query, so it
+  makes ``q'`` and ``q_rope`` apart, concatenates them and lets
+  ``attend`` pad the few rows (PERF.md section 6, PR 63: the map in
+  every decode step cost 2.3% of the Kanana cell's window).  Per
+  (query, cached token) pair the absorbed form costs
   ``heads x (2 rank + rope)`` multiply-adds against ``heads x (nope +
   rope + v)`` plus the re-expansion of every cached token through
   ``W_kvb`` a dispatch: cheaper up to a prefill chunk of ~170 tokens at
@@ -173,6 +190,22 @@ def _proj(cfg, features, axes, name):
                       nn.initializers.normal(0.02), axes), name=name)
 
 
+def _absorbed_query_map(w_uk, rope_dim):
+    """[heads, nope + rope, stored]: a head's map from ``[q_nope |
+    q_rope]`` to the query the latent pool is read with, at the pool's
+    stored width (``kvq.latent_stored_dim``) -- ``W_UK`` [rank, heads,
+    nope] on the nope features, the identity on the rope features, zero
+    columns for the pool's padding.  A feature times 1.0 summed with
+    zeros is that feature, so the rope features pass through bit for
+    bit and the padding is exact zeros."""
+    rank, h, dn = w_uk.shape
+    stored = kvq.latent_stored_dim(rank + rope_dim)
+    passes = jnp.eye(rope_dim, stored, rank, dtype=w_uk.dtype)
+    return jnp.concatenate([
+        jnp.pad(w_uk.transpose(1, 2, 0), ((0, 0), (0, 0), (0, stored - rank))),
+        jnp.broadcast_to(passes, (h, rope_dim, stored))], 1)
+
+
 class MLAttention(nn.Module):
     """Multi-head latent attention; runs in the block's ``attn`` scope."""
     cfg: DeepseekV3Config
@@ -207,11 +240,22 @@ class MLAttention(nn.Module):
         if isinstance(cache, kv_cache.PagedStep):
             # absorbed: queries into the latent space, scores and sums
             # over the cached vectors, values out of it
-            with jax.named_scope("mla_absorb"):
-                q_abs = jnp.einsum("blhd,rhd->blhr", q_nope,
-                                   w_kvb[..., :dn])
+            if b * l < dn + dr:
+                # fewer tokens than a head's query has features (a
+                # decode step): the map would be the larger tensor, so
+                # q' and q_rope are made apart and attend widens them
+                with jax.named_scope("mla_absorb"):
+                    q_abs = jnp.einsum("blhd,rhd->blhr", q_nope,
+                                       w_kvb[..., :dn])
+                with jax.named_scope("attn_proj"):
+                    q_lat = jnp.concatenate([q_abs, q_rope], -1)
+            else:
+                with jax.named_scope("mla_absorb"):
+                    q_lat = jnp.einsum(
+                        "blhd,hdw->blhw",
+                        jnp.concatenate([q_nope, q_rope], -1),
+                        _absorbed_query_map(w_kvb[..., :dn], dr))
             with jax.named_scope("attn_proj"):
-                q_lat = jnp.concatenate([q_abs, q_rope], -1)
                 k_lat = jnp.concatenate([c, k_r[:, :, 0]], -1)
             u, new_cache = kv_cache.attend(
                 q_lat, k_lat, None, positions, cache, value_dim=rank,

@@ -227,23 +227,34 @@ def attend(q, k, v, positions, cache, *, impl="auto", window=0,
     The LATENT case — ``v`` None and ``value_dim`` set, on a
     :class:`PagedStep` whose entry is a latent leaf only: ``k`` [b, l,
     w] is ONE vector a token (the normed latent and the rotated shared
-    key), ``q`` [b, l, h, w] the absorbed queries; every head scores
-    against the cached vector and sums its leading ``value_dim``
-    features, so the output is [b, l, h, value_dim].  ``scale`` is
-    required (the width is not the published head's).  The chunk's rows
-    are written through the page table first (``paged_write``'s rules),
-    then decode or the prefill kernel read each page ONCE."""
+    key), ``q`` the absorbed queries; every head scores against the
+    cached vector and sums its leading ``value_dim`` features, so the
+    output is [b, l, h, value_dim].  A chunk's ``q`` arrives [b, l, h,
+    stored], at the width the POOL stores (``ops/quant/kv.
+    latent_stored_dim``: the model's absorption contraction writes the
+    zero columns itself, models/deepseek_v3.py), and the key alone is
+    padded here — a [b, l, w] tensor, 1 / heads of the query; a ``q``
+    at the published ``w`` (a decode step's few rows) is widened too.
+    ``scale`` is required (the width is not the published head's).  The
+    chunk's rows are written through the page table first
+    (``paged_write``'s rules), then decode or the prefill kernel read
+    each page ONCE."""
     if value_dim is not None:
         assert v is None and scale is not None and key_bias is None \
             and sink is None and window == 0
         assert isinstance(cache, PagedStep) and LATENT_LEAF in cache.layers, \
             "the latent form of attend runs over a latent page pool only"
         # the pool's own width (ops/quant/kv.latent_stored_dim): zeros
-        # add nothing to a score, and the value is the leading features
-        pad = cache.layers[LATENT_LEAF].shape[-1] - k.shape[-1]
-        if pad:
-            q = jnp.pad(q, ((0, 0),) * 3 + ((0, pad),))
-            k = jnp.pad(k, ((0, 0),) * 2 + ((0, pad),))
+        # add nothing to a score, and the value is the leading features.
+        # A chunk's q is at that width already: what is padded there is
+        # the key alone, and no pad of 0 columns is traced
+        stored = cache.layers[LATENT_LEAF].shape[-1]
+
+        def widen(x):
+            lacks = stored - x.shape[-1]
+            return x if not lacks else jnp.pad(
+                x, ((0, 0),) * (x.ndim - 1) + ((0, lacks),))
+        q, k = widen(q), widen(k)
     elif sink is not None or q.shape[-1] != v.shape[-1]:
         assert key_bias is None, "no key bias beside a sink or d != d_v"
         if cache is None:

@@ -44,6 +44,15 @@ Design:
     ``kv * group + g``): one MXU-shaped tile per kv head — Mistral's
     chunk of 32 x group 4 is 128 x 128 — and MHA is group == 1.  A chunk
     whose tile would outgrow VMEM is split over the q-tile grid axis.
+    Over a LATENT pool (one head, group == heads) q and the output are
+    HEAD-MAJOR instead, [heads, rows, l, d]: the contractions on either
+    side of the call are batched over heads (the absorption that makes
+    q, the one that takes the output back to a head's value), and a dot
+    batched over heads writes and reads heads outermost — so [rows, 1,
+    l * heads, d] cost a layout copy of the whole query in and of the
+    whole output out, a layer a dispatch (PERF.md section 6, PR 63).  A
+    tile is [heads, cols, d], merged in VMEM to the same ``heads *
+    cols`` MXU rows (row ``g * cols + j`` is column j of head g).
   * live pages only: a page is listed iff its first position is <=
     the last position the tile may see, so a page past it costs no DMA
     and no grid step; the next step's pages are in flight while this one
@@ -222,7 +231,8 @@ def _paged_prefill_kernel(tile_ref, k_idx_ref, page_ref, start_ref, last_ref,
     ``_paged_decode_kernel``); the per-kv-head products are
     leading-batch dots.  ``value_dim`` marks a latent pool: no V refs,
     the value is the leading ``value_dim`` features of the K block (one
-    DMA a page), as in the decode kernel.  ``window`` > 0: a query sees
+    DMA a page), as in the decode kernel, and the q and output tiles
+    head-major, [group, cols, d].  ``window`` > 0: a query sees
     the last ``window`` positions alone, and the tile's steps start at
     :func:`_first_page` (``held`` counts its pages from there)."""
     k_refs, rest = rest[:block], rest[block:]
@@ -235,8 +245,12 @@ def _paged_prefill_kernel(tile_ref, k_idx_ref, page_ref, start_ref, last_ref,
     i = pl.program_id(0)
     ri, ti = _row_and_tile(tile_ref[i], tiles)
     ki = k_idx_ref[i]
-    tq = q_ref.shape[2]
-    cols = tq // group
+    if value_dim is not None:
+        cols = q_ref.shape[1]       # a HEAD-MAJOR tile [group, cols, d]
+        tq = cols * group
+    else:
+        tq = q_ref.shape[2]
+        cols = tq // group
     live = _last_page(start_ref[ri], last_ref[ri], ti, cols, page_size,
                       maxp) + 1
     lo = _first_page(start_ref[ri], ti, cols, page_size, window, live) \
@@ -262,11 +276,12 @@ def _paged_prefill_kernel(tile_ref, k_idx_ref, page_ref, start_ref, last_ref,
         which is finite, and the first step with a visible key -- its
         own position at the latest -- rescales them away by alpha = 0.)"""
         keys = w * page_size
-        q = q_ref[0]                                      # [kv_h, tq, d]
         if value_dim is not None:
+            q = _merge_rows(q_ref[...])[None]             # [1, tq, d]
             k = side_by_side(k_refs, w, 1)                # [1, keys, d]
             v = k[:, :, :value_dim]
         else:
+            q = q_ref[0]                                  # [kv_h, tq, d]
             k = side_by_side(k_refs, w, 0)                # [keys, kv_h, d]
             v = side_by_side(v_refs, w, 0)
             if quantized:
@@ -284,11 +299,19 @@ def _paged_prefill_kernel(tile_ref, k_idx_ref, page_ref, start_ref, last_ref,
         # <= r — no integer division in-kernel
         k_rel = ki * page_size - start_ref[ri] - ti * cols + \
             jax.lax.broadcasted_iota(jnp.int32, (1, 1, keys), 2)
-        row = jax.lax.broadcasted_iota(jnp.int32, (1, tq, 1), 1)
-        seen = k_rel * group <= row
-        if window:
-            # ... and p > start + col - window
-            seen &= (k_rel + window) * group > row
+        if value_dim is not None:
+            # head-major: tile row r is column r % cols, of ANY head
+            col = _merge_rows(jax.lax.broadcasted_iota(
+                jnp.int32, (group, cols, 1), 1))[None]
+            seen = k_rel <= col
+            if window:
+                seen &= k_rel + window > col
+        else:
+            row = jax.lax.broadcasted_iota(jnp.int32, (1, tq, 1), 1)
+            seen = k_rel * group <= row
+            if window:
+                # ... and p > start + col - window
+                seen &= (k_rel + window) * group > row
         s = jnp.where(seen, s, NEG_INF)
 
         m_prev = m_scr[:, :, :1]                          # [kv_h, tq, 1]
@@ -317,7 +340,24 @@ def _paged_prefill_kernel(tile_ref, k_idx_ref, page_ref, start_ref, last_ref,
     # the step that holds the tile's last page
     @pl.when(ki + pages >= live)
     def _finalize():
-        o_ref[0] = (acc_scr[:] / l_scr[:, :, :1]).astype(o_ref.dtype)
+        out = acc_scr[:] / l_scr[:, :, :1]
+        if value_dim is not None:
+            # split in float32, whose sublane tile ``cols`` always fills
+            o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
+        else:
+            o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _merge_rows(x):
+    """[group, cols, d] -> [group * cols, d] inside the kernel.  Leading
+    rows merge into the sublane dim for nothing where ``cols`` is whole
+    sublane tiles of the dtype (8 rows of 32 bits: 16 of bfloat16); a
+    narrower dtype at fewer columns (a short verify) goes through
+    float32, whose tile ``cols`` always fills."""
+    g, cols, d = x.shape
+    if cols % (8 * 4 // x.dtype.itemsize):
+        return x.astype(jnp.float32).reshape(g * cols, d).astype(x.dtype)
+    return x.reshape(g * cols, d)
 
 
 def _tile_cols(l, kv_h, group):
@@ -385,7 +425,9 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
     HLO, so readers that find the decode and flash kernels by the
     model's ``attn`` scope never count this one.  ``v_pages=None,
     value_dim=n`` is the shared read of a latent pool (``k_pages`` the
-    one leaf [num_pages, page_size, d]; see ``paged_decode_attention``).
+    one leaf [num_pages, page_size, d]; see ``paged_decode_attention``),
+    whose kernel operand and result are head-major (module docstring):
+    the transposes here are layouts to the compiler, not copies.
     ``window`` > 0 is sliding-window attention: column j sees positions
     ``start + j - window + 1 ... start + j``, and a tile visits the
     pages from its first column's oldest visible position on, so what a
@@ -405,11 +447,21 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
                                         q.dtype.itemsize,
                                         k_pages.dtype.itemsize)
     tq, l_pad = cols * group, cols * tiles
-    # [b, l, h, d] -> [b, kv_h, l_pad * group, d]: head kv*group + g is
-    # kv head kv's g-th query head (the _repeat_kv grouping)
-    q_g = jnp.pad(q, ((0, 0), (0, l_pad - l), (0, 0), (0, 0))) \
-        .reshape(b, l_pad, kv_h, group, d).transpose(0, 2, 1, 3, 4) \
-        .reshape(b, kv_h, l_pad * group, d)
+    if value_dim is not None:
+        # HEAD-MAJOR [h, b, l_pad, d]: what a dot batched over heads (the
+        # absorption that makes q, the one that takes the output) writes
+        # and reads as it is -- a transpose the compiler turns into the
+        # producer's layout, where [b, 1, l_pad * h, d] cost a copy of
+        # the whole query in and of the whole output out a call
+        q_g = q.transpose(2, 0, 1, 3)
+        if l_pad != l:
+            q_g = jnp.pad(q_g, ((0, 0), (0, 0), (0, l_pad - l), (0, 0)))
+    else:
+        # [b, l, h, d] -> [b, kv_h, l_pad * group, d]: head kv*group + g
+        # is kv head kv's g-th query head (the _repeat_kv grouping)
+        q_g = jnp.pad(q, ((0, 0), (0, l_pad - l), (0, 0), (0, 0))) \
+            .reshape(b, l_pad, kv_h, group, d).transpose(0, 2, 1, 3, 4) \
+            .reshape(b, kv_h, l_pad * group, d)
     start = start.astype(jnp.int32)
     # a padding row (count == 0) sees position 0 alone: finite, unused
     last = jnp.where(count > 0, start + count.astype(jnp.int32) - 1, 0)
@@ -422,7 +474,7 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
 
     def tile_index(i, tile, k_idx, pages, st, ls):
         ri, ti = _row_and_tile(tile[i], tiles)
-        return (ri, 0, ti, 0)
+        return (0, ri, ti, 0) if value_dim is not None else (ri, 0, ti, 0)
 
     def page_slots(shape):
         """``block`` views of one pool operand, slot j at the id the
@@ -432,7 +484,14 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
                              j=j: (pages[j * cap + i],) + zeros)
                 for j in range(block)]
 
-    in_specs, operands = [pl.BlockSpec((1, kv_h, tq, d), tile_index)], [q_g]
+    def tile_spec(width):
+        """A q or output tile: [1, kv_h, tq, width] of the grouped form,
+        [group, cols, width] of a latent pool's head-major one."""
+        shape = (group, None, cols, width) if value_dim is not None \
+            else (1, kv_h, tq, width)
+        return pl.BlockSpec(shape, tile_index)
+
+    in_specs, operands = [tile_spec(d)], [q_g]
     if value_dim is not None:
         pools = [((1, page_size, d), k_pages)]
     else:
@@ -452,7 +511,7 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
         num_scalar_prefetch=5,
         grid=(n[0],),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kv_h, tq, d_v), tile_index),
+        out_specs=tile_spec(d_v),
         scratch_shapes=[
             pltpu.VMEM((kv_h, tq, 128), jnp.float32),
             pltpu.VMEM((kv_h, tq, 128), jnp.float32),
@@ -465,6 +524,8 @@ def paged_prefill(q, k_pages, v_pages, k_scale, v_scale, page_table, start,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(tile, k_idx, pages, start, last, *operands)
+    if value_dim is not None:
+        return out.transpose(1, 2, 0, 3)[:, :l]
     return out.reshape(b, kv_h, l_pad, group, d_v).transpose(0, 2, 1, 3, 4) \
         .reshape(b, l_pad, h, d_v)[:, :l]
 

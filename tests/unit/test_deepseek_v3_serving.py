@@ -18,6 +18,7 @@ import dataclasses
 import importlib.util
 import os
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -313,6 +314,135 @@ def test_the_absorbed_paged_form_is_the_per_head_dense_form(engine):
     got = sched.run()[req.rid]
     out = engine.generate(prompt[None], max_new_tokens=9, do_sample=False)
     assert list(np.asarray(out)[0, 29:]) == got
+
+
+def _one_block_over_a_padded_pool(dtype, monkeypatch, rows, chunk):
+    """A latent pool of 40 stored at 48 (lane tile 16), a ``[rows,
+    chunk]`` step over it -- decode where ``chunk`` is 1 -- and an
+    ``MLAttention`` with its parameters."""
+    monkeypatch.setattr(kvq, "LANES", 16)
+    cfg = deepseek_v3_tiny(dtype=dtype, param_dtype=dtype)
+    assert kvq.latent_stored_dim(cfg.latent_dim) == 48
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.standard_normal((rows, chunk, cfg.hidden_size)),
+                    dtype)
+    pool = kvq.latent_pool_layer(3 * rows, 8, cfg.latent_dim, dtype)
+    table = jnp.arange(3 * rows, dtype=jnp.int32).reshape(rows, 3)
+    lengths = jnp.asarray([5, 0, 9, 2][:rows], jnp.int32)
+    if chunk == 1:
+        step = kv_cache.decode_step(pool, table, lengths,
+                                    jnp.ones(rows, bool))
+    else:
+        step = kv_cache.prefill_step(
+            pool, table, lengths, jnp.arange(rows, dtype=jnp.int32),
+            jnp.asarray([chunk, chunk - 2, 1, 0][:rows], jnp.int32))
+    pos = kv_cache.positions(step, rows, chunk)
+    attn = deepseek_v3.MLAttention(cfg)
+    params = nn.meta.unbox(attn.init(jax.random.PRNGKey(2), x, pos))
+    return cfg, attn, params, x, pos, step
+
+
+@pytest.mark.parametrize("rows,chunk", [(4, 8), (3, 1)],
+                         ids=["chunk", "decode"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_absorbed_query_reaches_attend_at_the_stored_width(
+        monkeypatch, dtype, rows, chunk):
+    """ONE contraction makes a chunk's query as the pool is read:
+    ``attend`` is handed q at ``latent_stored_dim(rank + rope)`` — its
+    rope features ``rope(q_rope)`` value for value (a feature times 1.0
+    summed with zeros), its trailing features exact zeros — and the key
+    at the published width, which ``attend`` alone pads.  A dispatch of
+    fewer tokens than a head's query has features (3 against 24: a
+    decode step) hands it ``[q' | q_rope]`` at the published width."""
+    cfg, attn, params, x, pos, step = _one_block_over_a_padded_pool(
+        dtype, monkeypatch, rows, chunk)
+    h, rank, dn, dr = cfg.num_heads, cfg.kv_lora_rank, \
+        cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    width = 48 if rows * chunk >= dn + dr else 40
+    seen, roped = {}, []
+    real_attend = kv_cache.attend
+    real_rope = deepseek_v3.apply_rotary_emb_interleaved
+
+    def attend(q, k, v, positions, cache, **kw):
+        seen.update(q=q, k=k, **kw)
+        return real_attend(q, k, v, positions, cache, **kw)
+
+    def rope(x, positions, base):
+        roped.append(real_rope(x, positions, base=base))
+        return roped[-1]
+    monkeypatch.setattr(kv_cache, "attend", attend)
+    monkeypatch.setattr(deepseek_v3, "apply_rotary_emb_interleaved", rope)
+    out, new = attn.apply(params, x, pos, step)
+    assert out.shape == x.shape and set(new) == {"c_pages"}
+    q = seen["q"]
+    assert q.shape == (rows, chunk, h, width) and q.dtype == dtype
+    assert seen["k"].shape == (rows, chunk, 40) and seen["value_dim"] == rank
+    q = np.asarray(q, np.float32)
+    # rope() ran on the shared key, then on the heads' q_rope
+    q_rope = np.asarray(roped[-1], np.float32)
+    assert q_rope.shape == (rows, chunk, h, dr)
+    assert np.array_equal(q[..., rank:rank + dr], q_rope)
+    assert np.abs(q_rope).max() > 0.01 and not q[..., rank + dr:].any()
+    # and the leading features are W_UK,h q_nope,h
+    wq = np.asarray(params["params"]["wq"]["kernel"], np.float32)
+    w_uk = np.asarray(params["params"]["wkv_b"], np.float32)[..., :dn]
+    q_nope = (np.asarray(x, np.float32) @ wq).reshape(
+        rows, chunk, h, dn + dr)[..., :dn]
+    if dtype == jnp.bfloat16:       # the projection's own rounding
+        q_nope = np.asarray(jnp.asarray(q_nope, dtype), np.float32)
+    want = np.einsum("blhd,rhd->blhr", q_nope, w_uk)
+    tol = 1e-6 if dtype == jnp.float32 else 2.0 ** -7 * np.abs(want).max()
+    assert np.abs(q[..., :rank] - want).max() <= tol
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("kernel", ["auto", "force"])
+def test_no_pass_widens_the_absorbed_query_in_the_prefill_program(
+        monkeypatch, kernel):
+    """A count over the jaxpr of the tiny model's ``[4, 8]`` prefill
+    program (never a time): no ``pad`` and no ``concatenate`` yields a
+    ``[rows, chunk, heads, w]`` array at the cached vector's width (40)
+    or the pool's (48) — the absorption writes the query at the stored
+    width itself.  What IS concatenated a layer is its 24-wide input,
+    ``[q_nope | rope(q_rope)]``, and the chunk's 40-wide key a token,
+    which is what is padded."""
+    monkeypatch.setattr(kvq, "LANES", 16)
+    engine = build_engine(paged_kernel=kernel)
+    cfg = engine.module.cfg
+    pools = engine.init_paged_cache(12, 8)
+    assert pools["layers"][0]["c_pages"].shape[-1] == 48
+
+    def program(params, layers, ids):
+        step = kv_cache.prefill_step(
+            layers, jnp.arange(12, dtype=jnp.int32).reshape(4, 3),
+            jnp.asarray([5, 0, 9, 2], jnp.int32),
+            jnp.asarray([1, 0, 3, 2], jnp.int32),
+            jnp.asarray([8, 6, 1, 0], jnp.int32))
+        with engine._serving_scope():
+            logits, new = engine.module.apply({"params": params}, ids,
+                                              cache=step)
+        return logits, new.pools
+    jaxpr = jax.make_jaxpr(program)(engine.params, pools["layers"],
+                                    jnp.zeros((4, 8), jnp.int32))
+    made = {}
+    for eqn in _equations(jaxpr.jaxpr):
+        if eqn.primitive.name in ("pad", "concatenate"):
+            for var in eqn.outvars:
+                key = (eqn.primitive.name,) + tuple(var.aval.shape)
+                made[key] = made.get(key, 0) + 1
+    head = (4, 8, cfg.num_heads)
+    for width in (40, 48):
+        assert not [k for k in made if k[1:] == head + (width,)], made
+    assert made[("concatenate",) + head + (24,)] == cfg.num_layers
+    assert made[("concatenate", 4, 8, 40)] == cfg.num_layers
+    assert made[("pad", 4, 8, 48)] == cfg.num_layers
 
 
 # -------------------------------------- (d): the shares of the experts

@@ -273,14 +273,19 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 # kernel and the kernel's own body, no source locations -- at commit
 # 4c308f1, the last without a ``window`` argument, for the ahead-of-time
 # case of that name (tests/chip_bench/aot/): Mistral's grouped-query
-# decode and Kanana's latent prefill.  The Mosaic payload of the lowered
-# text carries file names and line numbers, so it is the jaxpr that is
-# held.
+# decode and MiMo's grouped prefill (a key wider than a value).  The
+# Mosaic payload of the lowered text carries file names and line
+# numbers, so it is the jaxpr that is held.  Kanana's latent prefill is
+# held as PR 63 left it (its q and output tiles head-major; 23dade11...
+# at 4c308f1): the grouped form beside it shows that only the latent
+# branch moved.
 BEFORE_WINDOW = {
     "paged_decode.mistral-longprompt":
         "3581d441378a68c7e6b664abb02c48818fcd69b820e3bd69e80c082440cf37d0",
+    "paged_prefill.mimo-longctx":
+        "f3b1ef59e799a9375656b53f66524a974a67bd83a314592232e8a4d6070f9e65",
     "paged_prefill.kanana2-longdoc":
-        "23dade114796a330e6551ab718e9ef02ec24fbaf4cba7e37512f188bc87d29f8",
+        "c6d9d0290df4e25f5aa1ccd3a953027e029f90f47f57779a1c6e541b08b7aa2d",
 }
 
 
@@ -302,15 +307,24 @@ def _case_jaxpr(case, **window):
             spec(c["slots"], 1, c["heads"], c["head_dim"]), pool, pool,
             spec(c["slots"], c["max_pages"], **ints),
             spec(c["slots"], **ints)))
+    rows = (spec(c["rows"], c["max_pages"], **ints), spec(c["rows"], **ints),
+            spec(c["rows"], **ints))
+    if "kv_heads" in c:         # K and V pages, a key wider than a value
+        return str(jax.make_jaxpr(
+            lambda q, k, v, table, start, count: paged_prefill(
+                q, k, v, None, None, table, start, count,
+                scale=c["scale_dim"] ** -0.5, interpret=False, **window))(
+            spec(c["rows"], c["chunk"], c["heads"], c["k_dim"]),
+            spec(c["pages"], c["page_size"], c["kv_heads"], c["k_dim"]),
+            spec(c["pages"], c["page_size"], c["kv_heads"], c["v_dim"]),
+            *rows))
     return str(jax.make_jaxpr(
         lambda q, pool, table, start, count: paged_prefill(
             q, pool, None, None, None, table, start, count,
             scale=c["scale_dim"] ** -0.5, interpret=False,
             value_dim=c["value_dim"], **window))(
         spec(c["rows"], c["chunk"], c["heads"], c["stored_dim"]),
-        spec(c["pages"], c["page_size"], c["stored_dim"]),
-        spec(c["rows"], c["max_pages"], **ints), spec(c["rows"], **ints),
-        spec(c["rows"], **ints)))
+        spec(c["pages"], c["page_size"], c["stored_dim"]), *rows))
 
 
 @pytest.mark.parametrize("case", sorted(BEFORE_WINDOW))
